@@ -1,0 +1,402 @@
+"""Compile-and-compare sweep over every device kernel the engine can
+reach, run INSIDE the process that owns the chip.
+
+Tier-1 runs on the CPU backend, where Pallas kernels are simulated and
+`ops/uidvec` picks its CPU lowerings — so "does this kernel compile on
+the accelerator, and does it still agree with its twin there" is a
+question only the chip process can answer. `run()` answers it per
+kernel, at the shape the kernel has in service, against its host or
+XLA twin on the same inputs. chip_smoke.py calls it through
+`POST /debug/kernelcheck`, a route only an alpha started with
+`--kernelcheck` has: the sweep blocks for minutes and takes gigabytes
+of device memory beside the resident tiles, so a serving alpha never
+offers it.
+
+Each check reports {"ok", "shape", "seconds", ...detail} or, when the
+compiler or the runtime refuses, {"ok": false, "error": <its message>}.
+Nothing here falls back: a kernel that does not compile is recorded
+as exactly that. `tiny` shrinks every shape and `interpret` runs the
+Pallas kernels in the simulator — both exist for the CPU tests, which
+call `run()` directly; the HTTP route passes neither.
+"""
+
+from __future__ import annotations
+
+import time
+
+import numpy as np
+
+# relative error bounds against the float64 host dot, as a fraction of
+# |q|*|c| (Cauchy-Schwarz bounds sum |q_i c_i|): float32 products with
+# float32 accumulation over d=128, and a single bf16 pass (2^-8 per
+# operand) for the approximate int8 tier
+_TOL_F32 = 1e-5
+_TOL_BF16 = 1e-2
+
+
+def _rng(seed: int = 0):
+    return np.random.default_rng(seed)
+
+
+def _pick_uid_tablet(db, pred: str | None):
+    if pred:
+        tab = db.tablets.get(pred)
+        if tab is None:
+            raise ValueError(f"no predicate {pred!r}")
+        return tab
+    best = None
+    for tab in db.tablets.values():
+        if tab.schema.value_type.name != "UID":
+            continue
+        n = tab.edge_count(False)
+        if best is None or n > best[0]:
+            best = (n, tab)
+    if best is None:
+        raise ValueError("store has no uid predicate")
+    return best[1]
+
+
+def _badj(db, pred):
+    from dgraph_tpu.engine.device_cache import device_bitadjacency
+    tab = _pick_uid_tablet(db, pred)
+    badj = device_bitadjacency(db, tab, db.coordinator.max_assigned(),
+                               transpose=True)
+    if badj is None:
+        raise RuntimeError(
+            f"{tab.pred!r} has no device bitadjacency (dirty tablet, "
+            f"> 32-bit uids or fewer than device_min_edges edges)")
+    return tab, badj
+
+
+def check_bucket_or_pallas(db, pred, interpret) -> dict:
+    """bucket_or_pallas on the loaded graph's own widest bucket, W=128
+    lanes, with more rows than one SMEM index table holds so the
+    chunked path runs; twin = the XLA row-gather fold."""
+    import jax.numpy as jnp
+
+    from dgraph_tpu.ops import pallas_kernels as pk
+    from dgraph_tpu.ops.bitgraph import _gather_or
+
+    tab, badj = _badj(db, pred)
+    b = max(badj.buckets, key=lambda b: b.in_nb.shape[0] * b.degree)
+    m, d = b.in_nb.shape
+    # four index tables' worth of rows bounds the grid (one step per
+    # (row, neighbor)) while still splitting across calls
+    rows = min(m, max(1, 4 * pk.SMEM_IDX_CAPACITY // d))
+    in_nb = b.in_nb[:rows]
+    w = 128
+    f = _rng(1).integers(0, 2**32, (badj.n_slots + 1, w),
+                         dtype=np.uint32)
+    f[-1] = 0  # the dummy slot is always empty
+    f = jnp.asarray(f)
+    got = np.asarray(pk.bucket_or_pallas(f, in_nb, interpret=interpret))
+    calls = -(-rows * d // pk.SMEM_IDX_CAPACITY)
+    want = np.asarray(_gather_or(f, in_nb, b.degree))
+    return {"ok": bool(np.array_equal(got, want)), "pred": tab.pred,
+            "shape": {"f": [badj.n_slots + 1, w],
+                      "in_nb": [rows, d], "bucket_rows": m},
+            "smem_calls": calls}
+
+
+def check_bitmap_and_pallas(tiny, interpret) -> dict:
+    import jax.numpy as jnp
+
+    from dgraph_tpu.ops.pallas_kernels import bitmap_and_pallas
+
+    bsz = 16 if tiny else 1024
+    rng = _rng(2)
+    a = rng.integers(0, 2**32, (bsz, 2048), dtype=np.uint32)
+    b = rng.integers(0, 2**32, (bsz, 2048), dtype=np.uint32)
+    got = np.asarray(bitmap_and_pallas(jnp.asarray(a), jnp.asarray(b),
+                                       interpret=interpret))
+    return {"ok": bool(np.array_equal(got, a & b)),
+            "shape": [bsz, 2048]}
+
+
+def _vec_inputs(tiny):
+    """SIFT1M's shape: 1M x 128 corpus (padded to the score tile), a
+    256-query batch."""
+    from dgraph_tpu.ops.pallas_kernels import SCORE_TILE_N
+    n = 4096 if tiny else 1_000_000
+    n_pad = -(-n // SCORE_TILE_N) * SCORE_TILE_N
+    rng = _rng(3)
+    corpus = np.zeros((n_pad, 128), np.float32)
+    corpus[:n] = rng.standard_normal((n, 128), dtype=np.float32)
+    queries = rng.standard_normal((16 if tiny else 256, 128),
+                                  dtype=np.float32)
+    return n, corpus, queries
+
+
+def _dot_error(got: np.ndarray, corpus, queries, cols) -> float:
+    """max |got - float64 dot| / (|q| |c|) over a column sample."""
+    c = corpus[cols].astype(np.float64)
+    q = queries.astype(np.float64)
+    want = q @ c.T
+    scale = np.outer(np.linalg.norm(q, axis=1), np.linalg.norm(c, axis=1))
+    return float(np.max(np.abs(got[:, cols] - want)
+                        / np.maximum(scale, 1e-30)))
+
+
+def check_score_dot_pallas(tiny, interpret) -> dict:
+    import jax.numpy as jnp
+
+    from dgraph_tpu.ops.pallas_kernels import score_dot_pallas
+
+    n, corpus, queries = _vec_inputs(tiny)
+    got = np.asarray(score_dot_pallas(jnp.asarray(corpus),
+                                      jnp.asarray(queries),
+                                      interpret=interpret))
+    cols = _rng(4).choice(n, min(n, 65536), replace=False)
+    err = _dot_error(got, corpus, queries, cols)
+    return {"ok": err <= _TOL_F32, "rel_err": err, "tol": _TOL_F32,
+            "shape": {"corpus": list(corpus.shape),
+                      "queries": list(queries.shape)}}
+
+
+def check_score_int8_pallas(tiny, interpret) -> dict:
+    import jax.numpy as jnp
+
+    from dgraph_tpu.ops.pallas_kernels import (
+        score_int8_pallas, score_int8_xla,
+    )
+
+    n, corpus, queries = _vec_inputs(tiny)
+    codes = np.clip(np.rint(corpus * 32), -127, 127).astype(np.int8)
+    dc, dq = jnp.asarray(codes), jnp.asarray(queries)
+    got = np.asarray(score_int8_pallas(dc, dq, interpret=interpret))
+    twin = np.asarray(score_int8_xla(dc, dq))
+    cols = _rng(5).choice(n, min(n, 65536), replace=False)
+    err = _dot_error(got, codes, queries, cols)
+    err_twin = _dot_error(twin, codes, queries, cols)
+    return {"ok": err <= _TOL_BF16 and err_twin <= _TOL_BF16,
+            "rel_err": err, "rel_err_xla_twin": err_twin,
+            "tol": _TOL_BF16,
+            "shape": {"codes": list(codes.shape),
+                      "queries": list(queries.shape)}}
+
+
+def check_knn_exact(tiny) -> dict:
+    """The exact similar_to tier must return the float64 host's top-k
+    SETS — the tier is documented exact, so nothing is loosened."""
+    from dgraph_tpu.ops import knn
+
+    n, corpus, queries = _vec_inputs(tiny)
+    corpus = corpus[:n]
+    k, n_host = 10, (8 if tiny else 64)
+    out = {}
+    ok = True
+    for metric in ("cosine", "euclidean"):
+        idx, _sc = knn.topk_device(corpus, queries, k, metric,
+                                   two_stage=False)
+        want, _ = knn.topk_host(corpus, queries[:n_host], k, metric)
+        same = [set(idx[i].tolist()) == set(want[i].tolist())
+                for i in range(n_host)]
+        out[metric] = {"queries_checked": n_host,
+                       "topk_sets_equal": int(sum(same))}
+        ok = ok and all(same)
+    return {"ok": ok, "k": k, "metrics": out,
+            "shape": {"corpus": [n, 128],
+                      "queries": list(queries.shape)}}
+
+
+def check_bfs_digest(tiny) -> dict:
+    """make_bfs_digest_batched (XLA gathers) at bench.py's default
+    shape; twin = the NumPy CSR BFS on the first 32 queries."""
+    import jax.numpy as jnp
+
+    from dgraph_tpu.bench.bfsgraph import (
+        csr_to_dict, make_graph, numpy_bfs,
+    )
+    from dgraph_tpu.ops.bitgraph import (
+        build_bitadjacency, build_core_adjacency,
+        make_bfs_digest_batched, make_frontier_counts_batched,
+        uid_lists_to_seed_slots,
+    )
+
+    nodes, edges, batch = (2000, 20_000, 64) if tiny \
+        else (2_000_000, 21_000_000, 24576)
+    seeds, depth = 8, 3
+    uniq_src, indptr, dst = make_graph(nodes, edges)
+    badj = build_bitadjacency(csr_to_dict(uniq_src, indptr, dst))
+    core = build_core_adjacency(badj)
+    seed_mat = np.sort(uniq_src[_rng(1).integers(
+        0, len(uniq_src), (batch, seeds))], axis=1)
+    slots = jnp.asarray(uid_lists_to_seed_slots(badj, list(seed_mat),
+                                                seeds))
+    digest = make_bfs_digest_batched(badj, core, depth, batch, seeds)
+    sums, col0 = digest(slots)
+    sums = np.asarray(sums)
+    got = np.asarray(make_frontier_counts_batched(32)(col0))
+    want = [numpy_bfs(uniq_src, indptr, dst, np.unique(seed_mat[i]),
+                      depth) for i in range(32)]
+    return {"ok": got.tolist() == want,
+            "level_popcounts": sums.tolist(),
+            "shape": {"nodes": nodes, "edges": int(len(dst)),
+                      "batch": batch, "words": (batch + 31) // 32,
+                      "depth": depth}}
+
+
+def check_sssp_dist(db, pred) -> dict:
+    """sssp_dist over the loaded graph's bitadjacency; twin = a NumPy
+    level-synchronous BFS over the tablet's flat edge list, walked
+    against the edge direction as the transposed bitadjacency is."""
+    from dgraph_tpu.ops.bitgraph import sssp_dist
+
+    tab, badj = _badj(db, pred)
+    src = np.repeat(np.fromiter(tab.edges.keys(), np.uint64,
+                                len(tab.edges)),
+                    [len(dl) for dl in tab.edges.values()])
+    dst = np.concatenate([np.asarray(dl, np.uint64)
+                          for dl in tab.edges.values()])
+    heads = np.unique(dst)
+    seeds = np.sort(heads[_rng(6).choice(
+        len(heads), min(len(heads), 64), replace=False)])
+    iters = 4
+    got = sssp_dist(badj, seeds.astype(np.uint32), max_iters=iters)
+    want = {int(s): 0 for s in seeds}
+    frontier = seeds
+    for hop in range(1, iters + 1):
+        nxt = np.unique(src[np.isin(dst, frontier)])
+        frontier = nxt[~np.isin(nxt, np.fromiter(want, np.uint64,
+                                                 len(want)))]
+        if not len(frontier):
+            break
+        for v in frontier.tolist():
+            want[int(v)] = hop
+    return {"ok": got == want, "pred": tab.pred, "seeds": len(seeds),
+            "reached": len(got),
+            "shape": {"slots": badj.n_slots, "max_iters": iters}}
+
+
+def check_range_select(db) -> dict:
+    """The inequality range kernel over the loaded graph's largest
+    numeric value view — the planner's cold priors keep ineq stages on
+    the host's cached key arrays until a predicate holds several
+    hundred thousand keys, so below that only this check reaches the
+    kernel; twin = a NumPy mask over the tablet's sort-key arrays."""
+    from dgraph_tpu.engine.device_cache import device_values
+    from dgraph_tpu.ops.graph import range_select
+    from dgraph_tpu.ops.uidvec import to_numpy
+
+    ts = db.coordinator.max_assigned()
+    numeric = [t for t in db.tablets.values()
+               if t.schema.value_type.name in ("INT", "FLOAT",
+                                               "DATETIME")]
+    if not numeric:
+        raise ValueError("store has no numeric predicate")
+    tab = max(numeric, key=lambda t: len(t.values))
+    dv = device_values(db, tab, ts)
+    if dv is None:
+        raise RuntimeError(f"{tab.pred!r} has no device value view")
+    uids, keys = tab.sort_key_arrays("")
+    lo, hi = (int(v) for v in np.quantile(keys, (0.25, 0.75)))
+    got = to_numpy(range_select(dv, lo, hi)).astype(np.uint64)
+    want = uids[(keys >= lo) & (keys <= hi)]
+    return {"ok": bool(np.array_equal(got, want)), "pred": tab.pred,
+            "selected": int(len(want)),
+            "shape": {"rows": int(len(uids))}}
+
+
+def check_setops_cosort(tiny) -> dict:
+    """The k-way union and intersection co-sorts (ops/uidvec through
+    ops/setops' device variants) — off the CPU these take the sort
+    lowering tier-1 never runs, and the executor's gate only sends
+    them multi-million-element operands; twin = the NumPy set algebra."""
+    from dgraph_tpu.ops import setops
+
+    n = 1 << (10 if tiny else 18)
+    rng = _rng(8)
+    parts = [np.unique(rng.integers(1, 4 * n, n, dtype=np.uint64))
+             for _ in range(4)]
+    union = setops.union_many_device(parts)
+    isect = setops.intersect_many_device(parts)
+    return {"ok": bool(
+                union is not None and isect is not None
+                and np.array_equal(union, setops.union_many(parts))
+                and np.array_equal(isect, setops.intersect_many(parts))),
+            "union": None if union is None else int(len(union)),
+            "intersection": None if isect is None else int(len(isect)),
+            "shape": {"parts": 4, "rows_each": n}}
+
+
+def check_fused_rank_page(tiny) -> dict:
+    """One whole-block fused executable (filter mask -> order -> page)
+    through query/fusion's own jit seam at the 500M store's group
+    width; twin = NumPy filter + lexsort + slice."""
+    import jax.numpy as jnp
+
+    from dgraph_tpu.ops.graph import FUSED_SEL_CAP
+    from dgraph_tpu.ops.uidvec import SENTINEL
+    from dgraph_tpu.query import fusion
+
+    n = 4096 if tiny else 262_144
+    rng = _rng(7)
+    cand = np.sort(rng.choice(np.arange(1, 8 * n, dtype=np.uint32), n,
+                              replace=False))
+    ranks = rng.permutation(n).astype(np.int32)   # injective keys
+    mask = rng.random(n) < 0.5
+    window, offset = 16, 5
+    shift = max(0, (n - 1).bit_length() - 12)
+    run = fusion.fused_executable(
+        None, None, "and", (), (False,), True, (False,), window,
+        shift, (), (False,))
+    out = np.asarray(run(
+        jnp.asarray(cand), (), (), (), (jnp.asarray(mask),),
+        ((jnp.asarray(cand), jnp.asarray(ranks)),),
+        jnp.int32(0), jnp.int32(offset)))
+    sel_count, n_kept = int(out[-2]), int(out[-1])
+    kept, kr = cand[mask], ranks[mask]
+    want = kept[np.lexsort((kept, kr))][offset:offset + window]
+    page = out[:window]
+    return {"ok": bool(n_kept == int(mask.sum())
+                       and sel_count <= FUSED_SEL_CAP
+                       and np.array_equal(page[page != SENTINEL], want)),
+            "sel_count": sel_count, "n_kept": n_kept,
+            "shape": {"rows": n, "window": window, "shift": shift}}
+
+
+def run(db, pred: str | None = None, checks: tuple = (),
+        tiny: bool = False, interpret: bool = False) -> dict:
+    """Run the named checks (all by default); never raises for a
+    kernel's own failure. `pred` names the uid predicate whose
+    bitadjacency sssp_dist and bucket_or_pallas use, range_select
+    picks the store's largest numeric predicate; the rest make their
+    own inputs and need no data. The
+    memory-hungriest check (the BFS digest at its benchmark batch)
+    goes first, before the others have touched the allocator."""
+    from dgraph_tpu.utils.backend import device_report
+
+    table = [
+        ("bfs_digest_xla", lambda: check_bfs_digest(tiny)),
+        ("sssp_dist", lambda: check_sssp_dist(db, pred)),
+        ("range_select", lambda: check_range_select(db)),
+        ("fused_rank_page", lambda: check_fused_rank_page(tiny)),
+        ("setops_cosort", lambda: check_setops_cosort(tiny)),
+        ("knn_exact", lambda: check_knn_exact(tiny)),
+        ("bucket_or_pallas",
+         lambda: check_bucket_or_pallas(db, pred, interpret)),
+        ("bitmap_and_pallas",
+         lambda: check_bitmap_and_pallas(tiny, interpret)),
+        ("score_dot_pallas",
+         lambda: check_score_dot_pallas(tiny, interpret)),
+        ("score_int8_pallas",
+         lambda: check_score_int8_pallas(tiny, interpret)),
+    ]
+    unknown = set(checks) - {name for name, _ in table}
+    if unknown:
+        raise ValueError(f"unknown kernel checks {sorted(unknown)}")
+    kernels = {}
+    for name, fn in table:
+        if checks and name not in checks:
+            continue
+        t0 = time.monotonic()
+        try:
+            res = fn()
+        except Exception as e:  # noqa: BLE001 — the compiler's or the
+            # runtime's refusal IS this check's result; the sweep goes on
+            res = {"ok": False,
+                   "error": f"{type(e).__name__}: {e}"[:2000]}
+        res["seconds"] = round(time.monotonic() - t0, 2)
+        kernels[name] = res
+    return {"device": device_report(), "interpret": bool(interpret),
+            "tiny": bool(tiny), "kernels": kernels}

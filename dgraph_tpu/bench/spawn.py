@@ -110,8 +110,13 @@ class ProcessCluster:
         self._node_args: dict[str, list[str]] = {}
         self._node_env: dict[str, dict] = {}
         self._logs: dict[str, object] = {}
-        self._env = dict(os.environ, JAX_PLATFORMS=os.environ.get(
-            "JAX_PLATFORMS", "cpu"), PYTHONPATH=_REPO)
+        # clustered alphas run prefer_device=False (cluster/service.py)
+        # and zeros never compute: every spawned node is host-only, so
+        # pin it to the CPU backend whatever the parent runs on — N
+        # children inheriting an accelerator platform would all reach
+        # for the one chip, and a chip belongs to one process
+        self._env = dict(os.environ, JAX_PLATFORMS="cpu",
+                         PYTHONPATH=_REPO)
         if env_extra:
             self._env.update(env_extra)
         self._tick = ["--tick-ms", str(tick_ms),

@@ -101,10 +101,35 @@ def _apply_config_layers(sub_choices: dict, argv: list) -> list:
     return argv
 
 
+def _runtime_report(prefer_device: bool) -> dict:
+    """What this alpha runs on: device platform/kind/count, whether
+    the native C++ runtime loaded, and the compile cache directory.
+    With the device tier on this INITIALIZES the backend (takes the
+    chip) and raises when jax came up CPU-only without
+    JAX_PLATFORMS=cpu — a served device tier on a host backend is a
+    different system, not a slower one. A --no-device alpha never
+    opens the device."""
+    import jax
+
+    from dgraph_tpu import native
+    from dgraph_tpu.utils import backend
+
+    return {
+        "device": backend.device_report() if prefer_device else None,
+        "native": native.available(),
+        "nativeUnavailableReason": native.unavailable_reason(),
+        "compileCache": jax.config.jax_compilation_cache_dir,
+    }
+
+
 def cmd_alpha(args) -> int:
     from dgraph_tpu.engine.db import GraphDB
     from dgraph_tpu.server.http import serve
 
+    # before the (long) snapshot load: no chip is a start-up error
+    runtime = _runtime_report(prefer_device=not args.no_device)
+    print("dgraph-tpu alpha runtime: " + json.dumps(runtime),
+          file=sys.stderr, flush=True)
     _load_custom_toks(args)
     enc_key = _enc_key(args)
     if args.snapshot:
@@ -126,7 +151,7 @@ def cmd_alpha(args) -> int:
         with open(args.acl_secret_file, "rb") as f:
             secret = f.read().strip()
     print(f"dgraph-tpu alpha listening on http://{args.host}:{args.port}"
-          + (" (ACL on)" if secret else ""), file=sys.stderr)
+          + (" (ACL on)" if secret else ""), file=sys.stderr, flush=True)
     tls_ctx = None
     if args.tls_dir:
         from dgraph_tpu.server.tls import server_context
@@ -139,6 +164,8 @@ def cmd_alpha(args) -> int:
                          batch_window_us=args.batch_window_us,
                          tenant_rate=args.tenant_rate,
                          tenant_burst=args.tenant_burst)
+    alpha.runtime = runtime
+    alpha.kernelcheck = args.kernelcheck
     _start_watchdog(alpha, "alpha", wal_path=args.wal or "")
     grpc_srv = None
     if args.grpc_port:
@@ -845,6 +872,13 @@ def main(argv=None) -> int:
     a.add_argument("--snapshot", default="")
     a.add_argument("--no-device", action="store_true",
                    default=False)
+    a.add_argument("--kernelcheck", action="store_true", default=False,
+                   help="route POST /debug/kernelcheck: compile every "
+                        "device kernel in this process and compare it "
+                        "with its twin (bench/kernelcheck.py). Blocks "
+                        "for minutes and takes gigabytes of device "
+                        "memory: for bring-up (chip_smoke.py), not "
+                        "for an alpha that serves")
     a.add_argument("--max-pending", type=int, default=0,
                    help="admission control: max concurrently admitted "
                         "requests; excess sheds with HTTP 429 "
@@ -932,7 +966,10 @@ def main(argv=None) -> int:
     rs.add_argument("--encryption_key_file", default="")
     rs.set_defaults(fn=cmd_restore)
 
-    v = sub.add_parser("version", help="print version info")
+    v = sub.add_parser("version",
+                       help="print version info and the jax devices "
+                            "(lists devices, so it TAKES the chip "
+                            "for as long as it runs)")
     v.set_defaults(fn=cmd_version)
 
     c = sub.add_parser("increment", help="txn canary: increment a counter")
@@ -1177,6 +1214,8 @@ def main(argv=None) -> int:
     argv = _apply_config_layers(sub.choices,
                                 argv if argv is not None else sys.argv[1:])
     args = p.parse_args(argv)
+    from dgraph_tpu.utils.backend import configure_compile_cache
+    configure_compile_cache()
     return args.fn(args)
 
 

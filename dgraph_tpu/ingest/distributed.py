@@ -407,8 +407,9 @@ class IngestDriver:
         A jax-warm or threaded driver exec-spawns workers too."""
         import subprocess
         addr = f"{self.addr[0]}:{self.addr[1]}"
-        env = dict(os.environ, JAX_PLATFORMS=os.environ.get(
-            "JAX_PLATFORMS", "cpu"))
+        # ingest is host-only work: children never get to take the
+        # chip from (or race each other for) the one device process
+        env = dict(os.environ, JAX_PLATFORMS="cpu")
         env.setdefault("PYTHONPATH", os.path.dirname(os.path.dirname(
             os.path.dirname(os.path.abspath(__file__)))))
         # DGRAPH_TPU_INGEST_DEBUG=1 lets child stderr through — the

@@ -5,10 +5,14 @@ engine (KV + WAL + snapshots, the Badger/raftwal role: posting/mvcc.go,
 raftwal/storage.go in the reference), the group-varint UID codec
 (codec/codec.go), and string-match kernels (worker/match.go) — is C++.
 
-The shared library is built on first import (g++ is part of the
-toolchain); if the build fails, `available()` is False and pure-Python
-fallbacks in the calling modules take over, so the framework degrades
-rather than breaks on odd toolchains.
+The shared library is not committed: it is built on first import by
+`make -C native` (g++ is part of the toolchain). If the build or the
+symbol bind fails, `available()` is False, `unavailable_reason()` says
+why, and pure-Python fallbacks in the calling modules take over — kept
+for odd toolchains, but never silent on the served path: `alpha`
+reports `available()` at start-up and in /health, and chip_smoke.py
+fails when it is False (the Python tokenizer, codec and KV store are
+a different system to measure).
 """
 
 from __future__ import annotations
@@ -25,15 +29,22 @@ _SO = os.path.join(_REPO, "native", "build", "libdgraph_native.so")
 _lib = None
 _lock = threading.Lock()
 _tried = False
+_why = ""  # why the library is unavailable ("" while it is, or untried)
 
 
 def _build() -> bool:
+    global _why
     try:
         r = subprocess.run(["make", "-C", os.path.join(_REPO, "native")],
                            capture_output=True, timeout=120)
-        return r.returncode == 0 and os.path.exists(_SO)
-    except Exception:
+    except (OSError, subprocess.SubprocessError) as e:
+        _why = f"make -C native: {e!r}"
         return False
+    if r.returncode != 0 or not os.path.exists(_SO):
+        _why = (f"make -C native exited {r.returncode}: "
+                + r.stderr.decode(errors="replace")[-400:])
+        return False
+    return True
 
 
 def _stale() -> bool:
@@ -47,7 +58,7 @@ def _stale() -> bool:
 
 
 def _load():
-    global _lib, _tried
+    global _lib, _tried, _why
     with _lock:
         if _lib is not None or _tried:
             return _lib
@@ -57,16 +68,18 @@ def _load():
                 return None
         try:
             lib = ctypes.CDLL(_SO)
-        except OSError:
+        except OSError as e:
+            _why = f"dlopen {_SO}: {e}"
             return None
         try:
             _bind(lib)
-        except AttributeError:
+        except AttributeError as e:
             # missing symbol despite the staleness check (e.g. a
             # hand-copied .so): degrade to the pure-Python fallbacks
             # instead of poisoning every import
+            _why = f"symbol bind: {e}"
             return None
-        _lib = lib
+        _lib, _why = lib, ""
         return _lib
 
 
@@ -166,6 +179,12 @@ def _bind(lib):
 
 def available() -> bool:
     return _load() is not None
+
+
+def unavailable_reason() -> str:
+    """Why available() is False ("" when the library loaded)."""
+    _load()
+    return _why
 
 
 # Build eagerly at import (cached after the first build) so the compile

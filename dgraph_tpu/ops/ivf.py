@@ -403,14 +403,13 @@ def _approx_scores_host(ivf: IVFIndex, lists: np.ndarray,
 
 def _approx_scores_pallas(ivf: IVFIndex, lists: np.ndarray,
                           cs: np.ndarray, q: np.ndarray,
-                          interpret: bool | None
+                          interpret: bool
                           ) -> tuple[list, list]:
     """The same per-query (slots, approx dots) through the MXU tile
     kernel (ops/pallas_kernels.score_int8_pallas): per query, gather
     the probed slices into one padded int8 block and run the
-    dequant-and-dot kernel. The TPU serving path; CPU CI exercises it
-    in interpret mode on small corpora (test parity vs the host
-    engine)."""
+    dequant-and-dot kernel. Tests pass interpret=True on small
+    corpora (parity vs the host engine)."""
     from dgraph_tpu.ops.pallas_kernels import (
         SCORE_TILE_N, score_int8_pallas,
     )
@@ -519,7 +518,7 @@ def search(ivf: IVFIndex, vecs: np.ndarray, queries: np.ndarray,
            keep: np.ndarray | None = None,
            nprobe: int | None = None, rerank: int | None = None,
            use_pallas: bool = False,
-           pallas_interpret: bool | None = None,
+           pallas_interpret: bool = False,
            count: bool = True) -> tuple[np.ndarray, np.ndarray]:
     """Quantized top-k: IVF probe -> int8 approximate scores ->
     exact float64 re-rank of the top `rerank` survivors. Returns

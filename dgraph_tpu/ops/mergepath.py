@@ -50,8 +50,6 @@ import jax.numpy as jnp
 
 from .uidvec import SENTINEL
 
-_NEG = jnp.int32(-1)
-
 
 def _partition(a: jax.Array, b: jax.Array, diag: jax.Array
                ) -> jax.Array:

@@ -12,9 +12,10 @@ single VPU OR into the output row accumulated across the degree axis
 (TPU grids execute sequentially, so revisiting the same output block
 accumulates).
 
-Interpret mode runs the same kernel on CPU for CI parity; real
-compilation happens on TPU. Callers must pad the word axis W to a
-multiple of 128 (lane width).
+Every kernel compiles through Mosaic or raises: `interpret=True`
+(the Pallas TPU simulator) is something only tests pass, explicitly —
+a backend's name never turns it on. Callers must pad the word axis W
+to a multiple of 128 (lane width).
 """
 
 from __future__ import annotations
@@ -24,10 +25,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax >= 0.6 wants an InterpretParams object to simulate TPU kernels;
-# jax <= 0.4 takes interpret=True directly
-_INTERPRET_ON = (pltpu.InterpretParams()
-                 if hasattr(pltpu, "InterpretParams") else True)
+_INTERPRET_ON = pltpu.InterpretParams()
 
 # Max int32 scalar-prefetch elements one kernel instance can hold in
 # SMEM (v5e: 2^17 passes, 2^18 fails the Mosaic compile). Buckets whose
@@ -36,13 +34,11 @@ SMEM_IDX_CAPACITY = 1 << 17
 
 
 def bucket_or_pallas(f: jax.Array, in_nb: jax.Array,
-                     interpret: bool | None = None) -> jax.Array:
+                     interpret: bool = False) -> jax.Array:
     """OR of gathered frontier rows: f uint32[N+1, W], in_nb
     int32[M, D] -> uint32[M, W] where out[m] = OR_d f[in_nb[m, d]].
     Rows that pad with the dummy slot index N contribute zeros exactly
     like the XLA path (f's last row is the always-empty dummy)."""
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
     m, d = in_nb.shape
     w = f.shape[1]
     if w % 128 != 0:
@@ -85,8 +81,6 @@ def bucket_or_pallas(f: jax.Array, in_nb: jax.Array,
         out = pl.pallas_call(
             kernel, grid_spec=grid_spec,
             out_shape=jax.ShapeDtypeStruct((cm, 1, w), jnp.uint32),
-            # CPU CI simulates the TPU kernel; on real TPU this
-            # compiles through Mosaic
             interpret=_INTERPRET_ON if interpret else False,
         )(flat_idx, f3)
         return out[:, 0, :]
@@ -121,7 +115,7 @@ SCORE_TILE_N = 512
 
 
 def score_dot_pallas(corpus: jax.Array, queries: jax.Array,
-                     interpret: bool | None = None) -> jax.Array:
+                     interpret: bool = False) -> jax.Array:
     """Tiled (b, d) x (d, n) -> (b, n) float32 dot scores on the MXU:
     grid over n-axis tiles, each step DMAs one (TILE, d) corpus block
     HBM->VMEM, the queries stay resident, one jnp.dot per tile. This is
@@ -129,8 +123,6 @@ def score_dot_pallas(corpus: jax.Array, queries: jax.Array,
     (pallas_guide: Grid and Block Specifications); the XLA path in
     ops/knn._score_device emits the same contraction — callers opt in
     via use_pallas (same convention as bucket_or_pallas)."""
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
     n, d = corpus.shape
     b = queries.shape[0]
     if n % SCORE_TILE_N != 0:
@@ -139,8 +131,13 @@ def score_dot_pallas(corpus: jax.Array, queries: jax.Array,
             "(ops/knn pads)")
 
     def kernel(c_ref, q_ref, out_ref):
+        # HIGHEST for the same reason as ops/knn._score_device: this
+        # kernel feeds the exact tier, and Mosaic's default float32
+        # dot is one bfloat16 pass (v5e: 1.2e-3 of |q||c| off the
+        # float64 dot)
         out_ref[...] = jnp.dot(q_ref[...], c_ref[...].T,
-                               preferred_element_type=jnp.float32)
+                               preferred_element_type=jnp.float32,
+                               precision=jax.lax.Precision.HIGHEST)
 
     return pl.pallas_call(
         kernel,
@@ -156,7 +153,7 @@ def score_dot_pallas(corpus: jax.Array, queries: jax.Array,
 
 
 def score_int8_pallas(codes: jax.Array, queries: jax.Array,
-                      interpret: bool | None = None) -> jax.Array:
+                      interpret: bool = False) -> jax.Array:
     """Dequant-and-dot tile kernel for the quantized ANN tier
     (ops/ivf.py): int8 residual codes (n, d) x float32 queries (b, d)
     -> (b, n) float32 approximate dots. Same pipeline shape as
@@ -167,8 +164,6 @@ def score_int8_pallas(codes: jax.Array, queries: jax.Array,
     of the HBM traffic). Per-row dequant scales and the centroid dot
     term are rank-1 postprocessing the caller applies. XLA parity
     fallback: score_int8_xla."""
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
     n, d = codes.shape
     b = queries.shape[0]
     if n % SCORE_TILE_N != 0:
@@ -215,7 +210,7 @@ BITMAP_TILE_B = 8
 
 
 def bitmap_and_pallas(a: jax.Array, b: jax.Array,
-                      interpret: bool | None = None) -> jax.Array:
+                      interpret: bool = False) -> jax.Array:
     """Elementwise AND of two stacked bitmap word matrices
     (uint32[B, W], W % 128 == 0): the compressed intersection's dense
     inner loop as an explicit VPU pipeline — each grid step DMAs one
@@ -224,8 +219,6 @@ def bitmap_and_pallas(a: jax.Array, b: jax.Array,
     Intersection of Sorted Integers", PAPERS.md).  Callers opt in via
     use_pallas (setops.bitmap_and_device), same convention as
     score_dot_pallas."""
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
     bsz, w = a.shape
     if w % 128 != 0:
         raise ValueError(f"W={w} must be a multiple of 128 lanes")

@@ -285,7 +285,8 @@ def _uids_of(key: int, lows: np.ndarray) -> np.ndarray:
 
 
 def intersect_packs(packs, scratch=None, device: bool = False,
-                    use_pallas: bool = False) -> np.ndarray:
+                    use_pallas: bool = False,
+                    pallas_interpret: bool = False) -> np.ndarray:
     """k-way intersection over compressed packs.  Per surviving key the
     SMALLEST block decodes once and the others answer membership in
     compressed form (bitmap bit test / run interval probe); all-bitmap
@@ -329,10 +330,11 @@ def intersect_packs(packs, scratch=None, device: bool = False,
             rows = np.stack([p.block_words(int(bis[i]))
                              for i in bm_idx])
             mats.append(rows)
-        anded = None
         if device and len(bm_idx) >= 8:
-            anded = bitmap_and_device(mats, use_pallas=use_pallas)
-        if anded is None:
+            anded = bitmap_and_device(
+                mats, use_pallas=use_pallas,
+                pallas_interpret=pallas_interpret)
+        else:
             anded = mats[0]
             for m in mats[1:]:
                 anded = anded & m
@@ -546,28 +548,26 @@ def _take(scratch, n, dtype=np.uint64):
     return scratch.take(n, dtype)
 
 
-def bitmap_and_device(mats, use_pallas: bool = False):
+def bitmap_and_device(mats, use_pallas: bool = False,
+                      pallas_interpret: bool = False):
     """k-way AND of stacked bitmap word matrices ([B, 1024] uint64) in
     ONE device dispatch: uint64 splits into two uint32 lanes (TPUs
     have no 64-bit integer ALU), the jitted fold ANDs all k mats, and
     `use_pallas` routes the pairwise word-AND through the Mosaic
-    kernel (ops/pallas_kernels.bitmap_and_pallas).  None -> caller
-    folds on host (no device / import failure)."""
-    try:
-        import jax
-        import jax.numpy as jnp
+    kernel (ops/pallas_kernels.bitmap_and_pallas)."""
+    import jax
+    import jax.numpy as jnp
 
-        from dgraph_tpu.query.plan import jit_stage
-    except Exception:  # pragma: no cover - jax always importable in CI
-        return None
+    from dgraph_tpu.query.plan import jit_stage
     k = len(mats)
     mats32 = [np.ascontiguousarray(m).view(np.uint32) for m in mats]
     if use_pallas:
         from dgraph_tpu.ops.pallas_kernels import bitmap_and_pallas
         acc = mats32[0]
         for m in mats32[1:]:
-            acc = np.asarray(bitmap_and_pallas(jnp.asarray(acc),
-                                               jnp.asarray(m)))
+            acc = np.asarray(bitmap_and_pallas(
+                jnp.asarray(acc), jnp.asarray(m),
+                interpret=pallas_interpret))
         return np.ascontiguousarray(acc).view(np.uint64)
 
     def _fold(stack):

@@ -38,7 +38,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from dgraph_tpu.parallel.compat import shard_map
+from jax import shard_map
 
 from dgraph_tpu.ops.uidvec import (
     SENTINEL, compact, member_mask, pad_to, to_numpy,

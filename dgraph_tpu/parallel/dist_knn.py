@@ -24,7 +24,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from dgraph_tpu.parallel.compat import shard_map
+from jax import shard_map
 from dgraph_tpu.ops import knn
 
 

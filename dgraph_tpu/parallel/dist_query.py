@@ -25,7 +25,7 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
-from dgraph_tpu.parallel.compat import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from dgraph_tpu.ops.uidvec import (

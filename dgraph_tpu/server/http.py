@@ -22,6 +22,10 @@ Endpoint map mirrors the reference (dgraph/cmd/alpha/run.go:415-436):
     GET  /admin/schema        current schema text
     POST /admin/schema        same as /alter with schema text
     GET  /debug/prometheus_metrics   metrics text format (x/metrics.go)
+    POST /debug/kernelcheck   compile + compare every device kernel in
+                              this process (bench/kernelcheck.py); the
+                              route exists only under `alpha
+                              --kernelcheck`
     GET  /debug/stats         the always-on statistics plane: full
                               per-predicate tablet statistics, the
                               observed-cost store, engine cache states
@@ -128,6 +132,15 @@ class AlphaServer:
         # monotonic: /health uptime is a DURATION — an NTP step must
         # not make it jump (same for the txn idle clocks below)
         self.started_at = time.monotonic()
+        # what the process runs on (device platform/kind/count, native
+        # runtime, compile cache): `alpha` fills it at start-up
+        # (cli._runtime_report) and /health carries it; empty for an
+        # embedded server, which never asked
+        self.runtime: dict = {}
+        # POST /debug/kernelcheck is routed only when `alpha
+        # --kernelcheck` asked for it: a bring-up tool, not a route a
+        # serving alpha offers
+        self.kernelcheck = False
         # server-side micro-batching (engine/batcher.py): concurrent
         # best-effort queries sharing a plan-cache key coalesce into
         # one dispatch under ONE read-lock hold. 0 = off.
@@ -627,6 +640,24 @@ class AlphaServer:
         from dgraph_tpu.utils import pprof, tracing
         return pprof.handle_params(params or {}, node=tracing.node())
 
+    def handle_kernelcheck(self, params: Optional[dict] = None,
+                           token: str = "") -> dict:
+        """POST /debug/kernelcheck[?checks=a,b&pred=P]: compile the
+        device kernels the engine can reach at their serving shapes,
+        in THIS process (the one that owns the chip), and compare each
+        with its host or XLA twin (bench/kernelcheck.py). Blocks for
+        minutes, allocates gigabytes of device memory and holds the
+        read lock (writers wait): routed only when the alpha was
+        started with `--kernelcheck`, and guardians only under ACL."""
+        self._require_guardian(token, "/debug/kernelcheck")
+        from dgraph_tpu.bench import kernelcheck
+        p = params or {}
+        with self.rw.read:
+            return kernelcheck.run(
+                self.db, pred=p.get("pred") or None,
+                checks=tuple(c for c in p.get("checks", "").split(",")
+                             if c))
+
     def handle_requests(self, token: str = "") -> dict:
         """/debug/requests: the bounded recent + slowest request log
         (trace_id, latency breakdown, shed/abort outcome). ACL-gated
@@ -747,7 +778,8 @@ class AlphaServer:
                 "uptime_s": round(time.monotonic() - self.started_at, 3),
                 "openTxns": len(self.txns),
                 "pendingQueries": self.pending(),
-                "maxPending": self.max_pending}
+                "maxPending": self.max_pending,
+                "runtime": self.runtime}
 
     def handle_draining(self, enable: bool, token: str = "") -> dict:
         """Toggle draining (guardians only under ACL) — ref
@@ -1091,6 +1123,9 @@ class _Handler(BaseHTTPRequestHandler):
             elif path == "/login":
                 self._send(200, self.alpha.handle_login(
                     json.loads(body.decode()) if body else {}))
+            elif path == "/debug/kernelcheck" and self.alpha.kernelcheck:
+                self._send(200, self.alpha.handle_kernelcheck(params,
+                                                              token))
             else:
                 self._error(f"no handler for POST {path}", 404)
         except TxnAborted as e:

@@ -51,6 +51,7 @@ REGISTERED = (
     "device_cache_bytes",
     "device_cache_evictions",
     "device_cache_tiles",
+    "device_dispatch_seconds",
     "dgraph_num_edges_total",
     "dgraph_num_mutations_total",
     "dgraph_num_queries_total",

@@ -170,7 +170,8 @@ def test_intersect_device_and_pallas_parity():
     np.testing.assert_array_equal(
         setops.intersect_packs(packs, device=True), want)
     np.testing.assert_array_equal(
-        setops.intersect_packs(packs, device=True, use_pallas=True),
+        setops.intersect_packs(packs, device=True, use_pallas=True,
+                               pallas_interpret=True),
         want)
 
 

@@ -22,7 +22,7 @@ class _StubDB:
     planner_explore = False
 
     def device_dispatch_seconds(self) -> float:
-        return 0.01  # 10 ms: a tunneled remote TPU
+        return 0.01  # 10 ms: a deliberately expensive dispatch
 
 
 def _plan(h: int = 0xABCD) -> Plan:
@@ -110,7 +110,7 @@ def test_single_observed_tier_needs_margin_to_lose(pl):
 
 def test_device_pays_dispatch_rtt(pl):
     """The measured dispatch RTT rides every device cost estimate: a
-    10ms tunnel keeps small stages off the device whatever the
+    10ms dispatch keeps small stages off the device whatever the
     priors say."""
     dec = pl.choose(_plan(0x3333), "ineq", "age", EST,
                     ("postings", "columnar", "device"))

@@ -17,6 +17,11 @@
 #                      (jax/numpy renames) surfaces here first, not as
 #                      a tier-1 collection error three releases later
 #
+# Every gate below runs on the CPU backend (JAX_PLATFORMS defaults to
+# cpu here): they check bytes, counts and host-side budgets. Whether
+# the program starts and answers correctly on a chip is chip_smoke.py's
+# question, asked on a machine that has one.
+#
 # Usage: tools/check.sh          (from the repo root)
 
 set -euo pipefail
@@ -28,7 +33,7 @@ python -m tools.dglint --changed-only --assert-empty-baseline \
 
 echo "== compileall =="
 python -m compileall -q dgraph_tpu tests tools bench.py bench_micro.py \
-    bench_queries.py bench_vectors.py
+    bench_queries.py bench_vectors.py chip_smoke.py __graft_entry__.py
 
 echo "== import-warnings sweep =="
 JAX_PLATFORMS="${JAX_PLATFORMS:-cpu}" \

@@ -46,8 +46,9 @@ def log(msg: str):
 def _sub(code: str, timeout_s: float = 900.0) -> dict:
     """Run `code` in a fresh interpreter; it must print ONE line
     starting with DGINGEST: followed by a JSON payload."""
-    env = dict(os.environ, JAX_PLATFORMS=os.environ.get(
-        "JAX_PLATFORMS", "cpu"), PYTHONPATH=_REPO)
+    # ingest + parity reads are host-only: never let a child reach
+    # for the chip
+    env = dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=_REPO)
     out = subprocess.run([sys.executable, "-c", code], env=env,
                          cwd=_REPO, capture_output=True, text=True,
                          timeout=timeout_s)
