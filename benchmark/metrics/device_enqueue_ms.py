@@ -1,0 +1,11 @@
+"""Executor: median `server_latency.device_enqueue_ns` over the good
+replies that made a device call: operand padding and upload and the
+jitted call returning its future, the host's part of a `device.call`
+span (dgraph_tpu/query/devicecall.py). None where the key is not
+served."""
+
+
+def read(ctx):
+    v = [r["server"]["device_enqueue_ns"] / 1e6 for r in ctx["replies"]
+         if r["good"] and r["server"].get("device_calls", 0) >= 1]
+    return ctx["stats"].percentile(v, 50.0) if v else None

@@ -130,6 +130,11 @@ def cmd_alpha(args) -> int:
     runtime = _runtime_report(prefer_device=not args.no_device)
     print("dgraph-tpu alpha runtime: " + json.dumps(runtime),
           file=sys.stderr, flush=True)
+    from dgraph_tpu.utils import metrics
+    metrics.watch_gc()
+    if not args.no_device:  # the report above took them
+        import jax
+        metrics.watch_devices(jax.local_devices())
     _load_custom_toks(args)
     enc_key = _enc_key(args)
     if args.snapshot:

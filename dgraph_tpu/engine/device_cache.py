@@ -15,6 +15,8 @@ same 32-bit-low-word assumption per block (codec/codec.go:43).
 
 from __future__ import annotations
 
+import contextlib
+import time
 from typing import Optional
 
 import numpy as np
@@ -26,9 +28,28 @@ from dgraph_tpu.ops.graph import (
 )
 from dgraph_tpu.engine.tile_cache import DeviceCacheLRU  # noqa: F401
 from dgraph_tpu.ops.uidvec import SENTINEL, pad_to, to_numpy
+from dgraph_tpu.utils.metrics import set_gauge
 from dgraph_tpu.utils.tracing import span as _span
 
 _MAX_U32 = 0xFFFFFFFE  # SENTINEL reserved
+
+# seconds this process has spent building and uploading tiles: they
+# are built on first use, so after a restart this is the part of
+# getting warm that storage/snapshot.py's phases do not see
+_TILE_SECONDS = 0.0
+
+
+@contextlib.contextmanager
+def _tile_load(**attrs):
+    global _TILE_SECONDS
+    t0 = time.perf_counter()
+    try:
+        with _span("device.tile_load", **attrs):
+            yield
+    finally:
+        _TILE_SECONDS += time.perf_counter() - t0
+        set_gauge("startup_phase_seconds", round(_TILE_SECONDS, 6),
+                  labels={"phase": "tile_upload"})
 
 
 def device_adjacency(db, tab, read_ts: int,
@@ -51,7 +72,7 @@ def device_adjacency(db, tab, read_ts: int,
     edges32 = _edges32(tab.edges)
     if edges32 is None:
         return None
-    with _span("device.tile_load", pred=tab.pred, kind="adj",
+    with _tile_load(pred=tab.pred, kind="adj",
                edges=n_edges):
         adj = build_adjacency(edges32)
     tab._device_adj = adj
@@ -134,7 +155,7 @@ def device_radjacency(db, tab, read_ts: int,
     edges32 = _edges32(tab.reverse)
     if edges32 is None:
         return None
-    with _span("device.tile_load", pred=tab.pred, kind="radj",
+    with _tile_load(pred=tab.pred, kind="radj",
                edges=n_edges):
         adj = build_adjacency(edges32)
     tab._device_radj = adj
@@ -162,7 +183,7 @@ def device_bitadjacency(db, tab, read_ts: int, transpose: bool = False):
     if edges32 is None:
         return None
     from dgraph_tpu.ops.bitgraph import build_bitadjacency
-    with _span("device.tile_load", pred=tab.pred, kind="bitadj",
+    with _tile_load(pred=tab.pred, kind="bitadj",
                edges=n_edges):
         badj = build_bitadjacency(edges32)
     setattr(tab, attr, badj)
@@ -208,7 +229,7 @@ def device_sharded_adjacency(db, tab, read_ts: int,
     if edges32 is None:
         return None
     from dgraph_tpu.parallel.dist_graph import build_sharded_adjacency
-    with _span("device.tile_load", pred=tab.pred, kind="sharded",
+    with _tile_load(pred=tab.pred, kind="sharded",
                edges=n_edges):
         sadj = build_sharded_adjacency(
             edges32, n_shards=mesh.shape["uid"]).put(mesh)
@@ -247,7 +268,7 @@ def device_values(db, tab, read_ts: int, lang: str = ""):
         return None
     if pairs and max(pairs) > _MAX_U32:
         return None
-    with _span("device.tile_load", pred=tab.pred, kind="values",
+    with _tile_load(pred=tab.pred, kind="values",
                rows=len(pairs)):
         dv = build_values(pairs)
     setattr(tab, attr, dv)
@@ -256,11 +277,15 @@ def device_values(db, tab, read_ts: int, lang: str = ""):
     return dv
 
 
-def expand_np(adj: DeviceAdjacency, src_u64: np.ndarray) -> np.ndarray:
+def expand_np(adj: DeviceAdjacency, src_u64: np.ndarray,
+              sync=None) -> np.ndarray:
     """Host frontier -> device expand -> host result.
 
     The jitted expander is cached per (frontier bucket size) on the
     adjacency object, so repeated traversal levels reuse compiled code.
+    The executor calls this inside a `device_call` block and hands its
+    `wait` as `sync`: applied to the dispatched result before it is
+    fetched, it is where the request's device time is taken.
     """
     # uids beyond uint32 cannot exist in a <=32-bit tablet: drop them
     # instead of letting astype(uint32) alias them onto real low uids.
@@ -274,9 +299,13 @@ def expand_np(adj: DeviceAdjacency, src_u64: np.ndarray) -> np.ndarray:
     fn = cache.get(f_pad)
     if fn is None:
         out_size = max_expansion(adj, f_pad)
-        fn = jax.jit(lambda fr: expand(adj, fr, out_size))
+
+        def expand_frontier(fr):
+            return expand(adj, fr, out_size)
+
+        fn = jax.jit(expand_frontier)
         cache[f_pad] = fn
     fr = np.full(f_pad, SENTINEL, np.uint32)
     fr[: len(src_u64)] = src_u64.astype(np.uint32)
     res = fn(jax.numpy.asarray(fr))
-    return to_numpy(res).astype(np.uint64)
+    return to_numpy(sync(res) if sync else res).astype(np.uint64)

@@ -159,7 +159,11 @@ class PrefetchPool:
             self._closed = True
             self._inflight.clear()
             set_gauge("prefetch_queue_depth", 0)
-        self._pool.shutdown(wait=False, cancel_futures=True)
+        # queued decodes are dropped; a RUNNING one is waited out (one
+        # KV read + decode per worker at most): the caller closes the
+        # native store next, and a worker inside `kv.get` on a freed
+        # handle is a segfault, not an exception
+        self._pool.shutdown(wait=True, cancel_futures=True)
 
     # ------------------------------------------------------------ worker
 

@@ -656,8 +656,10 @@ def make_sssp_bits(badj: BitAdjacency, max_iters: int,
 
 
 def sssp_dist(badj: BitAdjacency, seeds_np: np.ndarray, max_iters: int,
-              weighted: bool = False) -> dict[int, int]:
-    """Host wrapper: {uid -> hop/weighted distance} for reachable uids."""
+              weighted: bool = False, sync=None) -> dict[int, int]:
+    """Host wrapper: {uid -> hop/weighted distance} for reachable uids.
+    `sync` is applied to the dispatched result before it is fetched
+    (query/devicecall.py's `wait`)."""
     if badj.n_slots == 0:
         return {}
     cache = getattr(badj, "_sssp_cache", None)
@@ -667,6 +669,7 @@ def sssp_dist(badj: BitAdjacency, seeds_np: np.ndarray, max_iters: int,
     if fn is None:
         fn = cache[(max_iters, weighted)] = make_sssp_bits(
             badj, max_iters, weighted)
-    dist = np.asarray(fn(jnp.asarray(uids_to_bits(badj, seeds_np))))
+    out = fn(jnp.asarray(uids_to_bits(badj, seeds_np)))
+    dist = np.asarray(sync(out) if sync else out)
     ok = dist < INT32_INF
     return {int(u): int(d) for u, d in zip(badj.slot_uids[ok], dist[ok])}

@@ -202,11 +202,13 @@ def _device_matrix(parts: Sequence[np.ndarray]) -> Optional[np.ndarray]:
     return mat
 
 
-def union_many_device(parts: Sequence[np.ndarray]
+def union_many_device(parts: Sequence[np.ndarray], sync=None
                       ) -> Optional[np.ndarray]:
     """k-way union in ONE device dispatch (uidvec.merge_many: concat +
     single co-sort + adjacent-unique). None -> caller uses the host
-    fold (empty input, >32-bit uids)."""
+    fold (empty input, >32-bit uids). `sync` is applied to the
+    dispatched result before it is fetched (query/devicecall.py's
+    `wait`: where a request's device time is taken)."""
     live = [p for p in parts if len(p)]
     if len(live) < 2:
         return union_many(live)
@@ -224,13 +226,15 @@ def union_many_device(parts: Sequence[np.ndarray]
     # BOTH matrix dimensions to pow-2, so jax's shape-keyed trace
     # cache under this wrapper stays small (log k x log width shapes)
     fn = jit_stage("setops.union_many", lambda: jax.jit(merge_many))
-    return to_numpy(fn(jnp.asarray(mat))).astype(np.uint64)
+    out = fn(jnp.asarray(mat))
+    return to_numpy(sync(out) if sync else out).astype(np.uint64)
 
 
-def intersect_many_device(parts: Sequence[np.ndarray]
+def intersect_many_device(parts: Sequence[np.ndarray], sync=None
                           ) -> Optional[np.ndarray]:
     """k-way intersection in one dispatch (uidvec.intersect_many's
-    fused co-sort fold). None -> host fold."""
+    fused co-sort fold). None -> host fold. `sync` as in
+    union_many_device."""
     if not len(parts):
         return _EMPTY
     if any(not len(p) for p in parts):
@@ -251,7 +255,8 @@ def intersect_many_device(parts: Sequence[np.ndarray]
 
     fn = jit_stage("setops.intersect_many",
                    lambda: jax.jit(_dev_isect))
-    return to_numpy(fn(jnp.asarray(mat))).astype(np.uint64)
+    out = fn(jnp.asarray(mat))
+    return to_numpy(sync(out) if sync else out).astype(np.uint64)
 
 
 # ======================================================================

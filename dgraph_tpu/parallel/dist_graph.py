@@ -186,20 +186,21 @@ def make_sharded_expand(mesh: Mesh, sadj: ShardedAdjacency,
     smapped = shard_map(step, mesh=mesh, in_specs=tuple(in_specs),
                         out_specs=P(), check_vma=False)
 
-    def fn(frontier):
+    def sharded_expand(frontier):
         args = []
         for b in sadj.buckets:
             args.extend([b.src, b.neighbors])
         return smapped(frontier, *args)
 
-    return jax.jit(fn)
+    return jax.jit(sharded_expand)
 
 
 def expand_sharded_np(mesh: Mesh, sadj: ShardedAdjacency,
-                      src_u64: np.ndarray) -> np.ndarray:
+                      src_u64: np.ndarray, sync=None) -> np.ndarray:
     """Host frontier -> sharded device expand -> host result; jitted
     expanders cached per frontier bucket size on the adjacency (the
-    expand_np contract, device tier instead of single chip)."""
+    expand_np contract, `sync` included, device tier instead of single
+    chip)."""
     src_u64 = np.sort(src_u64[src_u64 <= MAX_U32])
     f_pad = pad_to(len(src_u64))
     out_size = pad_to(max(sadj.n_dst, 1))
@@ -212,7 +213,8 @@ def expand_sharded_np(mesh: Mesh, sadj: ShardedAdjacency,
         cache[f_pad] = fn
     fr = np.full(f_pad, SENTINEL, np.uint32)
     fr[: len(src_u64)] = src_u64.astype(np.uint32)
-    return to_numpy(fn(jnp.asarray(fr))).astype(np.uint64)
+    out = fn(jnp.asarray(fr))
+    return to_numpy(sync(out) if sync else out).astype(np.uint64)
 
 
 @dataclass
@@ -348,13 +350,13 @@ def make_ring_bfs(mesh: Mesh, radj: RingAdjacency, seed_size: int,
         out_specs=(tuple(P(uid_axis) for _ in range(depth)), P()),
         check_vma=False)
 
-    def fn(seeds):
+    def ring_bfs(seeds):
         args = []
         for b in radj.buckets:
             args.extend([b.src, b.neighbors])
         return smapped(seeds, *args)
 
-    return jax.jit(fn)
+    return jax.jit(ring_bfs)
 
 
 def make_sharded_bfs(mesh: Mesh, sadj: ShardedAdjacency, seed_size: int,
@@ -394,10 +396,10 @@ def make_sharded_bfs(mesh: Mesh, sadj: ShardedAdjacency, seed_size: int,
         out_specs=(tuple(P() for _ in range(depth)), P()),
         check_vma=False)
 
-    def fn(seeds):
+    def sharded_bfs(seeds):
         args = []
         for b in sadj.buckets:
             args.extend([b.src, b.neighbors])
         return smapped(seeds, *args)
 
-    return jax.jit(fn)
+    return jax.jit(sharded_bfs)

@@ -155,10 +155,10 @@ def make_dist_query_step(mesh: Mesh, stack: TabletStack, batch: int,
     smapped = shard_map(step, mesh=mesh, in_specs=tuple(in_specs),
                         out_specs=out_specs, check_vma=False)
 
-    def fn(seeds):
+    def dist_query_step(seeds):
         args = []
         for s, nb in zip(stack.srcs, stack.neighbors):
             args.extend([s, nb])
         return smapped(seeds, *args)
 
-    return jax.jit(fn)
+    return jax.jit(dist_query_step)

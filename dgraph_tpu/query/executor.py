@@ -39,6 +39,7 @@ from dgraph_tpu.models.types import (
 from dgraph_tpu.cluster.coordinator import StaleSnapshot
 from dgraph_tpu.ops import setops
 from dgraph_tpu.query.colvar import ColVar, make_colvar
+from dgraph_tpu.query.devicecall import device_call
 from dgraph_tpu.query.retrigram import compile_trigram_query
 from dgraph_tpu.storage.tablet import Tablet
 from dgraph_tpu.utils import failpoint
@@ -334,9 +335,13 @@ class Executor:
     # dglint: guarded-by=*:single-thread (one Executor per request,
     # confined to the thread running that query; cross-request state
     # lives in GraphDB / Plan / AdaptivePlanner, never here)
-    def __init__(self, db, read_ts: int, ctx=None, plan=None):
+    def __init__(self, db, read_ts: int, ctx=None, plan=None,
+                 lat=None):
         self.db = db
         self.read_ts = read_ts
+        # the request's Latency (engine/db.py): every device_call
+        # below adds its phases to it; None outside a served query
+        self.lat = lat
         # compiled plan (query/plan.py) for this request's skeleton,
         # or None on the interpreted path (plan cache disabled, upsert
         # queries). Carries parameter-memoized stage artifacts and the
@@ -1102,9 +1107,11 @@ class Executor:
             if total >= (1 << 17) and self._device_worth(
                     total * self._HOST_PER_SETOP_EL,
                     device_ratio=self._DEVICE_RATIO_SETOP):
-                got = setops.union_many_device(parts)
+                with device_call("query_device_setops_total",
+                                 sink=self.lat,
+                                 program="merge_many") as dc:
+                    got = setops.union_many_device(parts, sync=dc.wait)
                 if got is not None:
-                    inc_counter("query_device_setops_total")
                     return got
         return setops.union_many(parts)
 
@@ -1117,9 +1124,12 @@ class Executor:
             if total >= (1 << 17) and self._device_worth(
                     total * self._HOST_PER_SETOP_EL,
                     device_ratio=self._DEVICE_RATIO_SETOP):
-                got = setops.intersect_many_device(parts)
+                with device_call("query_device_setops_total",
+                                 sink=self.lat,
+                                 program="intersect_many") as dc:
+                    got = setops.intersect_many_device(parts,
+                                                       sync=dc.wait)
                 if got is not None:
-                    inc_counter("query_device_setops_total")
                     return got
         pl = getattr(self.db, "planner_impl", None)
         if pl is not None and len(parts) >= 2:
@@ -2043,9 +2053,10 @@ class Executor:
         dv = device_values(self.db, tab, self.read_ts)
         if dv is None:
             return None
-        inc_counter("query_device_range_total")
-        return to_numpy(range_select(dv, lo, hi, lo_open, hi_open)
-                        ).astype(np.uint64)
+        with device_call("query_device_range_total", sink=self.lat,
+                         program="range_select") as dc:
+            out = dc.wait(range_select(dv, lo, hi, lo_open, hi_open))
+            return to_numpy(out).astype(np.uint64)
 
     def _ineq_scan_strings(self, tab, fn, candidates) -> np.ndarray:
         want = str(fn.args[0].value)
@@ -3520,6 +3531,7 @@ class Executor:
 
         if len(src) == 0:
             return None
+        way = {"dir": "rev" if reverse else "fwd"}
         if self.db.mesh is not None:
             # uid-range-sharded tier first: a predicate too big for one
             # chip expands via shard_map over the mesh (SURVEY §5.7).
@@ -3531,9 +3543,11 @@ class Executor:
             if sadj is not None:
                 from dgraph_tpu.parallel.dist_graph import \
                     expand_sharded_np
-                inc_counter("query_sharded_expand_total",
-                            labels={"dir": "rev" if reverse else "fwd"})
-                return expand_sharded_np(self.db.mesh, sadj, src)
+                with device_call("query_sharded_expand_total", way,
+                                 sink=self.lat,
+                                 program="sharded_expand") as dc:
+                    return expand_sharded_np(self.db.mesh, sadj, src,
+                                             sync=dc.wait)
         store = tab.reverse if reverse else tab.edges
         deg = tab.edge_count(reverse) / max(1, len(store))
         if not self._device_worth(
@@ -3560,19 +3574,23 @@ class Executor:
                 clean, dirty = src[~mask], src[mask]
                 parts = []
                 if len(clean):
-                    parts.append(expand_np(adj, clean))
+                    with device_call(
+                            "query_device_overlay_expand_total", way,
+                            sink=self.lat,
+                            program="expand_frontier") as dc:
+                        parts.append(expand_np(adj, clean,
+                                               sync=dc.wait))
                 if len(dirty):
                     parts.append(tab.expand_frontier(
                         dirty, self.read_ts, reverse))
-                inc_counter("query_device_overlay_expand_total",
-                            labels={"dir": "rev" if reverse else "fwd"})
                 if not parts:
                     return _EMPTY.copy()
                 return np.unique(np.concatenate(parts)) \
                     if len(parts) > 1 else parts[0]
-        inc_counter("query_device_expand_total",
-                    labels={"dir": "rev" if reverse else "fwd"})
-        return expand_np(adj, src)
+        with device_call("query_device_expand_total", way,
+                         sink=self.lat,
+                         program="expand_frontier") as dc:
+            return expand_np(adj, src, sync=dc.wait)
 
     # ------------------------------------------------------------------
     # internal nodes: uid/count(uid)/val()/aggregations/math
@@ -3992,14 +4010,15 @@ class Executor:
         if dvs is None:
             return None
         import jax.numpy as jnp
-        cand = np.full(pad_to(len(uids)), SENTINEL, np.uint32)
-        cand[: len(uids)] = np.sort(uids).astype(np.uint32)
-        inc_counter("query_device_multisort_total")
-        out = multisort(jnp.asarray(cand),
-                        tuple(dv.uids for dv in dvs),
-                        tuple(dv.ranks for dv in dvs),
-                        tuple(bool(o.desc) for o in orders))
-        res = to_numpy(out)
+        with device_call("query_device_multisort_total", sink=self.lat,
+                         program="multisort") as dc:
+            cand = np.full(pad_to(len(uids)), SENTINEL, np.uint32)
+            cand[: len(uids)] = np.sort(uids).astype(np.uint32)
+            out = multisort(jnp.asarray(cand),
+                            tuple(dv.uids for dv in dvs),
+                            tuple(dv.ranks for dv in dvs),
+                            tuple(bool(o.desc) for o in orders))
+            res = to_numpy(dc.wait(out))
         return res[: len(uids)].astype(np.uint64)
 
     _PAGE_MAX_FIRST = 2048
@@ -4073,20 +4092,21 @@ class Executor:
         import jax.numpy as jnp
 
         cand = self._device_resident_root(gq, uids)
-        if cand is None:
-            buf = np.full(pad_to(len(uids)), SENTINEL, np.uint32)
-            buf[: len(uids)] = np.sort(uids).astype(np.uint32)
-            cand = jnp.asarray(buf)
-        inc_counter("query_device_sort_page_total")
-        out = multisort_page(
-            cand,
-            tuple(dv.uids for dv in dvs),
-            tuple(dv.ranks for dv in dvs),
-            tuple(bool(o.desc) for o in gq.order),
-            self._page_window(first),
-            jnp.uint32(gq.after or 0),
-            jnp.int32(gq.offset or 0))
-        res = to_numpy(out)
+        with device_call("query_device_sort_page_total", sink=self.lat,
+                         program="multisort_page") as dc:
+            if cand is None:
+                buf = np.full(pad_to(len(uids)), SENTINEL, np.uint32)
+                buf[: len(uids)] = np.sort(uids).astype(np.uint32)
+                cand = jnp.asarray(buf)
+            out = multisort_page(
+                cand,
+                tuple(dv.uids for dv in dvs),
+                tuple(dv.ranks for dv in dvs),
+                tuple(bool(o.desc) for o in gq.order),
+                self._page_window(first),
+                jnp.uint32(gq.after or 0),
+                jnp.int32(gq.offset or 0))
+            res = to_numpy(dc.wait(out))
         start = int(np.int32(res[-1]))
         valid = max(0, min(first, len(uids) - start))
         return res[:valid].astype(np.uint64)
@@ -4198,8 +4218,8 @@ class Executor:
                 view, is_lut = dv_view(dv)
                 rank_views.append(view)
                 rank_luts.append(is_lut)
-                rank_los.append(jnp.int32(bounds[0]))
-                rank_his.append(jnp.int32(bounds[1]))
+                rank_los.append(bounds[0])
+                rank_his.append(bounds[1])
                 rank_negs.append(bool(neg))
                 continue
             # set form — host root-context probe (pointwise-equal to
@@ -4261,12 +4281,18 @@ class Executor:
             tuple(rank_negs), tuple(set_negs), host_root is not None,
             tuple(bool(o.desc) for o in gq.order), window, shift,
             tuple(rank_luts), tuple(is_lut for _, is_lut in ord_pairs))
-        inc_counter("query_fused_dispatch_total")
-        out = run(cand, tuple(rank_views),
-                  tuple(rank_los), tuple(rank_his), tuple(fparts),
-                  tuple(view for view, _ in ord_pairs),
-                  jnp.int32(base0), jnp.int32(offset))
-        res = to_numpy(out)
+        # operands the plan memoized across requests (root, masks,
+        # parts) were uploaded by the request that first needed them;
+        # what this call uploads are the traced scalars
+        with device_call("query_fused_dispatch_total", sink=self.lat,
+                         program=run.__name__) as dc:
+            out = run(cand, tuple(rank_views),
+                      tuple(jnp.int32(b) for b in rank_los),
+                      tuple(jnp.int32(b) for b in rank_his),
+                      tuple(fparts),
+                      tuple(view for view, _ in ord_pairs),
+                      jnp.int32(base0), jnp.int32(offset))
+            res = to_numpy(dc.wait(out))
         sel_count = int(res[-2])
         n_kept = int(res[-1])
         if sel_count > FUSED_SEL_CAP:
@@ -4409,18 +4435,19 @@ class Executor:
         import jax.numpy as jnp
         from dgraph_tpu.ops.uidvec import to_numpy
 
-        inc_counter("query_device_count_page_total")
-        out = count_filter_sort_page(
-            adj.src_uids, adj.degrees,
-            jnp.int32(min(bounds[0], 2**31 - 1)),
-            jnp.int32(min(bounds[1], 2**31 - 1)),
-            tuple(dv.uids for dv in dvs),
-            tuple(dv.ranks for dv in dvs),
-            tuple(bool(o.desc) for o in gq.order),
-            self._page_window(first),
-            jnp.uint32(gq.after or 0),
-            jnp.int32(gq.offset or 0))
-        res = to_numpy(out)
+        with device_call("query_device_count_page_total", sink=self.lat,
+                         program="count_filter_sort_page") as dc:
+            out = count_filter_sort_page(
+                adj.src_uids, adj.degrees,
+                jnp.int32(min(bounds[0], 2**31 - 1)),
+                jnp.int32(min(bounds[1], 2**31 - 1)),
+                tuple(dv.uids for dv in dvs),
+                tuple(dv.ranks for dv in dvs),
+                tuple(bool(o.desc) for o in gq.order),
+                self._page_window(first),
+                jnp.uint32(gq.after or 0),
+                jnp.int32(gq.offset or 0))
+            res = to_numpy(dc.wait(out))
         start = int(np.int32(res[-2]))
         n_kept = int(res[-1])
         valid = max(0, min(first, n_kept - start))
@@ -4540,10 +4567,12 @@ class Executor:
         u32 = uids[uids <= 0xFFFFFFFE].astype(np.uint32)
         if not len(u32):
             return {}
-        inc_counter("query_device_orderkeys_total")
-        cand = np.full(pad_to(len(u32)), SENTINEL, np.uint32)
-        cand[: len(u32)] = np.sort(u32)
-        ranks = np.asarray(key_gather(dv, jnp.asarray(cand)))
+        with device_call("query_device_orderkeys_total", sink=self.lat,
+                         program="key_gather") as dc:
+            cand = np.full(pad_to(len(u32)), SENTINEL, np.uint32)
+            cand[: len(u32)] = np.sort(u32)
+            ranks = np.asarray(
+                dc.wait(key_gather(dv, jnp.asarray(cand))))
         out = {}
         for u, r in zip(cand[: len(u32)].tolist(),
                         ranks[: len(u32)].tolist()):
@@ -4874,11 +4903,12 @@ class Executor:
         if badj_t is None:
             return None
         from dgraph_tpu.ops.bitgraph import sssp_dist
-        inc_counter("query_device_sssp_total")
         if src == dst:
             return [src]
-        dist_to = sssp_dist(badj_t, np.asarray([dst], np.uint32),
-                            max_iters=maxdepth)
+        with device_call("query_device_sssp_total", sink=self.lat,
+                         program="sssp") as dc:
+            dist_to = sssp_dist(badj_t, np.asarray([dst], np.uint32),
+                                max_iters=maxdepth, sync=dc.wait)
         d0 = dist_to.get(src)
         if d0 is None or d0 > maxdepth:
             return []

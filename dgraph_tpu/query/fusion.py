@@ -295,6 +295,11 @@ def fused_executable(mesh, mesh_key, fop: str, rank_negs: tuple,
                 ord_views, ord_luts, descs, base0, shift, window,
                 offset)
 
+        # the program's name in a device profile and in `device.call`
+        # spans: what it is and its leaf counts, not `jit_run`
+        run.__name__ = run.__qualname__ = (
+            f"fused_page_{fop}_r{len(rank_negs)}s{len(set_negs)}"
+            f"o{len(descs)}")
         return jax.jit(run)
 
     return jit_stage("fusion.block_page", build,

@@ -141,6 +141,9 @@ class AlphaServer:
         # --kernelcheck` asked for it: a bring-up tool, not a route a
         # serving alpha offers
         self.kernelcheck = False
+        # POST /debug/device_profile: the profiler is process-wide,
+        # one trace at a time
+        self._device_profile_lock = threading.Lock()
         # server-side micro-batching (engine/batcher.py): concurrent
         # best-effort queries sharing a plan-cache key coalesce into
         # one dispatch under ONE read-lock hold. 0 = off.
@@ -361,19 +364,35 @@ class AlphaServer:
         raise ValueError(
             f"explain must be true/plan/analyze, got {raw!r}")
 
+    @staticmethod
+    def _engine(marks: Optional[list], fn, *args, **kw):
+        """The call into the engine, with the clock read on either side
+        of it appended to `marks` (the HTTP handler's phase split:
+        what lies before the first mark is `pre`, after the second
+        `post`)."""
+        if marks is None:
+            return fn(*args, **kw)
+        marks.append(time.perf_counter_ns())
+        try:
+            return fn(*args, **kw)
+        finally:
+            marks.append(time.perf_counter_ns())
+
     def handle_query(self, body: dict | str, params: dict,
-                     token: str = "", ctx=None) -> dict:
+                     token: str = "", ctx=None,
+                     marks: Optional[list] = None) -> dict:
         with self._logged("query", ctx), self._admit(ctx):
             q, variables, ro_txn, be, pin_ts = self._query_prologue(
                 body, params, token)
             with self.rw.read:
-                return self.db.query(q, variables, txn=ro_txn,
-                                     best_effort=be, read_ts=pin_ts,
-                                     ctx=ctx,
-                                     explain=self._explain_param(params))
+                return self._engine(
+                    marks, self.db.query, q, variables, txn=ro_txn,
+                    best_effort=be, read_ts=pin_ts, ctx=ctx,
+                    explain=self._explain_param(params))
 
     def handle_query_json(self, body: dict | str, params: dict,
-                          token: str = "", ctx=None) -> str:
+                          token: str = "", ctx=None,
+                          marks: Optional[list] = None) -> str:
         """handle_query returning the serialized response body — flat
         blocks take the native columnar emitter (db.query_json), so
         the HTTP layer never re-serializes what the engine already
@@ -394,13 +413,14 @@ class AlphaServer:
                 # watermark) — dispatch follows arrival, so each
                 # member still observes every commit that completed
                 # before it arrived
-                return self.batcher.query_json(q, variables, ctx=ctx,
-                                               best_effort=be)
+                return self._engine(
+                    marks, self.batcher.query_json, q, variables,
+                    ctx=ctx, best_effort=be)
             with self.rw.read:
-                return self.db.query_json(q, variables, txn=ro_txn,
-                                          best_effort=be,
-                                          read_ts=pin_ts, ctx=ctx,
-                                          explain=explain)
+                return self._engine(
+                    marks, self.db.query_json, q, variables,
+                    txn=ro_txn, best_effort=be, read_ts=pin_ts,
+                    ctx=ctx, explain=explain)
 
     def handle_mutate(self, body: bytes, content_type: str,
                       params: dict, token: str = "", ctx=None) -> dict:
@@ -639,6 +659,33 @@ class AlphaServer:
                 self.acl.authorize(token)
         from dgraph_tpu.utils import pprof, tracing
         return pprof.handle_params(params or {}, node=tracing.node())
+
+    def handle_device_profile(self, params: Optional[dict] = None,
+                              token: str = "") -> dict:
+        """POST /debug/device_profile?seconds=N: a jax.profiler device
+        trace of this process (the one that holds the chip) for N
+        seconds (clamped like /debug/pprof) while it goes on serving,
+        written under a fresh directory whose path is the answer: load
+        it in TensorBoard's profile plugin or reduce its .xplane.pb.
+        Every span of utils/tracing is an event on its host plane. One
+        at a time: the profiler is process-wide, so a second call
+        while one runs is refused (429, retryable). Guardians only
+        under ACL: the trace names predicates and code paths."""
+        self._require_guardian(token, "/debug/device_profile")
+        import tempfile
+
+        from dgraph_tpu.utils import pprof
+        seconds = max(0.1, min(float((params or {}).get("seconds", 1.0)),
+                               pprof.MAX_SECONDS))
+        if not self._device_profile_lock.acquire(blocking=False):
+            raise Overloaded("a device profile is already being taken")
+        try:
+            out = tempfile.mkdtemp(prefix="dgraph_device_profile_")
+            with tracing.profile_device(out):
+                time.sleep(seconds)
+        finally:
+            self._device_profile_lock.release()
+        return {"dir": out, "seconds": seconds}
 
     def handle_kernelcheck(self, params: Optional[dict] = None,
                            token: str = "") -> dict:
@@ -1061,6 +1108,46 @@ class _Handler(BaseHTTPRequestHandler):
                       trace=traceback.format_exc()[-800:])
             self._error(str(e), 500)
 
+    def _post_query(self, ctx, params: dict, ctype: str, token: str):
+        """POST /query under one `http.request` span, split on the
+        handler's own clock: `pre` is entry to the call into the
+        engine (body, admission, the read lock), `engine` that call,
+        `post` its return to the last byte written. A request that
+        raises counts nothing here (its error reply is do_POST's)."""
+        marks = [time.perf_counter_ns()]
+        with tracing.bind_request(ctx), tracing.span("http.request"):
+            body = self._body()
+            if "json" in ctype:
+                payload: Any = json.loads(body.decode())
+            else:
+                payload = body.decode()
+            debug = params.get("debug", "false") == "true" \
+                or self.headers.get("X-Dgraph-Debug", ""
+                                    ).lower() not in ("", "false", "0")
+            if debug:
+                # per-request tier-routing profile: a metrics
+                # counter diff around the (dict-path) query shows
+                # where it routed — columnar hits, device ops,
+                # postings fallbacks, cache evictions. Counters
+                # are process-global, so concurrent traffic
+                # bleeds in; use on a quiet node or repeat.
+                before = metrics.counters_snapshot()
+                out = self.alpha.handle_query(payload, params, token,
+                                              ctx=ctx, marks=marks)
+                out.setdefault("extensions", {})["profile"] = {
+                    "counters": metrics.counters_delta(before)}
+                self._send(200, out)
+            else:
+                self._send_raw(200, self.alpha.handle_query_json(
+                    payload, params, token, ctx=ctx,
+                    marks=marks).encode())
+            marks.append(time.perf_counter_ns())
+        for phase, ns in zip(("pre", "engine", "post"),
+                             (b - a for a, b in zip(marks, marks[1:]))):
+            metrics.inc_counter("http_request_ns_total", ns,
+                                labels={"phase": phase})
+        metrics.inc_counter("http_requests_total")
+
     def do_POST(self):
         u = urlparse(self.path)
         path = u.path
@@ -1074,33 +1161,11 @@ class _Handler(BaseHTTPRequestHandler):
         try:
             ctx = self._ctx()
             self._trace_ctx = ctx
-            body = self._body()
             if path == "/query":
-                if "json" in ctype:
-                    payload: Any = json.loads(body.decode())
-                else:
-                    payload = body.decode()
-                debug = params.get("debug", "false") == "true" \
-                    or self.headers.get("X-Dgraph-Debug", ""
-                                        ).lower() not in ("", "false",
-                                                          "0")
-                if debug:
-                    # per-request tier-routing profile: a metrics
-                    # counter diff around the (dict-path) query shows
-                    # where it routed — columnar hits, device ops,
-                    # postings fallbacks, cache evictions. Counters
-                    # are process-global, so concurrent traffic
-                    # bleeds in; use on a quiet node or repeat.
-                    before = metrics.counters_snapshot()
-                    out = self.alpha.handle_query(payload, params,
-                                                  token, ctx=ctx)
-                    out.setdefault("extensions", {})["profile"] = {
-                        "counters": metrics.counters_delta(before)}
-                    self._send(200, out)
-                else:
-                    self._send_raw(200, self.alpha.handle_query_json(
-                        payload, params, token, ctx=ctx).encode())
-            elif path == "/mutate":
+                self._post_query(ctx, params, ctype, token)
+                return
+            body = self._body()
+            if path == "/mutate":
                 self._send(200, self.alpha.handle_mutate(
                     body, ctype, params, token, ctx=ctx))
             elif path == "/commit":
@@ -1126,6 +1191,9 @@ class _Handler(BaseHTTPRequestHandler):
             elif path == "/debug/kernelcheck" and self.alpha.kernelcheck:
                 self._send(200, self.alpha.handle_kernelcheck(params,
                                                               token))
+            elif path == "/debug/device_profile":
+                self._send(200, self.alpha.handle_device_profile(
+                    params, token))
             else:
                 self._error(f"no handler for POST {path}", 404)
         except TxnAborted as e:

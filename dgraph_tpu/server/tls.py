@@ -20,6 +20,10 @@ from cryptography.hazmat.primitives import hashes, serialization
 from cryptography.hazmat.primitives.asymmetric import ec
 from cryptography.x509.oid import NameOID
 
+# certificates are valid from a little before they are written: the
+# clock of the node that checks one may run behind the issuer's (or step
+# back), and "certificate is not yet valid" then refuses a fresh pair
+_SKEW = datetime.timedelta(minutes=5)
 _CA_CRT = "ca.crt"
 _CA_KEY = "ca.key"
 
@@ -54,7 +58,7 @@ def create_ca(tls_dir: str, days: int = 365 * 5) -> None:
             .issuer_name(_name("dgraph-tpu Root CA"))
             .public_key(key.public_key())
             .serial_number(x509.random_serial_number())
-            .not_valid_before(now)
+            .not_valid_before(now - _SKEW)
             .not_valid_after(now + datetime.timedelta(days=days))
             .add_extension(x509.BasicConstraints(ca=True, path_length=0),
                            critical=True)
@@ -86,7 +90,7 @@ def create_pair(tls_dir: str, kind: str, name: str = "",
                .issuer_name(ca_cert.subject)
                .public_key(key.public_key())
                .serial_number(x509.random_serial_number())
-               .not_valid_before(now)
+               .not_valid_before(now - _SKEW)
                .not_valid_after(now + datetime.timedelta(days=days))
                .add_extension(
                    x509.BasicConstraints(ca=False, path_length=None),

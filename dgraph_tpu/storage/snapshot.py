@@ -13,6 +13,7 @@ from __future__ import annotations
 
 import gzip
 import os
+import time
 
 
 def _load_payload(blob: bytes):
@@ -122,10 +123,13 @@ def dump_tablet(tab) -> dict:
     return out
 
 
-def restore_tablet(pred: str, schema, st: dict):
+def restore_tablet(pred: str, schema, st: dict,
+                   clock: dict | None = None):
     """Inverse of dump_tablet -> a fresh Tablet. Pre-compression
     payloads (dense "edges"/"reverse"/"index" keys) still restore —
-    the one migration seam, same policy as loads_compat."""
+    the one migration seam, same policy as loads_compat. `clock`
+    (load_snapshot's) gathers the seconds spent on the token-index
+    plane under "index_build"."""
     from dgraph_tpu.storage.tablet import Tablet
     tab = Tablet(pred, schema)
     tab.edges = _ungv_dict(st["edges_gv"]) if "edges_gv" in st \
@@ -134,8 +138,12 @@ def restore_tablet(pred: str, schema, st: dict):
         else st["reverse"]
     tab.values = _unpack_values(st["values_pk"]) \
         if "values_pk" in st else st["values"]
+    t0 = time.perf_counter()
     tab.index = _ungv_dict(st["index_gv"]) if "index_gv" in st \
         else st["index"]
+    if clock is not None:
+        clock["index_build"] = clock.get("index_build", 0.0) \
+            + time.perf_counter() - t0
     tab.edge_facets = st["edge_facets"]
     tab.base_ts = st["base_ts"]
     tab.deltas = list(st.get("deltas", ()))  # absent in old payloads
@@ -181,11 +189,11 @@ def dump_state(db) -> dict:
     }
 
 
-def restore_state(payload: dict, db=None):
+def restore_state(payload: dict, db=None, clock: dict | None = None):
     """State payload -> GraphDB (fresh one by default). Refuses
     payloads stamped NEWER than this build understands (typed
     UnsupportedFormat); unstamped legacy payloads are version 0 and
-    restore identically."""
+    restore identically. `clock` as in restore_tablet."""
     from dgraph_tpu.engine.db import GraphDB
     from dgraph_tpu.storage.versions import check_format
 
@@ -194,7 +202,7 @@ def restore_state(payload: dict, db=None):
     db.alter(payload["schema"])
     for pred, st in payload["tablets"].items():
         ps = db.schema.get_or_default(pred)
-        tab = restore_tablet(pred, ps, st)
+        tab = restore_tablet(pred, ps, st, clock)
         db.tablets[pred] = tab
         db.coordinator.should_serve(pred)
         # CDC floor: history at or below the restored base lives in
@@ -234,10 +242,33 @@ def save_snapshot(db, path: str):
 
 
 def load_snapshot(path: str, db=None):
-    """Restore a GraphDB from a snapshot file (fresh one by default)."""
-    with gzip.open(path, "rb") as f:
-        magic = f.read(len(SNAPSHOT_MAGIC))
-        if magic != SNAPSHOT_MAGIC:
-            raise ValueError(f"{path!r} is not a dgraph-tpu snapshot")
-        payload = _load_payload(f.read())
-    return restore_state(payload, db)
+    """Restore a GraphDB from a snapshot file (fresh one by default),
+    under one `snapshot.load` span, and say where the time went:
+    gauges `startup_phase_seconds{phase=...}`, set once per load.
+    `snapshot_read` is the file read and gunzip, `index_build` the
+    token-index plane's restore, `snapshot_decode` the rest (wire
+    decode, posting and value planes); `tile_upload` is added by
+    engine/device_cache.py as tiles are built on first use."""
+    from dgraph_tpu.utils.metrics import set_gauge
+    from dgraph_tpu.utils.tracing import span
+
+    clock: dict[str, float] = {}
+    with span("snapshot.load", path=path):
+        t0 = time.perf_counter()
+        with gzip.open(path, "rb") as f:
+            magic = f.read(len(SNAPSHOT_MAGIC))
+            if magic != SNAPSHOT_MAGIC:
+                raise ValueError(
+                    f"{path!r} is not a dgraph-tpu snapshot")
+            blob = f.read()
+        t1 = time.perf_counter()
+        payload = _load_payload(blob)
+        del blob  # the gunzipped file: not held through the restore
+        db = restore_state(payload, db, clock)
+        clock["snapshot_read"] = t1 - t0
+        clock["snapshot_decode"] = time.perf_counter() - t1 \
+            - clock.get("index_build", 0.0)
+    for phase, seconds in clock.items():
+        set_gauge("startup_phase_seconds", round(seconds, 6),
+                  labels={"phase": phase})
+    return db

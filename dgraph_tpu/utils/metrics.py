@@ -51,6 +51,9 @@ REGISTERED = (
     "device_cache_bytes",
     "device_cache_evictions",
     "device_cache_tiles",
+    # query/devicecall.py; NOT `query_device_*`: readers sum that
+    # prefix as a count of dispatches
+    "device_call_ns_total",
     "device_dispatch_seconds",
     "dgraph_num_edges_total",
     "dgraph_num_mutations_total",
@@ -63,6 +66,8 @@ REGISTERED = (
     # serving edge (server/http.py)
     "dgraph_pending_queries",
     "dgraph_queries_shed_total",
+    "http_request_ns_total",
+    "http_requests_total",
     # compiled plan cache + micro-batcher (query/plan.py,
     # engine/batcher.py)
     "batch_dispatches",
@@ -127,6 +132,9 @@ REGISTERED = (
     "raft_send_drops",
     # WAL durability (storage/wal.py fsync sites)
     "dgraph_wal_fsync_seconds",
+    # start-up phases (storage/snapshot.py load_snapshot,
+    # engine/device_cache.py tile builds)
+    "startup_phase_seconds",
     # alerting / incident flight recorder (utils/watchdog.py,
     # utils/alerts.py)
     "dgraph_alerts_firing",
@@ -156,11 +164,14 @@ REGISTERED = (
     "dgraph_net_fault_dups_total",
     "dgraph_net_fault_rules",
     # process gauges (utils/metrics.py collect_memory_gauges /
-    # collect_runtime_gauges)
+    # collect_runtime_gauges; the gc pauses and the device peak only
+    # in a process that called watch_gc / watch_devices)
+    "device_memory_peak_bytes",
     "memory_inuse_bytes",
     "memory_proc_bytes",
     "process_gc_collections",
     "process_gc_objects",
+    "process_gc_pause_seconds_total",
     "process_open_fds",
     "process_threads",
     "process_uptime_seconds",
@@ -310,6 +321,45 @@ import time as _time_mod  # noqa: E402
 
 _STARTED_AT_MONO = _time_mod.monotonic()
 
+# the collector's pauses by generation, kept by _on_gc. The callback
+# runs on whichever thread tripped the collector, possibly inside a
+# region that holds _LOCK (an allocation there can trip it), so it
+# takes no lock and touches nothing but these two lists; collections
+# never nest, and collect_runtime_gauges publishes the sums.
+_GC_PAUSE_S = [0.0, 0.0, 0.0]
+_GC_PUBLISHED = [0.0, 0.0, 0.0]
+_GC_STARTED = [0.0]
+# devices whose memory_stats() the runtime gauges read (watch_devices)
+_DEVICES: list = []
+
+
+def _on_gc(phase: str, info: dict) -> None:
+    if phase == "start":
+        _GC_STARTED[0] = _time_mod.perf_counter()
+    else:
+        _GC_PAUSE_S[info["generation"]] += \
+            _time_mod.perf_counter() - _GC_STARTED[0]
+
+
+def watch_gc() -> None:
+    """Count the seconds the interpreter's collector stops the process
+    for, per generation, as `process_gc_pause_seconds_total{gen}`: a
+    full collection (gen 2) of a server holding millions of tracked
+    objects stops every request thread at once. A server's entry
+    point calls this once; importing the module watches nothing."""
+    import gc
+
+    if _on_gc not in gc.callbacks:
+        gc.callbacks.append(_on_gc)
+
+
+def watch_devices(devices) -> None:
+    """Publish `device_memory_peak_bytes{device}` for these jax
+    devices on every scrape. Called by the process that holds them,
+    after it took them: asking jax for its devices here would be what
+    takes the chip."""
+    _DEVICES[:] = list(devices)
+
 
 def collect_runtime_gauges():
     """Process runtime gauges next to the memory ones (ref
@@ -328,6 +378,17 @@ def collect_runtime_gauges():
     for gen, st in enumerate(gc.get_stats()):
         set_gauge("process_gc_collections", st.get("collections", 0),
                   labels={"gen": str(gen)})
+    if _on_gc in gc.callbacks:
+        for gen, seconds in enumerate(list(_GC_PAUSE_S)):
+            inc_counter("process_gc_pause_seconds_total",
+                        seconds - _GC_PUBLISHED[gen],
+                        labels={"gen": str(gen)})
+            _GC_PUBLISHED[gen] = seconds
+    for d in _DEVICES:
+        peak = (d.memory_stats() or {}).get("peak_bytes_in_use")
+        if peak is not None:  # the CPU backend reports none
+            set_gauge("device_memory_peak_bytes", peak,
+                      labels={"device": str(d.id)})
     if not _PROC_SELF_OK:
         return  # non-Linux: no cheap fd count — gauge stays absent
     try:

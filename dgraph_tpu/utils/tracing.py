@@ -22,12 +22,21 @@ exposes pprof profiles. Here:
   multi-node merge (tools/trace_merge.py) shows one lane per node.
 - `profile_device(dir)` wraps jax.profiler.trace: a TensorBoard-
   loadable device profile of everything jitted inside the block — the
-  TPU analogue of the reference's pprof CPU profiles.
+  TPU analogue of the reference's pprof CPU profiles. Served as
+  `POST /debug/device_profile` (server/http.py).
+- every span is ALSO a `jax.profiler.TraceAnnotation` of the same
+  name for its duration, so a device profile taken by anyone (the
+  route above, a launcher that started the profiler itself) shows the
+  program's spans on the host plane, on the profiler's clock, beside
+  the device's ops. Outside a trace an annotation is a flag check.
 
 Spans are cheap (two clock reads + an 8-byte id + a deque append under
 GIL; budget < 5 µs each, enforced by bench_micro.py --span-overhead
-and tier-1) and on by default; the ring bounds memory. `set_enabled`
-turns recording off entirely for benchmarking the overhead itself.
+and tier-1) and on by default; the ring bounds memory: it is an
+operator's view of the last few seconds (4,096 spans), not a record of
+a run — measurements read the per-request roll-ups
+(`extensions.server_latency`) and the counters. `set_enabled` turns
+recording off entirely for benchmarking the overhead itself.
 """
 
 from __future__ import annotations
@@ -36,6 +45,7 @@ import contextlib
 import contextvars
 import itertools
 import os
+import sys
 import threading
 import time
 from collections import deque
@@ -76,11 +86,13 @@ SPAN_NAMES = (
     "batch.wait",
     "block",
     "commit",
+    "device.call",
     "device.tile_load",
     "encode",
     "eq",
     "execute",
     "expand",
+    "http.request",
     "ineq",
     "match",
     "mutate",
@@ -92,6 +104,7 @@ SPAN_NAMES = (
     "rpc.send",
     "setops",
     "similar_to",
+    "snapshot.load",
     "sort",
     "tablet.rollup",
     "vector.build",
@@ -190,45 +203,82 @@ def bind_request(ctx) -> Iterator[None]:
         yield
 
 
-@contextlib.contextmanager
-def span(name: str, **attrs: Any) -> Iterator[dict]:
-    """Record one wall-time span; yields the attr dict so callers can
-    attach results (e.g. result counts) before the span closes."""
-    # observers (the coststore's always-on aggregation) outlive the
-    # ring's enabled flag: set_enabled(False) stops RETAINING spans,
-    # not MEASURING them. Sheds to a true no-op only when nobody is
-    # listening at all.
-    if not _enabled and not _observers:
-        yield attrs
-        return
-    cur = _CUR.get()
-    sid = new_span_id()
-    if cur is None:
-        trace_id, parent = sid, ""  # self-rooted trace
-    else:
-        trace_id, parent = cur
-    # wall clock: chrome://tracing renders these as absolute instants.
-    # `attrs` is the call's own fresh kwargs dict — no defensive copy
-    rec = {"name": name, "trace_id": trace_id, "span_id": sid,
-           "parent_id": parent, "node": _NODE_CV.get() or _NODE,
-           "ts_us": time.time() * 1e6,  # dglint: disable=DG06
-           "tid": threading.get_ident(), "args": attrs}
-    tok = _CUR.set((trace_id, sid))
-    t0 = time.perf_counter_ns()
-    try:
-        yield rec["args"]
-    finally:
-        rec["dur_us"] = (time.perf_counter_ns() - t0) / 1e3
-        _CUR.reset(tok)
-        if _enabled:
-            with _lock:
-                _spans.append(rec)
-        if _observers:
-            for fn in list(_observers):
-                try:
-                    fn(rec)
-                except Exception:
-                    remove_span_observer(fn)
+_TraceAnnotation = None
+
+
+def trace_annotation(name: str):
+    """A `jax.profiler.TraceAnnotation(name)` (an event of that name on
+    the host plane of whatever device profile is running), or None in
+    a process that never imported jax — it cannot be under the
+    profiler, and tracing must not be what imports jax into a zero or
+    a tool. No key-values: the event's name is the span's name."""
+    global _TraceAnnotation
+    if _TraceAnnotation is None:
+        if "jax" not in sys.modules:
+            return None
+        from jax.profiler import TraceAnnotation
+        _TraceAnnotation = TraceAnnotation
+    return _TraceAnnotation(name)
+
+
+class span:
+    """Record one wall-time span; `with span(...) as attrs` yields the
+    attr dict so callers can attach results (e.g. result counts)
+    before the span closes. A class and not a generator: two method
+    calls are about a microsecond cheaper than a generator's frame,
+    which pays for the profiler annotation every span also opens."""
+
+    __slots__ = ("_name", "_attrs", "_rec", "_tok", "_t0", "_ann")
+
+    def __init__(self, name: str, **attrs: Any):
+        self._name = name
+        self._attrs = attrs
+
+    def __enter__(self) -> dict:
+        self._ann = ann = trace_annotation(self._name)
+        if ann is not None:
+            ann.__enter__()
+        # observers (the coststore's always-on aggregation) outlive
+        # the ring's enabled flag: set_enabled(False) stops RETAINING
+        # spans, not MEASURING them. Sheds to the annotation alone
+        # only when nobody is listening at all.
+        if not _enabled and not _observers:
+            self._rec = None
+            return self._attrs
+        cur = _CUR.get()
+        sid = new_span_id()
+        if cur is None:
+            trace_id, parent = sid, ""  # self-rooted trace
+        else:
+            trace_id, parent = cur
+        # wall clock: chrome://tracing renders these as absolute
+        # instants. `_attrs` is the call's own fresh kwargs dict — no
+        # defensive copy
+        self._rec = {"name": self._name, "trace_id": trace_id,
+                     "span_id": sid, "parent_id": parent,
+                     "node": _NODE_CV.get() or _NODE,
+                     "ts_us": time.time() * 1e6,  # dglint: disable=DG06
+                     "tid": threading.get_ident(), "args": self._attrs}
+        self._tok = _CUR.set((trace_id, sid))
+        self._t0 = time.perf_counter_ns()
+        return self._attrs
+
+    def __exit__(self, *exc) -> None:
+        rec = self._rec
+        if rec is not None:
+            rec["dur_us"] = (time.perf_counter_ns() - self._t0) / 1e3
+            _CUR.reset(self._tok)
+            if _enabled:
+                with _lock:
+                    _spans.append(rec)
+            if _observers:
+                for fn in list(_observers):
+                    try:
+                        fn(rec)
+                    except Exception:
+                        remove_span_observer(fn)
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
 
 
 # ------------------------------------------------------- W3C traceparent
@@ -347,9 +397,13 @@ def export_chrome_trace(trace_id: Optional[str] = None) -> list[dict]:
 @contextlib.contextmanager
 def profile_device(log_dir: str) -> Iterator[None]:
     """Capture a jax.profiler device trace (XLA compilation + kernel
-    timeline) for everything run inside the block. View with
-    TensorBoard's profile plugin pointed at log_dir."""
+    timeline, and every span above as a host event) for everything
+    run inside the block. View with TensorBoard's profile plugin
+    pointed at log_dir. Without the Python tracer: on a server it
+    would record every call of every request thread."""
     import jax
 
-    with jax.profiler.trace(log_dir):
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    with jax.profiler.trace(log_dir, profiler_options=opts):
         yield

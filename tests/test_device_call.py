@@ -1,0 +1,315 @@
+"""`device.call` (query/devicecall.py): every device dispatch counted
+and timed at one place, rolled up into `extensions.server_latency`,
+on the profiler's clock, under programs named for what they are; and
+the front end's, the storage layer's and the collector's own clocks
+(PERF.md section 3)."""
+
+import ast
+import glob
+import json
+import os
+import threading
+import time
+import urllib.error
+import urllib.request
+
+import numpy as np
+import pytest
+
+from dgraph_tpu.engine.db import GraphDB
+from dgraph_tpu.utils import metrics, tracing
+
+_REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+# every counter that stands for a device dispatch: the benchmark's
+# `device_ops_per_req` and `report_counters` read them by these names
+DISPATCH_COUNTERS = (
+    "query_device_count_page_total",
+    "query_device_expand_total",
+    "query_device_multisort_total",
+    "query_device_orderkeys_total",
+    "query_device_overlay_expand_total",
+    "query_device_range_total",
+    "query_device_setops_total",
+    "query_device_sort_page_total",
+    "query_device_sssp_total",
+    "query_fused_dispatch_total",
+    "query_sharded_expand_total",
+)
+DEVICE_KEYS = ("device_calls", "device_enqueue_ns", "device_wait_ns",
+               "device_fetch_ns")
+
+
+def _graph(**kw) -> GraphDB:
+    db = GraphDB(**kw)
+    db.alter("name: string @index(exact) .\nage: int @index(int) .\n"
+             "friend: [uid] @reverse @count .")
+    nq = []
+    for i in range(1, 60):
+        nq += [f'<{i}> <name> "n{i}" .', f'<{i}> <age> "{i % 17}" .',
+               f"<{i}> <friend> <{i * 7 % 59 + 1}> .",
+               f"<{i}> <friend> <{i * 11 % 59 + 1}> ."]
+    db.mutate(set_nquads="\n".join(nq))
+    db.rollup_all(window=0)
+    return db
+
+
+PAGE = "{ q(func: has(age), orderasc: age, first: 5) { name friend { name } } }"
+
+
+def test_device_forced_query_rolls_its_calls_up():
+    db = _graph(device_min_edges=1)
+    tracing.clear()
+    sl = db.query(PAGE)["extensions"]["server_latency"]
+    assert sl["device_calls"] >= 1
+    phases = (sl["device_enqueue_ns"], sl["device_wait_ns"],
+              sl["device_fetch_ns"])
+    assert all(p > 0 for p in phases)
+    assert sum(phases) <= sl["processing_ns"]
+    calls = [s for s in tracing.recent_spans()
+             if s["name"] == "device.call"]
+    assert len(calls) == sl["device_calls"]
+    # one place: the spans say what the roll-up says, to the rounding
+    # of each phase to whole microseconds
+    for key, attr in zip(DEVICE_KEYS[1:],
+                         ("enqueue_us", "wait_us", "fetch_us")):
+        total = sum(s["args"][attr] for s in calls)
+        assert 0 <= sl[key] // 1000 - total <= len(calls)
+    for s in calls:
+        assert s["args"]["family"] and s["args"]["program"]
+        assert s["args"]["out_bytes"] > 0
+    # each call hangs under a stage's span of the same request
+    by_id = {s["span_id"]: s for s in tracing.recent_spans()}
+    assert all(by_id[s["parent_id"]]["name"] != "device.call"
+               and s["trace_id"] == by_id[s["parent_id"]]["trace_id"]
+               for s in calls)
+
+
+def test_host_only_query_has_the_keys_at_zero():
+    db = _graph(prefer_device=False)
+    out = db.query(PAGE)
+    sl = out["extensions"]["server_latency"]
+    assert [sl[k] for k in DEVICE_KEYS] == [0, 0, 0, 0]
+    # the reference's own message keeps the reference's fields
+    assert set(out["extensions"]["latency"]) == {
+        "parsing_ns", "processing_ns", "encoding_ns",
+        "assign_timestamp_ns"}
+
+
+def test_phase_counters_move_with_the_site_counter():
+    db = _graph(device_min_edges=1)
+    before = metrics.counters_snapshot()
+    db.query(PAGE)
+    moved = metrics.counters_delta(before)
+    assert moved["query_device_sort_page_total"] == 1
+    for phase in ("enqueue", "wait", "fetch"):
+        key = ('device_call_ns_total{family="sort_page",'
+               f'phase="{phase}"}}')
+        assert moved[key] > 0
+
+
+def test_a_block_that_never_dispatched_counts_nothing():
+    from dgraph_tpu.query.devicecall import device_call
+    from dgraph_tpu.engine.db import Latency
+
+    lat = Latency()
+    before = metrics.counters_snapshot()
+    with device_call("query_device_setops_total", sink=lat):
+        pass  # the callee declined before it reached the device
+    with pytest.raises(RuntimeError):
+        with device_call("query_device_setops_total", sink=lat) as dc:
+            dc.wait(np.zeros(4))
+            raise RuntimeError("after the dispatch")
+    assert lat.device_calls == 0
+    assert not metrics.counters_delta(before)
+
+
+def _calls(tree):
+    for node in ast.walk(tree):
+        if isinstance(node, ast.Call) and node.args \
+                and isinstance(node.args[0], ast.Constant):
+            f = node.func
+            yield (f.attr if isinstance(f, ast.Attribute)
+                   else getattr(f, "id", "")), node.args[0].value
+
+
+@pytest.fixture(scope="module")
+def emission_sites():
+    """{counter name -> [(file, emitting function), ...]} over the
+    program's tree."""
+    sites: dict = {}
+    for path in glob.glob(os.path.join(_REPO, "dgraph_tpu", "**", "*.py"),
+                          recursive=True):
+        with open(path) as f:
+            tree = ast.parse(f.read(), filename=path)
+        for fn, name in _calls(tree):
+            if fn in ("inc_counter", "device_call"):
+                sites.setdefault(name, []).append(
+                    (os.path.relpath(path, _REPO), fn))
+    return sites
+
+
+@pytest.mark.parametrize("counter", DISPATCH_COUNTERS)
+def test_dispatch_counter_moves_only_in_the_helper(counter,
+                                                   emission_sites):
+    sites = emission_sites.get(counter, [])
+    assert sites, f"{counter} has no dispatch site left"
+    assert all(fn == "device_call" for _, fn in sites), sites
+
+
+def test_no_dispatch_counter_is_missing_from_the_list(emission_sites):
+    named = {n for n in emission_sites
+             if n.startswith(("query_device_", "query_fused_dispatch",
+                              "query_sharded_expand"))}
+    # what times the calls must not read as one more dispatch: the
+    # benchmark sums the `query_device_` prefix as a count
+    assert named == set(DISPATCH_COUNTERS)
+
+
+def test_profile_holds_the_programs_spans(tmp_path):
+    from jax.profiler import ProfileData
+
+    db = _graph(device_min_edges=1)
+    db.query(PAGE)  # compile outside the trace
+    with tracing.profile_device(str(tmp_path)):
+        db.query(PAGE)
+    (pb,) = glob.glob(str(tmp_path / "plugins" / "profile" / "*"
+                          / "*.xplane.pb"))
+    host = {e.name for plane in ProfileData.from_file(pb).planes
+            if plane.name == "/host:CPU"
+            for line in plane.lines for e in line.events}
+    assert {"query", "execute", "device.call", "device.enqueue",
+            "device.wait", "device.fetch", "encode"} <= host
+
+
+def _post(url: str, body: str = ""):
+    req = urllib.request.Request(url, data=body.encode(), method="POST")
+    with urllib.request.urlopen(req) as resp:
+        return json.loads(resp.read())
+
+
+def test_http_request_phases_and_device_profile_route():
+    from dgraph_tpu.server.http import serve
+
+    httpd, alpha = serve(_graph(device_min_edges=1), block=False, port=0)
+    try:
+        base = f"http://127.0.0.1:{httpd.server_address[1]}"
+        before = metrics.counters_snapshot()
+        tracing.clear()
+        out = _post(base + "/query", PAGE)
+        assert out["extensions"]["server_latency"]["device_calls"] >= 1
+        # `post` ends after the last byte is written: the client can
+        # be back before the handler has counted
+        deadline = time.monotonic() + 10.0
+        while "http_requests_total" not in metrics.counters_delta(before) \
+                and time.monotonic() < deadline:
+            time.sleep(0.01)
+        moved = metrics.counters_delta(before)
+        assert moved["http_requests_total"] == 1
+        phases = {p: moved[f'http_request_ns_total{{phase="{p}"}}']
+                  for p in ("pre", "engine", "post")}
+        assert all(v > 0 for v in phases.values())
+        sl = out["extensions"]["server_latency"]
+        assert phases["engine"] >= sl["total_ns"]
+        spans = {s["name"]: s for s in tracing.recent_spans()}
+        assert spans["query"]["parent_id"] \
+            == spans["http.request"]["span_id"]
+        # a request that fails counts no phases
+        before = metrics.counters_snapshot()
+        with pytest.raises(urllib.error.HTTPError):
+            _post(base + "/query", "{ q(func: nope")
+        assert "http_requests_total" not in metrics.counters_delta(before)
+
+        # the device profile: answers with the directory it wrote, and
+        # refuses a second call while one runs
+        got: list = []
+        t = threading.Thread(target=lambda: got.append(_post(
+            base + "/debug/device_profile?seconds=1.5")))
+        t.start()
+        while not alpha._device_profile_lock.locked() and t.is_alive():
+            time.sleep(0.01)
+        with pytest.raises(urllib.error.HTTPError) as refused:
+            _post(base + "/debug/device_profile?seconds=0.1")
+        assert refused.value.code == 429
+        t.join(timeout=60)
+        assert not t.is_alive()
+        assert got and got[0]["seconds"] == 1.5
+        assert glob.glob(os.path.join(got[0]["dir"], "plugins", "profile",
+                                      "*", "*.xplane.pb"))
+        import shutil
+        shutil.rmtree(got[0]["dir"], ignore_errors=True)
+    finally:
+        httpd.shutdown()
+
+
+def test_device_profile_is_guardian_only_under_acl():
+    from dgraph_tpu.server.acl import AclError
+    from dgraph_tpu.server.http import AlphaServer
+
+    srv = AlphaServer(acl_secret=b"s3cret")
+    with pytest.raises(AclError):
+        srv.handle_device_profile({"seconds": "0.1"}, "")
+
+
+def test_snapshot_load_says_where_its_time_went(tmp_path):
+    from dgraph_tpu.storage.snapshot import load_snapshot, save_snapshot
+
+    path = str(tmp_path / "p.snap")
+    save_snapshot(_graph(prefer_device=False), path)
+    tracing.clear()
+    db = load_snapshot(path, GraphDB(device_min_edges=1))
+    gauges = metrics.gauges_snapshot()
+    took = {p: gauges[f'startup_phase_seconds{{phase="{p}"}}']
+            for p in ("snapshot_read", "snapshot_decode", "index_build")}
+    assert all(v >= 0 for v in took.values())
+    (sp,) = [s for s in tracing.recent_spans()
+             if s["name"] == "snapshot.load"]
+    assert sum(took.values()) <= sp["dur_us"] / 1e6 + 1e-3
+    # tiles are built on first use: that is when their phase appears
+    tile = 'startup_phase_seconds{phase="tile_upload"}'
+    was = gauges.get(tile, 0.0)
+    db.query(PAGE)
+    assert metrics.gauges_snapshot()[tile] > was
+
+
+def test_collector_pauses_are_counted_once_watched():
+    import gc
+
+    metrics.watch_gc()
+    metrics.watch_gc()  # once, however often it is asked
+    assert gc.callbacks.count(metrics._on_gc) == 1
+    try:
+        metrics.collect_runtime_gauges()
+        key = 'process_gc_pause_seconds_total{gen="2"}'
+        was = metrics.counters_snapshot()[key]
+        junk = [[i] for i in range(50_000)]
+        gc.collect()
+        del junk
+        metrics.collect_runtime_gauges()
+        assert metrics.counters_snapshot()[key] > was
+        assert key in metrics.render_prometheus()
+    finally:
+        gc.callbacks.remove(metrics._on_gc)
+
+
+def test_device_memory_peak_is_read_from_watched_devices():
+    class Chip:
+        id = 3
+
+        def memory_stats(self):
+            return {"peak_bytes_in_use": 1234}
+
+    class Cpu:
+        id = 0
+
+        def memory_stats(self):
+            return None  # the CPU backend reports none
+
+    metrics.watch_devices([Chip(), Cpu()])
+    try:
+        metrics.collect_runtime_gauges()
+        g = metrics.gauges_snapshot()
+        assert g['device_memory_peak_bytes{device="3"}'] == 1234
+        assert 'device_memory_peak_bytes{device="0"}' not in g
+    finally:
+        metrics.watch_devices([])

@@ -74,3 +74,37 @@ def test_every_query_has_a_golden():
     assert len(names) >= 35
     for n in names:
         runner.load_expected(n)  # raises if missing
+
+
+def test_device_programs_carry_names_of_their_own():
+    """After the suite's device-forced pass (device_min_edges=1 in
+    the runner): every executable the served path compiled says what
+    it is in a device profile, and the two programs the benchmark has
+    costed (benchmark/costs/jit_<name>.py) keep their names."""
+    import os
+
+    from dgraph_tpu.ops import graph
+    from dgraph_tpu.query import plan
+
+    for name in runner.query_names():
+        if name.startswith(("q006", "q010", "q049", "q058")):
+            runner.run_query(name)  # alone too, the registry is filled
+    anonymous = {"run", "<lambda>", "fn", "f"}
+    with plan._JIT_LOCK:
+        staged = {key[0]: fn.__name__ for key, fn in plan._JIT.items()}
+    assert staged and not anonymous & set(staged.values()), staged
+    assert any(n.startswith("fused_page_") for n in staged.values())
+    expanders = [
+        fn.__name__ for tab in runner.get_db().tablets.values()
+        for attr in ("_device_adj", "_device_radj")
+        for fn in getattr(getattr(tab, attr, None),
+                          "_expander_cache", {}).values()]
+    assert expanders and set(expanders) == {"expand_frontier"}
+    costs = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "benchmark", "costs")
+    for fn in (graph.count_filter_sort_page, graph.multisort_page):
+        assert os.path.isfile(os.path.join(
+            costs, f"jit_{fn.__name__}.py")), fn.__name__
+    assert graph.count_filter_sort_page.__name__ \
+        == "count_filter_sort_page"
+    assert graph.multisort_page.__name__ == "multisort_page"

@@ -22,7 +22,6 @@ def test_spans_record_query_and_commit():
     q = next(s for s in reversed(tracing.recent_spans())
              if s["name"] == "query")
     assert q["args"]["blocks"] == 1 and q["dur_us"] > 0
-    assert q["args"]["process_us"] >= 0
 
 
 def test_chrome_trace_export_shape():
@@ -228,7 +227,9 @@ def test_server_latency_and_trace_over_http():
                           "{ q(func: uid(0x1)) { uid } }", hdr)
         sl = out["extensions"]["server_latency"]
         assert set(sl) == {"parsing_ns", "processing_ns",
-                           "encoding_ns", "total_ns"}
+                           "encoding_ns", "total_ns", "device_calls",
+                           "device_enqueue_ns", "device_wait_ns",
+                           "device_fetch_ns"}
         assert all(v >= 0 for v in sl.values())
         assert sl["total_ns"] >= (sl["parsing_ns"]
                                   + sl["processing_ns"]
