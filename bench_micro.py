@@ -304,6 +304,89 @@ def span_overhead_bench(n: int = 20_000, runs: int = 5,
     return rec
 
 
+def lookup_crossover_bench(
+        n_qs=(1_024, 4_096, 16_384, 262_144),
+        n_ts=(16_384, 524_288, 2_097_152), calls: int = 8) -> list:
+    """`--lookup-crossover`: device time of the two lowerings
+    `ops/uidvec.lookup_idx` picks between, over the grid its rule was
+    fitted on (n_q sorted queries x n_t table rows): the scan lowering
+    of jnp.searchsorted (cost ~ n_q * log2 n_t gathered elements)
+    against sorted_lookup's co-sort (two lax.sorts, cost ~ n_q + n_t).
+    Each is one jitted program with the table and the queries as its
+    parameters, as the served programs have them, and its time is the
+    device's own: the mean of the program's events in a profiler
+    trace of `calls` back-to-back calls (a host clock around one small
+    kernel measures the dispatch). Prints one JSON line per grid point
+    with both times, the faster one, and what lookup_idx picks there
+    on a sort backend. Meant for the chip; where the trace has no
+    device plane (the CPU) it falls back to the host clock, and the
+    line's `clock` says so."""
+    import glob
+    import tempfile
+
+    from bench import init_backend
+
+    _devs, platform = init_backend()
+    import jax
+    import jax.numpy as jnp
+    from jax.profiler import ProfileData
+
+    from dgraph_tpu.ops import uidvec
+    from dgraph_tpu.utils.tracing import profile_device
+
+    def timed_ms(fn, *args):
+        jax.block_until_ready(fn(*args))
+        with tempfile.TemporaryDirectory(
+                prefix="lookup_crossover_") as tmp:
+            with profile_device(tmp):
+                t = time.perf_counter()
+                for _ in range(calls):
+                    out = fn(*args)
+                jax.block_until_ready(out)
+                host_ms = (time.perf_counter() - t) * 1e3 / calls
+            pd = ProfileData.from_file(glob.glob(os.path.join(
+                tmp, "plugins", "profile", "*", "*.xplane.pb"))[0])
+        ns = [e.duration_ns for plane in pd.planes
+              if plane.name.startswith("/device:")
+              for ln in plane.lines if ln.name == "XLA Modules"
+              for e in ln.events]
+        if len(ns) == calls:
+            return sum(ns) / calls / 1e6, "device"
+        return host_ms, "host"
+
+    rng = np.random.default_rng(25)
+    out = []
+    for n_t in n_ts:
+        table = np.unique(rng.integers(
+            1, 1 << 31, 2 * n_t, dtype=np.uint32))[:n_t - 7]
+        dt = jax.device_put(uidvec.from_numpy(table, size=n_t))
+        for n_q in n_qs:
+            q = np.unique(np.concatenate([
+                rng.choice(table, min(n_q // 2, len(table)),
+                           replace=False),
+                rng.integers(1, 1 << 31, n_q, dtype=np.uint32)]))
+            q = q[np.sort(rng.choice(len(q), n_q - 5, replace=False))]
+            dq = jax.device_put(uidvec.from_numpy(q, size=n_q))
+            want = np.searchsorted(np.asarray(dt), np.asarray(dq))
+            ms = {}
+            for name, lookup in (("scan", jnp.searchsorted),
+                                 ("cosort", uidvec.sorted_lookup)):
+                fn = jax.jit(lookup)
+                assert np.array_equal(np.asarray(fn(dt, dq)), want), \
+                    (name, n_q, n_t)
+                ms[name], clock = timed_ms(fn, dt, dq)
+            rec = {"metric": "lookup_crossover", "platform": platform,
+                   "clock": clock, "n_q": n_q, "n_t": n_t,
+                   "scan_ms": round(ms["scan"], 4),
+                   "cosort_ms": round(ms["cosort"], 4),
+                   "faster": min(ms, key=ms.get),
+                   "rule_picks": "cosort"
+                   if uidvec.lookup_cosorts(n_q, n_t) else "scan"}
+            out.append(rec)
+            print(json.dumps(rec), flush=True)
+    return out
+
+
 def _summary_mix():
     """The golden summary-shape queries + the warm GraphDB — ONE
     definition of the 'high-QPS mix' every decomposed overhead gate
@@ -804,6 +887,9 @@ def main():
         return
     if "--span-overhead" in sys.argv:
         span_overhead_bench()
+        return
+    if "--lookup-crossover" in sys.argv:
+        lookup_crossover_bench()
         return
     if "--stats-overhead" in sys.argv:
         if not stats_overhead_bench()["within_budget"]:

@@ -475,8 +475,12 @@ def fused_rank_page(cand: jax.Array,
     REDUCTIONS finds the bucket threshold covering offset+window
     rows, and survivors compact through cumsum + searchsorted +
     gather — every full-width pass is a map or a reduce. Only the
-    <= FUSED_SEL_CAP survivors take the exact multi-key lax.sort, and
-    secondary order keys gather on the survivor vector alone. Buckets
+    <= FUSED_SEL_CAP survivors take the exact multi-key lax.sort. Every
+    order rank is looked up ONCE: the survivors inherit their primary
+    key from the full-width column the bucketing computed (`c0[sidx]`,
+    a gather), and only secondary order keys are looked up on the
+    survivor vector, a FUSED_SEL_CAP-row query that lookup_idx answers
+    by binary search whenever the table dwarfs it. Buckets
     are monotone in the primary rank, so the sorted survivors are a
     byte-exact prefix of the staged full ordering — the page slice is
     identical. `base0` recenters desc-negated ranks (traced: domain
@@ -552,8 +556,11 @@ def fused_rank_page(cand: jax.Array,
     # SENTINEL uid and the uid operand sinks them last
     out_u = jnp.where(got, cand[sidx], SENTINEL)
     svalid = out_u != SENTINEL
-    outs = []
-    for view, is_lut, desc in zip(ord_views, ord_luts, descs):
+    # survivors inherit the primary key from the full-width column the
+    # bucketing computed (same uid, same view, already desc-adjusted):
+    # a second lookup of it would co-sort the whole table again
+    outs = [jnp.where(got, c0[sidx], RANK_MISSING)]
+    for view, is_lut, desc in zip(ord_views[1:], ord_luts[1:], descs[1:]):
         r = view_ranks(out_u, view, is_lut, svalid)
         if desc:
             r = jnp.where(r == RANK_MISSING, r, -r)

@@ -153,15 +153,42 @@ def sorted_lookup(table: jax.Array, q: jax.Array) -> jax.Array:
     return out[:n].astype(jnp.int32)
 
 
-# static query size from which the co-sort lookup beats the scan
-# lowering of jnp.searchsorted (measured on v5e: scan is fine for
-# small frontiers, catastrophic for ~1M-query vectors)
+# lookup_idx's rule, fitted on one v5e (PR 25: `bench_micro.py
+# --lookup-crossover`, device time of one lookup in ms from a profiler
+# trace, scan / co-sort; PERF.md has the finer grid):
+#
+#   n_t \ n_q     1,024          4,096          16,384        262,144
+#   16,384     0.100 / 0.050  0.366 / 0.051  1.75 / 0.052  28.2 / 0.88
+#   524,288    0.442 / 1.95   1.84  / 1.95   7.96 / 1.95   37.7 / 2.53
+#   2,097,152  0.409 / 10.0   1.67  / 10.0   6.93 / 9.99   135  / 10.7
+#
+# The scan gathers n_q elements a round for log2(n_t)+1 rounds, 6-7 ns
+# an element while XLA keeps the table in fast memory and ~21 ns when
+# it does not (a parameter of 131,072 rows or more, alone in its
+# program); the co-sort costs two sorts of n_q + n_t rows whatever the
+# queries are. They cross where the table is 128 times the query
+# (4,096 against 524,288: 1.84 / 1.95; 16,384 against 2,097,152). Under
+# _LOOKUP_COSORT_MIN queries the scan stays: at most 0.14 ms is at
+# stake there (1,024 against 16,384: 0.100 / 0.050), no served program
+# has such a lookup, and a table in fast memory tilts it to the scan.
 _LOOKUP_COSORT_MIN = 4096
+_LOOKUP_TABLE_RATIO = 128
+
+
+def lookup_cosorts(n_q: int, n_t: int) -> bool:
+    """Whether lookup_idx co-sorts `n_q` queries with an `n_t`-row
+    table on a sort backend: both sizes are static, so this is a
+    constant inside a trace."""
+    return n_q >= _LOOKUP_COSORT_MIN and n_q * _LOOKUP_TABLE_RATIO > n_t
 
 
 def lookup_idx(table: jax.Array, q: jax.Array) -> jax.Array:
-    """searchsorted(table, q), picking the implementation by static
-    query size.
+    """searchsorted(table, q), picking the implementation from the two
+    static sizes (lookup_cosorts): the co-sort for a wide query
+    against a table of comparable size, jnp.searchsorted's scan when
+    the query is small or the table dwarfs it (a 4,096-row lookup
+    never co-sorts a half-million-row table). On the CPU always the
+    scan (_sort_backend).
 
     PRECONDITION (unlike jnp.searchsorted): `q` must be sorted
     ascending — the repo-wide padded-sorted-uid-vector invariant. The
@@ -169,7 +196,7 @@ def lookup_idx(table: jax.Array, q: jax.Array) -> jax.Array:
     co-sorted concat) - (its own q-rank), which underflows to garbage
     for out-of-order queries. Callers passing value-ordered or
     otherwise unsorted vectors must sort first."""
-    if q.shape[0] >= _LOOKUP_COSORT_MIN and _sort_backend():
+    if _sort_backend() and lookup_cosorts(q.shape[0], table.shape[0]):
         return sorted_lookup(table, q)
     return jnp.searchsorted(table, q)
 

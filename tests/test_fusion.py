@@ -17,10 +17,15 @@ The contract under test (query/fusion.py + ops/graph.fused_rank_page):
 """
 
 import random
+from collections import OrderedDict
 
+import jax
+import jax.numpy as jnp
 import pytest
 
 from dgraph_tpu.engine.db import GraphDB
+from dgraph_tpu.ops import graph, uidvec
+from dgraph_tpu.query import plan
 from dgraph_tpu.query.plan import jit_stage_stats
 from dgraph_tpu.utils import metrics
 
@@ -189,3 +194,218 @@ def test_dirty_overlay_falls_back_and_stays_correct(db):
         assert "0x7" in _uids(db, q, fused=True)
     finally:
         db.rollup_in_read = True
+
+
+# -- the rank lookups of the fused kernel, pinned at the trace level ----
+
+
+def _sort_eqns(jaxpr):
+    """Every `sort` equation of a jaxpr, nested jaxprs (pjit, while,
+    cond bodies) included."""
+    out = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "sort":
+            out.append(eqn)
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            out.extend(_sort_eqns(sub))
+    return out
+
+
+def _fused_jaxpr(monkeypatch, n_cand, views, luts, descs, fop):
+    """jaxpr of fused_rank_page over abstract operands, traced as the
+    chip traces it (comparator sorts are the fast lookup there)."""
+    monkeypatch.setattr(uidvec, "_sort_backend", lambda: True)
+    i32 = jax.ShapeDtypeStruct((), jnp.int32)
+    # fop "and" folds one cand-aligned set leaf: a pure vector operand
+    fparts = (jax.ShapeDtypeStruct((n_cand,), jnp.bool_),) \
+        if fop == "and" else ()
+
+    def run(cand, fparts, ord_views, base0, offset):
+        return graph.fused_rank_page(
+            cand, (), (), (), (), (), fparts, (False,) * len(fparts),
+            True, fop, ord_views, luts, descs, base0, 2, 8, offset)
+
+    return jax.make_jaxpr(run)(
+        jax.ShapeDtypeStruct((n_cand,), jnp.uint32), fparts, views,
+        i32, i32).jaxpr
+
+
+def _search_view(n_t):
+    return (jax.ShapeDtypeStruct((n_t,), jnp.uint32),
+            jax.ShapeDtypeStruct((n_t,), jnp.int32))
+
+
+def _lut_view(n):
+    return (jax.ShapeDtypeStruct((n,), jnp.int32),
+            jax.ShapeDtypeStruct((), jnp.uint32))
+
+
+@pytest.mark.parametrize("fop", ["none", "and"])
+@pytest.mark.parametrize("desc", [False, True])
+def test_primary_rank_is_looked_up_once(monkeypatch, desc, fop):
+    """One search-form order key: the full-width lookup co-sorts
+    candidates with the table (two sorts), the survivors INHERIT that
+    column, and the exact survivor sort is the third and last. A
+    second lookup of the primary rank would make it five."""
+    jaxpr = _fused_jaxpr(monkeypatch, 8192, (_search_view(16384),),
+                         (False,), (desc,), fop)
+    assert len(_sort_eqns(jaxpr)) == 3
+
+
+@pytest.mark.parametrize("fop", ["none", "and"])
+@pytest.mark.parametrize("desc", [False, True])
+def test_survivor_lookup_never_cosorts_a_big_table(monkeypatch, desc,
+                                                   fop):
+    """LUT primary, search-form secondary over a 524,288-row table:
+    the only lookup left is FUSED_SEL_CAP survivors against that
+    table, which must binary-search it — no sort in the program is
+    wider than the survivor vector."""
+    jaxpr = _fused_jaxpr(
+        monkeypatch, 65536, (_lut_view(262144), _search_view(524288)),
+        (True, False), (desc, not desc), fop)
+    sorts = _sort_eqns(jaxpr)
+    assert sorts, "the exact survivor sort is always there"
+    for eqn in sorts:
+        assert max(v.aval.shape[0] for v in eqn.invars) \
+            <= graph.FUSED_SEL_CAP
+
+
+# -- both lookup lowerings, byte for byte against the staged chain ------
+
+# uids 131 apart: a predicate on every node spans 1.23M uids, past the
+# dense-LUT budget (search form, table padded to 16,384 rows); `dense`
+# stops at node 7,900 so its span stays inside it (LUT form)
+W_N = 9400
+W_STRIDE = 131
+W_LUT_NODES = 7900
+
+W_SCHEMA = """
+k1: string @index(exact) .
+k4: string @index(exact) .
+k8: string @index(exact) .
+sname: int @index(int) .
+heat: float @index(float) .
+dense: int @index(int) .
+few: int @index(int) .
+"""
+
+
+def _wide_quads(rng: random.Random):
+    quads = []
+    for i in range(W_N):
+        u = f"<0x{16 + i * W_STRIDE:x}>"
+        if i < 8192:
+            quads.append(f'{u} <k8> "y" .')      # root of 8,192 rows
+            if i % 2:
+                quads.append(f'{u} <k4> "y" .')  # 4,096
+            if i % 8 == 3:
+                quads.append(f'{u} <k1> "y" .')  # 1,024
+        if i % 13:  # the rest miss the value: they must sink last
+            quads.append(f'{u} <sname> "{rng.randint(0, 499)}" .')
+        if i % 3:
+            quads.append(f'{u} <heat> "{rng.randint(0, 999) / 10}" .')
+        if i < W_LUT_NODES and i % 7:
+            quads.append(f'{u} <dense> "{rng.randint(0, 2999)}" .')
+        quads.append(f'{u} <few> "{rng.randint(0, 39)}" .')
+    return quads
+
+
+def _wide_build(**kw):
+    db = GraphDB(device_min_edges=8, fused_min_rows=8, **kw)
+    db.alter(schema_text=W_SCHEMA)
+    db.mutate(set_nquads="\n".join(_wide_quads(random.Random(SEED))))
+    db.rollup_all()
+    return db
+
+
+@pytest.fixture(scope="module")
+def wide_db():
+    return _wide_build()
+
+
+def _wq(root, order, page, flt=""):
+    return (f'{{ q(func: eq({root}, "y"), {order}, {page}){flt}'
+            ' { uid } }')
+
+
+WIDE_QUERIES = {
+    "search-asc-8k": _wq("k8", "orderasc: sname", "first: 10"),
+    "search-desc-4k-offset": _wq(
+        "k4", "orderdesc: sname", "first: 7, offset: 25"),
+    "search-asc-1k": _wq("k1", "orderasc: heat", "first: 20, offset: 3"),
+    "lut-asc-1k": _wq("k1", "orderasc: dense", "first: 12"),
+    "lut-desc-8k-offset": _wq(
+        "k8", "orderdesc: dense", "first: 9, offset: 40"),
+    # 3,781 of k4's 4,096 rows carry sname: the page crosses into the
+    # rows that miss it, which sink last under asc AND desc
+    "missing-tail-asc": _wq(
+        "k4", "orderasc: sname", "first: 20, offset: 3770"),
+    "missing-tail-desc": _wq(
+        "k4", "orderdesc: sname", "first: 20, offset: 3770"),
+    # 40 distinct values over 8,192 rows: ~205 ties a bucket, the page
+    # is cut inside the boundary bucket and uid breaks the ties
+    "boundary-ties": _wq("k8", "orderasc: few", "first: 15, offset: 200"),
+    "two-keys-search-search": _wq(
+        "k8", "orderasc: few, orderdesc: heat", "first: 25, offset: 100"),
+    "two-keys-lut-search": _wq(
+        "k4", "orderdesc: dense, orderasc: sname", "first: 16"),
+    "two-keys-search-lut": _wq(
+        "k1", "orderasc: few, orderasc: dense", "first: 30, offset: 7"),
+    "rank-leaf-and": _wq("k8", "orderasc: heat", "first: 12, offset: 5",
+                         " @filter(ge(sname, 100) AND lt(few, 30))"),
+}
+
+
+def test_wide_views_take_both_forms(wide_db):
+    """The parity cases below mean what their names say only if
+    `dense` is served as a LUT and the others by search."""
+    from dgraph_tpu.engine.device_cache import device_values
+
+    read_ts = wide_db.query(WIDE_QUERIES["lut-asc-1k"])[
+        "extensions"]["txn"]["start_ts"]
+    forms = {}
+    for pred in ("sname", "heat", "few", "dense"):
+        dv = device_values(wide_db, wide_db.tablets[pred], read_ts)
+        forms[pred] = dv.rank_lut is not None
+    assert forms == {"sname": False, "heat": False, "few": False,
+                     "dense": True}
+
+
+@pytest.mark.parametrize("sort_backend", [False, True],
+                         ids=["scan", "cosort"])
+@pytest.mark.parametrize("case", sorted(WIDE_QUERIES))
+def test_fused_page_parity_under_both_lookups(wide_db, monkeypatch,
+                                              case, sort_backend):
+    """Fused arm == staged chain, with the lookups lowered as the CPU
+    lowers them (binary search) and as the chip does (co-sort where
+    lookup_idx's rule says so): fresh executables per mode, since a
+    trace bakes the lowering in."""
+    monkeypatch.setattr(uidvec, "_sort_backend", lambda: sort_backend)
+    monkeypatch.setattr(plan, "_JIT", OrderedDict())
+    q = WIDE_QUERIES[case]
+    fused = _uids(wide_db, q, fused=True)
+    assert fused == _uids(wide_db, q, fused=False), q
+    assert fused, q
+    assert _fusion_tag(wide_db, q) == "fused", q
+
+
+@pytest.fixture(scope="module")
+def wide_mesh_db():
+    from dgraph_tpu.parallel.mesh import make_mesh
+
+    mesh = make_mesh(axes=("uid",))
+    assert mesh.shape["uid"] >= 2
+    return _wide_build(mesh=mesh)
+
+
+@pytest.mark.parametrize("case", ["search-desc-4k-offset",
+                                  "two-keys-lut-search"])
+def test_fused_page_parity_on_a_mesh(wide_mesh_db, case):
+    """On a uid-sharded mesh the survivors' primary key is a gather
+    over a SHARDED full-width column with a replicated index
+    (FUSION_RULES pin cand and the search planes to the `uid` axis):
+    same bytes as the staged chain."""
+    q = WIDE_QUERIES[case]
+    fused = _uids(wide_mesh_db, q, fused=True)
+    assert fused and fused == _uids(wide_mesh_db, q, fused=False), q
+    assert _fusion_tag(wide_mesh_db, q) == "fused", q

@@ -141,3 +141,41 @@ def test_sorted_lookup_matches_searchsorted():
         got = np.asarray(sorted_lookup(db, da))
         want = np.searchsorted(np.asarray(db), np.asarray(da))
         assert np.array_equal(got, want), (na, nb)
+
+
+# (n_q, n_t) -> does lookup_idx co-sort there on a sort backend: under
+# 4,096 queries the scan, above it the co-sort unless the table is 128
+# times the query or more
+LOOKUP_GRID = {(2048, 4096): False, (2048, 65536): False,
+               (4096, 4096): True, (4096, 65536): True,
+               (8192, 4096): True, (8192, 65536): True,
+               (4096, 524288): False}
+
+
+def test_lookup_grid_straddles_the_rule():
+    """The grid below tests BOTH lowerings only while lookup_idx's
+    rule splits it, by the query's size and by the table's."""
+    from dgraph_tpu.ops.uidvec import lookup_cosorts
+
+    assert {k: lookup_cosorts(*k) for k in LOOKUP_GRID} == LOOKUP_GRID
+
+
+@pytest.mark.parametrize("n_q,n_t", sorted(LOOKUP_GRID))
+def test_lookup_idx_matches_searchsorted(monkeypatch, n_q, n_t):
+    """lookup_idx == np.searchsorted whichever lowering its rule picks
+    from the two static sizes, traced as the chip traces it (sort
+    backend on): sentinel-padded table and queries, duplicate-free,
+    many of the queries absent from the table."""
+    from dgraph_tpu.ops import uidvec
+
+    monkeypatch.setattr(uidvec, "_sort_backend", lambda: True)
+    rng = np.random.default_rng(n_q * 31 + n_t)
+    table = rand_sorted(rng, n_t - 9, hi=1 << 22)
+    q = np.union1d(
+        rng.choice(table, min(n_q, n_t) // 2, replace=False),
+        rand_sorted(rng, n_q, hi=1 << 22))
+    q = q[np.sort(rng.choice(len(q), n_q - 5, replace=False))]
+    dt, dq = from_numpy(table, n_t), from_numpy(q, n_q)
+    got = np.asarray(uidvec.lookup_idx(dt, dq))
+    want = np.searchsorted(np.asarray(dt), np.asarray(dq))
+    np.testing.assert_array_equal(got, want)
