@@ -278,7 +278,9 @@ class GraphDB:
         self.shard_min_edges = shard_min_edges
         # quantized ANN tier for similar_to (ops/ivf.py via
         # storage/vecstore.py): IVF k-means + int8 residual codes,
-        # trained at rollup on clean base blocks once a vector
+        # for predicates whose schema asks for it
+        # (`@index(vector(ivf))`; `@index(vector)` stays exact at any
+        # size), trained at rollup on clean base blocks once such a
         # predicate crosses vec_index_min_rows (below it the exact
         # tiers are already fast), recall budgeted by
         # vec_target_recall at build. vec_quantized=False removes the
@@ -1589,7 +1591,8 @@ class GraphDB:
 
     def _train_vector_indexes(self):
         """Rollup hook: (re)train the quantized ANN index of every
-        vector tablet whose clean base crossed vec_index_min_rows.
+        `@index(vector(ivf))` tablet whose clean base crossed
+        vec_index_min_rows.
         A tablet whose base_ts did not move keeps its index (the
         cache validates the version); training failures degrade to
         the exact tiers, never to an error."""
@@ -1597,7 +1600,8 @@ class GraphDB:
             return
         from dgraph_tpu.models.types import TypeID
         for tab in self.tablets.values():
-            if tab.schema.value_type != TypeID.FLOAT32VECTOR:
+            if tab.schema.value_type != TypeID.FLOAT32VECTOR \
+                    or not tab.schema.vector_approx:
                 continue
             if len(tab.values) < self.vec_index_min_rows:
                 continue

@@ -81,6 +81,37 @@ def device_adjacency(db, tab, read_ts: int,
     return adj
 
 
+def device_vector_block(db, tab, base_vecs: np.ndarray):
+    """The tablet's base vector block (storage/vecstore.py) on the
+    device: a tile like the adjacency tiles, cached per base_ts,
+    counted in `device_cache_bytes` under the HBM budget and evictable
+    (a tile larger than the budget is admitted alone, tile_cache.py).
+    Rows are zero-padded to the bucket unit ONCE here, host-side, so
+    ops/knn.topk_device never copies the block per query. The gauge
+    `device_vector_block_bytes{predicate}` is what is resident: dtype
+    x padded shape, 0 after an eviction."""
+    from dgraph_tpu.ops.knn import pad_rows
+
+    arr = getattr(tab, "_device_vecs", None)
+    if arr is not None and getattr(tab, "_device_vecs_ts", -1) \
+            == tab.base_ts:
+        db.device_cache.touch(tab, "_device_vecs")
+        return arr
+    with _tile_load(pred=tab.pred, kind="vecs", rows=len(base_vecs)):
+        arr = jax.block_until_ready(
+            jax.numpy.asarray(pad_rows(base_vecs)))
+    tab._device_vecs = arr
+    tab._device_vecs_ts = tab.base_ts
+    labels = {"predicate": tab.pred}
+    db.device_cache.put(
+        tab, "_device_vecs", arr,
+        on_evict=lambda: set_gauge("device_vector_block_bytes", 0.0,
+                                   labels=labels))
+    set_gauge("device_vector_block_bytes", float(arr.nbytes),
+              labels=labels)
+    return arr
+
+
 def _clean_resident(db, tab, read_ts: int, want_uid: bool = True,
                     allow_dirty: bool = False) -> bool:
     """Shared residency policy: rolled-up committed state only.

@@ -94,6 +94,7 @@ class DeviceCacheLRU:
         # drop_tablet) — dead entries are pruned lazily so their bytes
         # never pin the budget.
         self._entries: OrderedDict[tuple, tuple] = OrderedDict()
+        self._on_evict: dict[tuple, object] = {}
         self.bytes = 0        # device bytes resident
         self.host_bytes = 0   # host export bytes resident
         self.peak_bytes = 0
@@ -113,10 +114,15 @@ class DeviceCacheLRU:
                 return True
             return False
 
-    def put(self, tab, attr: str, obj) -> None:
+    def put(self, tab, attr: str, obj, on_evict=None) -> None:
+        """Track a tile. `on_evict()` is called once the LRU has taken
+        the tile off the tablet (its owner's gauge of what is
+        resident); it must not hold the tablet."""
         with self._lock:
             self._prune_dead()
             key = (id(tab), attr)
+            if on_evict is not None:
+                self._on_evict[key] = on_evict
             old = self._entries.pop(key, None)
             if old is not None:
                 self.bytes -= old[2]
@@ -138,12 +144,14 @@ class DeviceCacheLRU:
         dead = [k for k, (ref, _, _, _) in self._entries.items()
                 if ref() is None]
         for k in dead:
+            self._on_evict.pop(k, None)
             _, _, dev, host = self._entries.pop(k)
             self.bytes -= dev
             self.host_bytes -= host
 
     def _evict_lru(self):
-        _, (ref, attr, dev, host) = self._entries.popitem(last=False)
+        key, (ref, attr, dev, host) = self._entries.popitem(last=False)
+        on_evict = self._on_evict.pop(key, None)
         self.bytes -= dev
         self.host_bytes -= host
         self.evictions += 1
@@ -161,12 +169,15 @@ class DeviceCacheLRU:
                 cache.clear()
             setattr(tab, attr, None)
             setattr(tab, attr + "_ts", -1)
+            if on_evict is not None:
+                on_evict()
 
     def drop_tablet(self, tab):
         """Forget every tile of a tablet (explicit drop paths; implicit
         removals are covered by the weak refs)."""
         with self._lock:
             for key in [k for k in self._entries if k[0] == id(tab)]:
+                self._on_evict.pop(key, None)
                 _, _, dev, host = self._entries.pop(key)
                 self.bytes -= dev
                 self.host_bytes -= host

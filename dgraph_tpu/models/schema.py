@@ -48,6 +48,15 @@ class PredicateSchema:
     upsert: bool = False
     lang: bool = False
     noconflict: bool = False
+    # the vector tokenizer's argument as written: "" (exact brute
+    # force) or "ivf" (the quantized tier, approximate)
+    vector_arg: str = ""
+
+    @property
+    def vector_approx(self) -> bool:
+        """similar_to may answer from the quantized IVF index: only
+        where the schema asks for it, never because of size."""
+        return self.vector_arg == "ivf"
 
     def describe(self) -> str:
         t = type_name(self.value_type)
@@ -55,7 +64,10 @@ class PredicateSchema:
             t = f"[{t}]"
         parts = [f"{self.predicate}: {t}"]
         if self.indexed:
-            parts.append(f"@index({', '.join(self.tokenizers)})")
+            toks = [f"{t}({self.vector_arg})"
+                    if t == "vector" and self.vector_arg else t
+                    for t in self.tokenizers]
+            parts.append(f"@index({', '.join(toks)})")
         if self.reverse:
             parts.append("@reverse")
         if self.count:
@@ -193,6 +205,24 @@ def _parse_predicate(cur: _Cursor) -> PredicateSchema:
     return ps
 
 
+VECTOR_ARGS = ("ivf",)
+
+
+def _parse_tokenizer_arg(cur: _Cursor, ps: PredicateSchema, tok: str):
+    """`vector(ivf)`: similar_to may answer from the quantized index.
+    `vector` alone is exact; approximation is something the schema
+    asks for."""
+    cur.next()  # lparen
+    arg = cur.expect("word")
+    if tok != "vector" or arg not in VECTOR_ARGS:
+        raise ValueError(
+            f"schema: tokenizer {tok!r} takes no argument {arg!r} "
+            f"(only vector({'|'.join(VECTOR_ARGS)}))")
+    if cur.next()[0] != "rparen":
+        raise ValueError(f"schema: {tok}({arg}: expected ')'")
+    ps.vector_arg = arg
+
+
 def _apply_directive(cur: _Cursor, ps: PredicateSchema, directive: str):
     if directive == "index":
         ps.indexed = True
@@ -211,6 +241,8 @@ def _apply_directive(cur: _Cursor, ps: PredicateSchema, directive: str):
                             f"{ps.predicate!r} of type "
                             f"{type_name(ps.value_type)}")
                     ps.tokenizers.append(v)
+                    if cur.peek()[0] == "lparen":
+                        _parse_tokenizer_arg(cur, ps, v)
                 elif k != "comma":
                     raise ValueError(f"schema: bad index arg {v!r}")
             cur.next()  # rparen

@@ -10,32 +10,47 @@ Design follows the two retrieved papers (PAPERS.md):
   A Faster Generalized Two-Stage Approximate Top-K (2506.04165) —
     replace the O(n log n)-ish exact top-k with: (1) partial reduce —
     split the n axis into `nb` buckets and take each bucket's top-L
-    candidates with a cheap max/argmax (L small); (2) exact
-    jax.lax.top_k over the nb*L surviving candidates. For a random
-    corpus permutation the expected recall@k is
-        E[recall] >= 1 - (k-1) / (2 * nb)          (L = 1)
-    so the bucket count is chosen from the recall target and the
-    kernel FALLS BACK to exact top-k whenever the corpus cannot
-    sustain nb >= (k-1) / (2 * (1 - target)).
+    candidates with a cheap max/argmax (L small); (2) an exact sort
+    of the nb*L surviving candidates.
+
+EVERY tier here is EXACT: the k rows of greatest score, ordered by
+(-score, row index), and row index order is uid order. The two-stage
+reduce is the device's fast path, not an approximation: its result is
+PROVED on the device (one more pass over the score row counts the
+rows that rank at or before the k-th result: exactly k means the
+result is the top-k), and a query whose proof fails, because one
+bucket held more than L of the true top-k, is answered by
+`lax.top_k` over the full row in the same call. L is chosen so that
+a random corpus order fails the proof less than once in a thousand
+queries (`plan_two_stage`); what a failure costs is time, never an
+answer.
 
 Three tiers, matching the repo's conventions:
   host    — numpy exact (float64 accumulate) for small/dirty data;
-  device  — jitted scoring + two-stage/exact lax.top_k; scoring can
-            route through a Pallas MXU tile kernel behind the existing
-            `use_pallas` opt-in convention (ops/bitgraph.py: None
-            resolves to False, callers own warmup+fallback);
+  device  — jitted scoring + proved two-stage, or lax.top_k; scoring
+            can route through a Pallas MXU tile kernel behind the
+            existing `use_pallas` opt-in convention (ops/bitgraph.py:
+            None resolves to False, callers own warmup+fallback);
   sharded — corpus rows sharded over a mesh axis via shard_map
             (parallel/dist_knn.py), per-shard top-k then a k-way merge.
 
+Approximation is another index, asked for in the schema
+(`@index(vector(ivf))`, ops/ivf.py); `@index(vector)` never
+approximates, whatever the predicate's size.
+
 Scores are "higher is better" for every metric: dot is the raw inner
 product, cosine normalizes both sides, euclidean is the NEGATED
-squared L2 distance (argmax order == nearest order).
+squared L2 distance (argmax order == nearest order). The device scores
+in float32 (precision HIGHEST) and the host in float64: on
+whole-number components up to 255 and 128 dimensions both are exact,
+so the tiers agree on ties too; on other data they agree as far as
+float32 tells two scores apart.
 """
 
 from __future__ import annotations
 
 import math
-from functools import lru_cache, partial
+from functools import partial
 
 import numpy as np
 
@@ -44,44 +59,47 @@ import jax
 METRICS = ("cosine", "dot", "euclidean")
 
 # two-stage engages only above this corpus size — below it the exact
-# top_k is already cheap and the bucket shuffle pure overhead
+# top_k is already cheap and the bucketing pure overhead
 TWO_STAGE_MIN_ROWS = 4096
-BUCKET_SIZE = 128          # n-axis bucket width (lane-aligned)
-RECALL_TARGET = 0.99
+BUCKET_SIZE = 128          # rows a bucket
+MAX_PER_BUCKET = 4         # each candidate kept costs a pass
+# the share of queries that may fail the proof and pay for lax.top_k
+# over the full row as well
+FALLBACK_BUDGET = 1e-3
+# score_host works through the corpus this many rows at a time
+HOST_BLOCK_ROWS = 1 << 16
 
 
-def expected_loss(nb: int, k: int, l_per_bucket: int) -> float:
-    """Expected fraction of the true top-k the two-stage reduce loses,
-    for a random corpus order over nb buckets keeping L candidates per
-    bucket (2506.04165 §3 collision analysis): item ranked i is lost
-    iff its bucket already holds >= L higher-ranked items, so the
-    per-item loss is ~ C(i, L)/nb^L and the mean over i < k is
-    C(k, L+1) / (k * nb^L)."""
+def fallback_probability(nb: int, k: int, l_per_bucket: int) -> float:
+    """Upper bound on the share of queries whose true top-k does NOT
+    fit into L candidates a bucket, for a random corpus order over nb
+    buckets: some bucket must hold L+1 of the k, and each of the
+    C(k, L+1) subsets shares a bucket with probability nb^-L (the
+    collision analysis of 2506.04165 §3, as a union bound)."""
     if k <= l_per_bucket:
         return 0.0
-    return math.comb(k, l_per_bucket + 1) / (k * float(nb) ** l_per_bucket)
+    return math.comb(k, l_per_bucket + 1) / float(nb) ** l_per_bucket
 
 
 def plan_two_stage(n: int, k: int,
-                   recall: float = RECALL_TARGET) -> int:
-    """Candidates-per-bucket L for the two-stage path, or 0 for exact
-    fallback. Picks the smallest L in {1, 2} whose EXPECTED loss is
-    under a quarter of the recall budget (4x margin so an empirical
-    recall assert at `recall` holds with room to spare); corpora too
-    small to bucket, or k too large for the budget, fall back to
-    exact — the acceptance contract."""
+                   budget: float = FALLBACK_BUDGET) -> int:
+    """Candidates-per-bucket L for the two-stage path, or 0 for
+    lax.top_k over the full row: the smallest L up to MAX_PER_BUCKET
+    whose expected share of failed proofs is under `budget`. Corpora
+    too small to bucket, or k too large for the bucket count, take the
+    full-row path. The answer is exact either way."""
     if n < TWO_STAGE_MIN_ROWS:
         return 0
     nb = n // BUCKET_SIZE
-    budget = (1.0 - recall) / 4.0
-    for l_per_bucket in (1, 2):
-        if expected_loss(nb, k, l_per_bucket) <= budget:
+    for l_per_bucket in range(1, MAX_PER_BUCKET + 1):
+        if k <= nb * l_per_bucket and \
+                fallback_probability(nb, k, l_per_bucket) <= budget:
             return l_per_bucket
     return 0
 
 
-def can_two_stage(n: int, k: int, recall: float = RECALL_TARGET) -> bool:
-    return plan_two_stage(n, k, recall) > 0
+def can_two_stage(n: int, k: int) -> bool:
+    return plan_two_stage(n, k) > 0
 
 
 # ---------------------------------------------------------------------------
@@ -91,44 +109,56 @@ def can_two_stage(n: int, k: int, recall: float = RECALL_TARGET) -> bool:
 
 def score_host(corpus: np.ndarray, queries: np.ndarray,
                metric: str) -> np.ndarray:
-    """(n, d) x (q, d) -> (q, n) float64 scores, higher = closer."""
-    c = np.asarray(corpus, np.float64)
+    """(n, d) x (q, d) -> (q, n) float64 scores, higher = closer.
+    The corpus is widened to float64 HOST_BLOCK_ROWS rows at a time:
+    the same arithmetic row for row as over the whole block, without
+    a float64 copy of a million-row corpus (and another for its
+    squares) on every query."""
+    if metric not in METRICS:
+        raise ValueError(f"unknown metric {metric!r}")
     q = np.atleast_2d(np.asarray(queries, np.float64))
-    if metric == "cosine":
-        cn = np.linalg.norm(c, axis=1)
-        qn = np.linalg.norm(q, axis=1)
+    corpus = np.asarray(corpus)
+    out = np.empty((len(q), len(corpus)), np.float64)
+    qn = np.linalg.norm(q, axis=1)
+    q2 = np.sum(q * q, axis=1)
+    for lo in range(0, len(corpus), HOST_BLOCK_ROWS):
+        c = np.asarray(corpus[lo:lo + HOST_BLOCK_ROWS], np.float64)
         dots = q @ c.T
-        denom = np.outer(qn, cn)
-        with np.errstate(invalid="ignore", divide="ignore"):
-            out = np.where(denom > 0, dots / np.where(denom > 0, denom, 1),
-                           0.0)
-        return out
-    if metric == "dot":
-        return q @ c.T
-    if metric == "euclidean":
-        c2 = np.sum(c * c, axis=1)
-        q2 = np.sum(q * q, axis=1)
-        return -(q2[:, None] - 2.0 * (q @ c.T) + c2[None, :])
-    raise ValueError(f"unknown metric {metric!r}")
+        if metric == "cosine":
+            denom = np.outer(qn, np.linalg.norm(c, axis=1))
+            with np.errstate(invalid="ignore", divide="ignore"):
+                dots = np.where(
+                    denom > 0, dots / np.where(denom > 0, denom, 1), 0.0)
+        elif metric == "euclidean":
+            c2 = np.sum(c * c, axis=1)
+            dots = -(q2[:, None] - 2.0 * dots + c2[None, :])
+        out[:, lo:lo + HOST_BLOCK_ROWS] = dots
+    return out
 
 
 def _topk_rows(scores: np.ndarray, k: int
                ) -> tuple[np.ndarray, np.ndarray]:
     """Per-row exact top-k with (-score, idx) order over a (q, n)
-    float matrix that may contain -inf for masked rows."""
+    float matrix that may contain -inf for masked rows. Ties ACROSS
+    the k-th place go to the lower idx too: every row that scores the
+    k-th value is a candidate, not the ones a partition happened to
+    leave in front."""
     q, n = scores.shape
     k_eff = min(k, n)
+    idx = np.empty((q, k_eff), np.int64)
+    sc = np.empty((q, k_eff), scores.dtype)
     if k_eff == 0:
-        return (np.empty((q, 0), np.int64), np.empty((q, 0), scores.dtype))
-    if k_eff < n:
-        part = np.argpartition(-scores, k_eff - 1, axis=1)[:, :k_eff]
-    else:
-        part = np.tile(np.arange(n), (q, 1))
-    psc = np.take_along_axis(scores, part, axis=1)
-    order = np.lexsort((part, -psc), axis=1)
-    idx = np.take_along_axis(part, order, axis=1)
-    sc = np.take_along_axis(psc, order, axis=1)
-    return idx.astype(np.int64), sc
+        return idx, sc
+    for r in range(q):
+        row = scores[r]
+        if k_eff < n:
+            kth = np.partition(row, n - k_eff)[n - k_eff]
+            cand = np.flatnonzero(row >= kth)
+        else:
+            cand = np.arange(n)
+        cand = cand[np.lexsort((cand, -row[cand]))[:k_eff]]
+        idx[r], sc[r] = cand, row[cand]
+    return idx, sc
 
 
 def topk_host(corpus: np.ndarray, queries: np.ndarray, k: int,
@@ -188,59 +218,48 @@ def _score_device(corpus, queries, metric: str, use_pallas: bool,
     raise ValueError(f"unknown metric {metric!r}")
 
 
-@lru_cache(maxsize=64)
-def _dispersal_perm(n_pad: int) -> np.ndarray:
-    """Deterministic row-dispersal permutation for the two-stage
-    bucketing. The recall bound assumes rows land in buckets at
-    random, but the scored block is packed uid-ASCENDING — near-
-    duplicate embeddings ingested under consecutive uids would share
-    one bucket and break the bound. A multiplicative stride coprime
-    with n_pad (golden-ratio start) sends any run of consecutive rows
-    to positions `stride` apart, i.e. distinct buckets, restoring the
-    TPU-KNN precondition without an RNG (stable across processes)."""
-    stride = (int(0.6180339887 * n_pad) | 1) or 1
-    while math.gcd(stride, n_pad) != 1:
-        stride += 2
-    # original row j lands at permuted slot (j * stride) % n_pad — the
-    # golden stride's three-distance spreading is what disperses runs.
-    # As a GATHER (slot i reads original perm[i]) that is the modular
-    # inverse; perm doubles as the slot -> original index map.
-    inv = pow(stride, -1, n_pad)
-    return ((np.arange(n_pad, dtype=np.int64) * inv) % n_pad
-            ).astype(np.int32)
-
-
 def _two_stage_topk_dev(scores, k: int, l_per_bucket: int):
-    """Bucketed approximate-then-exact top-k on device. scores is
-    (q, n_pad) with -inf in padded/masked columns; returns (vals, idx)
-    over the padded axis."""
+    """Bucketed top-k on device, proved exact. scores is (q, n_pad)
+    with -inf in padded/masked columns; -> (vals, idx, proved): the k
+    best of the bucket candidates by (-score, row), and whether they
+    ARE the top-k of the whole row, for every query of the batch.
+
+    Bucket j holds rows j, j + nb, j + 2 nb, ...: a reshape, no
+    gather, and rows ingested under consecutive uids (near-duplicate
+    embeddings) land in different buckets."""
     import jax.numpy as jnp
 
     qn, n_pad = scores.shape
     nb = n_pad // BUCKET_SIZE
-    # disperse uid-contiguous rows across buckets (see _dispersal_perm)
-    perm = jnp.asarray(_dispersal_perm(n_pad))
-    scores = scores[:, perm]
-    bucketed = scores.reshape(qn, nb, BUCKET_SIZE)
-    # stage 1: partial reduce — top-L inside each bucket (L=1 is a
-    # plain max+argmax, the TPU-KNN PartialReduce)
-    if l_per_bucket == 1:
-        bvals = jnp.max(bucketed, axis=2)                     # (q, nb)
-        barg = jnp.argmax(bucketed, axis=2)                   # (q, nb)
-        cand_vals = bvals
-        cand_idx = barg + jnp.arange(nb, dtype=jnp.int32)[None, :] \
-            * BUCKET_SIZE
-    else:
-        bvals, barg = jax.lax.top_k(bucketed, l_per_bucket)   # (q, nb, L)
-        base = (jnp.arange(nb, dtype=jnp.int32) * BUCKET_SIZE)[None, :,
-                                                               None]
-        cand_vals = bvals.reshape(qn, nb * l_per_bucket)
-        cand_idx = (barg + base).reshape(qn, nb * l_per_bucket)
-    # stage 2: exact top-k over the nb*L candidates, mapped back to
-    # the unpermuted row axis
-    vals, pos = jax.lax.top_k(cand_vals, min(k, cand_vals.shape[1]))
-    idx = jnp.take_along_axis(cand_idx, pos, axis=1)
-    return vals, perm[idx]
+    left = scores.reshape(qn, BUCKET_SIZE, nb)
+    depth = jnp.arange(BUCKET_SIZE, dtype=jnp.int32)[None, :, None]
+    lane = jnp.arange(nb, dtype=jnp.int32)[None, :]
+    cand_vals, cand_idx = [], []
+    # stage 1: partial reduce — the L best of each bucket, one max and
+    # argmax a candidate (the TPU-KNN PartialReduce). argmax takes the
+    # first of equal scores: the lowest row of the bucket
+    for i in range(l_per_bucket):
+        best = jnp.argmax(left, axis=1).astype(jnp.int32)     # (q, nb)
+        cand_vals.append(jnp.max(left, axis=1))
+        cand_idx.append(best * nb + lane)
+        if i + 1 < l_per_bucket:
+            left = jnp.where(depth == best[:, None, :], -jnp.inf, left)
+    # stage 2: the candidates in (-score, row) order
+    neg, idx = jax.lax.sort(
+        (-jnp.concatenate(cand_vals, axis=1),
+         jnp.concatenate(cand_idx, axis=1)), dimension=1, num_keys=2)
+    vals, idx = -neg[:, :k], idx[:, :k]
+    # the proof: the k-th result is a live row (finite, so all k are:
+    # k distinct rows, each with its own score) and exactly k rows of
+    # the whole row rank at or before it in (-score, row) order. With
+    # fewer than k live rows the k-th value is -inf (an exhausted
+    # bucket emits (-inf, lane), lane live or not) and nothing is
+    # proved: the full row answers
+    v_k, i_k = vals[:, -1:], idx[:, -1:]
+    col = jnp.arange(n_pad, dtype=jnp.int32)[None, :]
+    ahead = jnp.sum((scores > v_k) | ((scores == v_k) & (col <= i_k)),
+                    axis=1)
+    return vals, idx, jnp.all((ahead == k) & jnp.isfinite(v_k[:, 0]))
 
 
 @partial(jax.jit,
@@ -248,6 +267,9 @@ def _two_stage_topk_dev(scores, k: int, l_per_bucket: int):
                           "use_pallas", "pallas_interpret", "n_real"))
 def _topk_device_jit(corpus, queries, mask, k, metric, two_stage,
                      l_per_bucket, use_pallas, pallas_interpret, n_real):
+    """-> (vals, idx, fell_back): the exact top-k by (-score, row)
+    (lax.top_k keeps the lower index first among equal scores), and
+    whether a failed two-stage proof sent the batch to the full row."""
     import jax.numpy as jnp
 
     scores = _score_device(corpus, queries, metric, use_pallas,
@@ -258,9 +280,19 @@ def _topk_device_jit(corpus, queries, mask, k, metric, two_stage,
     if mask is not None:
         invalid = invalid | ~mask[None, :]
     scores = jnp.where(invalid, -jnp.inf, scores)
-    if two_stage:
-        return _two_stage_topk_dev(scores, k, l_per_bucket)
-    return jax.lax.top_k(scores, min(k, n_pad))
+    k = min(k, n_pad)
+    if not two_stage:
+        vals, idx = jax.lax.top_k(scores, k)
+        return vals, idx, jnp.bool_(False)
+    vals, idx, proved = _two_stage_topk_dev(scores, k, l_per_bucket)
+    vals, idx = jax.lax.cond(
+        proved, lambda: (vals, idx),
+        lambda: tuple(jax.lax.top_k(scores, k)))
+    return vals, idx, ~proved
+
+
+# what a device profile calls the one program topk_device dispatches
+DEVICE_PROGRAM = "jit_" + _topk_device_jit.__name__
 
 
 def pad_rows(corpus: np.ndarray, unit: int = BUCKET_SIZE) -> np.ndarray:
@@ -282,27 +314,36 @@ def topk_device(corpus_dev, queries: np.ndarray, k: int,
                 l_per_bucket: int | None = None,
                 use_pallas: bool | None = None,
                 pallas_interpret: bool = False,
-                n_real: int | None = None
+                n_real: int | None = None,
+                sync=None, info: dict | None = None
                 ) -> tuple[np.ndarray, np.ndarray]:
-    """Device top-k over a (possibly already device-resident) corpus.
-    Returns host (idx (q, k'), scores (q, k')) — idx into the corpus
-    row axis; rows masked out / padded return -inf scores.
+    """Exact device top-k over a (possibly already device-resident)
+    corpus. Returns host (idx (q, k'), scores (q, k')) ordered by
+    (-score, row) like topk_host — idx into the corpus row axis; rows
+    masked out / padded return -inf scores.
 
     `n_real` marks a corpus whose trailing rows are zero padding
     (pad_rows): only the first n_real rows are live. Hot-path callers
     should pre-pad their cached block so no per-query device copy
     happens here.
 
-    two_stage=None auto-selects the bucketed approximate path when the
-    corpus can hold the RECALL_TARGET bound and falls back to exact
-    lax.top_k otherwise (the acceptance contract). use_pallas follows
-    the repo convention: None resolves to False (ops/bitgraph.py)."""
+    two_stage=None takes the proved two-stage reduce where
+    plan_two_stage finds an L for it and lax.top_k over the full row
+    otherwise; two_stage=True with an explicit l_per_bucket forces the
+    reduce at that L (a test's way to a failing proof). use_pallas
+    follows the repo convention: None resolves to False
+    (ops/bitgraph.py). `sync` is applied to the dispatched result
+    before it is fetched (query/devicecall.py's `dc.wait`); `info`
+    receives `exact_fallback` (a two-stage proof failed and the full
+    row was searched as well)."""
     import jax.numpy as jnp
 
     corpus_dev = jnp.asarray(corpus_dev, jnp.float32)
     n_rows, d = corpus_dev.shape
     n = n_rows if n_real is None else int(n_real)
-    q = jnp.atleast_2d(jnp.asarray(queries, jnp.float32))
+    # host arrays ride the jitted call: no upload (and no program) of
+    # their own, each one more turn in the interpreter
+    q = np.atleast_2d(np.asarray(queries, np.float32))
     if use_pallas is None:
         use_pallas = False
     # pad the n axis so buckets tile exactly (and pallas tiles align —
@@ -317,27 +358,29 @@ def topk_device(corpus_dev, queries: np.ndarray, k: int,
         corpus_dev = jnp.concatenate(
             [corpus_dev, jnp.zeros((n_pad - n_rows, d), jnp.float32)])
     plan = plan_two_stage(n, k)
+    forced = bool(two_stage) and l_per_bucket is not None \
+        and k <= (n_pad // BUCKET_SIZE) * l_per_bucket
     if two_stage is None:
         two_stage = plan > 0
-    elif two_stage and plan == 0:
-        two_stage = False  # contract: fall back to exact when the
-        #                    bucket count can't hold the recall target
+    elif two_stage and plan == 0 and not forced:
+        two_stage = False  # too few buckets for this k: the full row
     if l_per_bucket is None:
         l_per_bucket = max(plan, 1)
-    mask_dev = None
+    mask_pad = None
     if mask is not None:
-        m = np.zeros(n_pad, bool)
-        m[:n] = np.asarray(mask, bool)
-        mask_dev = jnp.asarray(m)
-    vals, idx = _topk_device_jit(
-        corpus_dev, q, mask_dev, int(k), str(metric), bool(two_stage),
+        mask_pad = np.zeros(n_pad, bool)
+        mask_pad[:n] = np.asarray(mask, bool)
+    out = _topk_device_jit(
+        corpus_dev, q, mask_pad, int(k), str(metric), bool(two_stage),
         int(l_per_bucket), bool(use_pallas), bool(pallas_interpret),
         int(n))
-    vals = np.asarray(vals)
-    idx = np.asarray(idx, np.int64)
-    # deterministic tiebreak to match the host tier: lax.top_k is
-    # stable by index already (ties keep the lower index first)
-    return idx, vals
+    if sync is not None:
+        out = sync(out)
+    # one fetch of the three results, not three
+    vals, idx, fell_back = jax.device_get(out)
+    if info is not None:
+        info["exact_fallback"] = bool(fell_back)
+    return idx.astype(np.int64), vals
 
 
 # ---------------------------------------------------------------------------

@@ -50,11 +50,15 @@ def sharded_topk(mesh: Mesh, corpus_dev, queries: np.ndarray, k: int,
                  metric: str = "cosine",
                  mask: np.ndarray | None = None,
                  n_real: int | None = None,
-                 axis: str = "uid") -> tuple[np.ndarray, np.ndarray]:
+                 axis: str = "uid", sync=None
+                 ) -> tuple[np.ndarray, np.ndarray]:
     """Per-shard top-k + on-device merge. corpus_dev is the padded,
     sharded block from shard_corpus; returns host (idx (q, k'), scores
     (q, k')) with idx into the UNPADDED row axis (entries whose score
-    is -inf are padding and must be dropped by the caller)."""
+    is -inf are padding and must be dropped by the caller). Exact,
+    ordered by (-score, row): lax.top_k keeps the lower index first
+    and the gather is in shard order. `sync` is applied to the
+    dispatched result before it is fetched (`device_call.wait`)."""
     n_pad, d = corpus_dev.shape
     s = _axis_size(mesh, axis)
     per = n_pad // s
@@ -67,7 +71,8 @@ def sharded_topk(mesh: Mesh, corpus_dev, queries: np.ndarray, k: int,
                               NamedSharding(mesh, P(axis)))
     k_eff = min(k, per)
     fn = _sharded_step(mesh, axis, per, k, k_eff, metric)
-    vals, idx = fn(corpus_dev, q, mask_dev)
+    out = fn(corpus_dev, q, mask_dev)
+    vals, idx = out if sync is None else sync(out)
     return np.asarray(idx, np.int64), np.asarray(vals)
 
 

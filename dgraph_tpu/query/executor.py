@@ -21,6 +21,7 @@ against each other.
 from __future__ import annotations
 
 import re as _re
+import time as _time
 from collections.abc import Mapping
 from dataclasses import dataclass, field
 from typing import Any, Optional
@@ -990,6 +991,19 @@ class Executor:
         from dgraph_tpu.storage.tabstats import dirty_ops
         return dirty_ops(tab)
 
+    def _posting_est(self, tab, token: bytes) -> Optional[dict]:
+        """EXPLAIN-shaped row count of ONE token's posting list on a
+        clean tablet: the length of the base index's own array, exact
+        at any read_ts the base serves. None on dirty or historical
+        reads and on proxies without an index (callers keep the
+        histogram's guess)."""
+        index = getattr(tab, "index", None)
+        if index is None or tab.dirty() or self.read_ts < tab.base_ts:
+            return None
+        n = len(index.get(token, _EMPTY))
+        return {"estRows": n, "estRowsMax": n, "basis": "exact",
+                "source": "posting length"}
+
     def _token_est(self, tab, n_tokens: int) -> dict:
         """EXPLAIN-shaped row estimate for an n-token index probe:
         per-token quantile from the tabstats posting-length histogram
@@ -1233,22 +1247,36 @@ class Executor:
         raise GQLError(f"function {name!r} not supported")
 
     def _eval_similar_to(self, fn: Function, candidates) -> np.ndarray:
-        with _span("similar_to", pred=fn.attr) as sp:
-            return self._eval_similar_to_inner(fn, candidates, sp)
+        t0 = _time.perf_counter_ns()
+        try:
+            with _span("similar_to", pred=fn.attr) as sp:
+                return self._eval_similar_to_inner(fn, candidates, sp)
+        finally:
+            # the span's own time as a counter: less
+            # device_call_ns_total{family="similar"} it is what a
+            # similar_to costs off the chip
+            inc_counter("similar_ns_total",
+                        _time.perf_counter_ns() - t0)
 
     def _eval_similar_to_inner(self, fn: Function, candidates,
                                sp: Optional[dict] = None) -> np.ndarray:
         """similar_to(embedding, k, $vec[, metric]): the k uids whose
         stored float32vector scores closest to the query vector
         (forward-port of modern Dgraph's similar_to onto the v1.1.x
-        surface). Scoring is brute-force MIPS over the predicate's
-        columnar vector block (ops/knn.py, TPU-KNN formulation):
-        device tier with the two-stage approximate top-k when the
-        block is resident-sized, mesh-sharded per-shard top-k + k-way
-        merge above shard_min_edges, exact numpy otherwise. MVCC
-        overlay rows are scored host-side and merged, so reads at any
-        ts see exactly their snapshot. Scores land in the
-        `similar_to_score` value variable (val(similar_to_score))."""
+        surface). Under `@index(vector)` every tier gives ONE answer,
+        the k rows of greatest score ordered by (-score, uid):
+        brute-force scoring over the predicate's columnar vector block
+        (ops/knn.py, TPU-KNN formulation) on the device, where the
+        two-stage top-k is proved exact in the call or answered by the
+        full row (span attribute `exact_fallback`); mesh-sharded
+        per-shard top-k + k-way merge above shard_min_edges; exact
+        numpy otherwise. The quantized IVF tier answers only where the
+        schema asks for approximation (`@index(vector(ivf))`), never
+        because the predicate grew. MVCC overlay rows are scored
+        host-side and merged, so reads at any ts see exactly their
+        snapshot. Scores land in the `similar_to_score` value variable
+        (val(similar_to_score)). The span gains `k`, `rows` (the
+        block's), `candidates` (rows the mask leaves)."""
         from dgraph_tpu.models.types import parse_vector
         from dgraph_tpu.ops import knn as _knn
 
@@ -1321,9 +1349,15 @@ class Executor:
             ex_uids, ex_vecs = ex_uids[exm], ex_vecs[exm]
         parts: list = []
         n = len(view.base_uids)
-        if n and base_mask.any():
+        n_cand = int(base_mask.sum())
+        if sp is not None:
+            sp.update(k=int(k), rows=int(n), candidates=n_cand,
+                      exact_fallback=0)
+        if n_cand:
             qm = qvec[None, :]
-            # quantized eligibility: a trained index for the CURRENT
+            # quantized eligibility: a schema that asks for it
+            # (`@index(vector(ivf))`: size alone never makes an answer
+            # approximate), a trained index for the CURRENT
             # base state, root context (a filter's candidate subset
             # can defeat the probe's recall budget — candidates keep
             # the exact tiers), and k within the calibrated regime.
@@ -1331,6 +1365,7 @@ class Executor:
             ivf = tab.vector_ivf() \
                 if hasattr(tab, "vector_ivf") else None
             quant_ok = (ivf is not None and self.db.vec_quantized
+                        and schema.vector_approx
                         and candidates is None
                         and k <= self.db.vec_max_k)
             # tier arbitration: the planner weighs the measured
@@ -1407,15 +1442,29 @@ class Executor:
                     # where the planner never looks
                     sp["n"] = int(scanned)
             elif use_device:
-                idx, sc = _knn.topk_device(
-                    self._device_vec_block(tab, view), qm, k, metric,
-                    mask=base_mask, n_real=n)
-                inc_counter("query_similar_device_total")
+                from dgraph_tpu.engine.device_cache import \
+                    device_vector_block
+                # a counted, evictable tile (engine/device_cache.py)
+                block = device_vector_block(self.db, tab,
+                                            view.base_vecs)
+                info: dict = {}
+                with device_call("query_device_similar_total",
+                                 sink=self.lat,
+                                 program=_knn.DEVICE_PROGRAM) as dc:
+                    idx, sc = _knn.topk_device(
+                        block, qm, k, metric, mask=base_mask, n_real=n,
+                        sync=dc.wait, info=info)
+                # every call ships a mask today, the root's all-true
+                # one too
+                inc_counter("similar_masked_total")
+                if info["exact_fallback"]:
+                    inc_counter("similar_exact_fallback_total")
                 vdec["tier"] = "two_stage" \
                     if _knn.plan_two_stage(n, k) > 0 else "exact"
                 if sp is not None:
                     sp["tier"] = "device"
                     sp["n"] = int(n)
+                    sp["exact_fallback"] = int(info["exact_fallback"])
             else:
                 idx, sc = _knn.topk_host(view.base_vecs, qm, k,
                                          metric, mask=base_mask)
@@ -1441,22 +1490,6 @@ class Executor:
             # root: the block emits nearest-first (_similar_paginate)
             self._similar_order = [int(u) for u in uids.tolist()]
         return np.sort(uids.astype(np.uint64))
-
-    def _device_vec_block(self, tab, view):
-        """The base vector block as a device array, cached per base_ts
-        exactly like the adjacency tiles (_device_adj). Pre-padded to
-        the bucket unit HOST-SIDE so topk_device never re-copies the
-        block per query."""
-        from dgraph_tpu.ops import knn as _knn
-
-        cached = getattr(tab, "_device_vecs", None)
-        if cached is not None and cached[0] == tab.base_ts:
-            return cached[1]
-        import jax.numpy as jnp
-
-        arr = jnp.asarray(_knn.pad_rows(view.base_vecs))
-        tab._device_vecs = (tab.base_ts, arr)
-        return arr
 
     def _vec_rerank(self, k: int) -> int:
         """Effective exact re-rank depth for the quantized tier."""
@@ -1505,9 +1538,12 @@ class Executor:
         else:
             block, n_real = shard_corpus(mesh, view.base_vecs)
             tab._device_vecs_sharded = (tab.base_ts, block, n_real)
-        inc_counter("query_similar_sharded_total")
-        return sharded_topk(mesh, block, qm, k, metric,
-                            mask=base_mask, n_real=n_real)
+        with device_call("query_device_similar_sharded_total",
+                         sink=self.lat,
+                         program="sharded_topk") as dc:
+            return sharded_topk(mesh, block, qm, k, metric,
+                                mask=base_mask, n_real=n_real,
+                                sync=dc.wait)
 
     def _eval_geo(self, fn: Function, candidates) -> np.ndarray:
         """near/within/contains/intersects: geo-cell index prefilter +
@@ -1679,12 +1715,33 @@ class Executor:
                 all_toks, no_tok_vals = _analyze()
             dec = None
             if all_toks and self._adaptive:
+                # one token's posting length is known before the tier
+                # is chosen, so the decision is made (and cached) for
+                # THIS value's size: `eq(category, $c)` over values of
+                # 1,700 and 103,000 rows is two decisions, not one
+                # estimate that every other request violates
+                from dgraph_tpu.query.planner import _bucket
+                est = self._posting_est(tab, all_toks[0]) \
+                    if len(all_toks) == 1 else None
+                size = () if est is None else (_bucket(est["estRows"]),)
+                tiers = self._index_tiers(tab)
+                if est is not None:
+                    # ... and that one posting is a slice of the CSR
+                    # (or of the base index): there is no set
+                    # operation whose blocks the packs could skip,
+                    # only a decode of the whole list (0.7 ms for
+                    # 21,000 uids against 1 us, and 5 ms where eight
+                    # threads share the interpreter), so the packs are
+                    # not a tier of this stage and the span's noisy
+                    # wall time cannot drift a decision onto them
+                    tiers = tuple(t for t in tiers if t != "compressed")
                 dec = self._routed(
-                    ("eq", tab.pred, len(all_toks)),
+                    ("eq", tab.pred, len(all_toks)) + size,
                     lambda: self._tier_decision(
                         "eq", tab.pred,
-                        self._token_est(tab, len(all_toks)),
-                        self._index_tiers(tab)))
+                        est if est is not None
+                        else self._token_est(tab, len(all_toks)),
+                        tiers))
                 if dec is not None and candidates is not None \
                         and not no_tok_vals \
                         and self.db.planner_impl.probe_or_scan(

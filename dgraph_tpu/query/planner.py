@@ -314,7 +314,8 @@ class AdaptivePlanner:
             learned = self._learned.get(k)
         est_rows = max(0, int(est.get("estRows", -1)))
         est_basis = str(est.get("basis", "unknown"))
-        if learned is not None:
+        if learned is not None and est_basis != "exact":
+            # a count the caller KNOWS is not a guess to correct
             est_rows = int(learned)
             est_basis = "learned"
         bucket = _bucket(est_rows)

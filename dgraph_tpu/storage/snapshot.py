@@ -52,7 +52,9 @@ def _pack_values(values: dict) -> dict:
     the single largest line item of writing a snapshot, so values
     persist columnar like every other plane. Column order is the
     values-dict walk order — deterministic, and inverted exactly by
-    _unpack_values."""
+    _unpack_values. A vector tablet's payloads (float32 arrays of one
+    length) go as ONE (n, d) block, not a million small arrays:
+    _unpack_values walks its rows as it walks a list."""
     import numpy as np
     srcs: list[int] = []
     tids = bytearray()
@@ -70,6 +72,10 @@ def _pack_values(values: dict) -> dict:
             if p.facets:
                 facets.append((i, p.facets))
             i += 1
+    if pays and all(
+            isinstance(v, np.ndarray) and v.dtype == np.float32
+            and v.shape == pays[0].shape and v.ndim == 1 for v in pays):
+        pays = np.stack(pays)
     return {"src": np.asarray(srcs, np.uint64), "tid": bytes(tids),
             "pay": pays, "lang": langs, "facets": facets}
 
@@ -226,16 +232,24 @@ def save_snapshot(db, path: str):
     mtime=0 so identical state produces identical FILE BYTES — the
     determinism contract distributed ingest's retried reduce shards
     are checked against (ingest/distributed.py)."""
+    import numpy as np
+
     payload = dump_state(db)
     tmp = path + ".tmp"
     from dgraph_tpu import wire
     # compresslevel=6: level 9 costs ~7x the CPU of 6 for ~1% smaller
     # output on wire-encoded tablet payloads — at bulk-ingest scale
     # the snapshot encode IS the reduce tail, so the default-9 write
-    # was the single largest line item of a shard's wall clock
+    # was the single largest line item of a shard's wall clock.
+    # A payload that holds a vector block (_pack_values) is mostly
+    # float32 rows: level 1 writes them 6x faster for a file a sixth
+    # larger (50 s of a 1M x 128 bulk load's 180)
+    level = 1 if any(
+        isinstance(t["values_pk"]["pay"], np.ndarray)
+        for t in payload["tablets"].values()) else 6
     with open(tmp, "wb") as raw, \
             gzip.GzipFile(filename="", fileobj=raw, mode="wb",
-                          mtime=0, compresslevel=6) as f:
+                          mtime=0, compresslevel=level) as f:
         f.write(SNAPSHOT_MAGIC)
         f.write(wire.dumps(payload))
     os.replace(tmp, path)

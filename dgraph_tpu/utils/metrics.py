@@ -55,6 +55,8 @@ REGISTERED = (
     # prefix as a count of dispatches
     "device_call_ns_total",
     "device_dispatch_seconds",
+    # engine/device_cache.py: the resident vector block's bytes
+    "device_vector_block_bytes",
     "dgraph_num_edges_total",
     "dgraph_num_mutations_total",
     "dgraph_num_queries_total",
@@ -100,6 +102,8 @@ REGISTERED = (
     "query_device_overlay_expand_total",
     "query_device_range_total",
     "query_device_setops_total",
+    "query_device_similar_sharded_total",
+    "query_device_similar_total",
     "query_device_sort_page_total",
     "query_device_sssp_total",
     "query_flat_json_total",
@@ -110,9 +114,11 @@ REGISTERED = (
     "query_postings_fallback_total",
     "query_regexp_batch_total",
     "query_sharded_expand_total",
-    "query_similar_device_total",
     "query_similar_quantized_total",
     "query_similar_sharded_total",
+    "similar_exact_fallback_total",
+    "similar_masked_total",
+    "similar_ns_total",
     # quantized vector index (ops/ivf.py, storage/vecstore.py)
     "vector_index_builds_total",
     "vector_index_bytes",

@@ -161,7 +161,7 @@ def test_explain_vector_tier_decisions():
     vecs = C[rng.integers(0, 16, 400)] + np.float32(0.3) \
         * rng.standard_normal((400, 4)).astype(np.float32)
     d = GraphDB(prefer_device=False, vec_index_min_rows=100)
-    d.alter("embedding: float32vector @index(vector) .")
+    d.alter("embedding: float32vector @index(vector(ivf)) .")
     d.mutate(set_nquads="\n".join(
         f'<0x{i + 1:x}> <embedding> "{list(map(float, vecs[i]))}"'
         '^^<xs:float32vector> .' for i in range(len(vecs))),

@@ -196,7 +196,7 @@ def _quant_db(n=500, d=4, seed=40, **kw):
     kw.setdefault("prefer_device", False)
     kw.setdefault("vec_index_min_rows", 100)
     db = GraphDB(**kw)
-    db.alter("embedding: float32vector @index(vector) .")
+    db.alter("embedding: float32vector @index(vector(ivf)) .")
     db.mutate(set_nquads=rdf, commit_now=True)
     db.rollup_all()
     return db
@@ -693,7 +693,7 @@ def test_similar_to_sharded_tier_parity():
     assert got == want
     from dgraph_tpu.utils.metrics import snapshot
     assert snapshot()["counters"].get(
-        "query_similar_sharded_total", 0) >= 1
+        "query_device_similar_sharded_total", 0) >= 1
 
 
 def test_similar_to_json_mutation_and_bulk():

@@ -1,0 +1,472 @@
+"""similar_to gives ONE answer (PR 26): the device tier, the host
+tier and the benchmark's plain reference agree in set AND order, ties
+included; the dispatch runs inside `device.call`; the vector block is
+a counted, evictable tile; approximation is the schema's to ask for;
+and the SIFT-shaped dataset of the benchmark is reproducible."""
+
+import importlib.util
+import io
+import json
+import os
+
+import numpy as np
+import pytest
+
+from dgraph_tpu.engine.db import GraphDB
+from dgraph_tpu.models.schema import parse_schema
+from dgraph_tpu.ops import knn
+from dgraph_tpu.utils import metrics, tracing
+
+BENCH = os.path.join(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))), "benchmark")
+
+
+def _load(relpath):
+    name = "knn_exact_" + os.path.basename(relpath).removesuffix(".py")
+    spec = importlib.util.spec_from_file_location(
+        name, os.path.join(BENCH, relpath))
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+@pytest.fixture(scope="module")
+def sift():
+    return _load("datasets/sift.py")
+
+
+@pytest.fixture(scope="module")
+def plain():
+    return _load("datasets/sift_plain.py")
+
+
+@pytest.fixture(scope="module")
+def traffic():
+    return _load("traffic.py")
+
+
+# ---------------------------------------------------------------------------
+# (a) three tiers, one answer
+# ---------------------------------------------------------------------------
+
+
+def _tied_corpus(n, seed):
+    """Whole-number rows built to tie: few distinct values, the
+    query's nearest duplicated under adjacent and distant uids, and
+    more rows at the k-th distance than the k-th place has room for."""
+    rng = np.random.default_rng(seed)
+    c = rng.integers(0, 6, (n, 16)).astype(np.uint8)
+    q = rng.integers(0, 6, 16).astype(np.uint8)
+    near = q.copy()
+    near[0] += 1                      # distance 1
+    for row in (40, 41, 42, n // 2, n - 7):
+        c[row] = near
+    c[n // 3] = q                     # distance 0, once
+    far = q.copy()
+    far[1] += 2                       # distance 4: a dozen of them, so
+    for row in range(500, 512):       # k = 10 ends inside the tie
+        c[row] = far
+    return c, q
+
+
+def _plain_rows(plain, c, q, k, mask):
+    wide, norms = plain.widen(c)
+    rows, dist = plain.nearest(wide, norms, q.astype(np.float64), k,
+                               keep=mask)
+    return rows, -dist.astype(np.float64)
+
+
+@pytest.mark.parametrize("masked", [False, True], ids=["all", "masked"])
+@pytest.mark.parametrize("k", [10, 100])
+@pytest.mark.parametrize("n", [8192, 20000])
+def test_tiers_agree_in_set_and_order_on_ties(plain, n, k, masked):
+    c, q = _tied_corpus(n, seed=n + k)
+    mask = None
+    if masked:
+        mask = np.random.default_rng(5).random(n) < 0.4
+        mask[[40, 41, n // 2, 505]] = True
+    cf, qf = c.astype(np.float32), q.astype(np.float32)[None]
+    want_i, want_s = _plain_rows(plain, c, q, k, mask)
+    hi, hs = knn.topk_host(cf, qf, k, "euclidean", mask=mask)
+    assert np.array_equal(hi[0], want_i)
+    assert np.array_equal(hs[0], want_s)
+    # the default plan, the proved two-stage forced at L 2 and 3, and
+    # lax.top_k over the full row
+    for two_stage, l_per in ((None, None), (True, 2), (True, 3),
+                             (False, None)):
+        di, ds = knn.topk_device(cf, qf, k, "euclidean", mask=mask,
+                                 two_stage=two_stage,
+                                 l_per_bucket=l_per)
+        assert np.array_equal(di[0], want_i), (two_stage, l_per)
+        assert np.array_equal(ds[0], want_s), (two_stage, l_per)
+    assert knn.plan_two_stage(n, 10) > 0   # two-stage is engaged
+
+
+def test_a_bucket_with_too_many_neighbours_takes_the_exact_path(plain):
+    """Rows j and j + nb share a bucket: with L = 1 the second of two
+    nearest neighbours there cannot be a candidate, the proof fails,
+    and the same call answers from the full row."""
+    n, k = 8192, 4
+    nb = n // knn.BUCKET_SIZE
+    rng = np.random.default_rng(3)
+    c = rng.integers(0, 200, (n, 8)).astype(np.uint8)
+    q = rng.integers(50, 150, 8).astype(np.uint8)
+    c[5], c[5 + nb], c[5 + 3 * nb] = q, q, q
+    cf, qf = c.astype(np.float32), q.astype(np.float32)[None]
+    info = {}
+    di, ds = knn.topk_device(cf, qf, k, "euclidean", two_stage=True,
+                             l_per_bucket=1, info=info)
+    assert info["exact_fallback"] is True
+    want_i, want_s = _plain_rows(plain, c, q, k, None)
+    assert want_i[:3].tolist() == [5, 5 + nb, 5 + 3 * nb]
+    assert np.array_equal(di[0], want_i)
+    assert np.array_equal(ds[0], want_s)
+    # enough candidates a bucket and the proof holds
+    info = {}
+    di, _ = knn.topk_device(cf, qf, k, "euclidean", two_stage=True,
+                            l_per_bucket=3, info=info)
+    assert info["exact_fallback"] is False
+    assert np.array_equal(di[0], want_i)
+
+
+@pytest.mark.parametrize("metric", list(knn.METRICS))
+@pytest.mark.parametrize("k", [10, 100])
+def test_tiers_agree_on_the_set_on_gaussian_floats(metric, k):
+    rng = np.random.default_rng(11)
+    c = rng.standard_normal((20000, 32), dtype=np.float32)
+    q = c[[7, 9000]] + 0.05 * rng.standard_normal((2, 32),
+                                                  dtype=np.float32)
+    hi, _ = knn.topk_host(c, q, k, metric)
+    for two_stage in (None, False):
+        di, _ = knn.topk_device(c, q, k, metric, two_stage=two_stage)
+        for b in range(len(q)):
+            assert set(di[b].tolist()) == set(hi[b].tolist())
+
+
+_NB = 8192 // knn.BUCKET_SIZE      # bucket j holds rows j, j + _NB, ...
+
+
+@pytest.mark.parametrize("live,k", [
+    # every bucket holds at most L live rows
+    ([3, 4000, 8000], 10),
+    # bucket 5 holds four of nine live rows where L is 3, and row 0 is
+    # live: an exhausted bucket 0 emits (-inf, 0), row 0 a second time
+    ([0, _NB, 5, 5 + _NB, 5 + 2 * _NB, 5 + 3 * _NB,
+      10 + _NB, 11 + _NB, 12 + _NB], 10),
+    # all live rows in ONE bucket, more than L of them
+    ([7 + i * _NB for i in range(6)], 10),
+    # exactly k live rows, five of them in one bucket
+    ([9 + i * _NB for i in range(5)] + [1, 2, 3, 4, 6], 10),
+    # one live row; none
+    ([_NB], 10),
+    ([], 10),
+])
+def test_fewer_live_rows_than_k_come_back_whole(live, k):
+    c = np.random.default_rng(1).integers(0, 9, (8192, 8)).astype(
+        np.float32)
+    assert knn.plan_two_stage(len(c), k) == 3
+    mask = np.zeros(len(c), bool)
+    mask[live] = True
+    q = c[[0, 5 + _NB]]                # a batch: one proof for both
+    hi, hs = knn.topk_host(c, q, k, "euclidean", mask=mask)
+    di, ds = knn.topk_device(c, q, k, "euclidean", mask=mask)
+    for r in range(len(q)):
+        got = np.isfinite(ds[r])
+        assert sorted(hi[r].tolist()) == sorted(live)[:k]
+        assert di[r][got].tolist() == hi[r].tolist()
+        assert np.array_equal(ds[r][got], hs[r])
+
+
+def test_the_plan_keeps_failed_proofs_rare_or_takes_the_full_row():
+    for n, k in ((8192, 10), (20000, 10), (100_000, 100),
+                 (1_000_000, 10), (1_000_000, 100)):
+        l_per = knn.plan_two_stage(n, k)
+        assert 1 <= l_per <= knn.MAX_PER_BUCKET
+        assert knn.fallback_probability(
+            n // knn.BUCKET_SIZE, k, l_per) <= knn.FALLBACK_BUDGET
+    assert knn.plan_two_stage(1000, 5) == 0        # too small to bucket
+    assert knn.plan_two_stage(8192, 100) == 0      # too few buckets
+
+
+# ---------------------------------------------------------------------------
+# schema: approximation is asked for, never a matter of size
+# ---------------------------------------------------------------------------
+
+
+@pytest.mark.parametrize("text,arg,approx", [
+    ("e: float32vector @index(vector) .", "", False),
+    ("e: float32vector @index(vector(ivf)) .", "ivf", True),
+])
+def test_vector_tokenizer_argument_round_trips(text, arg, approx):
+    ps = parse_schema(text)[0][0]
+    assert ps.tokenizers == ["vector"]
+    assert (ps.vector_arg, ps.vector_approx) == (arg, approx)
+    assert ps.describe() == text
+    assert parse_schema(ps.describe())[0][0] == ps
+
+
+@pytest.mark.parametrize("text", [
+    "e: float32vector @index(vector(hnsw)) .",
+    "e: float32vector @index(vector(exact)) .",   # exact has one spelling
+    "e: float32vector @index(vector(ivf) .",
+    "n: string @index(term(ivf)) .",
+])
+def test_other_tokenizer_arguments_are_refused(text):
+    with pytest.raises(ValueError):
+        parse_schema(text)
+
+
+def _clustered_rdf(n, d=4, seed=40):
+    rng = np.random.default_rng(seed)
+    centres = rng.standard_normal((16, d)).astype(np.float32)
+    vecs = centres[rng.integers(0, 16, n)] + np.float32(0.3) \
+        * rng.standard_normal((n, d)).astype(np.float32)
+    return "\n".join(
+        f'<0x{i + 1:x}> <embedding> "{list(map(float, vecs[i]))}" .'
+        for i in range(n))
+
+
+@pytest.mark.parametrize("index,trained", [
+    ("vector", False), ("vector(ivf)", True)])
+def test_size_alone_never_trains_or_serves_the_quantized_tier(
+        index, trained):
+    db = GraphDB(prefer_device=False, vec_index_min_rows=100)
+    db.alter(f"embedding: float32vector @index({index}) .")
+    db.mutate(set_nquads=_clustered_rdf(500), commit_now=True)
+    db.rollup_all()
+    assert (db.tablets["embedding"].vector_ivf() is not None) == trained
+    q = ('{ q(func: similar_to(embedding, 3, "[1.0, 0.0, -1.0, 0.5]"))'
+         ' { uid } }')
+    tiers = db.query(q, explain="analyze")["extensions"]["explain"][
+        "tiers"]["vector"]
+    assert (tiers[0]["tier"] == "quantized") == trained
+    # an index that came with the tablet (an older snapshot's) is not
+    # consulted either, unless the schema asks
+    if not trained:
+        db.build_vector_index("embedding")
+        tiers = db.query(q, explain="analyze")["extensions"][
+            "explain"]["tiers"]["vector"]
+        assert tiers[0]["tier"] == "exact"
+
+
+# ---------------------------------------------------------------------------
+# (b) the served path: device.call, counters, the block as a tile
+# ---------------------------------------------------------------------------
+
+
+def _int_db(n=6000, d=8, seed=2, **kw):
+    rng = np.random.default_rng(seed)
+    vecs = rng.integers(0, 256, (n, d))
+    rdf = "\n".join(
+        f'<0x{i + 1:x}> <embedding> "{vecs[i].tolist()}" .\n'
+        f'<0x{i + 1:x}> <category> "{i % 5}" .' for i in range(n))
+    db = GraphDB(**kw)
+    db.alter("embedding: float32vector @index(vector) .\n"
+             "category: int @index(int) .")
+    db.mutate(set_nquads=rdf, commit_now=True)
+    db.rollup_all()
+    return db, vecs
+
+
+def _counter(name):
+    return sum(v for k, v in metrics.snapshot()["counters"].items()
+               if k.split("{")[0] == name)
+
+
+def _gauge(name):
+    return sum(v for k, v in metrics.snapshot()["gauges"].items()
+               if k.split("{")[0] == name)
+
+
+ROOT_Q = ('{ q(func: similar_to(embedding, 10, "%s", "euclidean")) '
+          '{ uid val(similar_to_score) } }')
+FILTER_Q = ('{ q(func: eq(category, 3)) @filter(similar_to(embedding, '
+            '10, "%s", "euclidean")) { uid } }')
+
+
+@pytest.mark.parametrize("template", [ROOT_Q, FILTER_Q],
+                         ids=["root", "filter"])
+def test_a_served_similar_to_runs_inside_device_call(template):
+    db, vecs = _int_db()
+    host, _ = _int_db(prefer_device=False)
+    q = template % vecs[17].tolist()
+    calls = _counter("query_device_similar_total")
+    masked = _counter("similar_masked_total")
+    spent = _counter("similar_ns_total")
+    tracing.clear()
+    res = db.query(q)
+    lat = res["extensions"]["server_latency"]
+    assert lat["device_calls"] >= 1
+    assert lat["device_wait_ns"] > 0 and lat["device_enqueue_ns"] > 0
+    assert _counter("query_device_similar_total") == calls + 1
+    assert _counter("similar_masked_total") == masked + 1
+    assert _counter("similar_ns_total") > spent
+    spans = {s["name"]: s for s in tracing.recent_spans()}
+    call, sim = spans["device.call"], spans["similar_to"]
+    assert call["args"]["family"] == "similar"
+    assert call["args"]["program"] == "jit__topk_device_jit"
+    assert call["parent_id"] == sim["span_id"]
+    assert sim["args"]["k"] == 10 and sim["args"]["rows"] == 6000
+    assert sim["args"]["exact_fallback"] == 0
+    want_candidates = 6000 if template is ROOT_Q else 1200
+    assert sim["args"]["candidates"] == want_candidates
+    # and the answer is the postings tier's, byte for byte
+    assert json.dumps(res["data"]) == json.dumps(
+        host.query(q)["data"])
+
+
+def test_the_vector_block_is_a_counted_and_evictable_tile():
+    db, vecs = _int_db(device_hbm_budget=1 << 20)
+    q = ROOT_Q % vecs[3].tolist()
+    db.query(q)
+    tab = db.tablets["embedding"]
+    block = 6016 * 8 * 4        # rows padded to the bucket unit
+    assert tab._device_vecs.nbytes == block
+    assert db.device_cache.stats()["bytes"] >= block
+    assert _gauge("device_cache_bytes") >= block
+    gauges = metrics.snapshot()["gauges"]
+    assert gauges['device_vector_block_bytes{predicate="embedding"}'] \
+        == block
+    # a second query finds the tile; nothing is uploaded again
+    first = tab._device_vecs
+    db.query(q)
+    assert tab._device_vecs is first
+    # a tile that does not fit beside it evicts the block (LRU)
+    evictions = _counter("device_cache_evictions")
+    other = db.tablets["category"]
+    db.device_cache.put(other, "_device_values", _DeviceBytes())
+    assert tab._device_vecs is None
+    assert _counter("device_cache_evictions") == evictions + 1
+    gauges = metrics.snapshot()["gauges"]
+    assert gauges['device_vector_block_bytes{predicate="embedding"}'] \
+        == 0
+    # and the next query builds it again
+    db.query(q)
+    assert tab._device_vecs is not None
+
+
+class _DeviceBytes:
+    """Counts as a megabyte of DEVICE bytes in the tile cache (which
+    tells device from host by duck type, engine/tile_cache.py)."""
+    nbytes = 1 << 20
+
+
+# ---------------------------------------------------------------------------
+# (c) the benchmark's dataset
+# ---------------------------------------------------------------------------
+
+
+def _rdf(sift, scale, seed, variant=""):
+    out = io.StringIO()
+    facts = sift.write_rdf(out, scale, seed, variant)
+    return out.getvalue(), facts
+
+
+def test_sift_same_seed_same_bytes_other_seed_other_rows(sift):
+    a, fa = _rdf(sift, 2, 7)
+    b, fb = _rdf(sift, 2, 7)
+    c, _ = _rdf(sift, 2, 8)
+    assert a == b and fa == fb
+    assert a != c
+    assert fa["rdf"] == a.count("\n") == 6000
+    assert fa["seed"] == 7 and sum(fa["category_sizes"]) == 2000
+    vecs, cats = sift.corpus(2, 7)
+    assert vecs.shape == (2000, sift.DIM) and vecs.dtype == np.uint8
+    other, _ = sift.corpus(2, 8)
+    assert (vecs != other).any(axis=1).mean() > 0.99
+    # the text is the rows: line 0 is row 0's literal
+    first = a.split("\n", 1)[0]
+    assert first.startswith('<0x1> <embedding> "[')
+    got = np.array(first.split('"')[1].strip("[]").split(), np.uint8)
+    assert np.array_equal(got, vecs[0])
+    assert "0x" not in open(os.path.join(
+        BENCH, "traffic", "queries", "knn10.gql")).read()
+
+
+def test_sift_value_range_and_category_skew(sift):
+    vecs, cats = sift.corpus(20, 1)
+    assert vecs.min() == 0 and 200 < vecs.max() <= 255
+    share = np.bincount(cats, minlength=sift.N_CATEGORIES) / len(cats)
+    assert 0.17 < share[0] < 0.25 and 0.001 < share[-1] < 0.006
+    w = sift.category_weights()
+    assert w[0] == pytest.approx(0.207, abs=0.002)
+
+
+def test_sift_traffic_file_regenerates_byte_for_byte(sift):
+    with open(os.path.join(BENCH, "traffic", "knn-mix.json")) as f:
+        text = f.read()
+    assert text == json.dumps(sift.traffic_mix(), indent=1) + "\n"
+    mix = json.loads(text)
+    assert (mix["loop"], mix["clients"], mix["bindings"]) \
+        == ("closed", 8, 32)
+    lits = mix["templates"][0]["params"]["vec"]["choice"]
+    assert lits == sift.query_literals() and len(set(lits)) == 256
+    assert mix["templates"][2]["params"]["c"] == {"int": [0, 63]}
+
+
+def test_sift_control_variant_moves_one_component_of_a_hundredth(sift):
+    sound = sift.corpus(3, 5)[0].astype(np.int16).copy()
+    bent = sift.corpus(3, 5, "off-by-one")[0].astype(np.int16)
+    delta = bent - sound
+    rows = np.flatnonzero((delta != 0).any(axis=1))
+    assert len(rows) == 30                       # 1% of 3,000
+    assert ((delta[rows] != 0).sum(axis=1) == 1).all()
+    assert set(np.unique(np.abs(delta[rows]).max(axis=1))) == {1}
+    with pytest.raises(ValueError):
+        sift.corpus(3, 5, "rounded")
+
+
+def test_sift_refuses_a_program_whose_schema_cannot_ask_for_ivf(
+        sift, tmp_path, monkeypatch):
+    """A program in which size, not the schema, chooses the quantized
+    tier cannot give this deployment's guarantee: the dataset writes
+    nothing for it (and the harness's run ends there, not in a
+    measured window of answers that differ by design)."""
+    sift.require_exact_program()                 # this program can
+    old = tmp_path / "dgraph_tpu" / "models"
+    old.mkdir(parents=True)
+    (tmp_path / "dgraph_tpu" / "__init__.py").write_text("")
+    (old / "__init__.py").write_text("")
+    (old / "schema.py").write_text(
+        "def parse_schema(text):\n"
+        "    raise ValueError(\"schema: bad index arg '('\")\n")
+    monkeypatch.setattr(sift, "PROGRAM_ROOT", str(tmp_path))
+    out = io.StringIO()
+    with pytest.raises(RuntimeError, match="bad index arg"):
+        sift.write_rdf(out, 1, 7)
+    assert out.getvalue() == ""
+    monkeypatch.undo()
+    assert _rdf(sift, 1, 7)[1]["rows"] == 1000
+
+
+# ---------------------------------------------------------------------------
+# (d) the plain reference against the program's postings tier
+# ---------------------------------------------------------------------------
+
+
+@pytest.mark.parametrize("seed", [11, 2400000011, 3100000019])
+def test_plain_reference_equals_the_postings_tier(sift, plain, traffic,
+                                                  seed, tmp_path):
+    from dgraph_tpu.ingest.bulk import bulk_load
+
+    scale = 3
+    rdf = tmp_path / "g.rdf"
+    with open(rdf, "w") as f:
+        facts = sift.write_rdf(f, scale, seed)
+    db = bulk_load([str(rdf)], schema=sift.SCHEMA,
+                   db=GraphDB(prefer_device=False))
+    mix = traffic.load_mix(os.path.join(BENCH, "traffic",
+                                        "knn-mix.json"))
+    mix["bindings"] = 5
+    pool = traffic.build_pool(mix, sift, scale, facts, seed)
+    assert {e["name"] for e in pool} == set(plain.ANSWERS)
+    assert len(pool) == 15
+    for e in pool:
+        got = json.loads(json.dumps(db.query(e["query"])["data"]))
+        want = plain.ANSWERS[e["name"]](sift, scale, facts, e["query"])
+        assert got == want, e["name"]
+        if e["name"] == "knn10_in_category":   # the smallest hold 9
+            assert 1 <= len(want["q"]) <= 10
+        else:
+            assert len(want["q"]) == (100 if e["name"] == "knn100"
+                                      else 10)

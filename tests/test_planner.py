@@ -317,6 +317,60 @@ def test_static_mode_has_no_planner():
     assert e["tierDecisions"] == []
 
 
+def test_a_known_count_is_not_overridden_by_a_learned_guess(pl):
+    plan = _plan(0x5656)
+    d1 = pl.choose(plan, "eq", "name", EST, IDX)
+    pl.record_outcome(d1, 5_000)            # the key learns 5,000
+    exact = {"estRows": 40, "estRowsMax": 40, "basis": "exact"}
+    d2 = pl.choose(plan, "eq", "name", exact, IDX)
+    assert (d2.est_basis, d2.est_rows) == ("exact", 40)
+    assert pl.choose(plan, "eq", "name", EST, IDX).est_basis == "learned"
+
+
+def test_one_tokens_eq_is_decided_for_its_own_posting_length():
+    """`eq(cat, $c)` over values whose postings differ 60-fold: one
+    decision a size bucket, exact estimates, and no request violates
+    an estimate made for another value (that flapped the tier of a
+    whole stage key: sift1m-exact.knn-mix, PERF.md PR 26)."""
+    coststore.reset()
+    db = GraphDB(prefer_device=False, planner="adaptive")
+    db.alter(schema_text="cat: int @index(int) .")
+    sizes = {0: 1200, 1: 150, 2: 18}
+    quads, uid = [], 0
+    for c, n in sizes.items():
+        for _ in range(n):
+            uid += 1
+            quads.append(f'<0x{uid:x}> <cat> "{c}" .')
+    db.mutate(set_nquads="\n".join(quads))
+    db.rollup_all()
+    before = metrics.counters_snapshot()
+    for _ in range(3):
+        for c, n in sizes.items():
+            r = db.query('{ q(func: eq(cat, %d)) { count(uid) } }' % c,
+                         explain="analyze")
+            assert r["data"]["q"][0]["count"] == n
+            (d,) = [d for d in r["extensions"]["explain"]["tierDecisions"]
+                    if d["stage"] == "eq"]
+            assert (d["estRows"], d["estBasis"]) == (n, "exact")
+            # one posting is a slice: the packs (a decode of the whole
+            # list, no block to skip) are not a tier of this stage, so
+            # a noisy span cannot drift the decision onto them
+            assert "compressed" not in d["costUs"]
+            assert d["tier"] in ("columnar", "postings")
+    moved = metrics.counters_delta(before)
+    assert moved.get("planner_estimate_violations_total", 0) == 0
+    assert moved.get("query_compressed_setops_total", 0) == 0
+    assert not any(k.startswith("planner_reoptimized_total")
+                   and "violation" in k for k in moved)
+    # two tokens are a set operation: there the packs stay on offer
+    r = db.query('{ q(func: eq(cat, [0, 1])) { count(uid) } }',
+                 explain="analyze")
+    assert r["data"]["q"][0]["count"] == 1350
+    (d,) = [d for d in r["extensions"]["explain"]["tierDecisions"]
+            if d["stage"] == "eq"]
+    assert "compressed" in d["costUs"]
+
+
 def test_planted_misestimate_reoptimizes_and_converges():
     """The acceptance scenario: a Zipfian token breaks the histogram
     estimate -> EXPLAIN ANALYZE shows the violation counter move ->

@@ -47,7 +47,7 @@ def _db(**kw):
     # covered by tests/test_knn.py, not this gate.
     kw.setdefault("planner", "static")
     db = GraphDB(**kw)
-    db.alter("embedding: float32vector @index(vector) .")
+    db.alter("embedding: float32vector @index(vector(ivf)) .")
     db.mutate(set_nquads=rdf, commit_now=True)
     db.rollup_all()
     return db, vecs
