@@ -17,6 +17,7 @@ from __future__ import annotations
 
 import contextlib
 import time
+import weakref
 from typing import Optional
 
 import numpy as np
@@ -110,6 +111,87 @@ def device_vector_block(db, tab, base_vecs: np.ndarray):
     set_gauge("device_vector_block_bytes", float(arr.nbytes),
               labels=labels)
     return arr
+
+
+_MASK_ATTR = "_device_mask@"
+
+
+class SimilarMask:
+    """One resident candidate mask of a vector block: the padded bool
+    rows on the device, how many are set, and what it is exact for
+    (the row map it was laid over and the two tablets' base_ts)."""
+
+    __slots__ = ("mask", "n_cand", "row_uids", "nbytes")
+
+    def __init__(self, mask, n_cand: int, row_uids: np.ndarray):
+        self.mask = mask
+        self.n_cand = n_cand
+        self.row_uids = row_uids
+        self.nbytes = int(mask.nbytes)     # device bytes (tile_cache)
+
+
+def _mask_attr(posting: tuple) -> str:
+    pred, token, _ = posting
+    return f"{_MASK_ATTR}{pred}@{token.hex()}"
+
+
+def _mask_ts(tab, posting: tuple) -> tuple:
+    return (tab.base_ts, posting[2])
+
+
+def similar_mask_tile(db, tab, posting: tuple, row_uids: np.ndarray
+                      ) -> Optional[SimilarMask]:
+    """The resident mask of a vector tablet's block for ONE clean
+    posting, `posting` = (filter predicate, token, that tablet's
+    base_ts): the provenance of a candidate set, never its contents.
+    None unless a tile was stored under both tablets' current base_ts
+    and over this very row map; a hit is marked used."""
+    attr = _mask_attr(posting)
+    tile = getattr(tab, attr, None)
+    if tile is None or tile.row_uids is not row_uids \
+            or getattr(tab, attr + "_ts", -1) != _mask_ts(tab, posting):
+        return None
+    db.device_cache.touch(tab, attr)
+    return tile
+
+
+def store_similar_mask(db, tab, posting: tuple, row_uids: np.ndarray,
+                       mask_pad: np.ndarray, n_cand: int) -> SimilarMask:
+    """Make a call's host mask (padded to the block's rows) the
+    resident tile of its posting: ONE `jax.device_put` (an upload, no
+    program), counted in `device_cache_bytes` under the HBM budget and
+    evictable like the block it masks. The gauge
+    `device_similar_mask_bytes{predicate}` sums a vector predicate's
+    resident masks."""
+    attr = _mask_attr(posting)
+    with _tile_load(pred=tab.pred, kind="similar_mask",
+                    rows=len(row_uids)):
+        tile = SimilarMask(jax.device_put(mask_pad), int(n_cand),
+                           row_uids)
+    setattr(tab, attr, tile)
+    setattr(tab, attr + "_ts", _mask_ts(tab, posting))
+    ref = weakref.ref(tab)
+
+    def evicted():
+        # the LRU has set both attributes to "absent": take them off,
+        # so a filter over many values does not grow the tablet
+        t = ref()
+        if t is not None:
+            vars(t).pop(attr, None)
+            vars(t).pop(attr + "_ts", None)
+            _set_mask_gauge(t)
+
+    db.device_cache.put(tab, attr, tile, on_evict=evicted)
+    _set_mask_gauge(tab)
+    return tile
+
+
+def _set_mask_gauge(tab) -> None:
+    resident = sum(
+        t.nbytes for a, t in list(vars(tab).items())
+        if a.startswith(_MASK_ATTR) and isinstance(t, SimilarMask))
+    set_gauge("device_similar_mask_bytes", float(resident),
+              labels={"predicate": tab.pred})
 
 
 def _clean_resident(db, tab, read_ts: int, want_uid: bool = True,

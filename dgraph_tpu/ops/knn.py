@@ -295,11 +295,16 @@ def _topk_device_jit(corpus, queries, mask, k, metric, two_stage,
 DEVICE_PROGRAM = "jit_" + _topk_device_jit.__name__
 
 
+def padded_rows(n: int, unit: int = BUCKET_SIZE) -> int:
+    """Rows of a block of n once padded to a `unit` multiple."""
+    return max(unit, ((n + unit - 1) // unit) * unit)
+
+
 def pad_rows(corpus: np.ndarray, unit: int = BUCKET_SIZE) -> np.ndarray:
     """Zero-pad the row axis to a `unit` multiple (host-side, ONCE per
     block build) so topk_device never copies the corpus per query."""
     n, d = corpus.shape
-    n_pad = max(unit, ((n + unit - 1) // unit) * unit)
+    n_pad = padded_rows(n, unit)
     if n_pad == n:
         return corpus
     out = np.zeros((n_pad, d), np.float32)
@@ -307,9 +312,26 @@ def pad_rows(corpus: np.ndarray, unit: int = BUCKET_SIZE) -> np.ndarray:
     return out
 
 
+def candidate_mask(row_uids: np.ndarray, candidates: np.ndarray,
+                   n_pad: int | None = None) -> np.ndarray:
+    """Bool mask over a block's rows (`row_uids`, sorted, one a row):
+    which rows' uids are among `candidates`, False in the padding up
+    to `n_pad`. The candidates are looked up in the row map, the
+    smaller side into the larger (O(c log n), not the O(n log c) of
+    testing every row), and scattered; duplicates and candidates the
+    block does not hold change nothing."""
+    n = len(row_uids)
+    mask = np.zeros(n if n_pad is None else n_pad, bool)
+    if n and len(candidates):
+        pos = np.searchsorted(row_uids, candidates)
+        pos[pos == n] = n - 1
+        mask[pos[row_uids[pos] == candidates]] = True
+    return mask
+
+
 def topk_device(corpus_dev, queries: np.ndarray, k: int,
                 metric: str = "cosine",
-                mask: np.ndarray | None = None,
+                mask=None,
                 two_stage: bool | None = None,
                 l_per_bucket: int | None = None,
                 use_pallas: bool | None = None,
@@ -326,6 +348,12 @@ def topk_device(corpus_dev, queries: np.ndarray, k: int,
     (pad_rows): only the first n_real rows are live. Hot-path callers
     should pre-pad their cached block so no per-query device copy
     happens here.
+
+    `mask` (bool, True = the row may answer) is None for every live
+    row, a host array over the live or the padded rows (uploaded with
+    the call), or a device array over the padded rows (a resident of
+    engine/device_cache.store_similar_mask: nothing is uploaded).
+    Host or device, it is the same operand to the one compiled program.
 
     two_stage=None takes the proved two-stage reduce where
     plan_two_stage finds an L for it and lax.top_k over the full row
@@ -353,7 +381,7 @@ def topk_device(corpus_dev, queries: np.ndarray, k: int,
     if use_pallas:
         from dgraph_tpu.ops.pallas_kernels import SCORE_TILE_N
         unit = SCORE_TILE_N
-    n_pad = max(unit, ((n_rows + unit - 1) // unit) * unit)
+    n_pad = padded_rows(n_rows, unit)
     if n_pad != n_rows:
         corpus_dev = jnp.concatenate(
             [corpus_dev, jnp.zeros((n_pad - n_rows, d), jnp.float32)])
@@ -366,12 +394,14 @@ def topk_device(corpus_dev, queries: np.ndarray, k: int,
         two_stage = False  # too few buckets for this k: the full row
     if l_per_bucket is None:
         l_per_bucket = max(plan, 1)
-    mask_pad = None
-    if mask is not None:
-        mask_pad = np.zeros(n_pad, bool)
-        mask_pad[:n] = np.asarray(mask, bool)
+    if mask is not None and not isinstance(mask, jax.Array):
+        mask = np.asarray(mask, bool)
+        if len(mask) != n_pad:
+            mask_pad = np.zeros(n_pad, bool)
+            mask_pad[:n] = mask
+            mask = mask_pad
     out = _topk_device_jit(
-        corpus_dev, q, mask_pad, int(k), str(metric), bool(two_stage),
+        corpus_dev, q, mask, int(k), str(metric), bool(two_stage),
         int(l_per_bucket), bool(use_pallas), bool(pallas_interpret),
         int(n))
     if sync is not None:

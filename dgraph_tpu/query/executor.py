@@ -361,6 +361,9 @@ class Executor:
         # score-descending uid order of the current block's similar_to
         # root, set by _eval_similar_to and consumed at pagination
         self._similar_order: Optional[list[int]] = None
+        # (array, (pred, token, base_ts)) of the last root that
+        # returned one clean posting whole (see _posting_of)
+        self._clean_posting: Optional[tuple] = None
         # per-request column-view memo (one snapshot, one verdict)
         self._cv_memo: dict = {}
         # adaptive-planner plumbing (query/planner.py): the tier
@@ -1340,16 +1343,47 @@ class Executor:
                 f"similar_to query vector has dimension {len(qvec)}; "
                 f"predicate {fn.attr!r} stores dimension {view.dim}")
 
-        base_mask = view.base_keep
         ex_uids, ex_vecs = view.extra_uids, view.extra_vecs
-        if candidates is not None:
-            base_mask = base_mask & _member_of(view.base_uids,
-                                               candidates)
+        if candidates is not None and len(ex_uids):
             exm = _member_of(ex_uids, candidates)
             ex_uids, ex_vecs = ex_uids[exm], ex_vecs[exm]
         parts: list = []
         n = len(view.base_uids)
-        n_cand = int(base_mask.sum())
+        # the rows the block may answer from, found without a pass
+        # over every row where the input says what they are: all of
+        # them ("none": no mask), one clean posting whose mask is a
+        # resident of the device ("tile_hit"; "tile_miss" stores it),
+        # or a mask made for this call from the candidates ("call")
+        n_pad = _knn.padded_rows(n)
+        posting = self._posting_of(candidates) if view.clean else None
+        tile = None
+        mask = None          # host bool, over the live or padded rows
+        if candidates is None and view.clean:
+            n_cand, source = n, "none"
+        else:
+            source = "call"
+            if posting is not None and self.db.prefer_device:
+                from dgraph_tpu.engine.device_cache import \
+                    similar_mask_tile
+                tile = similar_mask_tile(self.db, tab, posting,
+                                         view.base_uids)
+            if tile is not None:
+                n_cand = tile.n_cand
+            else:
+                mask = self._similar_host_mask(view, candidates, n_pad)
+                n_cand = int(np.count_nonzero(mask))
+
+        def host_mask() -> np.ndarray:
+            # the tiers off the single chip take a host mask over the
+            # live rows, as they always did
+            nonlocal source
+            if source == "none":
+                return view.base_keep
+            source = "call"
+            m = mask if mask is not None else \
+                self._similar_host_mask(view, candidates, n_pad)
+            return m[:n]
+
         if sp is not None:
             sp.update(k=int(k), rows=int(n), candidates=n_cand,
                       exact_fallback=0)
@@ -1409,12 +1443,12 @@ class Executor:
                     and n >= self.db.shard_min_edges:
                 if quant_ok:
                     idx, sc = self._sharded_ivf_topk(
-                        tab, ivf, view, qm, k, metric, base_mask)
+                        tab, ivf, view, qm, k, metric, host_mask())
                     vdec.update(tier="sharded_quantized",
                                 **self._vec_budget(ivf, k))
                 else:
                     idx, sc = self._sharded_vec_topk(
-                        tab, view, qm, k, metric, base_mask)
+                        tab, view, qm, k, metric, host_mask())
                     vdec["tier"] = "sharded"
                 if sp is not None:
                     # cost attribution follows the SERVING tier: the
@@ -1427,7 +1461,7 @@ class Executor:
                 from dgraph_tpu.ops import ivf as _ivf
                 idx, sc = _ivf.search(
                     ivf, view.base_vecs, qm, k, metric,
-                    keep=base_mask, nprobe=self.db.vec_nprobe,
+                    keep=host_mask(), nprobe=self.db.vec_nprobe,
                     rerank=self.db.vec_rerank)
                 inc_counter("query_similar_quantized_total")
                 budget = self._vec_budget(ivf, k)
@@ -1442,21 +1476,30 @@ class Executor:
                     # where the planner never looks
                     sp["n"] = int(scanned)
             elif use_device:
-                from dgraph_tpu.engine.device_cache import \
-                    device_vector_block
+                from dgraph_tpu.engine.device_cache import (
+                    device_vector_block, store_similar_mask,
+                )
                 # a counted, evictable tile (engine/device_cache.py)
                 block = device_vector_block(self.db, tab,
                                             view.base_vecs)
+                dev_mask = mask
+                if tile is not None:
+                    dev_mask, source = tile.mask, "tile_hit"
+                elif posting is not None:
+                    dev_mask = store_similar_mask(
+                        self.db, tab, posting, view.base_uids, mask,
+                        n_cand).mask
+                    source = "tile_miss"
+                if mask is not None:
+                    # a mask went up, with the call or as its tile
+                    inc_counter("similar_masked_total")
                 info: dict = {}
                 with device_call("query_device_similar_total",
                                  sink=self.lat,
                                  program=_knn.DEVICE_PROGRAM) as dc:
                     idx, sc = _knn.topk_device(
-                        block, qm, k, metric, mask=base_mask, n_real=n,
+                        block, qm, k, metric, mask=dev_mask, n_real=n,
                         sync=dc.wait, info=info)
-                # every call ships a mask today, the root's all-true
-                # one too
-                inc_counter("similar_masked_total")
                 if info["exact_fallback"]:
                     inc_counter("similar_exact_fallback_total")
                 vdec["tier"] = "two_stage" \
@@ -1467,11 +1510,14 @@ class Executor:
                     sp["exact_fallback"] = int(info["exact_fallback"])
             else:
                 idx, sc = _knn.topk_host(view.base_vecs, qm, k,
-                                         metric, mask=base_mask)
+                                         metric, mask=host_mask())
                 vdec["tier"] = "exact"
                 if sp is not None:
                     sp["tier"] = "postings"
                     sp["n"] = int(n)
+            inc_counter("similar_mask_total", labels={"source": source})
+            if sp is not None:
+                sp["mask"] = source
             self.vector_decisions.append(vdec)
             self._record_outcome(dec, n)
             row, s = idx[0], sc[0]
@@ -1490,6 +1536,31 @@ class Executor:
             # root: the block emits nearest-first (_similar_paginate)
             self._similar_order = [int(u) for u in uids.tolist()]
         return np.sort(uids.astype(np.uint64))
+
+    def _posting_of(self, candidates) -> Optional[tuple]:
+        """(predicate, token, base_ts) if `candidates` IS one clean
+        posting: the very array a one-token `eq` root returned
+        (_eval_eq_tokens_inner notes it). Whatever narrows or widens a
+        set makes another array, so identity is the whole test."""
+        noted = self._clean_posting
+        if candidates is None or noted is None \
+                or noted[0] is not candidates:
+            return None
+        return noted[1]
+
+    @staticmethod
+    def _similar_host_mask(view, candidates, n_pad: int) -> np.ndarray:
+        """The mask of one call: the candidates' rows, over the
+        block's padded rows, less the rows the overlay touches; at
+        the root the view's own mask over the live rows."""
+        from dgraph_tpu.ops.knn import candidate_mask
+
+        if candidates is None:
+            return view.base_keep
+        mask = candidate_mask(view.base_uids, candidates, n_pad)
+        if not view.clean:
+            mask[:len(view.base_keep)] &= view.base_keep
+        return mask
 
     def _vec_rerank(self, k: int) -> int:
         """Effective exact re-rank depth for the quantized tier."""
@@ -1714,6 +1785,9 @@ class Executor:
             else:
                 all_toks, no_tok_vals = _analyze()
             dec = None
+            # one clean posting, its length exact (None otherwise)
+            est = self._posting_est(tab, all_toks[0]) \
+                if len(all_toks) == 1 else None
             if all_toks and self._adaptive:
                 # one token's posting length is known before the tier
                 # is chosen, so the decision is made (and cached) for
@@ -1721,8 +1795,6 @@ class Executor:
                 # 1,700 and 103,000 rows is two decisions, not one
                 # estimate that every other request violates
                 from dgraph_tpu.query.planner import _bucket
-                est = self._posting_est(tab, all_toks[0]) \
-                    if len(all_toks) == 1 else None
                 size = () if est is None else (_bucket(est["estRows"]),)
                 tiers = self._index_tiers(tab)
                 if est is not None:
@@ -1780,6 +1852,14 @@ class Executor:
                         else tab.src_uids(self.read_ts)
                     extra = self._eq_scan(tab, scan, no_tok_vals, lang)
                     out = _union(out, extra)
+                elif candidates is None and est is not None \
+                        and not (spec.lossy or tab.schema.lang):
+                    # `out` is one token's posting off a clean tablet
+                    # at a read_ts its base serves: a later stage that
+                    # is handed this very array may key what it keeps
+                    # for it by where it came from (_posting_of)
+                    self._clean_posting = (
+                        out, (tab.pred, all_toks[0], tab.base_ts))
                 return out if candidates is None \
                     else _intersect(candidates, out)
             # EVERY value was tokenless: plain scan below

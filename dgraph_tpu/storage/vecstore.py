@@ -42,7 +42,10 @@ class VecView:
     base_uids/base_vecs are the packed BASE block (stable per base_ts —
     safe to keep device-resident); base_keep masks off rows the overlay
     touches at this read_ts. extra_uids/extra_vecs are the overlay-
-    visible rows, read through MVCC at read_ts.
+    visible rows, read through MVCC at read_ts. `clean` says that the
+    overlay touches nothing at this read_ts: base_keep is all true
+    (one read-only array shared by every such view, never summed or
+    copied to learn that) and there are no extra rows.
     """
 
     dim: int
@@ -51,6 +54,7 @@ class VecView:
     base_keep: np.ndarray       # [n] bool
     extra_uids: np.ndarray      # [m] uint64 sorted
     extra_vecs: np.ndarray      # [m, d] float32
+    clean: bool = False
 
     @property
     def n_rows(self) -> int:
@@ -111,36 +115,49 @@ def _base_block(tab) -> tuple[np.ndarray, np.ndarray]:
     return uarr, varr
 
 
+def _all_rows(tab, base_uids: np.ndarray) -> np.ndarray:
+    """The all-true keep mask of a base block, made once per block
+    (it is cached against the row map it covers) and read-only: a
+    view the overlay touches copies it before clearing rows."""
+    cached = getattr(tab, "_vec_all_rows", None)
+    if cached is not None and cached[0] is base_uids:
+        return cached[1]
+    keep = np.ones(len(base_uids), bool)
+    keep.flags.writeable = False
+    tab._vec_all_rows = (base_uids, keep)
+    return keep
+
+
 def vector_view(tab, read_ts: int) -> VecView:
     """The tablet's vectors visible at read_ts. The base block is
     shared across calls; only the (usually tiny) overlay side block is
     built per read timestamp."""
     base_uids, base_vecs = _base_block(tab)
     dim = base_vecs.shape[1] if base_vecs.size else 0
-    keep = np.ones(len(base_uids), bool)
+    keep = _all_rows(tab, base_uids)
     ex_uids: list[int] = []
     ex_rows: list[np.ndarray] = []
-    if tab.dirty():
-        touched = sorted(tab.overlay_srcs(read_ts))
-        if touched:
-            tarr = np.asarray(touched, np.uint64)
-            pos = np.searchsorted(base_uids, tarr)
-            pos = np.clip(pos, 0, max(len(base_uids) - 1, 0))
-            hit = (base_uids[pos] == tarr) if len(base_uids) \
-                else np.zeros(len(tarr), bool)
-            keep[pos[hit]] = False
-            for u in touched:
-                vec = _posting_vec(tab, tab.get_postings(int(u), read_ts))
-                if vec is None:
-                    continue
-                if dim == 0:
-                    dim = len(vec)
-                elif len(vec) != dim:
-                    raise ValueError(
-                        f"predicate {tab.pred!r} holds vectors of "
-                        f"differing dimension ({dim} vs {len(vec)})")
-                ex_uids.append(int(u))
-                ex_rows.append(vec)
+    touched = sorted(tab.overlay_srcs(read_ts)) if tab.dirty() else ()
+    if touched:
+        keep = keep.copy()
+        tarr = np.asarray(touched, np.uint64)
+        pos = np.searchsorted(base_uids, tarr)
+        pos = np.clip(pos, 0, max(len(base_uids) - 1, 0))
+        hit = (base_uids[pos] == tarr) if len(base_uids) \
+            else np.zeros(len(tarr), bool)
+        keep[pos[hit]] = False
+        for u in touched:
+            vec = _posting_vec(tab, tab.get_postings(int(u), read_ts))
+            if vec is None:
+                continue
+            if dim == 0:
+                dim = len(vec)
+            elif len(vec) != dim:
+                raise ValueError(
+                    f"predicate {tab.pred!r} holds vectors of "
+                    f"differing dimension ({dim} vs {len(vec)})")
+            ex_uids.append(int(u))
+            ex_rows.append(vec)
     if ex_uids:
         earr = np.asarray(ex_uids, np.uint64)
         order = np.argsort(earr, kind="stable")
@@ -152,7 +169,8 @@ def vector_view(tab, read_ts: int) -> VecView:
         ex_v = np.empty((0, dim), np.float32)
     if not base_vecs.size and dim:
         base_vecs = np.empty((0, dim), np.float32)
-    return VecView(dim, base_uids, base_vecs, keep, ex_u, ex_v)
+    return VecView(dim, base_uids, base_vecs, keep, ex_u, ex_v,
+                   clean=not touched)
 
 
 # ---------------------------------------------------------------------------

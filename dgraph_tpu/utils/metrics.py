@@ -55,7 +55,9 @@ REGISTERED = (
     # prefix as a count of dispatches
     "device_call_ns_total",
     "device_dispatch_seconds",
-    # engine/device_cache.py: the resident vector block's bytes
+    # engine/device_cache.py: a vector predicate's resident candidate
+    # masks, and its resident block
+    "device_similar_mask_bytes",
     "device_vector_block_bytes",
     "dgraph_num_edges_total",
     "dgraph_num_mutations_total",
@@ -117,6 +119,7 @@ REGISTERED = (
     "query_similar_quantized_total",
     "query_similar_sharded_total",
     "similar_exact_fallback_total",
+    "similar_mask_total",
     "similar_masked_total",
     "similar_ns_total",
     # quantized vector index (ops/ivf.py, storage/vecstore.py)

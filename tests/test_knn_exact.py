@@ -254,14 +254,24 @@ def test_size_alone_never_trains_or_serves_the_quantized_tier(
 # ---------------------------------------------------------------------------
 
 
+RARE = 99                         # a category of three rows
+
+
+def _category(i):
+    return RARE if i in (10, 3000, 5005) else i % 5
+
+
 def _int_db(n=6000, d=8, seed=2, **kw):
     rng = np.random.default_rng(seed)
     vecs = rng.integers(0, 256, (n, d))
     rdf = "\n".join(
         f'<0x{i + 1:x}> <embedding> "{vecs[i].tolist()}" .\n'
-        f'<0x{i + 1:x}> <category> "{i % 5}" .' for i in range(n))
+        f'<0x{i + 1:x}> <id> "{i}" .\n'
+        f'<0x{i + 1:x}> <category> "{_category(i)}" .'
+        for i in range(n))
     db = GraphDB(**kw)
     db.alter("embedding: float32vector @index(vector) .\n"
+             "id: int @index(int) .\n"
              "category: int @index(int) .")
     db.mutate(set_nquads=rdf, commit_now=True)
     db.rollup_all()
@@ -278,6 +288,32 @@ def _gauge(name):
                if k.split("{")[0] == name)
 
 
+MASK_SOURCES = ("none", "tile_hit", "tile_miss", "call")
+
+
+def _mask_sources():
+    counters = metrics.snapshot()["counters"]
+    return {src: counters.get(
+        'similar_mask_total{source="%s"}' % src, 0)
+        for src in MASK_SOURCES}
+
+
+class _Served:
+    """One query against the device tier: its `data` as JSON, what
+    the mask counters and the `similar_to` span say it did."""
+
+    def __init__(self, db, q, **kw):
+        before, masked = _mask_sources(), _counter("similar_masked_total")
+        tracing.clear()
+        self.data = json.dumps(db.query(q, **kw)["data"])
+        after = _mask_sources()
+        self.sources = {s: after[s] - before[s] for s in MASK_SOURCES
+                        if after[s] != before[s]}
+        self.uploads = _counter("similar_masked_total") - masked
+        self.span = [sp for sp in tracing.recent_spans()
+                     if sp["name"] == "similar_to"][-1]["args"]
+
+
 ROOT_Q = ('{ q(func: similar_to(embedding, 10, "%s", "euclidean")) '
           '{ uid val(similar_to_score) } }')
 FILTER_Q = ('{ q(func: eq(category, 3)) @filter(similar_to(embedding, '
@@ -292,6 +328,7 @@ def test_a_served_similar_to_runs_inside_device_call(template):
     q = template % vecs[17].tolist()
     calls = _counter("query_device_similar_total")
     masked = _counter("similar_masked_total")
+    sources = _mask_sources()
     spent = _counter("similar_ns_total")
     tracing.clear()
     res = db.query(q)
@@ -299,7 +336,12 @@ def test_a_served_similar_to_runs_inside_device_call(template):
     assert lat["device_calls"] >= 1
     assert lat["device_wait_ns"] > 0 and lat["device_enqueue_ns"] > 0
     assert _counter("query_device_similar_total") == calls + 1
-    assert _counter("similar_masked_total") == masked + 1
+    # the root ships no mask; a category's goes up once, as its tile
+    uploads, source = (0, "none") if template is ROOT_Q \
+        else (1, "tile_miss")
+    assert _counter("similar_masked_total") == masked + uploads
+    sources[source] += 1
+    assert _mask_sources() == sources
     assert _counter("similar_ns_total") > spent
     spans = {s["name"]: s for s in tracing.recent_spans()}
     call, sim = spans["device.call"], spans["similar_to"]
@@ -310,6 +352,7 @@ def test_a_served_similar_to_runs_inside_device_call(template):
     assert sim["args"]["exact_fallback"] == 0
     want_candidates = 6000 if template is ROOT_Q else 1200
     assert sim["args"]["candidates"] == want_candidates
+    assert sim["args"]["mask"] == source
     # and the answer is the postings tier's, byte for byte
     assert json.dumps(res["data"]) == json.dumps(
         host.query(q)["data"])
@@ -349,6 +392,272 @@ class _DeviceBytes:
     """Counts as a megabyte of DEVICE bytes in the tile cache (which
     tells device from host by duck type, engine/tile_cache.py)."""
     nbytes = 1 << 20
+
+
+# ---------------------------------------------------------------------------
+# (b2) the candidate mask lives on the device (PR 27)
+# ---------------------------------------------------------------------------
+
+MASK_BYTES = 6016                 # one bool a padded row
+MASK_GAUGE = 'device_similar_mask_bytes{predicate="embedding"}'
+
+
+@pytest.fixture(scope="module")
+def host_db():
+    return _int_db(prefer_device=False)[0]
+
+
+def _in_category(c, vec, k=10, filt="similar_to(embedding, %d, \"%s\", "
+                 "\"euclidean\")"):
+    return ('{ q(func: eq(category, %d)) @filter(%s) { uid id } }'
+            % (c, filt % (k, vec.tolist())))
+
+
+def test_a_categorys_mask_is_uploaded_once_and_found_again(host_db):
+    db, vecs = _int_db()
+    first = _Served(db, _in_category(3, vecs[17]))
+    assert first.sources == {"tile_miss": 1} and first.uploads == 1
+    assert first.span["mask"] == "tile_miss"
+    assert metrics.snapshot()["gauges"][MASK_GAUGE] == MASK_BYTES
+    tile = [v for a, v in vars(db.tablets["embedding"]).items()
+            if a.startswith("_device_mask@") and not a.endswith("_ts")]
+    assert len(tile) == 1 and tile[0].n_cand == 1200
+    assert db.device_cache.stats()["bytes"] \
+        == db.tablets["embedding"]._device_vecs.nbytes + MASK_BYTES
+    # another vector, the same category: nothing is built or uploaded
+    q = _in_category(3, vecs[18])
+    again = _Served(db, q)
+    assert again.sources == {"tile_hit": 1} and again.uploads == 0
+    assert again.span["mask"] == "tile_hit"
+    assert again.span["candidates"] == 1200
+    assert again.data == json.dumps(host_db.query(q)["data"])
+    # another category: another tile
+    other = _Served(db, _in_category(4, vecs[18]))
+    assert other.sources == {"tile_miss": 1}
+    assert metrics.snapshot()["gauges"][MASK_GAUGE] == 2 * MASK_BYTES
+
+
+SIM = 'similar_to(embedding, 10, "%s", "euclidean")'
+
+
+@pytest.mark.parametrize("root, filt, source", [
+    # the set was narrowed before similar_to saw it: a mask for the call
+    ("eq(category, 3)", "lt(id, 3000) AND " + SIM, "call"),
+    ("eq(category, 3)", "(lt(id, 900) OR gt(id, 4000)) AND " + SIM,
+     "call"),
+    ("eq(category, 3)", "NOT lt(id, 3000) AND " + SIM, "call"),
+    ("eq(category, [3, 4])", SIM, "call"),
+    ("has(category)", "eq(category, 3) AND " + SIM, "call"),
+    ("lt(id, 3000)", SIM, "call"),
+    # similar_to is handed the root's posting itself, whatever the
+    # tree does with its answer afterwards: the category's tile
+    ("eq(category, 3)", SIM + " AND lt(id, 3000)", "tile_miss"),
+    ("eq(category, 3)", SIM + " OR lt(id, 40)", "tile_miss"),
+    ("eq(category, 3)", "NOT " + SIM, "tile_miss"),
+], ids=["second-conjunct", "after-or", "after-not", "two-values",
+        "eq-as-filter", "lt-root", "first-conjunct", "under-or",
+        "under-not"])
+def test_the_masks_source_follows_where_the_candidates_came_from(
+        host_db, root, filt, source):
+    db, vecs = _int_db()
+    q = '{ q(func: %s) @filter(%s) { uid id } }' % (
+        root, filt % vecs[17].tolist())
+    got = _Served(db, q)
+    assert got.sources == {source: 1}
+    assert got.span["mask"] == source
+    assert got.uploads == 1
+    assert got.data == json.dumps(host_db.query(q)["data"])
+    # a second time: only a posting's tile is found again
+    again = _Served(db, q)
+    assert again.sources == {
+        "tile_hit" if source == "tile_miss" else "call": 1}
+    assert again.data == got.data
+
+
+def test_a_posting_bound_to_a_variable_is_still_that_posting(host_db):
+    db, vecs = _int_db()
+    q = ('{ A as var(func: eq(category, 3)) '
+         'q(func: uid(A)) @filter(%s) { uid id } }'
+         % (SIM % vecs[17].tolist()))
+    got = _Served(db, q)
+    assert got.sources == {"tile_miss": 1}
+    assert got.data == json.dumps(host_db.query(q)["data"])
+
+
+NEAR = [7, 7, 7, 7, 7, 7, 7, 7]
+
+
+def _add_row(db):
+    return db.mutate(set_nquads=(
+        f'<0x2000> <embedding> "{NEAR}" .\n<0x2000> <id> "8191" .\n'
+        '<0x2000> <category> "3" .'), commit_now=True)
+
+
+def _leave_category(db):
+    # row 3 (uid 4) keeps its vector and leaves the category: the
+    # vector tablet stays clean, the filter's does not
+    return db.mutate(del_nquads='<0x4> <category> * .', commit_now=True)
+
+
+def _move_row(db):
+    # uid 4's vector moves away: the filter's tablet stays clean
+    return db.mutate(set_nquads='<0x4> <embedding> "[255, 255, 255, '
+                     '255, 255, 255, 255, 255]" .', commit_now=True)
+
+
+@pytest.mark.parametrize("change, found", [
+    (_add_row, {"0x2000": True}), (_leave_category, {"0x4": False}),
+    (_move_row, {"0x4": False})], ids=["add", "leave", "move"])
+def test_a_tile_never_serves_a_snapshot_it_was_not_built_for(
+        change, found):
+    db, vecs = _int_db()
+    host, _ = _int_db(prefer_device=False)
+    # the query sits on row 3 (uid 0x4, category 3) or on the new row
+    at = NEAR if change is _add_row else vecs[3].tolist()
+    q = '{ q(func: eq(category, 3)) @filter(%s) { uid } }' % (SIM % at)
+
+    def uids(data):
+        return {r["uid"] for r in json.loads(data)["q"]}
+
+    old_ts = db.coordinator.max_assigned()
+    before = _Served(db, q)
+    assert before.sources == {"tile_miss": 1}
+    assert before.data == json.dumps(host.query(q)["data"])
+    change(db)
+    change(host)
+    # after the commit, before a rollup: a tablet is dirty, the tile
+    # is not consulted
+    after = _Served(db, q)
+    assert after.sources == {"call": 1}
+    assert after.data == json.dumps(host.query(q)["data"])
+    for uid, there in found.items():
+        assert (uid in uids(after.data)) == there
+        assert (uid in uids(before.data)) != there
+    # a root call too: no mask while the overlay touches nothing
+    # of the vector tablet, the view's own mask where it does
+    root = _Served(db, ROOT_Q % at)
+    assert root.sources == {
+        "none" if change is _leave_category else "call": 1}
+    assert root.data == json.dumps(host.query(ROOT_Q % at)["data"])
+    # below the commit the old answer stands, from the old tile
+    then = _Served(db, q, read_ts=old_ts)
+    assert then.data == before.data
+    assert then.sources == ({"tile_hit": 1} if change is _move_row
+                            else {"call": 1})
+    # after a rollup the tablets are clean under a new base_ts: the
+    # old tile is not found, a new one is built and then served
+    db.rollup_all()
+    host.rollup_all()
+    rolled = _Served(db, q)
+    assert rolled.sources == {"tile_miss": 1}
+    assert rolled.data == after.data
+    assert rolled.data == json.dumps(host.query(q)["data"])
+    hit = _Served(db, q)
+    assert hit.sources == {"tile_hit": 1} and hit.data == after.data
+    assert metrics.snapshot()["gauges"][MASK_GAUGE] == MASK_BYTES
+
+
+def test_a_mask_tile_is_evicted_under_the_budget_and_rebuilt(host_db):
+    db, vecs = _int_db(device_hbm_budget=1 << 20)
+    q = _in_category(3, vecs[17])
+    first = _Served(db, q)
+    tab = db.tablets["embedding"]
+    assert metrics.snapshot()["gauges"][MASK_GAUGE] == MASK_BYTES
+    evictions = _counter("device_cache_evictions")
+    db.device_cache.put(db.tablets["category"], "_device_values",
+                        _DeviceBytes())
+    # block and mask both went, and the tablet keeps no trace of it
+    assert _counter("device_cache_evictions") >= evictions + 2
+    assert tab._device_vecs is None
+    assert metrics.snapshot()["gauges"][MASK_GAUGE] == 0
+    assert not [a for a in vars(tab) if a.startswith("_device_mask@")]
+    again = _Served(db, q)
+    assert again.sources == {"tile_miss": 1} and again.uploads == 1
+    assert again.data == first.data
+    assert again.data == json.dumps(host_db.query(q)["data"])
+    assert metrics.snapshot()["gauges"][MASK_GAUGE] == MASK_BYTES
+
+
+def test_a_category_smaller_than_k_answers_from_the_full_row(host_db):
+    db, vecs = _int_db()
+    for vec, source in ((vecs[17], "tile_miss"), (vecs[18], "tile_hit")):
+        q = _in_category(RARE, vec)
+        got = _Served(db, q)
+        assert got.sources == {source: 1}
+        assert got.span["candidates"] == 3
+        # 6,000 rows plan the two-stage reduce; three live rows prove
+        # nothing, and the full row answers
+        assert got.span["exact_fallback"] == 1
+        assert len(json.loads(got.data)["q"]) == 3
+        assert got.data == json.dumps(host_db.query(q)["data"])
+
+
+def _sets(name):
+    rng = np.random.default_rng(5)
+    rows = np.unique(rng.integers(1000, 50000, 4000).astype(np.uint64))
+    some = rng.choice(rows, 300, replace=False)
+    return rows, {
+        "empty": np.empty(0, np.uint64),
+        "no-overlap": rows[:50] + np.uint64(100000),
+        "all-rows": rows,
+        "below-and-above": np.asarray([1, 2, 999, 50001, 1 << 40],
+                                      np.uint64),
+        "some": np.sort(some),
+        "some-and-strangers": np.sort(np.concatenate(
+            [some, np.asarray([3, 70000], np.uint64)])),
+        "duplicates": np.sort(np.concatenate([some, some[:40]])),
+        "unsorted": some,
+        "one-row": rows[-1:],
+    }[name]
+
+
+@pytest.mark.parametrize("name", [
+    "empty", "no-overlap", "all-rows", "below-and-above", "some",
+    "some-and-strangers", "duplicates", "unsorted", "one-row"])
+def test_candidate_mask_is_member_of_probed_the_other_way(name):
+    from dgraph_tpu.query.executor import _member_of
+    rows, cand = _sets(name)
+    # _member_of asks for a sorted unique set; candidate_mask does not
+    want = _member_of(rows, np.unique(cand))
+    got = knn.candidate_mask(rows, cand)
+    assert got.dtype == bool and got.tolist() == want.tolist()
+    padded = knn.candidate_mask(rows, cand, knn.padded_rows(len(rows)))
+    assert len(padded) == knn.padded_rows(len(rows))
+    assert padded[:len(rows)].tolist() == want.tolist()
+    assert not padded[len(rows):].any()
+    assert not knn.candidate_mask(rows[:0], cand).any()
+
+
+@pytest.mark.parametrize("form", ["live", "padded", "device"])
+def test_topk_device_takes_a_mask_from_the_host_or_the_device(
+        form, caplog):
+    import logging
+
+    import jax
+    c, q = _tied_corpus(8200, 3)
+    n_pad = knn.padded_rows(len(c))
+    block = jax.numpy.asarray(knn.pad_rows(c.astype(np.float32)))
+    mask = np.arange(len(c)) % 3 != 0
+    want = knn.topk_host(c.astype(np.float32), q, 10, "euclidean",
+                         mask=mask)
+    padded = np.zeros(n_pad, bool)
+    padded[:len(c)] = mask
+    forms = {"live": mask, "padded": padded,
+             "device": jax.device_put(padded)}
+    got = knn.topk_device(block, q, 10, "euclidean", mask=forms[form],
+                          n_real=len(c))
+    assert got[0].tolist() == want[0].tolist()
+    assert got[1].tolist() == want[1].tolist()
+    # host or device, one operand: the other forms compile nothing
+    with caplog.at_level(logging.DEBUG,
+                         logger="jax._src.interpreters.pxla"):
+        for other in forms.values():
+            again = knn.topk_device(block, q, 10, "euclidean",
+                                    mask=other, n_real=len(c))
+            assert again[0].tolist() == want[0].tolist()
+    assert not [r for r in caplog.records
+                if "Compiling" in r.getMessage()]
+
 
 
 # ---------------------------------------------------------------------------
