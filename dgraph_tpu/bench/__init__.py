@@ -1,8 +1,7 @@
 """Shared benchmark plumbing.
 
-The repo's benchmark entry points (bench_queries.py --concurrency,
-tools/dgbench.py, the tools/check.sh load smoke) all drive the same
-two primitives:
+The load harnesses (tools/dgbench.py, the tools/check.sh load smoke)
+drive the same two primitives:
 
   openloop   the open-loop arrival scheduler + latency/percentile
              summarizers (latency = finish - SCHEDULED arrival, so

@@ -1,9 +1,6 @@
-"""The synthetic scale-free graph of the BFS kernel benchmark, and its
-single-core NumPy traversal baseline.
-
-Shared by bench.py (which times the digest kernel over it) and
-bench/kernelcheck.py (which compiles the same kernel at the same shape
-on a served chip and checks it against the same baseline).
+"""The synthetic scale-free graph of the BFS digest kernel check, and
+its single-core NumPy traversal baseline (bench/kernelcheck.py compiles
+the kernel over it on a served chip and checks it against the baseline).
 """
 
 from __future__ import annotations

@@ -1,9 +1,8 @@
 """Compile-and-compare sweep over every device kernel the engine can
 reach, run INSIDE the process that owns the chip.
 
-Tier-1 runs on the CPU backend, where Pallas kernels are simulated and
-`ops/uidvec` picks its CPU lowerings — so "does this kernel compile on
-the accelerator, and does it still agree with its twin there" is a
+Tier-1 runs on the CPU backend, where `ops/uidvec` picks its CPU
+lowerings — so "does this kernel compile on the accelerator, and does it still agree with its twin there" is a
 question only the chip process can answer. `run()` answers it per
 kernel, at the shape the kernel has in service, against its host or
 XLA twin on the same inputs. chip_smoke.py calls it through
@@ -15,9 +14,8 @@ offers it.
 Each check reports {"ok", "shape", "seconds", ...detail} or, when the
 compiler or the runtime refuses, {"ok": false, "error": <its message>}.
 Nothing here falls back: a kernel that does not compile is recorded
-as exactly that. `tiny` shrinks every shape and `interpret` runs the
-Pallas kernels in the simulator — both exist for the CPU tests, which
-call `run()` directly; the HTTP route passes neither.
+as exactly that. `tiny` shrinks every shape — it exists for the CPU
+tests, which call `run()` directly; the HTTP route does not pass it.
 """
 
 from __future__ import annotations
@@ -25,13 +23,6 @@ from __future__ import annotations
 import time
 
 import numpy as np
-
-# relative error bounds against the float64 host dot, as a fraction of
-# |q|*|c| (Cauchy-Schwarz bounds sum |q_i c_i|): float32 products with
-# float32 accumulation over d=128, and a single bf16 pass (2^-8 per
-# operand) for the approximate int8 tier
-_TOL_F32 = 1e-5
-_TOL_BF16 = 1e-2
 
 
 def _rng(seed: int = 0):
@@ -68,111 +59,14 @@ def _badj(db, pred):
     return tab, badj
 
 
-def check_bucket_or_pallas(db, pred, interpret) -> dict:
-    """bucket_or_pallas on the loaded graph's own widest bucket, W=128
-    lanes, with more rows than one SMEM index table holds so the
-    chunked path runs; twin = the XLA row-gather fold."""
-    import jax.numpy as jnp
-
-    from dgraph_tpu.ops import pallas_kernels as pk
-    from dgraph_tpu.ops.bitgraph import _gather_or
-
-    tab, badj = _badj(db, pred)
-    b = max(badj.buckets, key=lambda b: b.in_nb.shape[0] * b.degree)
-    m, d = b.in_nb.shape
-    # four index tables' worth of rows bounds the grid (one step per
-    # (row, neighbor)) while still splitting across calls
-    rows = min(m, max(1, 4 * pk.SMEM_IDX_CAPACITY // d))
-    in_nb = b.in_nb[:rows]
-    w = 128
-    f = _rng(1).integers(0, 2**32, (badj.n_slots + 1, w),
-                         dtype=np.uint32)
-    f[-1] = 0  # the dummy slot is always empty
-    f = jnp.asarray(f)
-    got = np.asarray(pk.bucket_or_pallas(f, in_nb, interpret=interpret))
-    calls = -(-rows * d // pk.SMEM_IDX_CAPACITY)
-    want = np.asarray(_gather_or(f, in_nb, b.degree))
-    return {"ok": bool(np.array_equal(got, want)), "pred": tab.pred,
-            "shape": {"f": [badj.n_slots + 1, w],
-                      "in_nb": [rows, d], "bucket_rows": m},
-            "smem_calls": calls}
-
-
-def check_bitmap_and_pallas(tiny, interpret) -> dict:
-    import jax.numpy as jnp
-
-    from dgraph_tpu.ops.pallas_kernels import bitmap_and_pallas
-
-    bsz = 16 if tiny else 1024
-    rng = _rng(2)
-    a = rng.integers(0, 2**32, (bsz, 2048), dtype=np.uint32)
-    b = rng.integers(0, 2**32, (bsz, 2048), dtype=np.uint32)
-    got = np.asarray(bitmap_and_pallas(jnp.asarray(a), jnp.asarray(b),
-                                       interpret=interpret))
-    return {"ok": bool(np.array_equal(got, a & b)),
-            "shape": [bsz, 2048]}
-
-
 def _vec_inputs(tiny):
-    """SIFT1M's shape: 1M x 128 corpus (padded to the score tile), a
-    256-query batch."""
-    from dgraph_tpu.ops.pallas_kernels import SCORE_TILE_N
-    n = 4096 if tiny else 1_000_000
-    n_pad = -(-n // SCORE_TILE_N) * SCORE_TILE_N
+    """SIFT1M's shape: 1M x 128 corpus, a 256-query batch."""
     rng = _rng(3)
-    corpus = np.zeros((n_pad, 128), np.float32)
-    corpus[:n] = rng.standard_normal((n, 128), dtype=np.float32)
+    corpus = rng.standard_normal((4096 if tiny else 1_000_000, 128),
+                                 dtype=np.float32)
     queries = rng.standard_normal((16 if tiny else 256, 128),
                                   dtype=np.float32)
-    return n, corpus, queries
-
-
-def _dot_error(got: np.ndarray, corpus, queries, cols) -> float:
-    """max |got - float64 dot| / (|q| |c|) over a column sample."""
-    c = corpus[cols].astype(np.float64)
-    q = queries.astype(np.float64)
-    want = q @ c.T
-    scale = np.outer(np.linalg.norm(q, axis=1), np.linalg.norm(c, axis=1))
-    return float(np.max(np.abs(got[:, cols] - want)
-                        / np.maximum(scale, 1e-30)))
-
-
-def check_score_dot_pallas(tiny, interpret) -> dict:
-    import jax.numpy as jnp
-
-    from dgraph_tpu.ops.pallas_kernels import score_dot_pallas
-
-    n, corpus, queries = _vec_inputs(tiny)
-    got = np.asarray(score_dot_pallas(jnp.asarray(corpus),
-                                      jnp.asarray(queries),
-                                      interpret=interpret))
-    cols = _rng(4).choice(n, min(n, 65536), replace=False)
-    err = _dot_error(got, corpus, queries, cols)
-    return {"ok": err <= _TOL_F32, "rel_err": err, "tol": _TOL_F32,
-            "shape": {"corpus": list(corpus.shape),
-                      "queries": list(queries.shape)}}
-
-
-def check_score_int8_pallas(tiny, interpret) -> dict:
-    import jax.numpy as jnp
-
-    from dgraph_tpu.ops.pallas_kernels import (
-        score_int8_pallas, score_int8_xla,
-    )
-
-    n, corpus, queries = _vec_inputs(tiny)
-    codes = np.clip(np.rint(corpus * 32), -127, 127).astype(np.int8)
-    dc, dq = jnp.asarray(codes), jnp.asarray(queries)
-    got = np.asarray(score_int8_pallas(dc, dq, interpret=interpret))
-    twin = np.asarray(score_int8_xla(dc, dq))
-    cols = _rng(5).choice(n, min(n, 65536), replace=False)
-    err = _dot_error(got, codes, queries, cols)
-    err_twin = _dot_error(twin, codes, queries, cols)
-    return {"ok": err <= _TOL_BF16 and err_twin <= _TOL_BF16,
-            "rel_err": err, "rel_err_xla_twin": err_twin,
-            "tol": _TOL_BF16,
-            "shape": {"codes": list(codes.shape),
-                      "queries": list(queries.shape)}}
+    return corpus, queries
 
 
 def check_knn_exact(tiny) -> dict:
@@ -180,8 +74,7 @@ def check_knn_exact(tiny) -> dict:
     SETS — the tier is documented exact, so nothing is loosened."""
     from dgraph_tpu.ops import knn
 
-    n, corpus, queries = _vec_inputs(tiny)
-    corpus = corpus[:n]
+    corpus, queries = _vec_inputs(tiny)
     k, n_host = 10, (8 if tiny else 64)
     out = {}
     ok = True
@@ -195,12 +88,12 @@ def check_knn_exact(tiny) -> dict:
                        "topk_sets_equal": int(sum(same))}
         ok = ok and all(same)
     return {"ok": ok, "k": k, "metrics": out,
-            "shape": {"corpus": [n, 128],
+            "shape": {"corpus": list(corpus.shape),
                       "queries": list(queries.shape)}}
 
 
 def check_bfs_digest(tiny) -> dict:
-    """make_bfs_digest_batched (XLA gathers) at bench.py's default
+    """make_bfs_digest_batched (XLA gathers) at the serving batch
     shape; twin = the NumPy CSR BFS on the first 32 queries."""
     import jax.numpy as jnp
 
@@ -356,12 +249,12 @@ def check_fused_rank_page(tiny) -> dict:
 
 
 def run(db, pred: str | None = None, checks: tuple = (),
-        tiny: bool = False, interpret: bool = False) -> dict:
+        tiny: bool = False) -> dict:
     """Run the named checks (all by default); never raises for a
     kernel's own failure. `pred` names the uid predicate whose
-    bitadjacency sssp_dist and bucket_or_pallas use, range_select
-    picks the store's largest numeric predicate; the rest make their
-    own inputs and need no data. The
+    bitadjacency sssp_dist uses, range_select picks the store's
+    largest numeric predicate; the rest make their own inputs and
+    need no data. The
     memory-hungriest check (the BFS digest at its benchmark batch)
     goes first, before the others have touched the allocator."""
     from dgraph_tpu.utils.backend import device_report
@@ -373,14 +266,6 @@ def run(db, pred: str | None = None, checks: tuple = (),
         ("fused_rank_page", lambda: check_fused_rank_page(tiny)),
         ("setops_cosort", lambda: check_setops_cosort(tiny)),
         ("knn_exact", lambda: check_knn_exact(tiny)),
-        ("bucket_or_pallas",
-         lambda: check_bucket_or_pallas(db, pred, interpret)),
-        ("bitmap_and_pallas",
-         lambda: check_bitmap_and_pallas(tiny, interpret)),
-        ("score_dot_pallas",
-         lambda: check_score_dot_pallas(tiny, interpret)),
-        ("score_int8_pallas",
-         lambda: check_score_int8_pallas(tiny, interpret)),
     ]
     unknown = set(checks) - {name for name, _ in table}
     if unknown:
@@ -398,5 +283,5 @@ def run(db, pred: str | None = None, checks: tuple = (),
                    "error": f"{type(e).__name__}: {e}"[:2000]}
         res["seconds"] = round(time.monotonic() - t0, 2)
         kernels[name] = res
-    return {"device": device_report(), "interpret": bool(interpret),
-            "tiny": bool(tiny), "kernels": kernels}
+    return {"device": device_report(), "tiny": bool(tiny),
+            "kernels": kernels}

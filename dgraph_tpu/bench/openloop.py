@@ -10,9 +10,8 @@ coordinated-omission correction; the reference load-tests the same way
 with its `dgraph counter`/increment traffic tools at fixed rates,
 SURVEY §4.5).
 
-Factored out of bench_queries.py --concurrency so the single-node
-batching gate, the cluster harness (tools/dgbench.py) and the CI load
-smoke share ONE definition of "offered load" and "p99".
+The cluster harness (tools/dgbench.py) and the CI load smoke share
+this ONE definition of "offered load" and "p99".
 """
 
 from __future__ import annotations
@@ -81,7 +80,7 @@ def run_open_loop(submit: Callable, reqs: Sequence,
 
 
 def percentiles(lat: Sequence[float]) -> dict:
-    """The BENCH_BATCH.json column shape: p50/p99/mean in ms."""
+    """p50/p99/mean in ms."""
     import numpy as np
 
     a = np.asarray(lat) * 1e3

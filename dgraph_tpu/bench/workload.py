@@ -82,7 +82,7 @@ ZIPF_READ_MIX = (
     ("zipf_agg", 0.20),
 )
 
-# --mix name -> weights table (tools/dgbench.py, scale-out bench)
+# --mix name -> weights table (tools/dgbench.py)
 MIXES = {"default": DEFAULT_MIX, "zipf-read": ZIPF_READ_MIX}
 
 ZIPF_S = 1.1  # the exponent: ~YCSB's scrambled-zipfian skew

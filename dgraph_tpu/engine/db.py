@@ -152,15 +152,10 @@ class GraphDB:
                  rollup_window: int = 0,
                  prefer_columnar: bool = True,
                  prefer_compressed: bool = True,
-                 host_tile_budget: int = 512 << 20,
                  plan_cache_size: int = 128,
                  planner: str = "auto",
                  vec_quantized: bool = True,
                  vec_index_min_rows: int = 1 << 17,
-                 vec_target_recall: float = 0.98,
-                 vec_nprobe: int | None = None,
-                 vec_rerank: int | None = None,
-                 vec_max_k: int = 128,
                  result_cache_entries: int = 0,
                  prefer_fused: bool = True,
                  fused_min_rows: int = 1024,
@@ -261,7 +256,7 @@ class GraphDB:
         # default) disables — every store load stays synchronous and
         # the query path takes zero new branches. Opt-in because it
         # only pays on store-backed engines whose working set exceeds
-        # tablet_budget (the BENCH_500M regime)
+        # tablet_budget
         self.prefetcher = None
         if prefetch_workers and self.tablet_store is not None:
             from dgraph_tpu.engine.prefetch import PrefetchPool
@@ -282,19 +277,13 @@ class GraphDB:
         # (`@index(vector(ivf))`; `@index(vector)` stays exact at any
         # size), trained at rollup on clean base blocks once such a
         # predicate crosses vec_index_min_rows (below it the exact
-        # tiers are already fast), recall budgeted by
-        # vec_target_recall at build. vec_quantized=False removes the
-        # tier everywhere (the exact-path parity oracle, same policy
-        # as prefer_columnar); vec_nprobe / vec_rerank override the
-        # calibrated probe count and re-rank depth; k > vec_max_k
-        # falls back to the exact tiers (calibration holds at
-        # k_ref=10, not at arbitrary depth)
+        # tiers are already fast), recall budgeted at build
+        # (ops/ivf.TARGET_RECALL), probe count and re-rank depth as
+        # calibrated. vec_quantized=False removes the tier everywhere
+        # (the exact-path parity oracle, same policy as
+        # prefer_columnar)
         self.vec_quantized = vec_quantized
         self.vec_index_min_rows = vec_index_min_rows
-        self.vec_target_recall = vec_target_recall
-        self.vec_nprobe = vec_nprobe
-        self.vec_rerank = vec_rerank
-        self.vec_max_k = vec_max_k
         # background rollups lag this many LOGICAL ts behind the
         # newest commit, so pinned snapshot readers (zero-issued
         # global ts) rarely find their snapshot already folded; a
@@ -306,8 +295,7 @@ class GraphDB:
         # HBM residency budget for device tiles (ref posting/lists.go
         # LRU bound on cached posting lists) + host budget for the
         # columnar/compressed exports riding the same LRU
-        self.device_cache = DeviceCacheLRU(device_hbm_budget,
-                                           host_tile_budget)
+        self.device_cache = DeviceCacheLRU(device_hbm_budget)
         self.enc_key = enc_key
         # cross-group 2PC participants: start_ts -> (staged ops, keys).
         # Replicated via ("xstage", ...) records so the stage survives
@@ -1607,8 +1595,7 @@ class GraphDB:
                 continue
             try:
                 tab.build_vector_ivf(
-                    min_rows=self.vec_index_min_rows,
-                    target_recall=self.vec_target_recall)
+                    min_rows=self.vec_index_min_rows)
             except Exception as e:
                 from dgraph_tpu.utils.logger import log
                 log.error("vector_index_build_failed", pred=tab.pred,
@@ -1623,9 +1610,7 @@ class GraphDB:
         tab = self.tablets.get(pred)
         if tab is None:
             raise ValueError(f"no tablet for predicate {pred!r}")
-        ix = tab.build_vector_ivf(
-            nlist=nlist, force=force,
-            target_recall=self.vec_target_recall)
+        ix = tab.build_vector_ivf(nlist=nlist, force=force)
         return ix.describe() if ix is not None else None
 
     def state(self) -> dict:

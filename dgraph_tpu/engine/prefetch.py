@@ -2,9 +2,9 @@
 
 At the 500M regime most tablets live in the cold store (group-varint
 blobs behind engine/lazy_tablets.TabletStore), and a query that touches
-a non-resident predicate pays the whole blob fetch + decode inline —
-the decode STALL the BENCH_500M report measures. This pool moves that
-decode off the query's critical path: the executor announces the
+a non-resident predicate pays the whole blob fetch + decode inline:
+the decode stall. This pool moves that decode off the query's
+critical path: the executor announces the
 predicates a parsed query MAY touch (query/fusion.collect_preds)
 before running its first block, a bounded worker pool decodes the
 stored blobs concurrently, and TabletMap.get consumes the decoded

@@ -338,34 +338,18 @@ def _gather_or(f: jax.Array, in_nb: jax.Array, degree: int) -> jax.Array:
 
 
 def make_bfs_bits_batched(badj: BitAdjacency, depth: int,
-                          dedup: bool = True,
-                          use_pallas: bool | None = None,
-                          pallas_interpret: bool = False
-                          ) -> Callable:
+                          dedup: bool = True) -> Callable:
     """Compile multi-query BFS: packed uint32[N+1, W] seed frontier ->
     tuple of per-level packed frontiers (same shape).
 
     One device call runs 32*W independent traversals. Per-edge work is
-    one row-gather + OR — under XLA as D separate [M, W] gathers (no
-    [M, D, W] intermediate), or with use_pallas as the scalar-prefetch
-    Pallas kernel (ops/pallas_kernels.bucket_or_pallas) that DMAs each
-    needed frontier row HBM->VMEM directly. use_pallas is an explicit
-    opt-in (None -> XLA); a kernel Mosaic refuses raises at the first
-    call, it is never swapped for the XLA path behind the caller."""
+    one row-gather + OR: D separate [M, W] gathers (no [M, D, W]
+    intermediate)."""
     ncov = badj.n_covered
     n = badj.n_slots
-    if use_pallas is None:
-        use_pallas = False
-
-    def bucket_or(f, b):
-        if use_pallas and f.shape[1] % 128 == 0:
-            from dgraph_tpu.ops.pallas_kernels import bucket_or_pallas
-            return bucket_or_pallas(f, b.in_nb,
-                                    interpret=pallas_interpret)
-        return _gather_or(f, b.in_nb, b.degree)
 
     def level(f):
-        parts = [bucket_or(f, b) for b in badj.buckets]
+        parts = [_gather_or(f, b.in_nb, b.degree) for b in badj.buckets]
         W = f.shape[1]
         tail = n - ncov
         if tail:
@@ -526,10 +510,7 @@ def uid_lists_to_seed_slots(badj: BitAdjacency,
 
 def make_bfs_digest_batched(badj: BitAdjacency, core: CoreAdjacency,
                             depth: int, n_queries: int,
-                            n_seeds: int,
-                            use_pallas: bool | None = None,
-                            pallas_interpret: bool = False
-                            ) -> Callable:
+                            n_seeds: int) -> Callable:
     """Compile the serving-shape BFS: int32[B, S] seed slots ->
     (uint32[depth] per-level popcount checksums,
      uint32[n_core+1, 1] final level's first word column).
@@ -539,24 +520,13 @@ def make_bfs_digest_batched(badj: BitAdjacency, core: CoreAdjacency,
     per batch — never an [N, W] bitmap. Level 1 gathers the full
     adjacency once; every deeper level runs in core slot space. Only
     frontier+visited (+ the level's reach) are live — no per-level
-    bitmap pile-up, which is what held BENCH_BATCH at 8192 on a 16GB
+    bitmap pile-up, which is what held the batch at 8192 on a 16GB
     chip (ref regime: worker/task.go:581 fan-out at systest/21million
     scale). The first-word column ships ~n_core*4 bytes so the caller
     can parity-check queries 0..31 via make_frontier_counts_batched
     without pulling a full bitmap."""
     N, ncov = badj.n_slots, badj.n_covered
     W = (n_queries + 31) // 32
-    # same opt-in convention as make_bfs_bits_batched: None -> XLA.
-    # The pallas kernel needs lane-aligned W.
-    if use_pallas is None:
-        use_pallas = False
-
-    def gather_or(f, b):
-        if use_pallas and f.shape[1] % 128 == 0:
-            from dgraph_tpu.ops.pallas_kernels import bucket_or_pallas
-            return bucket_or_pallas(f, b.in_nb,
-                                    interpret=pallas_interpret)
-        return _gather_or(f, b.in_nb, b.degree)
 
     def digest(seed_slots: jax.Array):
         q = jnp.arange(n_queries, dtype=jnp.uint32)
@@ -568,7 +538,8 @@ def make_bfs_digest_batched(badj: BitAdjacency, core: CoreAdjacency,
         f = f.at[N].set(jnp.uint32(0))   # dummy slot absorbs padding
         zrow = jnp.zeros((1, W), jnp.uint32)
         if badj.buckets:
-            parts = [gather_or(f, b) for b in badj.buckets]
+            parts = [_gather_or(f, b.in_nb, b.degree)
+                     for b in badj.buckets]
             reach1 = jnp.concatenate(parts) if len(parts) > 1 else parts[0]
         else:
             reach1 = jnp.zeros((ncov, W), jnp.uint32)
@@ -581,7 +552,8 @@ def make_bfs_digest_batched(badj: BitAdjacency, core: CoreAdjacency,
         frontier = jnp.concatenate([new[core.row_slots], zrow])
         visited = jnp.concatenate([vis_s[core.row_slots], zrow])
         for _ in range(depth - 1):
-            parts = [gather_or(frontier, b) for b in core.buckets]
+            parts = [_gather_or(frontier, b.in_nb, b.degree)
+                     for b in core.buckets]
             reach = jnp.concatenate(parts + [zrow])
             frontier = reach & ~visited
             visited = visited | frontier

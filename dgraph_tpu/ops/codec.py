@@ -164,8 +164,7 @@ def decode_padded(pack: UidPack32, size: int) -> jax.Array:
 #
 # Encode is host/numpy at export time (rollup-path, like UidPack32);
 # the decode/membership kernels are vectorized numpy on host with the
-# bitmap word ops mirrored on device (ops/setops.py + the Pallas
-# bitmap kernel in ops/pallas_kernels.py).
+# bitmap word ops mirrored on device (ops/setops.py).
 # ======================================================================
 
 BLOCK_SPAN = 1 << 16          # uid space per block (key = uid >> 16)

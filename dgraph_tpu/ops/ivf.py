@@ -8,9 +8,7 @@ both retrieved papers point at (PAPERS.md):
   TPU-KNN (2206.14286) — keep the distance computation a dense matmul
     so it runs at peak throughput: centroid scoring is a (q, d) x
     (d, nc) dot, candidate scoring a gathered (R, d) int8
-    dequant-and-dot, both MXU-shaped (the Pallas tile kernel is
-    ops/pallas_kernels.score_int8_pallas; the jitted XLA contraction
-    below is the CPU-parity fallback).
+    dequant-and-dot, both MXU-shaped.
 
   A Faster Generalized Two-Stage Approximate Top-K (2506.04165) —
     budget the approximate stage from a recall target and finish with
@@ -401,49 +399,6 @@ def _approx_scores_host(ivf: IVFIndex, lists: np.ndarray,
              for dp in dot_parts])
 
 
-def _approx_scores_pallas(ivf: IVFIndex, lists: np.ndarray,
-                          cs: np.ndarray, q: np.ndarray,
-                          interpret: bool
-                          ) -> tuple[list, list]:
-    """The same per-query (slots, approx dots) through the MXU tile
-    kernel (ops/pallas_kernels.score_int8_pallas): per query, gather
-    the probed slices into one padded int8 block and run the
-    dequant-and-dot kernel. Tests pass interpret=True on small
-    corpora (parity vs the host engine)."""
-    from dgraph_tpu.ops.pallas_kernels import (
-        SCORE_TILE_N, score_int8_pallas,
-    )
-    import jax.numpy as jnp
-
-    slot_out: list[np.ndarray] = []
-    dot_out: list[np.ndarray] = []
-    for qi in range(len(lists)):
-        parts = []
-        cent = []
-        for li in lists[qi]:
-            s, e = int(ivf.starts[li]), int(ivf.starts[li + 1])
-            if e > s:
-                parts.append(np.arange(s, e, dtype=np.int64))
-                cent.append(np.full(e - s, cs[qi, li], np.float32))
-        if not parts:
-            slot_out.append(np.empty(0, np.int64))
-            dot_out.append(np.empty(0, np.float32))
-            continue
-        slots = np.concatenate(parts)
-        n_pad = -len(slots) % SCORE_TILE_N
-        codes_g = ivf.codes[slots]
-        if n_pad:
-            codes_g = np.concatenate(
-                [codes_g, np.zeros((n_pad, ivf.dim), np.int8)])
-        dots = np.asarray(score_int8_pallas(
-            jnp.asarray(codes_g), jnp.asarray(q[qi][None]),
-            interpret=interpret))[0][:len(slots)]
-        dot_out.append(dots * ivf.scales[slots]
-                       + np.concatenate(cent))
-        slot_out.append(slots)
-    return slot_out, dot_out
-
-
 def _cut_top_r(slots: np.ndarray, approx: np.ndarray, r: int
                ) -> tuple[np.ndarray, np.ndarray]:
     """Deterministic top-r truncation by (-approx, slot): every slot
@@ -517,8 +472,6 @@ def search(ivf: IVFIndex, vecs: np.ndarray, queries: np.ndarray,
            k: int, metric: str = "cosine",
            keep: np.ndarray | None = None,
            nprobe: int | None = None, rerank: int | None = None,
-           use_pallas: bool = False,
-           pallas_interpret: bool = False,
            count: bool = True) -> tuple[np.ndarray, np.ndarray]:
     """Quantized top-k: IVF probe -> int8 approximate scores ->
     exact float64 re-rank of the top `rerank` survivors. Returns
@@ -542,11 +495,7 @@ def search(ivf: IVFIndex, vecs: np.ndarray, queries: np.ndarray,
                            p, str(metric))
     cs = np.asarray(cs)
     lists = np.asarray(lists, np.int64)
-    if use_pallas:
-        slot_l, dot_l = _approx_scores_pallas(ivf, lists, cs, q,
-                                              pallas_interpret)
-    else:
-        slot_l, dot_l = _approx_scores_host(ivf, lists, cs, q)
+    slot_l, dot_l = _approx_scores_host(ivf, lists, cs, q)
     keep_b = np.asarray(keep, bool) if keep is not None else None
     qn2 = (q.astype(np.float64) ** 2).sum(axis=1)
     out_i = np.full((nq, k), -1, np.int64)

@@ -27,10 +27,7 @@ answer.
 
 Three tiers, matching the repo's conventions:
   host    — numpy exact (float64 accumulate) for small/dirty data;
-  device  — jitted scoring + proved two-stage, or lax.top_k; scoring
-            can route through a Pallas MXU tile kernel behind the
-            existing `use_pallas` opt-in convention (ops/bitgraph.py:
-            None resolves to False, callers own warmup+fallback);
+  device  — jitted scoring + proved two-stage, or lax.top_k;
   sharded — corpus rows sharded over a mesh axis via shard_map
             (parallel/dist_knn.py), per-shard top-k then a k-way merge.
 
@@ -186,23 +183,17 @@ def topk_host(corpus: np.ndarray, queries: np.ndarray, k: int,
 # ---------------------------------------------------------------------------
 
 
-def _score_device(corpus, queries, metric: str, use_pallas: bool,
-                  pallas_interpret):
+def _score_device(corpus, queries, metric: str):
     import jax.numpy as jnp
 
-    if use_pallas:
-        from dgraph_tpu.ops.pallas_kernels import score_dot_pallas
-        dots = score_dot_pallas(corpus, queries,
-                                interpret=pallas_interpret)
-    else:
-        # HIGHEST: a TPU multiplies float32 operands in ONE bfloat16
-        # pass by default, which reorders near-tied neighbours (v5e,
-        # 1M x 128: 56/64 cosine and 61/64 euclidean top-10 sets
-        # equal to the float64 host's). This tier is the EXACT one;
-        # the approximate tiers (two-stage buckets, IVF) re-rank.
-        dots = jnp.dot(queries, corpus.T,
-                       preferred_element_type=jnp.float32,
-                       precision=jax.lax.Precision.HIGHEST)
+    # HIGHEST: a TPU multiplies float32 operands in ONE bfloat16
+    # pass by default, which reorders near-tied neighbours (v5e,
+    # 1M x 128: 56/64 cosine and 61/64 euclidean top-10 sets
+    # equal to the float64 host's). This tier is the EXACT one;
+    # the approximate tiers (two-stage buckets, IVF) re-rank.
+    dots = jnp.dot(queries, corpus.T,
+                   preferred_element_type=jnp.float32,
+                   precision=jax.lax.Precision.HIGHEST)
     if metric == "dot":
         return dots
     if metric == "cosine":
@@ -264,16 +255,15 @@ def _two_stage_topk_dev(scores, k: int, l_per_bucket: int):
 
 @partial(jax.jit,
          static_argnames=("k", "metric", "two_stage", "l_per_bucket",
-                          "use_pallas", "pallas_interpret", "n_real"))
+                          "n_real"))
 def _topk_device_jit(corpus, queries, mask, k, metric, two_stage,
-                     l_per_bucket, use_pallas, pallas_interpret, n_real):
+                     l_per_bucket, n_real):
     """-> (vals, idx, fell_back): the exact top-k by (-score, row)
     (lax.top_k keeps the lower index first among equal scores), and
     whether a failed two-stage proof sent the batch to the full row."""
     import jax.numpy as jnp
 
-    scores = _score_device(corpus, queries, metric, use_pallas,
-                           pallas_interpret)
+    scores = _score_device(corpus, queries, metric)
     n_pad = scores.shape[1]
     col = jnp.arange(n_pad)
     invalid = col[None, :] >= n_real
@@ -295,16 +285,16 @@ def _topk_device_jit(corpus, queries, mask, k, metric, two_stage,
 DEVICE_PROGRAM = "jit_" + _topk_device_jit.__name__
 
 
-def padded_rows(n: int, unit: int = BUCKET_SIZE) -> int:
-    """Rows of a block of n once padded to a `unit` multiple."""
-    return max(unit, ((n + unit - 1) // unit) * unit)
+def padded_rows(n: int) -> int:
+    """Rows of a block of n once padded to a BUCKET_SIZE multiple."""
+    return max(BUCKET_SIZE, -(-n // BUCKET_SIZE) * BUCKET_SIZE)
 
 
-def pad_rows(corpus: np.ndarray, unit: int = BUCKET_SIZE) -> np.ndarray:
-    """Zero-pad the row axis to a `unit` multiple (host-side, ONCE per
-    block build) so topk_device never copies the corpus per query."""
+def pad_rows(corpus: np.ndarray) -> np.ndarray:
+    """Zero-pad the row axis to a BUCKET_SIZE multiple (host-side, ONCE
+    per block build) so topk_device never copies the corpus per query."""
     n, d = corpus.shape
-    n_pad = padded_rows(n, unit)
+    n_pad = padded_rows(n)
     if n_pad == n:
         return corpus
     out = np.zeros((n_pad, d), np.float32)
@@ -334,8 +324,6 @@ def topk_device(corpus_dev, queries: np.ndarray, k: int,
                 mask=None,
                 two_stage: bool | None = None,
                 l_per_bucket: int | None = None,
-                use_pallas: bool | None = None,
-                pallas_interpret: bool = False,
                 n_real: int | None = None,
                 sync=None, info: dict | None = None
                 ) -> tuple[np.ndarray, np.ndarray]:
@@ -358,12 +346,11 @@ def topk_device(corpus_dev, queries: np.ndarray, k: int,
     two_stage=None takes the proved two-stage reduce where
     plan_two_stage finds an L for it and lax.top_k over the full row
     otherwise; two_stage=True with an explicit l_per_bucket forces the
-    reduce at that L (a test's way to a failing proof). use_pallas
-    follows the repo convention: None resolves to False
-    (ops/bitgraph.py). `sync` is applied to the dispatched result
-    before it is fetched (query/devicecall.py's `dc.wait`); `info`
-    receives `exact_fallback` (a two-stage proof failed and the full
-    row was searched as well)."""
+    reduce at that L (a test's way to a failing proof). `sync` is
+    applied to the dispatched result before it is fetched
+    (query/devicecall.py's `dc.wait`); `info` receives
+    `exact_fallback` (a two-stage proof failed and the full row was
+    searched as well)."""
     import jax.numpy as jnp
 
     corpus_dev = jnp.asarray(corpus_dev, jnp.float32)
@@ -372,16 +359,9 @@ def topk_device(corpus_dev, queries: np.ndarray, k: int,
     # host arrays ride the jitted call: no upload (and no program) of
     # their own, each one more turn in the interpreter
     q = np.atleast_2d(np.asarray(queries, np.float32))
-    if use_pallas is None:
-        use_pallas = False
-    # pad the n axis so buckets tile exactly (and pallas tiles align —
-    # SCORE_TILE_N is a multiple of BUCKET_SIZE); padding scores are
+    # pad the n axis so buckets tile exactly; padding scores are
     # forced to -inf via n_real
-    unit = BUCKET_SIZE
-    if use_pallas:
-        from dgraph_tpu.ops.pallas_kernels import SCORE_TILE_N
-        unit = SCORE_TILE_N
-    n_pad = padded_rows(n_rows, unit)
+    n_pad = padded_rows(n_rows)
     if n_pad != n_rows:
         corpus_dev = jnp.concatenate(
             [corpus_dev, jnp.zeros((n_pad - n_rows, d), jnp.float32)])
@@ -402,8 +382,7 @@ def topk_device(corpus_dev, queries: np.ndarray, k: int,
             mask = mask_pad
     out = _topk_device_jit(
         corpus_dev, q, mask, int(k), str(metric), bool(two_stage),
-        int(l_per_bucket), bool(use_pallas), bool(pallas_interpret),
-        int(n))
+        int(l_per_bucket), int(n))
     if sync is not None:
         out = sync(out)
     # one fetch of the three results, not three

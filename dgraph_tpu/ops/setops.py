@@ -289,14 +289,13 @@ def _uids_of(key: int, lows: np.ndarray) -> np.ndarray:
     return (np.uint64(key) << np.uint64(16)) | lows.astype(np.uint64)
 
 
-def intersect_packs(packs, scratch=None, device: bool = False,
-                    use_pallas: bool = False,
-                    pallas_interpret: bool = False) -> np.ndarray:
+def intersect_packs(packs, scratch=None,
+                    device: bool = False) -> np.ndarray:
     """k-way intersection over compressed packs.  Per surviving key the
     SMALLEST block decodes once and the others answer membership in
     compressed form (bitmap bit test / run interval probe); all-bitmap
-    keys batch into one vectorized word-AND — on device (jit_stage /
-    Pallas) when `device` and enough blocks survive."""
+    keys batch into one vectorized word-AND — on device (jit_stage)
+    when `device` and enough blocks survive."""
     if not len(packs):
         return _EMPTY
     if any(p.n == 0 for p in packs):
@@ -336,9 +335,7 @@ def intersect_packs(packs, scratch=None, device: bool = False,
                              for i in bm_idx])
             mats.append(rows)
         if device and len(bm_idx) >= 8:
-            anded = bitmap_and_device(
-                mats, use_pallas=use_pallas,
-                pallas_interpret=pallas_interpret)
+            anded = bitmap_and_device(mats)
         else:
             anded = mats[0]
             for m in mats[1:]:
@@ -553,27 +550,16 @@ def _take(scratch, n, dtype=np.uint64):
     return scratch.take(n, dtype)
 
 
-def bitmap_and_device(mats, use_pallas: bool = False,
-                      pallas_interpret: bool = False):
+def bitmap_and_device(mats):
     """k-way AND of stacked bitmap word matrices ([B, 1024] uint64) in
     ONE device dispatch: uint64 splits into two uint32 lanes (TPUs
-    have no 64-bit integer ALU), the jitted fold ANDs all k mats, and
-    `use_pallas` routes the pairwise word-AND through the Mosaic
-    kernel (ops/pallas_kernels.bitmap_and_pallas)."""
+    have no 64-bit integer ALU) and the jitted fold ANDs all k mats."""
     import jax
     import jax.numpy as jnp
 
     from dgraph_tpu.query.plan import jit_stage
     k = len(mats)
     mats32 = [np.ascontiguousarray(m).view(np.uint32) for m in mats]
-    if use_pallas:
-        from dgraph_tpu.ops.pallas_kernels import bitmap_and_pallas
-        acc = mats32[0]
-        for m in mats32[1:]:
-            acc = np.asarray(bitmap_and_pallas(
-                jnp.asarray(acc), jnp.asarray(m),
-                interpret=pallas_interpret))
-        return np.ascontiguousarray(acc).view(np.uint64)
 
     def _fold(stack):
         out = stack[0]
@@ -640,8 +626,8 @@ def union_mixed(ops, scratch=None) -> np.ndarray:
     return union_many(dense)
 
 
-def intersect_mixed(ops, scratch=None, device: bool = False,
-                    use_pallas: bool = False) -> np.ndarray:
+def intersect_mixed(ops, scratch=None,
+                    device: bool = False) -> np.ndarray:
     """k-way intersection over mixed operands: the dense sides
     intersect smallest-first, then the (small) survivor vector probes
     each pack's membership in compressed form — blocks the survivors
@@ -655,8 +641,7 @@ def intersect_mixed(ops, scratch=None, device: bool = False,
     if not packs:
         return intersect_many(dense)
     if not dense:
-        return intersect_packs(packs, scratch=scratch, device=device,
-                               use_pallas=use_pallas)
+        return intersect_packs(packs, scratch=scratch, device=device)
     acc = intersect_many(dense) if len(dense) > 1 \
         else np.asarray(dense[0])
     for p in sorted(packs, key=lambda q: q.n):

@@ -86,7 +86,7 @@ def _sharded_step(mesh: Mesh, axis: str, per: int, k: int, k_eff: int,
     retrace, as jit always does; repeated shapes now hit the cache."""
 
     def step(rows, qm, keep):
-        scores = knn._score_device(rows, qm, metric, False, None)
+        scores = knn._score_device(rows, qm, metric)
         scores = jnp.where(keep[None, :], scores, -jnp.inf)
         vals, idx = jax.lax.top_k(scores, k_eff)       # (q, k) local
         shard = jax.lax.axis_index(axis)
@@ -139,7 +139,7 @@ def sharded_ivf_topk(mesh: Mesh, ivf, vecs: np.ndarray,
     merge-shaped for the multi-chip recipe, but not yet dispatched
     through shard_map like sharded_topk; device-dispatching the int8
     stage is ROADMAP depth (needs the codes block resident per
-    device + the pallas kernel per shard)."""
+    device)."""
     from dgraph_tpu.ops import ivf as _ivf
     import jax.numpy as jnp
 

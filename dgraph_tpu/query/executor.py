@@ -59,6 +59,9 @@ _FLAT_TYPES = {TypeID.INT, TypeID.FLOAT, TypeID.BOOL, TypeID.STRING,
 # value variable a similar_to() root/filter binds its per-uid scores
 # to, readable as val(similar_to_score) (see _eval_similar_to)
 SIMILAR_SCORE_VAR = "similar_to_score"
+# similar_to's quantized tier answers k up to this; a deeper k takes
+# the exact tiers (calibration holds at k_ref=10, not at any depth)
+_VEC_MAX_K = 128
 
 
 def _member_of(uids: np.ndarray, sorted_set: np.ndarray) -> np.ndarray:
@@ -413,8 +416,7 @@ class Executor:
             # announce the request's predicate working set before the
             # first block runs: cold-store blobs decode on the
             # prefetch pool while earlier blocks compute, and
-            # TabletMap.get consumes them on arrival (the decode-stall
-            # overlap BENCH_500M measures)
+            # TabletMap.get consumes them on arrival
             from dgraph_tpu.query.fusion import collect_preds
             pf.schedule(self.db, collect_preds(parsed))
         if self.plan is None:
@@ -831,7 +833,7 @@ class Executor:
         """THE chokepoint every columnar value read goes through: the
         tablet's cached column view (None on dirty/historical/mixed
         tablets or with the tier disabled), budgeted against the tile
-        LRU and counted so BENCH_QUERIES can report tier routing.
+        LRU and counted (tier routing shows in the counters).
         Memoized per request — one snapshot, one verdict — so a block
         that reads a column at eval AND emit time resolves, budgets
         and counts it once."""
@@ -1401,7 +1403,7 @@ class Executor:
             quant_ok = (ivf is not None and self.db.vec_quantized
                         and schema.vector_approx
                         and candidates is None
-                        and k <= self.db.vec_max_k)
+                        and k <= _VEC_MAX_K)
             # tier arbitration: the planner weighs the measured
             # dispatch RTT / observed per-stage cost against the
             # per-tier scanned-row counts (the quantized tier scores
@@ -1420,8 +1422,7 @@ class Executor:
                     and not force_device and self.db.mesh is None:
                 rows_by_tier = None
                 if quant_ok:
-                    rows_by_tier = {"quantized": ivf.scanned_rows(
-                        self.db.vec_nprobe)}
+                    rows_by_tier = {"quantized": ivf.scanned_rows()}
                 dec = self._tier_decision(
                     "similar_to", fn.attr,
                     {"estRows": n, "estRowsMax": n, "basis": "exact",
@@ -1461,8 +1462,7 @@ class Executor:
                 from dgraph_tpu.ops import ivf as _ivf
                 idx, sc = _ivf.search(
                     ivf, view.base_vecs, qm, k, metric,
-                    keep=host_mask(), nprobe=self.db.vec_nprobe,
-                    rerank=self.db.vec_rerank)
+                    keep=host_mask())
                 inc_counter("query_similar_quantized_total")
                 budget = self._vec_budget(ivf, k)
                 scanned = budget["scannedRows"]
@@ -1562,22 +1562,17 @@ class Executor:
             mask[:len(view.base_keep)] &= view.base_keep
         return mask
 
-    def _vec_rerank(self, k: int) -> int:
-        """Effective exact re-rank depth for the quantized tier."""
-        from dgraph_tpu.ops import ivf as _ivf
-        return int(self.db.vec_rerank or _ivf.rerank_depth(k))
-
     def _vec_budget(self, ivf, k: int) -> dict:
         """The quantized tier's live budget as EXPLAIN reports it —
         ONE builder so the sharded and single-device tiers.vector
         entries can't drift apart. nprobe clamps to nlist exactly
         like ops/ivf.search does."""
+        from dgraph_tpu.ops import ivf as _ivf
         return {
-            "nprobe": min(ivf.nlist,
-                          int(self.db.vec_nprobe or ivf.nprobe)),
-            "rerank": self._vec_rerank(k),
+            "nprobe": min(ivf.nlist, int(ivf.nprobe)),
+            "rerank": _ivf.rerank_depth(k),
             "nlist": ivf.nlist,
-            "scannedRows": ivf.scanned_rows(self.db.vec_nprobe),
+            "scannedRows": ivf.scanned_rows(),
             "sampleRecall": round(float(ivf.sample_recall), 4),
         }
 
@@ -1591,8 +1586,7 @@ class Executor:
         inc_counter("query_similar_sharded_total")
         return sharded_ivf_topk(
             self.db.mesh, ivf, view.base_vecs, qm, k, metric,
-            keep=base_mask, nprobe=self.db.vec_nprobe,
-            rerank=self.db.vec_rerank)
+            keep=base_mask)
 
     def _sharded_vec_topk(self, tab, view, qm, k, metric, base_mask):
         """Mesh-sharded scoring: the block rides the `uid` axis, each

@@ -84,8 +84,7 @@ from dgraph_tpu.utils import coststore, metrics
 TIERS = ("postings", "columnar", "compressed", "device")
 
 # -- documented static priors: (fixed_us, per_row_us) per (stage,
-# tier). docs/deployment.md publishes this table; re-measure against
-# `bench_micro.py --planner-overhead` + the round-5 constants when the
+# tier). docs/deployment.md publishes this table; re-measure when the
 # data plane changes. ORDERING invariant (checked by
 # tests/test_planner.py): for every stage and every row count,
 # compressed <= columnar <= postings, so cold decisions reproduce the
@@ -116,8 +115,7 @@ STATIC_PRIORS: dict[tuple[str, str], tuple[float, float]] = {
     # carries the probe's selectivity; per-row covers the int8
     # convert+gemm) vs MXU exact top-k vs host brute-force MIPS.
     # postings per-row is the MEASURED float64 host constant
-    # (~180 ms / 100k x 128 single query, BENCH_VECTORS
-    # host_exact_qps) — an optimistic figure here makes observed
+    # (~180 ms / 100k x 128 single query) — an optimistic figure here makes observed
     # quantized/device evidence "lose" to a fantasy host tier and
     # mis-routes similar_to onto a path that is orders slower
     ("similar_to", "quantized"): (6.0, 0.010),

@@ -2,8 +2,7 @@
 
 The per-edge ingest path (mutate -> overlay delta -> rollup fold) costs
 microseconds per triple in Python — honest for OLTP, hopeless for
-standing up a 500M-edge regime (BENCH_500M, tools/bench_500m.py) where
-seeding would take days. The reference has the same split: live writes
+standing up a 500M-edge regime, where seeding would take days. The reference has the same split: live writes
 go through the Raft/posting pipeline while dgraph bulk (bulk/loader.go,
 bulk/reduce.go) writes finished Badger SSTs directly. This module is
 that bulk lane: it builds the EXACT wire payload TabletStore.save would

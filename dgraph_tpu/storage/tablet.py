@@ -268,8 +268,7 @@ class CompressedTokenIndex:
     host_resident = True
 
     # below this posting-list length the roaring descriptor overhead
-    # exceeds the dense bytes it saves; measured crossover on the
-    # bench index shapes (bench_micro --setops-compressed)
+    # exceeds the dense bytes it saves
     PACK_MIN = 128
 
     __slots__ = ("packs", "rows", "offsets", "uids", "nbytes",

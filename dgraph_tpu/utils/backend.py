@@ -1,7 +1,7 @@
 """JAX start-up: which platform a process runs on, and where its
 persistent compile cache lives.
 
-One rule for every entry point (cli.main, bench.init_backend, the
+One rule for every entry point (cli.main, the root scripts, the
 tests): the platform is whatever JAX_PLATFORMS says, else the
 accelerator JAX finds, and finding none is an error — never a quiet
 CPU run. A CPU run (tests, the CI gates) is asked for with

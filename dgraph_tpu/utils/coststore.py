@@ -39,10 +39,8 @@ Mechanics:
   it automatically.
 
 The observer is ALWAYS ON once this module is imported (the engine
-imports it). Budget: one frozenset probe for non-stage spans, a few
-dict operations for stage spans — enforced by
-`bench_micro.py --stats-overhead` (< 1% on the 21M-regime summary
-queries) and the existing per-span budget in tests/test_tracing.py.
+imports it). Cost: one frozenset probe for non-stage spans, a few
+dict operations for stage spans.
 """
 
 from __future__ import annotations
@@ -190,9 +188,7 @@ class CostStore:
 
     def observe_span(self, rec: dict) -> None:
         """The tracing observer: aggregate one finished span record.
-        Runs on every stage span the process closes — bench_micro
-        --stats-overhead holds the whole plane under 1% of the
-        summary-query mix."""
+        Runs on every stage span the process closes."""
         name = rec["name"]
         if name not in STAGES or not self._enabled:
             return

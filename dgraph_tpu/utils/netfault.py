@@ -35,9 +35,7 @@ First matching rule wins (exact dst before "*", in arm order).
 by protocol, while duplicating a framed RPC would desynchronize the
 request/response pairing on the pooled client connection.
 
-Inert cost: `armed()` is one falsy-dict check — the transport seam
-gate (`bench_micro.py --netfault-overhead`) holds it under 1% of the
-summary mix. Determinism: `seed()` pins the module RNG so a chaos
+Inert cost: `armed()` is one falsy-dict check. Determinism: `seed()` pins the module RNG so a chaos
 schedule replays; the env var DGRAPH_TPU_NETFAULT (a JSON rule list)
 arms subprocess cluster nodes at boot, like DGRAPH_TPU_FAILPOINTS.
 """

@@ -13,10 +13,8 @@ Wall-clock on purpose: a thread blocked on a lock, a socket or the
 GIL is exactly what "where did my p99 go" needs to show — a CPU-only
 profile of a Python server under IO hides the story.
 
-Cost model (bench_micro.py --pprof-overhead gates it): each sample
-holds the GIL for one frames() walk, so overhead ≈ hz x per-sample
-walk time. At the default 100 Hz over a few dozen threads that is
-well under the 2% budget; `seconds` and `hz` are clamped so a typo'd
+Cost model: each sample holds the GIL for one frames() walk, so
+overhead ≈ hz x per-sample walk time; `seconds` and `hz` are clamped so a typo'd
 request cannot turn the profiler into a DoS.
 """
 

@@ -59,11 +59,6 @@ vector-clock TSan):
     once per armed window (a real race fires on every loop iteration
     — one report with both stacks is the signal, a thousand is log
     spam).
-
-Overhead is budgeted, not hoped for: bench_micro.py's
-`racecheck_overhead_bench` decomposes per-sampled-access cost ×
-sampled-access count on the batcher workload and tools/check.sh gates
-the product at < 5% (DGRAPH_TPU_RACECHECK_BUDGET).
 """
 
 from __future__ import annotations

@@ -31,8 +31,7 @@ exposes pprof profiles. Here:
   the device's ops. Outside a trace an annotation is a flag check.
 
 Spans are cheap (two clock reads + an 8-byte id + a deque append under
-GIL; budget < 5 µs each, enforced by bench_micro.py --span-overhead
-and tier-1) and on by default; the ring bounds memory: it is an
+GIL) and on by default; the ring bounds memory: it is an
 operator's view of the last few seconds (4,096 spans), not a record of
 a run — measurements read the per-request roll-ups
 (`extensions.server_latency`) and the counters. `set_enabled` turns

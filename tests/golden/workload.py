@@ -2,9 +2,9 @@
 
 The committed goldens validate scale 1; the same query strings run at
 any `generate(scale)` once their uid literals are remapped to the
-scaled uid bases. Shared by bench_queries.py (in-process host/device
-cross-check) and chip_smoke.py (the same queries over HTTP against a
-served snapshot). Pure stdlib on purpose: chip_smoke.py's parent
+scaled uid bases. Shared by chip_smoke.py (the queries over HTTP
+against a served snapshot) and tools/mesh_smoke.py (the same queries
+in-process over a mesh). Pure stdlib on purpose: chip_smoke.py's parent
 process must import this without importing jax or dgraph_tpu.
 """
 

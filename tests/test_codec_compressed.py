@@ -159,7 +159,7 @@ def test_intersect_disjoint_blocks_never_decodes():
     assert not calls, "disjoint blocks must not decode"
 
 
-def test_intersect_device_and_pallas_parity():
+def test_intersect_device_parity():
     rng = RNG(3)
     sets = [np.unique(rng.integers(0, 1 << 19, 150_000,
                                    dtype=np.uint64))
@@ -169,10 +169,6 @@ def test_intersect_device_and_pallas_parity():
     want = setops.intersect_many(sets)
     np.testing.assert_array_equal(
         setops.intersect_packs(packs, device=True), want)
-    np.testing.assert_array_equal(
-        setops.intersect_packs(packs, device=True, use_pallas=True,
-                               pallas_interpret=True),
-        want)
 
 
 # ------------------------------------------------- gv stream parity

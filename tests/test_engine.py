@@ -652,3 +652,18 @@ def test_count_reverse_filter():
     r = data(db2.query(
         '{ q(func: eq(count(~friend), 1)) { name } }'))
     assert sorted(x["name"] for x in r["q"]) == ["Glenn", "M", "Rick"]
+
+
+def test_graphdb_options_are_the_ones_callers_set():
+    """GraphDB's keyword set, pinned: an option comes (or comes back)
+    with the caller that sets it, and this list changes with it."""
+    import inspect
+
+    assert sorted(inspect.signature(GraphDB.__init__).parameters) == [
+        "device_hbm_budget", "device_min_edges", "enc_key",
+        "fused_min_rows", "mesh", "plan_cache_size", "planner",
+        "planner_explore", "prefer_columnar", "prefer_compressed",
+        "prefer_device", "prefer_fused", "prefetch_workers",
+        "result_cache_entries", "rollup_window", "self",
+        "shard_min_edges", "store_dir", "tablet_budget",
+        "vec_index_min_rows", "vec_quantized", "wal_path"]

@@ -1,8 +1,7 @@
-"""bench/kernelcheck.py at toy shapes with the Pallas kernels
-simulated: every check runs and agrees with its twin on the CPU
-backend. The real shapes are chip_smoke.py's job. `tiny` and
-`interpret` are arguments of `run()` only; the HTTP route passes
-neither and exists only where `alpha --kernelcheck` asked for it."""
+"""bench/kernelcheck.py at toy shapes: every check runs and agrees
+with its twin on the CPU backend. The real shapes are chip_smoke.py's
+job. `tiny` is an argument of `run()` only; the HTTP route does not
+pass it and exists only where `alpha --kernelcheck` asked for it."""
 
 import json
 import urllib.error
@@ -26,30 +25,37 @@ def db():
     return db
 
 
-def test_every_kernel_matches_its_twin(db):
-    out = kernelcheck.run(db, pred="starring", tiny=True, interpret=True)
-    assert out["device"]["platform"] == "cpu"
-    assert out["tiny"] and out["interpret"]
-    assert sorted(out["kernels"]) == [
-        "bfs_digest_xla", "bitmap_and_pallas", "bucket_or_pallas",
-        "fused_rank_page", "knn_exact", "range_select",
-        "score_dot_pallas", "score_int8_pallas", "setops_cosort",
-        "sssp_dist"]
-    bad = {k: v for k, v in out["kernels"].items() if not v["ok"]}
-    assert not bad, bad
+CHECKS = ["bfs_digest_xla", "fused_rank_page", "knn_exact",
+          "range_select", "setops_cosort", "sssp_dist"]
 
 
-def test_refusal_is_recorded_not_raised(db):
-    """interpret off on the CPU backend: Pallas refuses, the sweep
-    records the compiler's words and the other checks still run."""
-    out = kernelcheck.run(
-        db, checks=("bitmap_and_pallas", "fused_rank_page"), tiny=True)
-    assert not out["interpret"]
-    assert sorted(out["kernels"]) == ["bitmap_and_pallas",
-                                      "fused_rank_page"]
-    assert out["kernels"]["fused_rank_page"]["ok"]
-    refused = out["kernels"]["bitmap_and_pallas"]
-    assert not refused["ok"] and "interpret" in refused["error"]
+@pytest.mark.parametrize("name", CHECKS)
+def test_every_kernel_matches_its_twin(db, name):
+    out = kernelcheck.run(db, pred="starring", checks=(name,),
+                          tiny=True)
+    assert out["device"]["platform"] == "cpu" and out["tiny"]
+    assert list(out["kernels"]) == [name]
+    assert out["kernels"][name]["ok"], out["kernels"][name]
+
+
+def test_refusal_is_recorded_not_raised(db, monkeypatch):
+    """A check the compiler refuses: the sweep records the compiler's
+    words and the other checks still run. Every check is a stand-in
+    here, so the whole table runs and names itself."""
+    def refuse(*_a):
+        raise RuntimeError("Mosaic: not implemented")
+
+    for fn in ("check_bfs_digest", "check_sssp_dist",
+               "check_range_select", "check_fused_rank_page",
+               "check_knn_exact"):
+        monkeypatch.setattr(kernelcheck, fn, lambda *_a: {"ok": True})
+    monkeypatch.setattr(kernelcheck, "check_setops_cosort", refuse)
+    out = kernelcheck.run(db, tiny=True)
+    assert sorted(out["kernels"]) == CHECKS
+    refused = out["kernels"].pop("setops_cosort")
+    assert not refused["ok"] and refused["error"] == \
+        "RuntimeError: Mosaic: not implemented"
+    assert all(v["ok"] for v in out["kernels"].values())
 
 
 def _post(url):

@@ -1,7 +1,7 @@
 """Async cold-store prefetch (engine/prefetch.PrefetchPool): decode
 overlap, at-most-once handover, staleness discard, metric wiring and
-shutdown — the pipeline BENCH_500M leans on to hide tablet decode
-behind query compute."""
+shutdown — the pipeline that hides tablet decode behind query
+compute."""
 
 import time
 

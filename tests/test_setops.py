@@ -98,6 +98,80 @@ def test_device_variants_parity():
         assert np.array_equal(di, _oracle_intersect(parts))
 
 
+def _pair(n_a, ratio, overlap, seed=3):
+    """Two sorted unique uint32 lists, |b| = n_a * ratio, about
+    `overlap` of a's elements shared (the reference's sweep axes,
+    algo/uidlist_test.go BenchmarkListIntersect*)."""
+    rng = np.random.default_rng(seed)
+    b = np.unique(rng.integers(0, 4_000_000_000, n_a * ratio,
+                               dtype=np.uint32))
+    take = rng.random(len(b)) < (overlap * n_a / max(len(b), 1))
+    shared = b[take][:n_a]
+    fresh = np.unique(rng.integers(0, 4_000_000_000, n_a,
+                                   dtype=np.uint32))
+    a = np.unique(np.concatenate([shared, fresh]))[:n_a]
+    return a, b
+
+
+def _skewed_sliver():
+    # a clustered inside a sliver of b's range
+    rng = np.random.default_rng(11)
+    a = np.sort(rng.choice(
+        np.arange(1_000_000, 1_050_000, dtype=np.uint32),
+        2048, replace=False))
+    b = np.unique(rng.integers(0, 4_000_000_000, 64 * 2048,
+                               dtype=np.uint32))
+    return [(a, b)]
+
+
+def _dense_subset():
+    # every element of a is a hit, b barely bigger than a
+    rng = np.random.default_rng(5)
+    b = np.unique(rng.integers(0, 1_000_000, 6_000, dtype=np.uint32))
+    return [(np.sort(rng.choice(b, 4096, replace=False)), b)]
+
+
+def _identical_disjoint_empty():
+    rng = np.random.default_rng(9)
+    a = np.unique(rng.integers(0, 1 << 30, 3000, dtype=np.uint32))
+    e = np.empty(0, np.uint32)
+    return [(a, a.copy()), (a, a + np.uint32(1 << 30)), (e, a), (a, e)]
+
+
+def _every_other():
+    # shared values everywhere: a run of equal pairs with no gap
+    return [(np.arange(0, 4096, 2, dtype=np.uint32),
+             np.arange(0, 4096, 1, dtype=np.uint32))]
+
+
+_TIERS = {"host": setops.intersect_many,
+          "device": setops.intersect_many_device}
+
+
+def _check_pairs(pairs, tier):
+    for a, b in pairs:
+        got = _TIERS[tier]([a.astype(np.uint64), b.astype(np.uint64)])
+        assert got is not None
+        assert np.array_equal(
+            got, np.intersect1d(a, b, assume_unique=True))
+
+
+@pytest.mark.parametrize("tier", list(_TIERS))
+@pytest.mark.parametrize("n_a,ratio,overlap",
+                         [(2048, 1, 0.3), (2048, 8, 0.1),
+                          (1024, 16, 0.05), (4096, 2, 0.5)])
+def test_intersect_pair_sweep(n_a, ratio, overlap, tier):
+    _check_pairs([_pair(n_a, ratio, overlap)], tier)
+
+
+@pytest.mark.parametrize("pairs", [
+    _skewed_sliver, _dense_subset, _identical_disjoint_empty,
+    _every_other], ids=lambda f: f.__name__.lstrip("_"))
+def test_intersect_pair_shapes(pairs):
+    for tier in _TIERS:
+        _check_pairs(pairs(), tier)
+
+
 def test_device_variants_reject_wide_uids():
     wide = np.array([1, 2, 0xFFFFFFFF00], np.uint64)
     other = np.array([1, 2, 3], np.uint64)

@@ -18,8 +18,10 @@
 #                      a tier-1 collection error three releases later
 #
 # Every gate below runs on the CPU backend (JAX_PLATFORMS defaults to
-# cpu here): they check bytes, counts and host-side budgets. Whether
-# the program starts and answers correctly on a chip is chip_smoke.py's
+# cpu here): they check bytes and counts, and time nothing — what an
+# always-on plane costs is read from a benchmark cell with it on
+# against off (benchmark/run.py, PERF_LEDGER.jsonl). Whether the
+# program starts and answers correctly on a chip is chip_smoke.py's
 # question, asked on a machine that has one.
 #
 # Usage: tools/check.sh          (from the repo root)
@@ -32,8 +34,8 @@ python -m tools.dglint --changed-only --assert-empty-baseline \
     dgraph_tpu tests
 
 echo "== compileall =="
-python -m compileall -q dgraph_tpu tests tools bench.py bench_micro.py \
-    bench_queries.py bench_vectors.py chip_smoke.py __graft_entry__.py
+python -m compileall -q dgraph_tpu tests tools benchmark bench_micro.py \
+    chip_smoke.py __graft_entry__.py
 
 echo "== import-warnings sweep =="
 JAX_PLATFORMS="${JAX_PLATFORMS:-cpu}" \
@@ -50,27 +52,9 @@ echo "== fusion smoke =="
 JAX_PLATFORMS="${JAX_PLATFORMS:-cpu}" python -m tools.fusion_smoke
 
 echo "== cold-store smoke =="
-# miniature BENCH_500M: bulk-seeded store reopened under tablet-budget
-# pressure with async prefetch on; fused == staged == postings oracle
+# bulk-seeded store reopened under tablet-budget pressure with async
+# prefetch on; fused == staged == postings oracle
 JAX_PLATFORMS="${JAX_PLATFORMS:-cpu}" python -m tools.coldstore_smoke
-
-echo "== span overhead =="
-# per-span tracing cost vs the 5 µs budget (spans sit on executor hot
-# paths; tests/test_tracing.py enforces the same budget with CI slack)
-JAX_PLATFORMS="${JAX_PLATFORMS:-cpu}" python bench_micro.py --span-overhead
-
-echo "== stats overhead =="
-# the always-on statistics plane (coststore span observer + tablet
-# touch counters) must cost < 1% on the golden summary workload;
-# non-zero exit = over budget (DGRAPH_TPU_STATS_BUDGET overrides)
-JAX_PLATFORMS="${JAX_PLATFORMS:-cpu}" python bench_micro.py --stats-overhead
-
-echo "== planner overhead + smoke =="
-# adaptive-planner decision cost (consults x warm per-consult cost)
-# must stay < 1% of the summary mix, AND a warm pass must serve every
-# tier decision from the plan cache (zero rebuilds after convergence)
-# — non-zero exit on either (DGRAPH_TPU_PLANNER_BUDGET overrides)
-JAX_PLATFORMS="${JAX_PLATFORMS:-cpu}" python bench_micro.py --planner-overhead
 
 echo "== ann smoke =="
 # ~5 s quantized vector tier gate (tools/ann_smoke.py): train + query
@@ -82,38 +66,6 @@ echo "== ann smoke =="
 # SITES), so the dglint step above already gates their names.
 JAX_PLATFORMS="${JAX_PLATFORMS:-cpu}" python -m tools.ann_smoke
 
-echo "== pprof overhead =="
-# the on-demand sampling profiler at its default 100 Hz must cost
-# < 2% of throughput while active (decomposed per-sample x rate gate;
-# DGRAPH_TPU_PPROF_BUDGET overrides)
-JAX_PLATFORMS="${JAX_PLATFORMS:-cpu}" python bench_micro.py --pprof-overhead
-
-echo "== netfault overhead =="
-# the DISARMED network-fault seam on the wire hot paths (one
-# falsy-dict check per send) must cost < 1% of the summary mix
-# (decomposed gate; DGRAPH_TPU_NETFAULT_BUDGET overrides)
-JAX_PLATFORMS="${JAX_PLATFORMS:-cpu}" python bench_micro.py --netfault-overhead
-
-echo "== racecheck overhead =="
-# the ARMED attribute-access race witness (utils/racecheck, the
-# `racecheck` marker the tier-1 concurrency suites run under) must
-# cost < 5% of the summary mix (decomposed: per-sampled-access cost
-# x nominal accesses/op; DGRAPH_TPU_RACECHECK_BUDGET overrides)
-JAX_PLATFORMS="${JAX_PLATFORMS:-cpu}" python bench_micro.py --racecheck-overhead
-
-echo "== watchdog overhead =="
-# the always-on alerting plane (watchdog evaluator tick + the reqlog
-# observer feeding the SLO burn windows) must cost < 1% of the
-# summary mix (decomposed: tick duty cycle + per-observation cost;
-# DGRAPH_TPU_WATCHDOG_BUDGET overrides)
-JAX_PLATFORMS="${JAX_PLATFORMS:-cpu}" python bench_micro.py --watchdog-overhead
-
-echo "== compressed setops =="
-# compressed-vs-dense set algebra sweep: block-descriptor skipping
-# must beat decode-then-intersect on the selective-intersection
-# config, with full result parity (DGRAPH_TPU_SETOPS_BUDGET overrides)
-JAX_PLATFORMS="${JAX_PLATFORMS:-cpu}" python bench_micro.py --setops-compressed
-
 echo "== cluster load smoke =="
 # ~30 s mini-cluster open-loop run (1 zero + 2 single-replica groups,
 # tiny seeded graph, gentle fixed rate) through tools/dgbench.py:
@@ -123,7 +75,7 @@ echo "== cluster load smoke =="
 # the archived cluster-state artifact.
 SMOKE_DIR="${TMPDIR:-/tmp}/dgbench-smoke"
 JAX_PLATFORMS="${JAX_PLATFORMS:-cpu}" python -m tools.dgbench --smoke \
-    --report-dir "$SMOKE_DIR" --out "$SMOKE_DIR/BENCH_SMOKE.json"
+    --report-dir "$SMOKE_DIR" --out "$SMOKE_DIR/report.json"
 test -s "$SMOKE_DIR/dgtop.txt"   # the archived cluster-state artifact
 echo "smoke report: $SMOKE_DIR"
 
@@ -135,8 +87,8 @@ echo "== ingest smoke =="
 # single-core bulk_load oracle. Exit non-zero on any parity mismatch.
 INGEST_DIR="${TMPDIR:-/tmp}/dgingest-smoke"
 JAX_PLATFORMS="${JAX_PLATFORMS:-cpu}" python -m tools.dgingest --smoke \
-    --report-dir "$INGEST_DIR" --out "$INGEST_DIR/BENCH_INGEST.json"
-test -s "$INGEST_DIR/BENCH_INGEST.json"
+    --report-dir "$INGEST_DIR" --out "$INGEST_DIR/report.json"
+test -s "$INGEST_DIR/report.json"
 
 echo "== cdc smoke =="
 # ~5 s change-stream gate (tools/cdc_smoke.py): subscribe -> mutate ->
@@ -149,7 +101,8 @@ echo "== scaleout smoke =="
 # result-cache byte parity under churn (cached hit == uncached oracle,
 # footprint isolation), then a live 1 voter + 1 learner cluster —
 # learner conf-joins non-voting, serves the voter's exact bytes at one
-# zero-granted read_ts, best-effort reads observe fresh commits, and
+# zero-granted read_ts, best-effort reads observe fresh commits, a
+# SIGSTOPped learner refuses and never serves an older state, and
 # per-tenant QoS sheds a hot tenant without touching a quiet one.
 JAX_PLATFORMS="${JAX_PLATFORMS:-cpu}" python -m tools.scaleout_smoke
 
@@ -171,8 +124,8 @@ echo "== dr smoke =="
 DR_DIR="${TMPDIR:-/tmp}/dr-smoke"
 rm -rf "$DR_DIR"
 JAX_PLATFORMS="${JAX_PLATFORMS:-cpu}" python -m tools.dr_smoke \
-    --report-dir "$DR_DIR" --out "$DR_DIR/BENCH_DR.json"
-test -s "$DR_DIR/BENCH_DR.json"
+    --report-dir "$DR_DIR" --out "$DR_DIR/report.json"
+test -s "$DR_DIR/report.json"
 
 echo "== chaos smoke =="
 # ~45 s nemesis cycle on a 2-group mini cluster with durable dirs
@@ -183,7 +136,7 @@ echo "== chaos smoke =="
 CHAOS_DIR="${TMPDIR:-/tmp}/dgchaos-smoke"
 rm -rf "$CHAOS_DIR"   # durable dirs + history are per-run state
 JAX_PLATFORMS="${JAX_PLATFORMS:-cpu}" python -m tools.dgchaos --smoke \
-    --report-dir "$CHAOS_DIR" --out "$CHAOS_DIR/BENCH_CHAOS.json"
+    --report-dir "$CHAOS_DIR" --out "$CHAOS_DIR/report.json"
 test -s "$CHAOS_DIR/history.jsonl"   # the checked per-op history
 echo "chaos report: $CHAOS_DIR"
 

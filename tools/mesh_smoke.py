@@ -59,9 +59,11 @@ def main(argv=None) -> int:
     ap.add_argument("--devices", type=int, default=4)
     args = ap.parse_args(argv)
 
-    from bench import init_backend
-    from dgraph_tpu.utils.backend import device_report
-    devs, _platform = init_backend()
+    from dgraph_tpu.utils.backend import (
+        configure_compile_cache, device_report, require_devices,
+    )
+    configure_compile_cache()
+    devs = require_devices()
     if len(devs) < args.devices:
         raise SystemExit(f"need {args.devices} devices, have {devs}")
 

@@ -19,7 +19,13 @@ Part 2 — live cluster: 1 voter + 1 learner, cache + tenant QoS armed:
      data bytes as the voter at the SAME read_ts (replica parity);
   5. routed best-effort reads keep observing fresh writes (a read_ts
      granted after a commit can never see state older than it);
-  6. tenant QoS isolation: a hot tenant flooding reads degrades to
+  6. bounded staleness under a partition: the learner is SIGSTOPped
+     (a network-indistinguishable partition) while acked writes keep
+     advancing a counter, then SIGCONTed and read directly at fresh
+     zero grants. Every read it SERVES observes a counter >= the last
+     write acked before its grant; StaleRead / unreachable are
+     acceptable refusals, an older counter is a violation;
+  7. tenant QoS isolation: a hot tenant flooding reads degrades to
      typed sheds (Overloaded -> the 429 class) while a quiet tenant's
      trickle completes with ZERO errors.
 
@@ -29,7 +35,9 @@ Exit 0 = pass. Wired into tools/check.sh.
 from __future__ import annotations
 
 import json
+import signal
 import sys
+import threading
 import time
 
 
@@ -93,6 +101,84 @@ def part1_embedded() -> dict:
     return {"invalidations": inv}
 
 
+def bounded_staleness(pc, rc, laddr, lname: str) -> dict:
+    """Step 6: no read the learner serves is older than its grant."""
+    from dgraph_tpu.cluster.client import ClusterClient
+    from dgraph_tpu.cluster.errors import StaleRead
+
+    stop_s = 2.0
+    lcl = ClusterClient({1: laddr}, timeout=3.0)
+    state = {"acked": 0, "stop": False}
+    wlock = threading.Lock()
+
+    def writer():
+        # paced: the learner must be able to out-apply the stream or
+        # recovery never converges — the bound under test is
+        # staleness, not apply bandwidth
+        i = 0
+        while not state["stop"]:
+            i += 1
+            try:
+                rc.mutate(set_nquads=f'<0x77> <so.ctr> "{i}" .')
+            except Exception:  # noqa: BLE001 — keep writing  # dglint: disable=DG07 (nemesis load loop: a refused write just retries next tick)
+                continue
+            with wlock:
+                state["acked"] = i
+            time.sleep(0.1)
+
+    tallies = {"ok": 0, "refused": 0, "violation": 0}
+
+    def read_learner():
+        # the acked floor is captured BEFORE the grant, so every
+        # served value must be >= it
+        with wlock:
+            floor = state["acked"]
+        ts = rc.zero.read_ts()
+        try:
+            out = lcl.query_at(1, '{ q(func: uid(0x77)) { so.ctr } }',
+                               read_ts=ts, deadline_ms=2500)
+        except (StaleRead, ConnectionError, OSError):
+            tallies["refused"] += 1
+            return
+        rows = (out.get("data") or {}).get("q") or []
+        v = int(rows[0].get("so.ctr", 0)) if rows else 0
+        tallies["violation" if v < floor else "ok"] += 1
+
+    wt = threading.Thread(target=writer, daemon=True)
+    wt.start()
+    try:
+        end = time.monotonic() + 2.0
+        while time.monotonic() < end:   # healthy: the learner serves
+            read_learner()
+            time.sleep(0.05)
+        healthy_ok = tallies["ok"]
+        pc.kill(lname, signal.SIGSTOP)
+        try:
+            end = time.monotonic() + stop_s
+            while time.monotonic() < end:
+                read_learner()          # refuses, never serves old
+        finally:
+            pc.kill(lname, signal.SIGCONT)
+        # catch-up must finish BEFORE the learner answers again
+        resumed_ok = 0
+        end = time.monotonic() + 30.0
+        while time.monotonic() < end and resumed_ok < 8:
+            before = tallies["ok"]
+            read_learner()
+            resumed_ok += tallies["ok"] - before
+            time.sleep(0.05)
+    finally:
+        state["stop"] = True
+        wt.join(timeout=5.0)
+        lcl.close()
+    assert tallies["violation"] == 0, f"stale read served: {tallies}"
+    assert healthy_ok >= 3 and resumed_ok >= 8, \
+        f"learner stopped serving: {tallies}, resumed {resumed_ok}"
+    log(f"bounded staleness ok across a {stop_s}s partition: "
+        f"{tallies}, {state['acked']} acked writes")
+    return tallies
+
+
 def part2_cluster() -> dict:
     from dgraph_tpu.bench.spawn import ProcessCluster
     from dgraph_tpu.cluster.client import ClusterClient
@@ -108,7 +194,8 @@ def part2_cluster() -> dict:
         log("1 voter + 1 learner up; learner conf-joined")
         rc = pc.routed()
         try:
-            rc.alter("so.name: string @index(exact) .")
+            rc.alter("so.name: string @index(exact) .\n"
+                     "so.ctr: int .")
             for i in range(8):
                 rc.mutate(set_nquads=f'<{hex(0x100 + i)}> <so.name> '
                           f'"n{i}" .')
@@ -154,7 +241,10 @@ def part2_cluster() -> dict:
                     f"best-effort read missed committed f{i}"
             log("routed best-effort reads observe fresh commits")
 
-            # 6: tenant shed isolation — the hog sheds, quiet doesn't
+            # 6: a partitioned learner refuses, it never serves old
+            bounded_staleness(pc, rc, laddr, "alpha-g1-n2")
+
+            # 7: tenant shed isolation — the hog sheds, quiet doesn't
             sheds = served = 0
             for _ in range(60):
                 try:
