@@ -44,6 +44,13 @@ mutex guards the txn table and ACL cache; lock order is rw -> meta,
 never the reverse. Rollup (folds MVCC overlays — a write) is kept OFF
 the read path (db.rollup_in_read=False) and runs throttled from the
 write path instead.
+
+Connections: HTTP/1.1, persistent. A handler thread serves one
+CONNECTION, request after request, until the client closes it, asks
+for `Connection: close`, speaks HTTP/1.0, stays idle past
+`_Handler.timeout`, or gets a reply that was sent before its body was
+read. `http_connections_total` against `http_requests_total` says how
+often a request found its connection open (docs/deployment.md).
 """
 
 from __future__ import annotations
@@ -970,28 +977,66 @@ def _brace_body(s: str) -> str:
 
 
 class _Handler(BaseHTTPRequestHandler):
+    """One handler a CONNECTION: HTTP/1.1, so the connection outlives
+    a reply unless the request says otherwise (`Connection: close`, an
+    HTTP/1.0 request line) or a reply left part of the request unread.
+    Every reply carries Content-Length and leaves in one send."""
+
     server_version = "dgraph-tpu/0.1"
+    protocol_version = "HTTP/1.1"
+    # a reply is one small segment: never held back for the ACK of
+    # the one before it
+    disable_nagle_algorithm = True
+    # seconds a connection may stand idle between requests (and any
+    # one read or send may take): a client that vanished without a
+    # FIN gives its thread back
+    timeout = 120.0
     alpha: AlphaServer  # set by serve()
+
+    def setup(self):
+        super().setup()
+        metrics.inc_counter("http_connections_total")
 
     def log_message(self, fmt, *args):  # quiet by default
         pass
 
+    def _begin(self):
+        """Per-request state: the handler now lives as long as its
+        connection, so nothing of the request before may show."""
+        self._trace_ctx = None  # don't echo a stale trace
+        self._body_read = False
+
     def _send(self, code: int, obj: Any):
         self._send_raw(code, json.dumps(obj).encode())
 
-    def _send_raw(self, code: int, data: bytes):
+    def _send_raw(self, code: int, data: bytes,
+                  ctype: str = "application/json"):
+        """The whole reply in ONE send: two small segments would have
+        the second wait for the client's delayed ACK of the first."""
+        if not self._body_read and (
+                self.headers.get("Content-Length", "0").strip() != "0"
+                or "Transfer-Encoding" in self.headers):
+            # answered before the request's body was read (a header
+            # refused, a GET that brought one, a failure on the way):
+            # what is left of it must never be parsed as the next
+            # request
+            self.close_connection = True
         self.send_response(code)
-        self.send_header("Content-Type", "application/json")
+        self.send_header("Content-Type", ctype)
         self.send_header("Content-Length", str(len(data)))
-        ctx = getattr(self, "_trace_ctx", None)
+        if self.close_connection:
+            self.send_header("Connection", "close")
+        ctx = self._trace_ctx
         if ctx is not None:
             # traceparent OUT: the caller (or its collector) learns
             # which trace id to pull from /debug/traces on every node
             self.send_header("X-Dgraph-Trace-Id", ctx.trace_id)
             self.send_header("traceparent", tracing.format_traceparent(
                 ctx.trace_id, ctx.parent_span))
-        self.end_headers()
-        self.wfile.write(data)
+        # end_headers() would send what send_header() gathered by
+        # itself; here it leaves joined with the body
+        head, self._headers_buffer = self._headers_buffer, []
+        self.wfile.write(b"".join((*head, b"\r\n", data)))
 
     def _error(self, msg: str, code: int = 400, ecode: str = "Error",
                retryable: bool = False):
@@ -1002,8 +1047,16 @@ class _Handler(BaseHTTPRequestHandler):
                                       "extensions": ext}]})
 
     def _body(self) -> bytes:
+        if "Transfer-Encoding" in self.headers:
+            raise ValueError("a request body needs Content-Length; "
+                             "Transfer-Encoding is not supported")
         n = int(self.headers.get("Content-Length", 0))
-        return self.rfile.read(n) if n else b""
+        if n < 0:
+            raise ValueError(f"Content-Length must not be negative, "
+                             f"got {n}")
+        data = self.rfile.read(n) if n else b""
+        self._body_read = True
+        return data
 
     def _ctx(self) -> Optional[RequestContext]:
         """RequestContext from the request headers: the remaining
@@ -1044,7 +1097,7 @@ class _Handler(BaseHTTPRequestHandler):
         path = u.path
         params = {k: v[-1] for k, v in parse_qs(u.query).items()}
         token = self.headers.get("X-Dgraph-AccessToken", "")
-        self._trace_ctx = None  # keep-alive: don't echo a stale trace
+        self._begin()
         try:
             if path == "/health":
                 self._send(200, self.alpha.handle_health())
@@ -1071,14 +1124,8 @@ class _Handler(BaseHTTPRequestHandler):
             elif path == "/debug/pprof":
                 self._send(200, self.alpha.handle_pprof(params, token))
             elif path == "/debug/prometheus_metrics":
-                from dgraph_tpu.utils.metrics import render_prometheus
-
-                text = render_prometheus().encode()
-                self.send_response(200)
-                self.send_header("Content-Type", "text/plain; version=0.0.4")
-                self.send_header("Content-Length", str(len(text)))
-                self.end_headers()
-                self.wfile.write(text)
+                self._send_raw(200, metrics.render_prometheus().encode(),
+                               "text/plain; version=0.0.4")
             else:
                 self._error(f"no handler for GET {path}", 404)
         except AclError as e:
@@ -1157,7 +1204,7 @@ class _Handler(BaseHTTPRequestHandler):
         # reset BEFORE _ctx() can raise: a malformed deadline header's
         # 400 must not echo a previous request's trace on a reused
         # connection
-        self._trace_ctx = None
+        self._begin()
         try:
             ctx = self._ctx()
             self._trace_ctx = ctx
@@ -1217,6 +1264,13 @@ class _Handler(BaseHTTPRequestHandler):
             self._error(str(e), 500)
 
 
+class _Server(ThreadingHTTPServer):
+    # the listen backlog: a burst of connects from clients that do NOT
+    # keep their connections must never overflow it, or the kernel
+    # drops the SYN and the client retransmits a second later
+    request_queue_size = 128
+
+
 def serve(db: Optional[GraphDB] = None, host: str = "127.0.0.1",
           port: int = 8080, block: bool = True,
           acl_secret: Optional[bytes] = None,
@@ -1240,7 +1294,7 @@ def serve(db: Optional[GraphDB] = None, host: str = "127.0.0.1",
                         tenant_rate=tenant_rate,
                         tenant_burst=tenant_burst)
     handler = type("BoundHandler", (_Handler,), {"alpha": alpha})
-    httpd = ThreadingHTTPServer((host, port), handler)
+    httpd = _Server((host, port), handler)
     if tls_context is not None:
         # defer the handshake to the per-request handler thread: with
         # the default handshake-on-accept, one client that connects and

@@ -70,6 +70,7 @@ REGISTERED = (
     # serving edge (server/http.py)
     "dgraph_pending_queries",
     "dgraph_queries_shed_total",
+    "http_connections_total",
     "http_request_ns_total",
     "http_requests_total",
     # compiled plan cache + micro-batcher (query/plan.py,
