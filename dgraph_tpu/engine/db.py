@@ -1415,17 +1415,12 @@ class GraphDB:
                 dedup)
             return [lv.astype(np.uint64) for lv in lv32]
         # host fallback: same semantics over the MVCC overlay
-        levels = []
-        visited = seeds
-        frontier = seeds
-        for _ in range(depth):
-            nxt = tab.expand_frontier(frontier, read_ts)
-            if dedup:
-                nxt = np.setdiff1d(nxt, visited, assume_unique=True)
-                visited = np.union1d(visited, nxt)
-            levels.append(nxt)
-            frontier = nxt
-        return levels
+        from dgraph_tpu.storage.tablet import bfs_levels
+        levels = [nxt for _, nxt in bfs_levels(
+            [lambda fr: tab.expand_frontier(fr, read_ts)], seeds, depth,
+            dedup)]
+        return levels + [np.empty(0, np.uint64)
+                         for _ in range(depth - len(levels))]
 
     # -- maintenance --
 

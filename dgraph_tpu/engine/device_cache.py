@@ -277,31 +277,60 @@ def device_radjacency(db, tab, read_ts: int,
     return adj
 
 
-def device_bitadjacency(db, tab, read_ts: int, transpose: bool = False):
-    """Bitmap adjacency (ops/bitgraph) for analytical BFS/SSSP.
+def device_bitadjacency(db, tab, read_ts: int, transpose: bool = False,
+                        dense: bool = False):
+    """Bitmap adjacency (ops/bitgraph) for @recurse, BFS and SSSP.
     Same residency policy as device_adjacency: clean rolled-up tablets
     only; cached per base_ts. With transpose=True the expansion walks
-    edges dst->src (used for distance-to-target in shortest paths)."""
+    edges dst->src (`~pred`, and distance-to-target in shortest
+    paths). With dense=True the tile also carries the hub rows the
+    served traversal streams (bitgraph.attach_dense), as many as the
+    HBM budget has room for when they are set, on first asking; they
+    stay with the tile, and a tile evicted and built again takes the
+    room there is then.
+    A tile like the others: counted in `device_cache_bytes` under the
+    HBM budget and evictable. The gauges
+    `device_bitadj_bytes{predicate}` (the in-neighbour matrices' and
+    the hub rows' bytes on the device) and
+    `device_bitadj_edges{predicate}` say what is resident, 0 after an
+    eviction; the transposed tile is labelled `~pred`."""
     if not _clean_resident(db, tab, read_ts):
         return None
     attr = "_device_badj_t" if transpose else "_device_badj"
     badj = getattr(tab, attr, None)
-    if badj is not None and getattr(tab, attr + "_ts", -1) == tab.base_ts:
+    fresh = badj is None or getattr(tab, attr + "_ts", -1) != tab.base_ts
+    if not fresh and (badj.dense_from is not None or not dense):
         db.device_cache.touch(tab, attr)
         return badj
-    n_edges = sum(len(v) for v in tab.edges.values())
-    if n_edges < db.device_min_edges:
-        return None
-    edges32 = _edges32(_transposed_edges(tab) if transpose else tab.edges)
-    if edges32 is None:
-        return None
-    from dgraph_tpu.ops.bitgraph import build_bitadjacency
-    with _tile_load(pred=tab.pred, kind="bitadj",
-               edges=n_edges):
-        badj = build_bitadjacency(edges32)
+    from dgraph_tpu.ops.bitgraph import attach_dense, build_bitadjacency
+    if fresh:
+        n_edges = sum(len(v) for v in tab.edges.values())
+        if n_edges < db.device_min_edges:
+            return None
+        edges32 = _edges32(_transposed_edges(tab) if transpose
+                           else tab.edges)
+        if edges32 is None:
+            return None
+        with _tile_load(pred=tab.pred, kind="bitadj", edges=n_edges):
+            badj = build_bitadjacency(edges32)
+    if dense:
+        with _tile_load(pred=tab.pred, kind="bitadj_dense",
+                        edges=badj.n_edges):
+            attach_dense(badj, max(
+                0, db.device_cache.budget - db.device_cache.bytes))
     setattr(tab, attr, badj)
     setattr(tab, attr + "_ts", tab.base_ts)
-    db.device_cache.put(tab, attr, badj)
+    labels = {"predicate": ("~" if transpose else "") + tab.pred}
+
+    def gauges(nbytes: float, edges: float) -> None:
+        set_gauge("device_bitadj_bytes", nbytes, labels=labels)
+        set_gauge("device_bitadj_edges", edges, labels=labels)
+
+    db.device_cache.put(tab, attr, badj,
+                        on_evict=lambda: gauges(0.0, 0.0))
+    gauges(float(sum(b.in_nb.nbytes for b in badj.buckets)
+                 + (badj.dense.nbytes if badj.dense is not None else 0)),
+           float(badj.n_edges))
     return badj
 
 

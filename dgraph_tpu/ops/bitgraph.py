@@ -36,6 +36,7 @@ optional per-edge weights aligned to the in-neighbor matrices.
 
 from __future__ import annotations
 
+import functools
 from dataclasses import dataclass
 from typing import Callable, Optional
 
@@ -79,6 +80,17 @@ class BitAdjacency:
     n_slots: int
     n_covered: int                   # slots with in-degree > 0 (prefix)
     n_edges: int
+    # the served traversal's hub rows (attach_dense): buckets
+    # [dense_from:] as one bitmap row a slot, uint32[rows, ceil(N/32)],
+    # bit s of row r set where slot s points at the row's slot
+    dense: Optional[jax.Array] = None
+    dense_from: Optional[int] = None     # None: attach_dense not run
+
+    @property
+    def gathered(self) -> list[RevBucket]:
+        """The degree classes the served traversal gathers: those
+        below the hub rows, all of them where there are none."""
+        return self.buckets[:self.dense_from]
 
     @property
     def shape_sig(self):
@@ -212,14 +224,23 @@ def bits_to_uids(badj: BitAdjacency, bits: np.ndarray) -> np.ndarray:
 
 def _level(badj: BitAdjacency, f: jax.Array) -> jax.Array:
     """One frontier expansion: bool[N] -> bool[N] (reachable-in-1)."""
-    fe = jnp.concatenate([f, jnp.zeros((1,), jnp.bool_)])
-    parts = [jnp.any(fe[b.in_nb], axis=1) for b in badj.buckets]
-    tail = badj.n_slots - badj.n_covered
-    if tail:
-        parts.append(jnp.zeros((tail,), jnp.bool_))
-    if not parts:
-        return jnp.zeros((badj.n_slots,), jnp.bool_)
-    return jnp.concatenate(parts)
+    return jnp.concatenate([
+        _gathered_reach([b.in_nb for b in badj.buckets], f),
+        jnp.zeros((badj.n_slots - badj.n_covered,), jnp.bool_)])
+
+
+def _gathered_reach(in_nbs, f: jax.Array) -> jax.Array:
+    """Which rows of the in-neighbour matrices `in_nbs` a frontier
+    over every slot reaches, in the matrices' order. The matrices are
+    ARGUMENTS, so that a jitted caller does not bake every edge into
+    its program as a constant. Gathers every padded in-edge whatever
+    the frontier holds: a level costs the same for every frontier.
+    The table is gathered as int32: 12% faster than bool on a v5e
+    (PERF.md, PR 31)."""
+    fe = jnp.concatenate([f.astype(jnp.int32), jnp.zeros((1,), jnp.int32)])
+    parts = [jnp.max(fe.at[nb].get(mode="promise_in_bounds"), axis=1) > 0
+             for nb in in_nbs]
+    return jnp.concatenate(parts) if parts else jnp.zeros((0,), jnp.bool_)
 
 
 def make_bfs_bits(badj: BitAdjacency, depth: int,
@@ -265,6 +286,151 @@ def _bfs_cache(badj: BitAdjacency, depth: int, dedup: bool) -> Callable:
     if fn is None:
         fn = cache[(depth, dedup)] = make_bfs_bits(badj, depth, dedup)
     return fn
+
+
+# -- the served traversal: one program a request ------------------------------
+
+
+# What a level costs on one v5e, measured (PERF.md, PR 31): a gathered
+# in-edge (XLA's gather of one table element an index, padding
+# included) and a streamed byte of the dense rows. A row of N bits
+# costs N / 8 / DENSE_BYTES_PER_S however many in-edges it holds, so a
+# slot with more than a handful of in-edges is cheaper as a row.
+GATHER_SECONDS = 7.0e-9
+DENSE_BYTES_PER_S = 6.5e11
+
+
+def attach_dense(badj: BitAdjacency, budget_bytes: int) -> None:
+    """Give the adjacency its hub rows: the degree classes whose rows
+    are cheaper streamed than gathered, from the highest class down,
+    as many whole classes as `budget_bytes` holds. On a skewed graph
+    a small share of the slots holds most of the in-edges, so a
+    bounded block of rows takes most of a level's gathers away."""
+    n, words = badj.n_slots, -(-badj.n_slots // 32)
+    row_bytes = 4 * words
+    first, rows = len(badj.buckets), 0
+    for i in range(len(badj.buckets) - 1, -1, -1):
+        b = badj.buckets[i]
+        m = int(b.in_nb.shape[0])
+        if b.degree * GATHER_SECONDS <= row_bytes / DENSE_BYTES_PER_S \
+                or (rows + m) * row_bytes > budget_bytes:
+            break
+        first, rows = i, rows + m
+    badj.dense_from = first
+    if not rows:
+        badj.dense = None
+        return
+    start = badj.buckets[first].offset
+    keys, bits = [], []
+    for b in badj.buckets[first:]:
+        nb = b.in_nb_host if b.in_nb_host is not None \
+            else np.asarray(b.in_nb)
+        r, c = np.nonzero(nb < n)
+        src = nb[r, c].astype(np.int64)
+        keys.append((r + (b.offset - start)) * words + (src >> 5))
+        bits.append(np.uint32(1) << (src & 31).astype(np.uint32))
+    # OR the bits that share a word, then one write a word
+    keys, bits = np.concatenate(keys), np.concatenate(bits)
+    order = np.argsort(keys, kind="stable")
+    keys, bits = keys[order], bits[order]
+    at = np.flatnonzero(np.r_[True, keys[1:] != keys[:-1]])
+    block = np.zeros(rows * words, np.uint32)
+    block[keys[at]] = np.bitwise_or.reduceat(bits, at)
+    badj.dense = jnp.asarray(block.reshape(rows, words))
+
+
+def level_seconds(badj: BitAdjacency) -> float:
+    """What one level of bfs_traverse costs on the device, from the
+    adjacency's layout alone: the gathered classes' padded in-edges
+    and the dense rows' bytes."""
+    gathered = sum(int(b.in_nb.size) for b in badj.gathered)
+    dense = 0 if badj.dense is None else int(badj.dense.nbytes)
+    return gathered * GATHER_SECONDS + dense / DENSE_BYTES_PER_S
+
+
+@functools.partial(jax.jit,
+                   static_argnames=("n_slots", "n_covered", "want_bits"))
+def bfs_traverse(in_nbs, dense, seed_slots, depth, *, n_slots: int,
+                 n_covered: int, want_bits: bool):
+    """A whole `@recurse(loop: false)` in one program: every level,
+    the visited set and the count.
+
+    in_nbs      the gathered degree classes' in-neighbour matrices
+    dense       the other classes' rows (attach_dense), or None
+    seed_slots  int32[S]: the roots' slots, padded with n_slots (the
+                dummy slot, dropped)
+    depth       int32 scalar, a RUNTIME value: levels to expand (edge
+                hops), so one program serves every depth
+    ->  (reached, levels_run, bits)
+    reached     int32: distinct slots reached through an edge in
+                1..depth hops (a root counts where an edge leads back
+                to it): what DQL's uid variable on the child holds
+    levels_run  int32: levels expanded; the loop ends early once a
+                level finds no new slot
+    bits        uint8[ceil(N / 8)] with want_bits, the reached set
+                packed big-endian as np.unpackbits reads it; else
+                None, and only two scalars leave the device
+    """
+    seed = jnp.zeros((n_slots + 1,), jnp.bool_).at[seed_slots].set(
+        True, mode="promise_in_bounds")[:n_slots]
+    shifts = jnp.arange(32, dtype=jnp.uint32)
+
+    def level(frontier):
+        parts = [_gathered_reach(in_nbs, frontier)]
+        if dense is not None:
+            words = dense.shape[1]
+            f = jnp.pad(frontier, (0, 32 * words - n_slots))
+            fw = jnp.sum(f.reshape(words, 32).astype(jnp.uint32) << shifts,
+                         axis=1, dtype=jnp.uint32)
+            parts.append(jnp.any((dense & fw[None, :]) != 0, axis=1))
+        parts.append(jnp.zeros((n_slots - n_covered,), jnp.bool_))
+        return jnp.concatenate(parts)
+
+    def cond(state):
+        lvl, _, _, _, alive = state
+        return (lvl < depth) & alive
+
+    def body(state):
+        lvl, frontier, visited, reached, _ = state
+        reach = level(frontier)
+        new = reach & ~visited
+        return (lvl + 1, new, visited | new, reached | reach,
+                jnp.any(new))
+
+    levels_run, _, _, reached, _ = jax.lax.while_loop(
+        cond, body, (jnp.int32(0), seed, seed,
+                     jnp.zeros((n_slots,), jnp.bool_), jnp.any(seed)))
+    count = jnp.sum(reached, dtype=jnp.int32)
+    return count, levels_run, (jnp.packbits(reached) if want_bits
+                               else None)
+
+
+def traverse(badj: BitAdjacency, slots: np.ndarray, depth: int,
+             want_bits: bool):
+    """bfs_traverse over an adjacency as attach_dense left it."""
+    return bfs_traverse(
+        [b.in_nb for b in badj.gathered], badj.dense,
+        jnp.asarray(slots), np.int32(min(depth, 2**31 - 1)),
+        n_slots=badj.n_slots, n_covered=badj.n_covered,
+        want_bits=want_bits)
+
+
+def seed_slots(badj: BitAdjacency, uids32: np.ndarray,
+               pad: int) -> Optional[np.ndarray]:
+    """Root uids -> int32[pad] slots for bfs_traverse, or None where
+    the adjacency does not know one of them (the caller answers on
+    the host, as for any uid the tile cannot speak for)."""
+    slots, hit = _uid_slots(badj, uids32)
+    if not hit.all():
+        return None
+    out = np.full(pad, badj.n_slots, np.int32)
+    out[: len(slots)] = slots
+    return out
+
+
+def packed_to_uids(badj: BitAdjacency, packed: np.ndarray) -> np.ndarray:
+    """bfs_traverse's `bits` -> sorted uid uint32 array."""
+    return bits_to_uids(badj, np.unpackbits(packed, count=badj.n_slots))
 
 
 # -- batched (multi-query) kernels -------------------------------------------

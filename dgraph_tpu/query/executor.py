@@ -328,6 +328,10 @@ class ExecNode:
     # filter/pagination (-1 = not measured, e.g. the device
     # count-at-root fast path never materializes the set)
     root_rows: int = -1
+    # a root that is only COUNTED and whose uids never left the device
+    # (a bound @recurse's variable read by `count(uid)` alone): the
+    # block's count, with `dest` empty; -1 = count `dest`
+    root_count: int = -1
     # whole-plan fusion attribution (query/fusion.py): "fused" when
     # the block's filter+order+page chain ran as ONE device
     # executable, "staged:<reason>" when a structurally-eligible
@@ -361,6 +365,10 @@ class Executor:
         self.uid_vars: dict[str, np.ndarray] = {}
         self.value_vars: dict[str, dict[int, Val]] = {}
         self._path_var_order: dict[str, list[int]] = {}
+        # uid variables whose SIZE is all the request reads of them
+        # (see _run_recurse_bound): name -> count; uid_vars holds an
+        # empty array under the name, so the variable is defined
+        self._uid_var_counts: dict[str, int] = {}
         # score-descending uid order of the current block's similar_to
         # root, set by _eval_similar_to and consumed at pagination
         self._similar_order: Optional[list[int]] = None
@@ -700,6 +708,17 @@ class Executor:
             self._run_shortest(node)
             return node
         self._similar_order = None
+        if self._uid_var_counts and gq.func is not None \
+                and gq.func.name == "uid" \
+                and len(gq.func.needs_var) == 1 \
+                and gq.func.needs_var[0].name in self._uid_var_counts:
+            # the one shape _count_only_readers admits: the block is
+            # `count(uid)` over the variable, whose uids stayed on
+            # the device
+            node.root_count = self._uid_var_counts[
+                gq.func.needs_var[0].name]
+            self._expand_children(node, gq.children, _EMPTY)
+            return node
         root = self._device_root_count_page(gq)
         if root is None:
             fspec = self._fused_spec(gq, i)
@@ -4720,6 +4739,188 @@ class Executor:
         # depth counts LEVELS including the root: depth 2 expands one
         # edge hop (ref query3_test.go TestRecurseQueryLimitDepth1)
         depth = (gq.recurse.depth or 64) - 1
+        t0 = _time.perf_counter_ns()
+        try:
+            with _span("recurse", depth=depth + 1,
+                       roots=int(len(node.dest))) as sp:
+                bound = self._recurse_bound(gq)
+                if bound is None:
+                    sp["tier"] = "host"
+                    sp["bound"] = False
+                    self._run_recurse_nested(node, depth)
+                else:
+                    self._run_recurse_bound(node, depth, bound, sp)
+                inc_counter("recurse_tier_total",
+                            labels={"tier": sp["tier"]})
+        finally:
+            # the span's own time as a counter: less
+            # device_call_ns_total{family="recurse"} it is what a
+            # @recurse costs off the chip
+            inc_counter("recurse_ns_total",
+                        _time.perf_counter_ns() - t0)
+
+    def _recurse_bound(self, gq: GraphQuery) -> Optional[list]:
+        """[(child, tablet, reverse)] where the block is a BOUND
+        @recurse: nothing of it is emitted (a `var` block), it drops
+        visited uids (`loop: false`), and every child is a bare uid
+        predicate, read at most through the uid variable on it. Such
+        a traversal needs no parent -> children map, only each level's
+        reach. None keeps the general path: loops, filters, facets,
+        expand(), scalar children, `uid`, nested output."""
+        if self._block_emits or gq.recurse.allow_loop or gq.cascade \
+                or gq.normalize or gq.ignore_reflex or not gq.children:
+            return None
+        out = []
+        for c in gq.children:
+            if (c.is_internal or c.expand or c.filter is not None
+                    or c.facets is not None or c.facets_filter is not None
+                    or c.facet_var or c.children or c.is_count
+                    or c.langs or c.order or c.first is not None
+                    or c.offset or c.after or c.agg_func
+                    or c.math is not None or c.needs_var):
+                return None
+            rev = c.attr.startswith("~")
+            tab = self._tablet(c.attr[1:] if rev else c.attr)
+            if tab is None or tab.schema.value_type != TypeID.UID \
+                    or (rev and not tab.schema.reverse):
+                return None
+            out.append((c, tab, rev))
+        return out
+
+    def _count_only_readers(self, name: str, own: GraphQuery) -> bool:
+        """Whether every block that reads uid variable `name` (bound in
+        block `own`) is `b(func: uid(name)) { count(uid) }` and no
+        more: then the variable's SIZE answers the request and its
+        uids need not leave the device. Only a served read query (one
+        with a Latency) is known to keep its variables to itself: an
+        upsert's mutation reads them off the executor afterwards
+        (engine/db.py). (Not memoized on the plan: `offset` and
+        `after` are parameters of a skeleton.)"""
+        if self.lat is None:
+            return False
+        readers = 0
+        for b in self.parsed.queries:
+            if b is own:
+                if any(vc.name == name for vc in self._all_needs(b)):
+                    return False
+                continue
+            if all(vc.name != name for vc in self._all_needs(b)):
+                continue
+            readers += 1
+            if (b.func is None or b.func.name != "uid"
+                    or [vc.name for vc in b.func.needs_var] != [name]
+                    or any(vc.name != name for vc in b.needs_var)
+                    or b.uids or b.filter is not None or b.order
+                    or b.first is not None or b.offset or b.after
+                    or b.var or b.cascade or b.normalize
+                    or b.ignore_reflex or b.is_groupby
+                    or b.recurse is not None or b.shortest is not None
+                    or not b.children
+                    or any(c.attr != "uid" or not c.is_count or c.var
+                           or c.children or c.filter is not None
+                           or c.needs_var for c in b.children)):
+                return False
+        return readers > 0
+
+    def _recurse_device(self, tab: Tablet, rev: bool, roots: np.ndarray,
+                        depth: int, want_uids: bool
+                        ) -> Optional[tuple]:
+        """The whole traversal as ONE device program and ONE
+        device_call -> (reached count, reached uids or None, levels
+        run); None where the host tier is to answer: the gate says so,
+        or the device cannot speak for the traversal (roots over 32
+        bits, a dirty tablet or one under device_min_edges, a root
+        the adjacency does not know).
+
+        The family's `_device_worth` site. Both sides of the choice
+        are reckoned before the traversal runs and never from a
+        measured span: the host's cost from the depth, the root set's
+        size and the tablet's degree moments (planner.recurse_costs),
+        the device's from the levels and the adjacency's layout
+        (bitgraph.level_seconds). The tile is built only for a
+        traversal the device could win at all: one that costs the
+        host more than the in-edges' bytes cost the chip's memory."""
+        from dgraph_tpu.engine.device_cache import (
+            _MAX_U32, device_bitadjacency,
+        )
+        from dgraph_tpu.ops import bitgraph
+        from dgraph_tpu.ops.uidvec import pad_to
+        from dgraph_tpu.query.planner import recurse_costs
+        if int(roots[-1]) > _MAX_U32 or not hasattr(tab, "degree_moments"):
+            return None       # (a federated proxy is host-only)
+        moments = tab.degree_moments(rev)
+        host, levels = recurse_costs(len(roots), depth, *moments)
+
+        def worth(device_seconds: float) -> bool:
+            return self._device_worth(
+                host, device_ratio=min(1.0, device_seconds / host)
+                if host else 1.0)
+
+        if not worth(levels * 4 * moments[1] / bitgraph.DENSE_BYTES_PER_S):
+            return None
+        badj = device_bitadjacency(self.db, tab, self.read_ts,
+                                   transpose=rev, dense=True)
+        if badj is None or badj.n_slots == 0 \
+                or not worth(levels * bitgraph.level_seconds(badj)):
+            return None
+        slots = bitgraph.seed_slots(badj, roots.astype(np.uint32),
+                                    pad_to(len(roots)))
+        if slots is None:
+            return None
+        with device_call("query_device_recurse_total", sink=self.lat,
+                         program="bfs_traverse") as dc:
+            count, levels, bits = dc.wait(
+                bitgraph.traverse(badj, slots, depth, want_uids))
+            uids = bitgraph.packed_to_uids(
+                badj, np.asarray(bits)).astype(np.uint64) \
+                if want_uids else None
+            return int(count), uids, int(levels)
+
+    def _run_recurse_bound(self, node: ExecNode, depth: int, bound: list,
+                           sp: dict):
+        """A bound @recurse: level-at-a-time on the host tier
+        (storage/tablet.bfs_levels, one expand_frontier a predicate a
+        level), one device traversal on the device tier. Both give the
+        general path's answer: a child's variable holds every uid
+        reached through it in 1..depth hops."""
+        gq = node.gq
+        roots = node.dest
+        self._checkpoint(f"recurse {gq.alias or gq.attr}")
+        if len(bound) == 1 and len(roots) and self.db.prefer_device:
+            cgq, tab, rev = bound[0]
+            # the uids leave the device only where a later block reads
+            # more of the variable than its size
+            want = bool(cgq.var) \
+                and not self._count_only_readers(cgq.var, gq)
+            got = self._recurse_device(tab, rev, roots, depth, want)
+            if got is not None:
+                count, uids, levels = got
+                sp.update(tier="device", levels_run=levels, reached=count)
+                if cgq.var:
+                    self.uid_vars[cgq.var] = uids if want else _EMPTY
+                    if not want:
+                        self._uid_var_counts[cgq.var] = count
+                return
+        from dgraph_tpu.storage.tablet import bfs_levels
+        expanders = [
+            (lambda fr, tab=tab, rev=rev:
+             tab.expand_frontier(fr, self.read_ts, rev))
+            for _, tab, rev in bound]
+        # what each child's edges led to, over every level
+        accum = [_EMPTY] * len(bound)
+        levels = 0
+        for reaches, _ in bfs_levels(expanders, roots, depth):
+            levels += 1
+            accum = [_union(a, r) for a, r in zip(accum, reaches)]
+            self._checkpoint(f"recurse {gq.alias or gq.attr}")
+        sp.update(tier="host", levels_run=levels,
+                  reached=int(len(self._union_many(accum))))
+        for (cgq, _, _), uids in zip(bound, accum):
+            if cgq.var:
+                self.uid_vars[cgq.var] = uids
+
+    def _run_recurse_nested(self, node: ExecNode, depth: int):
+        gq = node.gq
         allow_loop = gq.recurse.allow_loop
         frontier = node.dest
         visited = frontier.copy()
@@ -5264,7 +5465,9 @@ class Executor:
         n_counts = 0
         for ch in node.children:
             if ch.gq.attr == "uid" and ch.gq.is_count:
-                out.append({ch.gq.alias or "count": len(node.dest)})
+                out.append({ch.gq.alias or "count":
+                            node.root_count if node.root_count >= 0
+                            else len(node.dest)})
                 n_counts += 1
         if n_counts and n_counts == len(node.children):
             # count-only block: the per-uid walk below would emit (and

@@ -173,6 +173,53 @@ def _bucket(n: int) -> int:
     return n.bit_length() if n > 0 else 0
 
 
+# -- @recurse: a static choice, from what the plan knows ---------------
+# A bound @recurse has two tiers whose costs differ by orders of
+# magnitude and in opposite directions (a one-hop look-up is
+# microseconds on the host and a whole program on the chip; a deep
+# traversal of a skewed graph is the whole graph either way, tens of
+# milliseconds on the chip and seconds on the host). Its tier is
+# therefore reckoned BEFORE it runs, from the depth, the root set's
+# size and the tablet's degree moments, and never learned from the
+# span's wall time: eight request threads on one interpreter make that
+# mostly waiting, and a stage judged by it drifts between its tiers
+# (PERF.md, section 7).
+RECURSE_HOST_PER_UID = 2e-7     # executor._HOST_PER_FRONTIER_UID
+RECURSE_HOST_PER_EDGE = 4e-8    # executor._HOST_PER_EDGE
+
+
+def recurse_costs(n_roots: int, depth: int, rows: int, edges: int,
+                  sum_sq: int) -> tuple[float, int]:
+    """(host seconds, device levels) a bound @recurse of `depth` edge
+    hops from `n_roots` uids is expected to take over a tablet of
+    `rows` edge rows, `edges` edges and `sum_sq` = the sum of the
+    squared row lengths (Tablet.degree_moments).
+
+    Host: the edges the frontiers touch. A root has the mean degree;
+    a uid reached THROUGH an edge is drawn in proportion to its degree
+    (as far as in- and out-degree go together), so its expected degree
+    is sum_sq / edges, far above the mean on a skewed graph: that is
+    what makes three hops on a power-law graph most of the graph. A
+    traversal that drops visited uids touches no edge twice, which
+    caps the total at `edges`. Device: every level costs the same
+    whatever the frontier holds (ops/bitgraph.level_seconds says
+    what), and the program stops at the first level that finds
+    nothing new: once the walk has touched every edge that is a level
+    or two of thinning tail away, however deep the query asks."""
+    if rows <= 0 or edges <= 0 or depth <= 0 or n_roots <= 0:
+        return 0.0, 0
+    degree, later = edges / rows, sum_sq / edges
+    frontier, left, host, levels = float(n_roots), float(edges), 0.0, 0
+    while levels < depth and left > 0 and frontier >= 1:
+        touched = min(frontier * degree, left)
+        host += frontier * RECURSE_HOST_PER_UID \
+            + touched * RECURSE_HOST_PER_EDGE
+        left -= touched
+        frontier, degree = min(touched, float(rows)), later
+        levels += 1
+    return host, min(depth, levels + 2)
+
+
 def token_quantile(token_index: dict, q: float = 0.75) -> float:
     """Per-token posting-length quantile from the tabstats histogram
     (log2 buckets; bucket b covers lengths with bit_length b). The

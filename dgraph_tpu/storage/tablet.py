@@ -756,17 +756,86 @@ class Tablet:
                 if len(self.get_reverse_uids(u, read_ts))]
         return np.asarray(keep, dtype=np.uint64)
 
+    def _csr(self, reverse: bool
+             ) -> tuple[np.ndarray, np.ndarray, np.ndarray]:
+        """(sorted srcs, offsets [n + 1], flat dsts) of the BASE edge
+        rows (reverse rows with reverse=True), cached per base_ts like
+        count_table(): what lets a level of a traversal be a handful of
+        array operations instead of a dictionary lookup per uid."""
+        attr = "_csr_rev" if reverse else "_csr_fwd"
+        cached = getattr(self, attr, None)
+        if cached is not None and cached[0] == self.base_ts:
+            return cached[1]
+        store = self.reverse if reverse else self.edges
+        srcs = np.fromiter(store.keys(), np.uint64, len(store))
+        srcs.sort()
+        rows = [store[u] for u in srcs.tolist()]
+        offs = np.zeros(len(rows) + 1, np.int64)
+        np.cumsum(np.fromiter((len(r) for r in rows), np.int64,
+                              len(rows)), out=offs[1:])
+        flat = np.concatenate(rows).astype(np.uint64, copy=False) \
+            if rows else _EMPTY.copy()
+        csr = (srcs, offs, flat)
+        setattr(self, attr, (self.base_ts, csr))
+        return csr
+
+    # under this many frontier uids a dictionary lookup each is
+    # cheaper than the CSR's fixed dozen of array operations (and a
+    # small frontier never builds the CSR)
+    _CSR_MIN_FRONTIER = 64
+
     def expand_frontier(self, frontier: np.ndarray, read_ts: int,
                         reverse: bool = False) -> np.ndarray:
         """Union of destination uids over a frontier — the single host
         implementation of one BFS level (device analogue:
-        ops/graph.expand). Both the executor and GraphDB.bfs use this."""
+        ops/graph.expand). Both the executor and GraphDB.bfs use this.
+        A wide frontier reads the base rows through the CSR in one
+        pass; only uids whose rows the MVCC overlay touches go through
+        the per-uid getters."""
         getter = self.get_reverse_uids if reverse else self.get_dst_uids
-        parts = [getter(int(u), read_ts) for u in frontier.tolist()]
+        if len(frontier) < self._CSR_MIN_FRONTIER:
+            slow, frontier = frontier, _EMPTY
+        elif self.deltas:
+            touched = self.overlay_srcs(read_ts, reverse)
+            hit = np.isin(frontier, np.fromiter(
+                touched, np.uint64, len(touched)))
+            slow, frontier = frontier[hit], frontier[~hit]
+        else:
+            slow = _EMPTY
+        parts = [getter(int(u), read_ts) for u in slow.tolist()]
         parts = [p for p in parts if len(p)]
+        if len(frontier):
+            srcs, offs, flat = self._csr(reverse)
+            idx = np.searchsorted(srcs, frontier)
+            idx[idx == len(srcs)] = 0
+            idx = idx[srcs[idx] == frontier] if len(srcs) else idx[:0]
+            starts = offs[idx]
+            lens = offs[idx + 1] - starts
+            total = int(lens.sum())
+            if total:
+                # row i's edges sit at starts[i] .. starts[i] + lens[i]
+                pos = np.repeat(starts - (np.cumsum(lens) - lens), lens)
+                pos += np.arange(total, dtype=np.int64)
+                parts.append(flat[pos])
         if not parts:
             return _EMPTY.copy()
         return np.unique(np.concatenate(parts))
+
+    def degree_moments(self, reverse: bool = False
+                       ) -> tuple[int, int, int]:
+        """(rows, edges, sum of squared row lengths) of the base edge
+        rows, cached per base_ts: what a plan knows of a traversal's
+        growth before it runs (query/planner.recurse_costs)."""
+        attr = "_deg_moments_rev" if reverse else "_deg_moments_fwd"
+        cached = getattr(self, attr, None)
+        if cached is None or cached[0] != self.base_ts:
+            store = self.reverse if reverse else self.edges
+            d = np.fromiter((len(v) for v in store.values()), np.int64,
+                            len(store))
+            cached = (self.base_ts,
+                      (len(d), int(d.sum()), int((d * d).sum())))
+            setattr(self, attr, cached)
+        return cached[1]
 
     def edge_count(self, reverse: bool = False) -> int:
         """Total base edges (cached per base_ts): the executor's
@@ -1358,3 +1427,29 @@ class Tablet:
             except ValueError:
                 pass
         return out
+
+
+def bfs_levels(expanders, seeds: np.ndarray, depth: int,
+               dedup: bool = True):
+    """The tree's one host breadth-first search, a level at a time.
+
+    `expanders` are callables frontier -> sorted unique uids reached
+    in one hop (`Tablet.expand_frontier` bound to a tablet, a
+    direction and a read_ts; one per traversed predicate). Yields,
+    for each level that has a frontier, (each expander's reach, the
+    next frontier): with `dedup` the next frontier leaves out every
+    uid seen before (the seeds among them), as `@recurse(loop:
+    false)` and GraphDB.bfs do; without it the frontier is the whole
+    reach. Ends early once a frontier is empty."""
+    visited = frontier = seeds
+    for _ in range(depth):
+        if not len(frontier):
+            return
+        reaches = [ex(frontier) for ex in expanders]
+        nxt = reaches[0] if len(reaches) == 1 else \
+            np.unique(np.concatenate(reaches))
+        if dedup:
+            nxt = np.setdiff1d(nxt, visited, assume_unique=True)
+            visited = np.union1d(visited, nxt)
+        yield reaches, nxt
+        frontier = nxt

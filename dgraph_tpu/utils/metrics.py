@@ -51,6 +51,10 @@ REGISTERED = (
     "device_cache_bytes",
     "device_cache_evictions",
     "device_cache_tiles",
+    # engine/device_cache.py: a uid predicate's resident bitmap
+    # adjacency (`~pred` for the transposed one)
+    "device_bitadj_bytes",
+    "device_bitadj_edges",
     # query/devicecall.py; NOT `query_device_*`: readers sum that
     # prefix as a count of dispatches
     "device_call_ns_total",
@@ -104,6 +108,7 @@ REGISTERED = (
     "query_device_orderkeys_total",
     "query_device_overlay_expand_total",
     "query_device_range_total",
+    "query_device_recurse_total",
     "query_device_setops_total",
     "query_device_similar_sharded_total",
     "query_device_similar_total",
@@ -119,6 +124,10 @@ REGISTERED = (
     "query_sharded_expand_total",
     "query_similar_quantized_total",
     "query_similar_sharded_total",
+    # query/executor.py _run_recurse: the span's time, and which tier
+    # a @recurse took
+    "recurse_ns_total",
+    "recurse_tier_total",
     "similar_exact_fallback_total",
     "similar_mask_total",
     "similar_masked_total",

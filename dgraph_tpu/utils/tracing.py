@@ -99,6 +99,7 @@ SPAN_NAMES = (
     "plan.compile",
     "query",
     "raft.apply",
+    "recurse",
     "rpc.recv",
     "rpc.send",
     "setops",

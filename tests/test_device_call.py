@@ -30,6 +30,7 @@ DISPATCH_COUNTERS = (
     "query_device_orderkeys_total",
     "query_device_overlay_expand_total",
     "query_device_range_total",
+    "query_device_recurse_total",
     "query_device_setops_total",
     "query_device_similar_sharded_total",
     "query_device_similar_total",
