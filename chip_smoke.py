@@ -569,8 +569,8 @@ def smoke(args, workdir: str) -> dict:
     def _kernels():
         try:
             kc_synth["kernels"] = kernelcheck(
-                probe, "checks=bfs_digest_xla,fused_rank_page,"
-                "setops_cosort,knn_exact", deadline)
+                probe, "checks=bfs_digest_xla,bfs_traverse,"
+                "fused_rank_page,setops_cosort,knn_exact", deadline)
         except Exception as e:  # noqa: BLE001 — surfaced after join
             kc_synth["error"] = f"{type(e).__name__}: {e}"
 

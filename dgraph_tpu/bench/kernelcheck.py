@@ -129,6 +129,54 @@ def check_bfs_digest(tiny) -> dict:
                       "depth": depth}}
 
 
+def check_bfs_traverse(tiny) -> dict:
+    """bitgraph.bfs_traverse, the served k-hop traversal, every lane
+    ridden with its own root set and depth, over hub rows (on the
+    chip, the Pallas kernel that reads them once for all lanes) and
+    gathered classes both; twin = ops/traverse.bfs_reach with dedup
+    off over the forward adjacency: a lane's reached set is every uid
+    a walk of 1..depth edges from its roots ends at."""
+    from dgraph_tpu.bench.bfsgraph import csr_to_dict, make_graph
+    from dgraph_tpu.ops import bitgraph
+    from dgraph_tpu.ops.graph import build_adjacency
+    from dgraph_tpu.ops.traverse import bfs_reach
+
+    nodes, n_edges = (2000, 20_000) if tiny else (200_000, 4_000_000)
+    uniq_src, indptr, dst = make_graph(nodes, n_edges)
+    edges = csr_to_dict(uniq_src, indptr, dst)
+    badj = bitgraph.build_bitadjacency(edges)
+    # half of the rows the budget would hold, so that both halves of
+    # a level run
+    rows = sum(int(b.in_nb.shape[0]) for b in badj.buckets) // 2
+    bitgraph.attach_dense(
+        badj, rows * 4 * bitgraph.hub_row_words(badj.n_slots))
+    adj = build_adjacency(edges)
+    rng = _rng(9)
+    riders, want = [], []
+    for lane in range(bitgraph.LANES):
+        roots = np.unique(uniq_src[rng.integers(
+            0, len(uniq_src), 1 + lane % 3)]).astype(np.uint32)
+        depth = 1 + lane % 3
+        riders.append((bitgraph.seed_slots(badj, roots), depth))
+        want.append(np.unique(np.concatenate(
+            [lv[lv != 0xFFFFFFFF] for lv in
+             bfs_reach(adj, roots, depth, dedup=False)])))
+    tally, reached = (np.asarray(x) for x in
+                      bitgraph.traverse(badj, riders))
+    counts, levels = tally
+    got = [bitgraph.lane_uids(badj, reached, i)
+           for i in range(len(riders))]
+    same = [np.array_equal(g, w) and int(c) == len(w)
+            for g, w, c in zip(got, want, counts)]
+    return {"ok": all(same), "lanes_equal": int(sum(same)),
+            "reached": counts.tolist(), "levels_run": levels.tolist(),
+            "shape": {"slots": badj.n_slots, "edges": badj.n_edges,
+                      "hub_rows": 0 if badj.dense is None
+                      else list(badj.dense.shape),
+                      "gathered_classes": len(badj.gathered),
+                      "lanes": bitgraph.LANES}}
+
+
 def check_sssp_dist(db, pred) -> dict:
     """sssp_dist over the loaded graph's bitadjacency; twin = a NumPy
     level-synchronous BFS over the tablet's flat edge list, walked
@@ -261,6 +309,7 @@ def run(db, pred: str | None = None, checks: tuple = (),
 
     table = [
         ("bfs_digest_xla", lambda: check_bfs_digest(tiny)),
+        ("bfs_traverse", lambda: check_bfs_traverse(tiny)),
         ("sssp_dist", lambda: check_sssp_dist(db, pred)),
         ("range_select", lambda: check_range_select(db)),
         ("fused_rank_page", lambda: check_fused_rank_page(tiny)),

@@ -81,8 +81,9 @@ class BitAdjacency:
     n_covered: int                   # slots with in-degree > 0 (prefix)
     n_edges: int
     # the served traversal's hub rows (attach_dense): buckets
-    # [dense_from:] as one bitmap row a slot, uint32[rows, ceil(N/32)],
-    # bit s of row r set where slot s points at the row's slot
+    # [dense_from:] as one bitmap row a slot, uint32[rows, W] with
+    # 32 W >= N: bit s // W of word s % W of row r set where slot s
+    # points at the row's slot
     dense: Optional[jax.Array] = None
     dense_from: Optional[int] = None     # None: attach_dense not run
 
@@ -225,22 +226,26 @@ def bits_to_uids(badj: BitAdjacency, bits: np.ndarray) -> np.ndarray:
 def _level(badj: BitAdjacency, f: jax.Array) -> jax.Array:
     """One frontier expansion: bool[N] -> bool[N] (reachable-in-1)."""
     return jnp.concatenate([
-        _gathered_reach([b.in_nb for b in badj.buckets], f),
+        _gathered_reach([b.in_nb for b in badj.buckets],
+                        f.astype(jnp.uint32)) != 0,
         jnp.zeros((badj.n_slots - badj.n_covered,), jnp.bool_)])
 
 
 def _gathered_reach(in_nbs, f: jax.Array) -> jax.Array:
     """Which rows of the in-neighbour matrices `in_nbs` a frontier
-    over every slot reaches, in the matrices' order. The matrices are
-    ARGUMENTS, so that a jitted caller does not bake every edge into
-    its program as a constant. Gathers every padded in-edge whatever
-    the frontier holds: a level costs the same for every frontier.
-    The table is gathered as int32: 12% faster than bool on a v5e
-    (PERF.md, PR 31)."""
-    fe = jnp.concatenate([f.astype(jnp.int32), jnp.zeros((1,), jnp.int32)])
-    parts = [jnp.max(fe.at[nb].get(mode="promise_in_bounds"), axis=1) > 0
-             for nb in in_nbs]
-    return jnp.concatenate(parts) if parts else jnp.zeros((0,), jnp.bool_)
+    over every slot reaches, in the matrices' order. `f` is uint32[N],
+    bit b of a slot's word lane b's membership, and so is the answer:
+    one gather an index serves every lane, because a gather costs the
+    same whatever the width of what it fetches (bool, uint8 and int32
+    within 12% on a v5e: PERF.md, PR 31). The matrices are ARGUMENTS,
+    so that a jitted caller does not bake every edge into its program
+    as a constant. Gathers every padded in-edge whatever the frontier
+    holds: a level costs the same for every frontier."""
+    fe = jnp.concatenate([f, jnp.zeros((1,), jnp.uint32)])
+    parts = [jnp.bitwise_or.reduce(
+        fe.at[nb].get(mode="promise_in_bounds"), axis=1)
+        for nb in in_nbs]
+    return jnp.concatenate(parts) if parts else jnp.zeros((0,), jnp.uint32)
 
 
 def make_bfs_bits(badj: BitAdjacency, depth: int,
@@ -288,7 +293,7 @@ def _bfs_cache(badj: BitAdjacency, depth: int, dedup: bool) -> Callable:
     return fn
 
 
-# -- the served traversal: one program a request ------------------------------
+# -- the served traversal: one program for the requests in flight -------------
 
 
 # What a level costs on one v5e, measured (PERF.md, PR 31): a gathered
@@ -300,13 +305,37 @@ GATHER_SECONDS = 7.0e-9
 DENSE_BYTES_PER_S = 6.5e11
 
 
+# Traversals one call of bfs_traverse carries: the requests in flight
+# over one adjacency ride it together (query/devicecall.py's
+# Rendezvous), bit b of every word lane b's. A gathered class costs
+# the same for 32 lanes as for one; the hub rows cost a lane's ANDs
+# over every row word, which meet the time to stream the rows near
+# eight lanes (PERF.md, PR 34).
+LANES = 8
+
+# the hub rows' kernel: rows a grid step holds in VMEM, and the width
+# (in words) a row is padded to so that a step's block is whole vregs
+_HUB_TILE_ROWS = 256
+_HUB_WORDS_UNIT = 128
+
+
+def hub_row_words(n_slots: int) -> int:
+    """Words of one hub row over `n_slots` slots: a bit a slot, padded
+    to whole vregs of 128 words, which is what the chip's tiled layout
+    holds of a row anyway."""
+    return -(-n_slots // (32 * _HUB_WORDS_UNIT)) * _HUB_WORDS_UNIT
+
+
 def attach_dense(badj: BitAdjacency, budget_bytes: int) -> None:
     """Give the adjacency its hub rows: the degree classes whose rows
     are cheaper streamed than gathered, from the highest class down,
     as many whole classes as `budget_bytes` holds. On a skewed graph
     a small share of the slots holds most of the in-edges, so a
-    bounded block of rows takes most of a level's gathers away."""
-    n, words = badj.n_slots, -(-badj.n_slots // 32)
+    bounded block of rows takes most of a level's gathers away. In a
+    row of W words (hub_row_words) slot s is bit s // W of word s % W,
+    so that a frontier over the slots folds into a row's layout
+    without a transpose (_frontier_words)."""
+    n, words = badj.n_slots, hub_row_words(badj.n_slots)
     row_bytes = 4 * words
     first, rows = len(badj.buckets), 0
     for i in range(len(badj.buckets) - 1, -1, -1):
@@ -327,8 +356,8 @@ def attach_dense(badj: BitAdjacency, budget_bytes: int) -> None:
             else np.asarray(b.in_nb)
         r, c = np.nonzero(nb < n)
         src = nb[r, c].astype(np.int64)
-        keys.append((r + (b.offset - start)) * words + (src >> 5))
-        bits.append(np.uint32(1) << (src & 31).astype(np.uint32))
+        keys.append((r + (b.offset - start)) * words + src % words)
+        bits.append(np.uint32(1) << (src // words).astype(np.uint32))
     # OR the bits that share a word, then one write a word
     keys, bits = np.concatenate(keys), np.concatenate(bits)
     order = np.argsort(keys, kind="stable")
@@ -348,89 +377,225 @@ def level_seconds(badj: BitAdjacency) -> float:
     return gathered * GATHER_SECONDS + dense / DENSE_BYTES_PER_S
 
 
+def _lane_planes(words_by_slot: jax.Array, lanes: int) -> jax.Array:
+    """uint32[N] lane words -> uint32[lanes, N] of 0 / 1: lane b's
+    membership of every slot."""
+    b = jnp.arange(lanes, dtype=jnp.uint32)[:, None]
+    return (words_by_slot[None, :] >> b) & jnp.uint32(1)
+
+
+def _hub_kernel(live_ref, fw_ref, rows_ref, out_ref):
+    """A tile of hub rows against every LIVE lane's frontier, the
+    tile read from HBM once. live_ref int32[1 + LANES] (SMEM): how
+    many lanes are live, then their numbers; fw_ref uint32[LANES, 8,
+    W]: a lane's frontier in the rows' own layout (_frontier_words),
+    on eight sublanes; rows_ref uint32[T, W]; out_ref uint32[T, 128]:
+    bit b of a row's words set where the row meets lane b's frontier
+    in that column of vregs (the caller ORs the 128 together). All
+    elementwise on whole vregs: no reduction across lanes of a vreg,
+    no relayout."""
+    from jax.experimental import pallas as pl
+
+    n_live = live_ref[0]
+    chunks = rows_ref.shape[1] // 128
+
+    def group(g, carry):
+        r0 = pl.multiple_of(g * 8, 8)
+        rows = rows_ref[pl.ds(r0, 8), :]
+
+        def lane(i, packed):
+            b = live_ref[1 + i]
+            met = rows & fw_ref[b]
+            parts = [met[:, c * 128:(c + 1) * 128] for c in range(chunks)]
+            while len(parts) > 1:       # a tree: no chain of 43 ORs
+                parts = [parts[j] | parts[j + 1] if j + 1 < len(parts)
+                         else parts[j] for j in range(0, len(parts), 2)]
+            bit = jnp.left_shift(jnp.int32(1), b).astype(jnp.uint32)
+            return packed | jnp.where(parts[0] != 0, bit, jnp.uint32(0))
+
+        out_ref[pl.ds(r0, 8), :] = jax.lax.fori_loop(
+            0, n_live, lane, jnp.zeros((8, 128), jnp.uint32))
+        return carry
+
+    jax.lax.fori_loop(0, rows_ref.shape[0] // 8, group, 0)
+
+
+def _frontier_words(frontier: jax.Array, words: int,
+                    lanes: int) -> jax.Array:
+    """uint32[N] lane words -> uint32[lanes, words]: a lane's frontier
+    as the hub rows hold their in-neighbours, bit s // words of word
+    s % words slot s: 32 runs of `words` slots, each shifted to its
+    bit and summed, the slots on the minor axis throughout."""
+    planes = _lane_planes(
+        jnp.pad(frontier, (0, 32 * words - frontier.shape[0])), lanes)
+    return jnp.sum(planes.reshape(lanes, 32, words)
+                   << jnp.arange(32, dtype=jnp.uint32)[:, None],
+                   axis=1, dtype=jnp.uint32)
+
+
+def _hub_reach(dense: jax.Array, frontier: jax.Array,
+               active: jax.Array, lanes: int) -> jax.Array:
+    """Which hub rows a frontier reaches, lane by lane: uint32[rows]
+    lane words. `frontier` uint32[N] lane words over every slot,
+    `active` the word of the lanes that hold any. The rows are read
+    ONCE for all lanes: on the chip by _hub_kernel, a tile of rows in
+    VMEM and a loop over the live lanes; elsewhere (the CPU the tests
+    run on) by the same ANDs in plain jnp."""
+    fw = _frontier_words(frontier, dense.shape[1], lanes)
+    if jax.default_backend() != "tpu":
+        bit = jnp.uint32(1) << jnp.arange(lanes, dtype=jnp.uint32)
+        met = jnp.any((dense[None, :, :] & fw[:, None, :]) != 0, axis=2)
+        return jnp.sum(jnp.where(met, bit[:, None], jnp.uint32(0)),
+                       axis=0, dtype=jnp.uint32)
+    return jnp.bitwise_or.reduce(
+        _hub_call(dense, fw, active, lanes), axis=1)
+
+
+def _hub_call(dense, fw, active, lanes: int, interpret: bool = False):
+    """_hub_kernel over every tile of `dense` -> uint32[rows, 128]."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    rows, words = dense.shape
+    is_live = ((active >> jnp.arange(lanes, dtype=jnp.uint32)) & 1) != 0
+    live = jnp.concatenate([
+        jnp.sum(is_live, dtype=jnp.int32)[None],
+        jnp.argsort(~is_live, stable=True).astype(jnp.int32)])
+    tile = min(_HUB_TILE_ROWS, -(-rows // 8) * 8)
+    return pl.pallas_call(
+        _hub_kernel,
+        out_shape=jax.ShapeDtypeStruct((rows, 128), jnp.uint32),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(pl.cdiv(rows, tile),),
+            in_specs=[
+                pl.BlockSpec((lanes, 8, words), lambda i, live: (0, 0, 0)),
+                pl.BlockSpec((tile, words), lambda i, live: (i, 0))],
+            out_specs=pl.BlockSpec((tile, 128), lambda i, live: (i, 0))),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=2 * 4 * words * (tile + 8 * lanes)
+            + (8 << 20)),
+        interpret=interpret,
+        name="bfs_hub_rows",
+    )(live, jnp.broadcast_to(fw[:, None, :], (lanes, 8, words)), dense)
+
+
 @functools.partial(jax.jit,
-                   static_argnames=("n_slots", "n_covered", "want_bits"))
-def bfs_traverse(in_nbs, dense, seed_slots, depth, *, n_slots: int,
-                 n_covered: int, want_bits: bool):
-    """A whole `@recurse(loop: false)` in one program: every level,
-    the visited set and the count.
+                   static_argnames=("n_slots", "n_covered", "lanes"))
+def bfs_traverse(in_nbs, dense, riders, *, n_slots: int, n_covered: int,
+                 lanes: int):
+    """`@recurse(loop: false)` whole, for every LANE of the call: up
+    to `lanes` traversals over one adjacency in one program, each
+    with its own roots, depth, visited set and count. The frontier,
+    the visited set and the reached set are uint32[N] LANE WORDS: bit
+    b of a slot's word is lane b's membership, so a lane never sees
+    another's bits and a level's gathers serve all of them.
 
     in_nbs      the gathered degree classes' in-neighbour matrices
     dense       the other classes' rows (attach_dense), or None
-    seed_slots  int32[S]: the roots' slots, padded with n_slots (the
-                dummy slot, dropped)
-    depth       int32 scalar, a RUNTIME value: levels to expand (edge
-                hops), so one program serves every depth
-    ->  (reached, levels_run, bits)
-    reached     int32: distinct slots reached through an edge in
-                1..depth hops (a root counts where an edge leads back
-                to it): what DQL's uid variable on the child holds
-    levels_run  int32: levels expanded; the loop ends early once a
-                level finds no new slot
-    bits        uint8[ceil(N / 8)] with want_bits, the reached set
-                packed big-endian as np.unpackbits reads it; else
-                None, and only two scalars leave the device
+    riders      int32[2 S + lanes], ONE upload a call: S root slots of
+                all lanes, padded with n_slots (the dummy slot,
+                dropped); beside each its lane's bit (0 on padding; a
+                (slot, lane) pair appears once); then a depth a lane,
+                RUNTIME values: levels the lane expands (edge hops),
+                0 for a lane nobody rides; a lane stops after its
+                own, whatever the others do
+    ->  (tally, reached)
+    tally       int32[2, lanes], ONE fetch a call. Row 0: distinct
+                slots a lane reached through an edge in 1..depth hops
+                (a root counts where an edge leads back to it): what
+                DQL's uid variable on the child holds. Row 1: levels
+                a lane expanded; it ends early once a level finds it
+                no new slot, and the loop ends when no lane is alive
+    reached     uint32[N] lane words: the reached sets themselves.
+                They stay on the device unless a lane's reader wants
+                the uids (lane_uids)
     """
-    seed = jnp.zeros((n_slots + 1,), jnp.bool_).at[seed_slots].set(
-        True, mode="promise_in_bounds")[:n_slots]
-    shifts = jnp.arange(32, dtype=jnp.uint32)
+    n_seeds = (riders.shape[0] - lanes) // 2
+    seed_slots = riders[:n_seeds]
+    seed_bits = jax.lax.bitcast_convert_type(
+        riders[n_seeds:2 * n_seeds], jnp.uint32)
+    depths = riders[2 * n_seeds:]
+    lane = jnp.arange(lanes, dtype=jnp.uint32)
+    seed = jnp.zeros((n_slots + 1,), jnp.uint32).at[seed_slots].add(
+        seed_bits, mode="promise_in_bounds")[:n_slots]
 
-    def level(frontier):
+    def expanding(lvl):
+        """The word of the lanes whose depth reaches past `lvl`."""
+        return jnp.sum(jnp.where(depths > lvl, jnp.uint32(1) << lane,
+                                 jnp.uint32(0)), dtype=jnp.uint32)
+
+    def level(frontier, active):
         parts = [_gathered_reach(in_nbs, frontier)]
         if dense is not None:
-            words = dense.shape[1]
-            f = jnp.pad(frontier, (0, 32 * words - n_slots))
-            fw = jnp.sum(f.reshape(words, 32).astype(jnp.uint32) << shifts,
-                         axis=1, dtype=jnp.uint32)
-            parts.append(jnp.any((dense & fw[None, :]) != 0, axis=1))
-        parts.append(jnp.zeros((n_slots - n_covered,), jnp.bool_))
+            parts.append(_hub_reach(dense, frontier, active, lanes))
+        parts.append(jnp.zeros((n_slots - n_covered,), jnp.uint32))
         return jnp.concatenate(parts)
 
     def cond(state):
-        lvl, _, _, _, alive = state
-        return (lvl < depth) & alive
+        return state[-1] != 0
 
     def body(state):
-        lvl, frontier, visited, reached, _ = state
-        reach = level(frontier)
+        lvl, frontier, visited, reached, levels_run, active = state
+        reach = level(frontier, active)
         new = reach & ~visited
-        return (lvl + 1, new, visited | new, reached | reach,
-                jnp.any(new))
+        # a lane goes on only within its depth and from a new slot
+        frontier = new & expanding(lvl + 1)
+        return (lvl + 1, frontier, visited | new, reached | reach,
+                levels_run + ((active >> lane) & 1).astype(jnp.int32),
+                jnp.bitwise_or.reduce(frontier))
 
-    levels_run, _, _, reached, _ = jax.lax.while_loop(
-        cond, body, (jnp.int32(0), seed, seed,
-                     jnp.zeros((n_slots,), jnp.bool_), jnp.any(seed)))
-    count = jnp.sum(reached, dtype=jnp.int32)
-    return count, levels_run, (jnp.packbits(reached) if want_bits
-                               else None)
+    first = seed & expanding(jnp.int32(0))
+    _, _, _, reached, levels_run, _ = jax.lax.while_loop(
+        cond, body, (jnp.int32(0), first, seed,
+                     jnp.zeros((n_slots,), jnp.uint32),
+                     jnp.zeros((lanes,), jnp.int32),
+                     jnp.bitwise_or.reduce(first)))
+    counts = jnp.sum(_lane_planes(reached, lanes), axis=1, dtype=jnp.int32)
+    return jnp.stack([counts, levels_run]), reached
 
 
-def traverse(badj: BitAdjacency, slots: np.ndarray, depth: int,
-             want_bits: bool):
-    """bfs_traverse over an adjacency as attach_dense left it."""
+def traverse(badj: BitAdjacency, riders: list):
+    """bfs_traverse over an adjacency as attach_dense left it, one
+    lane a rider: `riders` is [(root slots as seed_slots gives them,
+    depth)], at most LANES of them; rider i is lane i of both
+    results. ONE compiled shape an adjacency while the riders' roots
+    number eight or fewer together (then a power of two), whatever
+    their count and depths."""
+    from dgraph_tpu.ops.uidvec import pad_to
+    if not 0 < len(riders) <= LANES:
+        raise ValueError(f"{len(riders)} riders for {LANES} lanes")
+    n_seeds = pad_to(sum(len(slots) for slots, _ in riders))
+    packed = np.zeros(2 * n_seeds + LANES, np.int32)
+    packed[:n_seeds] = badj.n_slots
+    at = 0
+    for b, (slots, depth) in enumerate(riders):
+        packed[at:at + len(slots)] = slots
+        packed[n_seeds + at:n_seeds + at + len(slots)] = 1 << b
+        packed[2 * n_seeds + b] = min(depth, 2**31 - 1)
+        at += len(slots)
     return bfs_traverse(
-        [b.in_nb for b in badj.gathered], badj.dense,
-        jnp.asarray(slots), np.int32(min(depth, 2**31 - 1)),
-        n_slots=badj.n_slots, n_covered=badj.n_covered,
-        want_bits=want_bits)
+        [b.in_nb for b in badj.gathered], badj.dense, packed,
+        n_slots=badj.n_slots, n_covered=badj.n_covered, lanes=LANES)
 
 
-def seed_slots(badj: BitAdjacency, uids32: np.ndarray,
-               pad: int) -> Optional[np.ndarray]:
-    """Root uids -> int32[pad] slots for bfs_traverse, or None where
-    the adjacency does not know one of them (the caller answers on
-    the host, as for any uid the tile cannot speak for)."""
+def seed_slots(badj: BitAdjacency, uids32: np.ndarray
+               ) -> Optional[np.ndarray]:
+    """Root uids -> their int32 slots (each once) for traverse, or
+    None where the adjacency does not know one of them (the caller
+    answers on the host, as for any uid the tile cannot speak for)."""
     slots, hit = _uid_slots(badj, uids32)
     if not hit.all():
         return None
-    out = np.full(pad, badj.n_slots, np.int32)
-    out[: len(slots)] = slots
-    return out
+    return np.unique(slots).astype(np.int32)
 
 
-def packed_to_uids(badj: BitAdjacency, packed: np.ndarray) -> np.ndarray:
-    """bfs_traverse's `bits` -> sorted uid uint32 array."""
-    return bits_to_uids(badj, np.unpackbits(packed, count=badj.n_slots))
+def lane_uids(badj: BitAdjacency, reached: np.ndarray,
+              lane: int) -> np.ndarray:
+    """One lane of bfs_traverse's `reached` -> sorted uid uint32
+    array."""
+    return bits_to_uids(badj, (reached >> np.uint32(lane)) & np.uint32(1))
 
 
 # -- batched (multi-query) kernels -------------------------------------------

@@ -33,10 +33,18 @@ dispatch overhead, queueing or transfer.
 Callees that dispatch and fetch in one function (`expand_np`,
 `setops.union_many_device`, `bitgraph.sssp_dist`) take `sync=dc.wait`:
 a function applied to the dispatched result before it is fetched.
+
+Where the requests in flight can share ONE call of a program (the
+k-hop traversal's lanes), each still runs its own block and meets the
+others at a `Rendezvous`: its `wait` is then `dc.wait_for(...)`, from
+joining until its call's result is in, queueing behind the call in
+flight included, exactly as queueing behind other requests' programs
+is above.
 """
 
 from __future__ import annotations
 
+import threading
 import time
 
 from dgraph_tpu.utils.metrics import inc_counter
@@ -51,6 +59,8 @@ def _family(counter: str) -> str:
 
 
 class device_call:
+    # dglint: guarded-by=*:single-thread (one block per dispatch of one
+    # request: the thread that enters it leaves it)
     __slots__ = ("_sink", "_counter", "_labels", "_span", "_attrs",
                  "_ann", "_t0", "_t1", "_t2", "_out_bytes")
 
@@ -81,16 +91,30 @@ class device_call:
         """The dispatched result, once the device has produced it."""
         import jax
 
+        ready = self.wait_for(lambda: jax.block_until_ready(out))
+        nbytes = getattr(ready, "nbytes", None)
+        self._out_bytes = int(nbytes) if nbytes is not None else sum(
+            int(x.nbytes) for x in jax.tree_util.tree_leaves(ready))
+        return ready
+
+    def wait_for(self, ready, out_bytes: int = 0):
+        """`ready()`'s result, for a block whose dispatch is not a
+        device array yet: `ready` returns once the device has produced
+        what the block waits for (a seat in a call that a Rendezvous
+        dispatches). `out_bytes`: what the block takes off the device
+        of it."""
         t1 = time.perf_counter_ns()
         self._phase("device.wait")
-        out = jax.block_until_ready(out)
+        out = ready()
         t2 = time.perf_counter_ns()
         self._phase("device.fetch")
         self._t1, self._t2 = t1, t2
-        nbytes = getattr(out, "nbytes", None)
-        self._out_bytes = int(nbytes) if nbytes is not None else sum(
-            int(x.nbytes) for x in jax.tree_util.tree_leaves(out))
+        self._out_bytes = out_bytes
         return out
+
+    def note(self, **attrs) -> None:
+        """More attributes of the block's `device.call` span."""
+        self._attrs.update(attrs)
 
     def __exit__(self, etype, exc, tb) -> None:
         t3 = time.perf_counter_ns()
@@ -114,3 +138,192 @@ class device_call:
                 sink.device_wait_ns += phases[1][1]
                 sink.device_fetch_ns += phases[2][1]
         self._span.__exit__(etype, exc, tb)
+
+
+class _Flight:
+    """One call of a Rendezvous: its riders in lane order, what
+    `launch` handed back, and who blocks for the result."""
+
+    __slots__ = ("riders", "handle", "launched", "lander", "t_launch")
+
+    def __init__(self, riders: list):
+        self.riders = riders
+        self.handle = None
+        self.launched = False
+        self.lander = None
+        self.t_launch = 0
+
+
+class Ride:
+    """A caller's seat: `result` is its own element of what `land`
+    returned, `lane` its place in the call, `lanes` how many rode the
+    call, `waited_ns` how long it stood before its call was launched
+    (0 for a caller that found the chip free)."""
+
+    __slots__ = ("item", "flight", "done", "result", "error", "lane",
+                 "t_join", "stood")
+
+    def __init__(self, item):
+        self.item = item
+        self.flight = None
+        self.done = False
+        self.result = self.error = None
+        self.lane = 0
+        self.stood = False
+        self.t_join = time.perf_counter_ns()
+
+    @property
+    def lanes(self) -> int:
+        return len(self.flight.riders)
+
+    @property
+    def waited_ns(self) -> int:
+        return max(0, self.flight.t_launch - self.t_join) \
+            if self.stood else 0
+
+
+class Rendezvous:
+    """Where the callers of one device program over ONE resident tile
+    meet, so that those in flight ride one call of it.
+
+    A caller that finds no call of the tile in flight launches at
+    once, with whoever waits with it: alone, if alone, and then it
+    never waits. One that finds a call in flight waits; when that
+    call's result is in, the thread that took it launches ALL the
+    waiters (up to `capacity`; the rest form the next call) before it
+    hands anything out, so the chip never stands idle for a thread to
+    wake, and one of the new call's riders then blocks for its
+    result. A call is closed by who is waiting when the chip comes
+    free, never by a timer: there is no window and no knob.
+
+    `launch(items) -> handle` puts one call for `items` (the riders'
+    own, in lane order) on the device and returns without waiting;
+    `land(handle, n) -> [n results]` returns once the device has
+    produced them. Every caller passes the same two. One that raises
+    fails the riders of ITS call with that error, each in its own
+    thread, and frees the chip for the waiters; none waits for ever.
+    A rider whose context is cancelled or past its deadline while it
+    waits leaves with its own error; its lane, if its call is
+    launched already, is computed and dropped.
+
+    Kept on the tile it serves (`Rendezvous.at`), so requests meet
+    only over the very object their own read_ts resolved to: another
+    base_ts, direction or predicate is another tile and another
+    rendezvous."""
+
+    _POLL_S = 0.05      # how often a waiter looks at its context
+    _make = threading.Lock()
+
+    def __init__(self, capacity: int):
+        self.capacity = capacity
+        self._cond = threading.Condition()
+        self._flight: _Flight | None = None     # the call on the chip
+        self._waiting: list[Ride] = []
+
+    @classmethod
+    def at(cls, tile, capacity: int) -> "Rendezvous":
+        """The tile's own rendezvous, made on first asking."""
+        meet = getattr(tile, "_rendezvous", None)
+        if meet is None:
+            with cls._make:
+                meet = getattr(tile, "_rendezvous", None)
+                if meet is None:
+                    meet = tile._rendezvous = cls(capacity)
+        return meet
+
+    def _board(self, first: Ride | None = None) -> _Flight | None:
+        """(under the lock) The waiters' call, oldest first, `first`
+        among them; None where nobody waits."""
+        riders = self._waiting[:self.capacity]
+        if first is not None and first not in riders:
+            riders[-1] = first
+        if not riders:
+            self._flight = None
+            return None
+        self._waiting = [r for r in self._waiting if r not in riders]
+        flight = self._flight = _Flight(riders)
+        for lane, r in enumerate(riders):
+            r.flight, r.lane = flight, lane
+        return flight
+
+    def _launch(self, flight: _Flight, launch):
+        """(outside the lock) Put `flight` on the device. Where that
+        raises, its riders fail with the error, the chip is free
+        again, and the error is handed back."""
+        flight.t_launch = time.perf_counter_ns()
+        try:
+            flight.handle = launch([r.item for r in flight.riders])
+        except BaseException as e:  # noqa: BLE001 -- its riders' to
+            # raise; an interrupt is raised again by ride()
+            with self._cond:
+                self._settle(flight, None, e)
+                self._flight = None
+                self._cond.notify_all()
+            return e
+        flight.launched = True
+        return None
+
+    def _settle(self, flight: _Flight, results, error) -> None:
+        """(under the lock) Every rider of `flight` gets its own
+        element of `results`, or `error`."""
+        if error is None and len(results) != len(flight.riders):
+            error = RuntimeError(
+                f"{len(results)} results for {len(flight.riders)} riders")
+        for i, r in enumerate(flight.riders):
+            r.error = error
+            r.result = None if error is not None else results[i]
+            r.done = True
+
+    def ride(self, item, launch, land, ctx=None) -> Ride:
+        me = Ride(item)
+        cond = self._cond
+        mine = None             # the flight this thread lands
+        with cond:
+            self._waiting.append(me)
+            while not me.done:
+                f = me.flight
+                if f is None and self._flight is None:
+                    mine = self._board(me)      # the chip is free
+                    mine.lander = me
+                    break
+                if f is not None and f.launched and f.lander is None:
+                    mine = f
+                    mine.lander = me
+                    break
+                me.stood = True
+                left = None if ctx is None else ctx.remaining()
+                cond.wait(None if ctx is None else self._POLL_S
+                          if left is None else min(self._POLL_S, left))
+                if ctx is not None and not me.done \
+                        and (me.flight is None
+                             or me.flight.lander is not None):
+                    # still standing, or somebody else lands my call:
+                    # free to go
+                    try:
+                        ctx.check("device rendezvous")
+                    except BaseException:
+                        if me.flight is None:
+                            self._waiting.remove(me)
+                        raise
+        if mine is not None:
+            if not mine.launched:
+                self._launch(mine, launch)
+            if mine.launched:
+                try:
+                    results, error = land(mine.handle,
+                                          len(mine.riders)), None
+                except BaseException as e:
+                    results, error = None, e
+                # the chip is free: the waiters' call goes on it
+                # before anything is handed out
+                with cond:
+                    nxt = self._board()
+                failed = None if nxt is None else self._launch(nxt, launch)
+                with cond:
+                    self._settle(mine, results, error)
+                    cond.notify_all()
+                if failed is not None and not isinstance(failed, Exception):
+                    raise failed    # an interrupt is this thread's too
+        if me.error is not None:
+            raise me.error
+        return me

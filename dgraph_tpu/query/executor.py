@@ -20,6 +20,7 @@ against each other.
 
 from __future__ import annotations
 
+import functools
 import re as _re
 import time as _time
 from collections.abc import Mapping
@@ -40,7 +41,7 @@ from dgraph_tpu.models.types import (
 from dgraph_tpu.cluster.coordinator import StaleSnapshot
 from dgraph_tpu.ops import setops
 from dgraph_tpu.query.colvar import ColVar, make_colvar
-from dgraph_tpu.query.devicecall import device_call
+from dgraph_tpu.query.devicecall import Rendezvous, device_call
 from dgraph_tpu.query.retrigram import compile_trigram_query
 from dgraph_tpu.storage.tablet import Tablet
 from dgraph_tpu.utils import failpoint
@@ -277,6 +278,31 @@ def _np_sorted(uids) -> np.ndarray:
         return np.unique(uids.astype(np.uint64, copy=False))
     arr = np.fromiter((int(u) for u in uids), dtype=np.uint64)
     return np.unique(arr)
+
+
+def _launch_traversals(badj, riders: list):
+    """A Rendezvous' `launch` for the k-hop traversal: ONE call of
+    bitgraph.bfs_traverse for `riders` ([(root slots, depth)], a lane
+    each), not waited for. `recurse_batch_total` counts the calls,
+    `recurse_batch_lanes_total` the traversals they carried: lanes a
+    call is their ratio."""
+    from dgraph_tpu.ops import bitgraph
+    tally, reached = bitgraph.traverse(badj, riders)
+    # the one small result starts for the host as soon as the device
+    # has it, not a round trip after somebody asks
+    tally.copy_to_host_async()
+    inc_counter("recurse_batch_total")
+    inc_counter("recurse_batch_lanes_total", len(riders))
+    return tally, reached
+
+
+def _land_traversals(handle, n: int) -> list:
+    """A Rendezvous' `land`: every rider's (reached count, levels
+    run, the lanes' reached sets still on the device), once the call
+    has run. One small array leaves the device for all of them."""
+    tally, reached = handle
+    counts, levels = np.asarray(tally)
+    return [(int(counts[i]), int(levels[i]), reached) for i in range(n)]
 
 
 def _var_domain(vmap) -> np.ndarray:
@@ -4827,7 +4853,8 @@ class Executor:
                         ) -> Optional[tuple]:
         """The whole traversal as ONE device program and ONE
         device_call -> (reached count, reached uids or None, levels
-        run); None where the host tier is to answer: the gate says so,
+        run, {lanes of the call it rode, batch_wait_us}); None where
+        the host tier is to answer: the gate says so,
         or the device cannot speak for the traversal (roots over 32
         bits, a dirty tablet or one under device_min_edges, a root
         the adjacency does not know).
@@ -4844,7 +4871,6 @@ class Executor:
             _MAX_U32, device_bitadjacency,
         )
         from dgraph_tpu.ops import bitgraph
-        from dgraph_tpu.ops.uidvec import pad_to
         from dgraph_tpu.query.planner import recurse_costs
         if int(roots[-1]) > _MAX_U32 or not hasattr(tab, "degree_moments"):
             return None       # (a federated proxy is host-only)
@@ -4863,18 +4889,29 @@ class Executor:
         if badj is None or badj.n_slots == 0 \
                 or not worth(levels * bitgraph.level_seconds(badj)):
             return None
-        slots = bitgraph.seed_slots(badj, roots.astype(np.uint32),
-                                    pad_to(len(roots)))
-        if slots is None:
-            return None
+        # the traversals in flight over this tile ride one call
+        # (devicecall.Rendezvous): this request's block is its own
+        # all the same, its wait the time until its call's result
         with device_call("query_device_recurse_total", sink=self.lat,
                          program="bfs_traverse") as dc:
-            count, levels, bits = dc.wait(
-                bitgraph.traverse(badj, slots, depth, want_uids))
-            uids = bitgraph.packed_to_uids(
-                badj, np.asarray(bits)).astype(np.uint64) \
+            slots = bitgraph.seed_slots(badj, roots.astype(np.uint32))
+            if slots is None:
+                return None
+            meet = Rendezvous.at(badj, bitgraph.LANES)
+            ride = dc.wait_for(
+                lambda: meet.ride(
+                    (slots, depth),
+                    functools.partial(_launch_traversals, badj),
+                    _land_traversals, self.ctx),
+                out_bytes=8 + (4 * badj.n_slots if want_uids else 0))
+            count, levels, reached = ride.result
+            batch = {"lanes": ride.lanes,
+                     "batch_wait_us": ride.waited_ns // 1000}
+            dc.note(**batch)
+            uids = bitgraph.lane_uids(
+                badj, np.asarray(reached), ride.lane).astype(np.uint64) \
                 if want_uids else None
-            return int(count), uids, int(levels)
+            return count, uids, levels, batch
 
     def _run_recurse_bound(self, node: ExecNode, depth: int, bound: list,
                            sp: dict):
@@ -4894,8 +4931,9 @@ class Executor:
                 and not self._count_only_readers(cgq.var, gq)
             got = self._recurse_device(tab, rev, roots, depth, want)
             if got is not None:
-                count, uids, levels = got
-                sp.update(tier="device", levels_run=levels, reached=count)
+                count, uids, levels, batch = got
+                sp.update(tier="device", levels_run=levels, reached=count,
+                          **batch)
                 if cgq.var:
                     self.uid_vars[cgq.var] = uids if want else _EMPTY
                     if not want:

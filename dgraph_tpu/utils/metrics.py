@@ -125,7 +125,10 @@ REGISTERED = (
     "query_similar_quantized_total",
     "query_similar_sharded_total",
     # query/executor.py _run_recurse: the span's time, and which tier
-    # a @recurse took
+    # a @recurse took; _launch_traversals: the device calls the
+    # rendezvous dispatched and the traversals they carried
+    "recurse_batch_lanes_total",
+    "recurse_batch_total",
     "recurse_ns_total",
     "recurse_tier_total",
     "similar_exact_fallback_total",

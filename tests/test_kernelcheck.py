@@ -25,8 +25,8 @@ def db():
     return db
 
 
-CHECKS = ["bfs_digest_xla", "fused_rank_page", "knn_exact",
-          "range_select", "setops_cosort", "sssp_dist"]
+CHECKS = ["bfs_digest_xla", "bfs_traverse", "fused_rank_page",
+          "knn_exact", "range_select", "setops_cosort", "sssp_dist"]
 
 
 @pytest.mark.parametrize("name", CHECKS)
@@ -45,7 +45,7 @@ def test_refusal_is_recorded_not_raised(db, monkeypatch):
     def refuse(*_a):
         raise RuntimeError("Mosaic: not implemented")
 
-    for fn in ("check_bfs_digest", "check_sssp_dist",
+    for fn in ("check_bfs_digest", "check_bfs_traverse", "check_sssp_dist",
                "check_range_select", "check_fused_rank_page",
                "check_knn_exact"):
         monkeypatch.setattr(kernelcheck, fn, lambda *_a: {"ok": True})

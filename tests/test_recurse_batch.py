@@ -1,0 +1,700 @@
+"""The k-hop traversals in flight ride one device call (PR 34):
+`ops/bitgraph.bfs_traverse`'s lanes, `query/devicecall.Rendezvous`,
+and the executor's one dispatch site for them, `_recurse_device`.
+Exactness and isolation are the deployment's guarantees: a lane's
+answer is what the request gets alone, from the host tier and from the
+plain reference's method (benchmark/datasets/graph500_plain.py)."""
+
+import importlib.util
+import json
+import os
+import threading
+import time
+
+import numpy as np
+import pytest
+
+from dgraph_tpu.engine.db import GraphDB
+from dgraph_tpu.ingest.bulk import bulk_load
+from dgraph_tpu.ops import bitgraph
+from dgraph_tpu.query import executor as executor_mod
+from dgraph_tpu.query.devicecall import Rendezvous
+from dgraph_tpu.utils import metrics, tracing
+from dgraph_tpu.utils.reqctx import (
+    Cancelled, DeadlineExceeded, RequestContext,
+)
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+
+def _load(name):
+    path = os.path.join(ROOT, "benchmark", "datasets", name + ".py")
+    spec = importlib.util.spec_from_file_location("tb_" + name, path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+graph500 = _load("graph500")
+plain = _load("graph500_plain")
+graph500._CACHE["program"] = graph500.PROGRAM_ROOT
+
+GRAPHS = [(8, 2**31 + 5), (9, 31_000_017), (10, 7)]
+KHOP = ("{ var(func: uid(%s)) @recurse(depth: %d, loop: false) "
+        "{ n as link } khop(func: uid(n)) { count(uid) } }")
+UIDS = ("{ var(func: uid(%s)) @recurse(depth: %d, loop: false) "
+        "{ n as link } khop(func: uid(n)) { uid } }")
+
+
+def _q(template, roots, depth):
+    return template % (", ".join(hex(r) for r in roots), depth)
+
+
+def _db(tmp, scale, seed, **kw):
+    path = os.path.join(tmp, f"g{scale}-{seed}.rdf")
+    if not os.path.exists(path):
+        with open(path, "w") as f:
+            graph500.write_rdf(f, scale, seed)
+    return bulk_load([path], schema=graph500.SCHEMA, db=GraphDB(**kw))
+
+
+@pytest.fixture(scope="module")
+def worlds(tmp_path_factory):
+    """(facts, device-tier db, host-tier db, csr) a seeded graph."""
+    tmp = str(tmp_path_factory.mktemp("graph500"))
+    out = {}
+    for scale, seed in GRAPHS:
+        src, dst, roots, vertices = graph500.graph(scale, seed)
+        offsets = np.zeros(vertices + 1, np.int64)
+        np.cumsum(np.bincount(src, minlength=vertices), out=offsets[1:])
+        out[scale, seed] = (
+            {"seed": seed, "roots": roots, "vertices": vertices},
+            _db(tmp, scale, seed, prefer_device=True, device_min_edges=1),
+            _db(tmp, scale, seed, prefer_device=False),
+            (offsets, dst, vertices))
+    return out
+
+
+@pytest.fixture(autouse=True)
+def every_bound_recurse_on_the_device(monkeypatch):
+    """The gate is not what is tested here (tests/test_recurse_bound.py
+    does): every bound @recurse of a device-tier db takes the chip."""
+    monkeypatch.setattr(executor_mod.Executor, "_device_worth",
+                        lambda self, *a, **kw: True)
+
+
+def _counter(name):
+    return sum(v for k, v in metrics.snapshot()["counters"].items()
+               if k.startswith(name))
+
+
+def _data(db, q, **kw):
+    body = db.query_json(q, **kw)
+    return body[len('{"data":'):body.rfind(',"extensions":')]
+
+
+def _plain_reached(csr, roots, hops):
+    """The plain reference's walk from a root SET: a vertex is within
+    k hops of the set where it is of one of its members."""
+    n = np.zeros(csr[2], bool)
+    for r in roots:
+        n |= plain.reached(*csr, r - graph500.FIRST_UID, hops)
+    return n
+
+
+def _requests(facts, n, seed):
+    """n requests of mixed depths 1-7 and root sets of 1-5 roots."""
+    rng = np.random.default_rng(seed)
+    out = []
+    for i in range(n):
+        roots = sorted({graph500.FIRST_UID + int(r) for r in rng.integers(
+            0, facts["roots"], int(rng.integers(1, 6)))})
+        out.append((roots, 1 + (i + int(rng.integers(0, 7))) % 7))
+    return out
+
+
+def _traverse(badj, riders):
+    """bitgraph.traverse on the host -> (counts, levels, reached)."""
+    tally, reached = bitgraph.traverse(badj, riders)
+    counts, levels = np.asarray(tally)
+    return counts, levels, np.asarray(reached)
+
+
+def _tile(dev):
+    """The forward adjacency tile of a device-tier db, built by a
+    query if it is not there."""
+    _data(dev, _q(KHOP, [graph500.FIRST_UID], 3))
+    return dev.tablets["link"]._device_badj
+
+
+# -- the program: lanes ------------------------------------------------
+
+
+@pytest.mark.parametrize("riders", (1, 3, bitgraph.LANES))
+@pytest.mark.parametrize("graph", GRAPHS, ids=lambda g: f"scale{g[0]}")
+def test_a_lane_answers_what_it_answers_alone(worlds, graph, riders):
+    facts, dev, host, csr = worlds[graph]
+    badj = _tile(dev)
+    reqs = _requests(facts, riders, graph[0] * 10 + riders)
+    lanes = [(bitgraph.seed_slots(badj, np.array(r, np.uint32)), d - 1)
+             for r, d in reqs]
+    counts, levels, reached = _traverse(badj, lanes)
+    for i, (roots, depth) in enumerate(reqs):
+        alone = _traverse(badj, [lanes[i]])
+        want = _plain_reached(csr, roots, depth - 1)
+        assert counts[i] == alone[0][0] == int(want.sum()), (roots, depth)
+        assert levels[i] == alone[1][0] <= depth - 1
+        uids = bitgraph.lane_uids(badj, reached, i)
+        assert np.array_equal(uids, bitgraph.lane_uids(badj, alone[2], 0))
+        assert uids.tolist() == (np.flatnonzero(want)
+                                 + graph500.FIRST_UID).tolist()
+        assert json.loads(_data(host, _q(KHOP, roots, depth))) \
+            == {"khop": [{"count": int(counts[i])}]}
+    # a lane nobody rides reaches nothing and runs no level
+    assert not counts[riders:].any() and not levels[riders:].any()
+    assert not (reached >> np.uint32(riders)).any()
+
+
+def test_a_lane_stops_at_its_own_depth_beside_a_deeper_one(worlds):
+    facts, dev, _, csr = worlds[10, 7]
+    badj = _tile(dev)
+    root = graph500.FIRST_UID + 3
+    slots = bitgraph.seed_slots(badj, np.array([root], np.uint32))
+    counts, levels, _ = _traverse(
+        badj, [(slots, k) for k in (1, 2, 3, 6, 0)])
+    want = [int(_plain_reached(csr, [root], k).sum()) for k in (1, 2, 3, 6)]
+    assert counts[:5].tolist() == want + [0]
+    assert want[0] < want[1] < want[2] <= want[3]
+    assert levels[:5].tolist()[:3] == [1, 2, 3] and levels[4] == 0
+    # the same root twice is two lanes, each exact
+    twice = _traverse(badj, [(slots, 3), (slots, 3)])[0]
+    assert twice[0] == twice[1] == want[2]
+
+
+def test_the_loop_ends_when_every_lane_is_dead(worlds):
+    facts, dev, _, _ = worlds[8, 2**31 + 5]
+    badj = _tile(dev)
+    # a vertex that only has in-edges: its walk finds nothing
+    sink = bitgraph.seed_slots(badj, np.array(
+        [graph500.FIRST_UID + facts["vertices"] - 1], np.uint32))
+    counts, levels, _ = _traverse(badj, [(sink, 50), (sink, 7)])
+    assert counts[:2].tolist() == [0, 0] and levels[:2].tolist() == [1, 1]
+    # and a live one runs only until no lane finds a new vertex
+    src = bitgraph.seed_slots(badj, np.array([graph500.FIRST_UID], np.uint32))
+    _, levels, _ = _traverse(badj, [(src, 2**31 - 1), (sink, 3)])
+    assert 1 < levels[0] < 64 and levels[1] == 1
+
+
+def test_one_compiled_traversal_an_adjacency_for_every_batch_size(worlds):
+    facts, dev, _, _ = worlds[9, 31_000_017]
+    badj = _tile(dev)
+    slots = [bitgraph.seed_slots(badj, np.array(
+        [graph500.FIRST_UID + i], np.uint32)) for i in range(bitgraph.LANES)]
+    programs = bitgraph.bfs_traverse._cache_size()
+    for n in range(1, bitgraph.LANES + 1):
+        bitgraph.traverse(badj, [(slots[i], 2 + i) for i in range(n)])
+    assert bitgraph.bfs_traverse._cache_size() == programs
+
+
+def test_the_hub_kernel_reads_what_the_plain_form_reads(worlds):
+    """_hub_kernel (the chip's path, here in interpret mode) against
+    the jnp form the CPU runs, on a frontier of mixed lanes, some of
+    them dead."""
+    import jax.numpy as jnp
+    facts, dev, _, _ = worlds[10, 7]
+    badj = _tile(dev)
+    assert badj.dense is not None and badj.dense.shape[1] % 128 == 0
+    rng = np.random.default_rng(5)
+    lanes = bitgraph.LANES
+    for live in (0b1, 0b10100101, (1 << lanes) - 1):
+        words = (rng.integers(0, 1 << lanes, badj.n_slots)
+                 * (rng.random(badj.n_slots) < 0.05)).astype(np.uint32)
+        frontier = jnp.asarray(words & np.uint32(live))
+        active = jnp.bitwise_or.reduce(frontier)
+        want = np.asarray(bitgraph._hub_reach(
+            badj.dense, frontier, active, lanes))
+        rows, width = badj.dense.shape
+        fw = bitgraph._frontier_words(frontier, width, lanes)
+        got = np.asarray(jnp.bitwise_or.reduce(bitgraph._hub_call(
+            badj.dense, fw, active, lanes, interpret=True), axis=1))
+        assert np.array_equal(got, want) and want.any()
+        assert not (want & ~np.uint32(live)).any()
+
+
+# -- the rendezvous, alone ---------------------------------------------
+
+
+class _Chip:
+    """A device that runs one call at a time, as slowly as told."""
+
+    def __init__(self):
+        self.calls = []
+        self.gate = threading.Event()
+        self.gate.set()
+        self.fail_launch = self.fail_land = None
+
+    def launch(self, items):
+        if self.fail_launch and len(self.calls) == self.fail_launch[0]:
+            self.calls.append(None)
+            raise self.fail_launch[1]
+        self.calls.append(list(items))
+        return len(self.calls) - 1
+
+    def land(self, handle, n):
+        self.gate.wait(30)
+        if self.fail_land and handle == self.fail_land[0]:
+            raise self.fail_land[1]
+        return [("answer", x) for x in self.calls[handle]]
+
+
+def _ride_all(meet, chip, items, ctxs=None, stagger=None):
+    """Every item from a thread of its own -> {item: Ride or error}."""
+    out = {}
+
+    def one(x):
+        try:
+            out[x] = meet.ride(x, chip.launch, chip.land,
+                               (ctxs or {}).get(x))
+        except BaseException as e:
+            out[x] = e
+
+    threads = [threading.Thread(target=one, args=(x,)) for x in items]
+    for t in threads:
+        t.start()
+        if stagger:
+            stagger(t)
+    return threads, out
+
+
+def _standing(meet, n):
+    deadline = time.monotonic() + 10
+    while len(meet._waiting) != n and time.monotonic() < deadline:
+        time.sleep(0.001)
+    assert len(meet._waiting) == n
+
+
+def test_a_lone_caller_launches_at_once_and_never_waits():
+    meet, chip = Rendezvous(4), _Chip()
+    for x in "abc":
+        ride = meet.ride(x, chip.launch, chip.land)
+        assert ride.result == ("answer", x)
+        assert (ride.lanes, ride.lane, ride.waited_ns) == (1, 0, 0)
+    assert chip.calls == [["a"], ["b"], ["c"]]
+    assert meet._flight is None and not meet._waiting
+
+
+def test_waiters_ride_the_next_call_and_the_overflow_the_one_after():
+    meet, chip = Rendezvous(4), _Chip()
+    chip.gate.clear()
+    first, got1 = _ride_all(meet, chip, ["lead"])
+    while not chip.calls:
+        time.sleep(0.001)
+    rest = [f"w{i}" for i in range(6)]
+    threads, got = _ride_all(meet, chip, rest)
+    _standing(meet, 6)
+    chip.gate.set()
+    for t in first + threads:
+        t.join(30)
+    # capacity 4: the six waiters are two calls, oldest first, and
+    # nobody is lost or answered from another's result
+    assert [len(c) for c in chip.calls] == [1, 4, 2]
+    assert sorted(chip.calls[1] + chip.calls[2]) == rest
+    for x in rest:
+        assert got[x].result == ("answer", x)
+        assert got[x].lanes == (4 if x in chip.calls[1] else 2)
+        assert got[x].waited_ns > 0
+    assert got1["lead"].lanes == 1
+    assert meet._flight is None and not meet._waiting
+
+
+def test_the_next_call_is_on_the_chip_before_results_are_handed_out():
+    """The thread that took a call's result launches the waiters'
+    call first: the chip does not wait for a thread to wake."""
+    meet, chip = Rendezvous(8), _Chip()
+    chip.gate.clear()
+    order = []
+    launch0 = chip.launch
+
+    def launch(items):
+        order.append(("launch", tuple(items)))
+        return launch0(items)
+
+    chip.launch = launch
+    first, got1 = _ride_all(meet, chip, ["lead"])
+    while not chip.calls:
+        time.sleep(0.001)
+    threads, got = _ride_all(meet, chip, ["a", "b"])
+    _standing(meet, 2)
+    lead_thread = first[0]
+    chip.gate.set()
+    lead_thread.join(30)
+    order.append("lead returned")
+    for t in threads:
+        t.join(30)
+    assert order[0] == ("launch", ("lead",))
+    assert order[1][0] == "launch" and sorted(order[1][1]) == ["a", "b"]
+    assert order[2] == "lead returned"
+    assert {x: got[x].result for x in "ab"} \
+        == {"a": ("answer", "a"), "b": ("answer", "b")}
+
+
+@pytest.mark.parametrize("where", ("launch", "land"))
+def test_a_call_that_raises_fails_its_riders_and_frees_the_chip(where):
+    meet, chip = Rendezvous(2), _Chip()
+    chip.gate.clear()
+    boom = RuntimeError("the device said no")
+    setattr(chip, "fail_" + where, (1, boom))
+    first, got1 = _ride_all(meet, chip, ["lead"])
+    while not chip.calls:
+        time.sleep(0.001)
+    threads, got = _ride_all(meet, chip, ["a", "b", "c"])
+    _standing(meet, 3)
+    chip.gate.set()
+    for t in first + threads:
+        t.join(30)
+        assert not t.is_alive()
+    assert got1["lead"].result == ("answer", "lead")
+    failed = [x for x in "abc" if got[x] is boom]
+    answered = [x for x in "abc" if not isinstance(got[x], Exception)]
+    # the call of two failed whole, the third rode the next and none
+    # waits for ever
+    assert len(failed) == 2 and len(answered) == 1
+    assert got[answered[0]].result == ("answer", answered[0])
+    assert meet._flight is None and not meet._waiting
+    # and the rendezvous serves on
+    assert meet.ride("z", chip.launch, chip.land).result == ("answer", "z")
+
+
+def test_a_rider_past_its_deadline_leaves_alone():
+    meet, chip = Rendezvous(8), _Chip()
+    chip.gate.clear()
+    first, _ = _ride_all(meet, chip, ["lead"])
+    while not chip.calls:
+        time.sleep(0.001)
+    gone = RequestContext.background()
+    threads, got = _ride_all(meet, chip, ["a", "gone", "b"], {"gone": gone})
+    _standing(meet, 3)
+    late, got_late = _ride_all(
+        meet, chip, ["late"], {"late": RequestContext.with_timeout(0.05)})
+    late[0].join(30)
+    gone.cancel()
+    _standing(meet, 2)          # both left while the chip was busy
+    got.update(got_late)
+    chip.gate.set()
+    for t in first + threads:
+        t.join(30)
+        assert not t.is_alive()
+    assert isinstance(got["late"], DeadlineExceeded)
+    assert isinstance(got["gone"], Cancelled)
+    assert got["a"].result == ("answer", "a") and got["a"].lanes == 2
+    assert got["b"].result == ("answer", "b")
+    assert sorted(chip.calls[1]) == ["a", "b"]
+
+
+def test_many_threads_lose_no_rider_and_never_share_the_chip():
+    """More threads than cores under a short switch interval: every
+    ride gets its own answer, the calls' lanes add up to the rides,
+    and no two calls are on the chip at once."""
+    import sys
+    meet = Rendezvous(8)
+    lock = threading.Lock()
+    state = {"on_chip": 0, "worst": 0, "calls": 0, "lanes": 0}
+
+    def launch(items):
+        with lock:
+            state["on_chip"] += 1
+            state["worst"] = max(state["worst"], state["on_chip"])
+            state["calls"] += 1
+            state["lanes"] += len(items)
+        return list(items)
+
+    def land(handle, n):
+        time.sleep(0.0002)
+        with lock:
+            state["on_chip"] -= 1
+        return [x * x for x in handle]
+
+    threads_n, rides_n = 48, 40
+    wrong = []
+
+    def client(k):
+        for j in range(rides_n):
+            x = k * 1000 + j
+            if meet.ride(x, launch, land).result != x * x:
+                wrong.append(x)
+
+    was = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        threads = [threading.Thread(target=client, args=(k,))
+                   for k in range(threads_n)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(120)
+            assert not t.is_alive()
+    finally:
+        sys.setswitchinterval(was)
+    assert not wrong
+    assert state["lanes"] == threads_n * rides_n
+    assert state["worst"] == 1 and state["on_chip"] == 0
+    assert state["calls"] < state["lanes"]
+    assert meet._flight is None and not meet._waiting
+
+
+def test_a_tile_has_one_rendezvous_and_another_tile_another():
+    class Tile:
+        pass
+
+    a, b = Tile(), Tile()
+    made = []
+    threads = [threading.Thread(
+        target=lambda: made.append(Rendezvous.at(a, 8))) for _ in range(8)]
+    for t in threads:
+        t.start()
+    for t in threads:
+        t.join()
+    assert len({id(m) for m in made}) == 1
+    assert Rendezvous.at(b, 8) is not made[0]
+
+
+# -- the executor's site -----------------------------------------------
+
+
+def _hold_first_call(monkeypatch):
+    """Keep the first traversal on the 'chip' until released, so that
+    what arrives meanwhile is known to wait."""
+    gate, calls = threading.Event(), []
+    land0 = executor_mod._land_traversals
+
+    def land(handle, n):
+        calls.append(n)
+        if len(calls) == 1:
+            gate.wait(30)
+        return land0(handle, n)
+
+    monkeypatch.setattr(executor_mod, "_land_traversals", land)
+    return gate, calls
+
+
+def _serve(db, queries, ctxs=None):
+    """Each query from a thread of its own -> ({i: parsed reply or
+    error}, threads)."""
+    out = {}
+
+    def one(i, q):
+        try:
+            out[i] = json.loads(db.query_json(
+                q, **({"ctx": ctxs[i]} if ctxs and i in ctxs else {})))
+        except BaseException as e:
+            out[i] = e
+
+    threads = [threading.Thread(target=one, args=(i, q))
+               for i, q in enumerate(queries)]
+    for t in threads:
+        t.start()
+    return out, threads
+
+
+def test_a_lone_request_is_one_call_of_one_lane_and_never_waits(worlds):
+    facts, dev, host, _ = worlds[10, 7]
+    _tile(dev)
+    q = _q(KHOP, [graph500.FIRST_UID + 9], 7)
+    metrics.reset()
+    tracing.clear()
+    sl = json.loads(dev.query_json(q))["extensions"]["server_latency"]
+    counters = metrics.snapshot()["counters"]
+    assert sl["device_calls"] == 1
+    assert counters["recurse_batch_total"] == 1
+    assert counters["recurse_batch_lanes_total"] == 1
+    assert counters["query_device_recurse_total"] == 1
+    spans = {s["name"]: s["args"] for s in tracing.recent_spans()}
+    for name in ("recurse", "device.call"):
+        assert spans[name]["lanes"] == 1
+        assert spans[name]["batch_wait_us"] == 0
+    assert "recurse_batch_total 1" in metrics.render_prometheus()
+    assert _data(dev, q) == _data(host, q)
+
+
+@pytest.mark.parametrize("graph", GRAPHS, ids=lambda g: f"scale{g[0]}")
+def test_requests_in_flight_share_calls_and_keep_their_own_accounts(
+        worlds, graph, monkeypatch):
+    facts, dev, host, csr = worlds[graph]
+    _tile(dev)
+    n = 2 * bitgraph.LANES + 3
+    reqs = _requests(facts, n, graph[0])
+    # every third reader wants the uids themselves
+    queries = [_q(UIDS if i % 3 == 0 else KHOP, r, d)
+               for i, (r, d) in enumerate(reqs)]
+    gate, calls = _hold_first_call(monkeypatch)
+    metrics.reset()
+    out, threads = _serve(dev, queries[:1])
+    while not calls:
+        time.sleep(0.001)
+    more, threads2 = _serve(dev, queries[1:])
+    meet = Rendezvous.at(dev.tablets["link"]._device_badj, bitgraph.LANES)
+    _standing(meet, n - 1)
+    held_ns = 150_000_000   # every one of them stands this long at least
+    time.sleep(held_ns / 1e9)
+    gate.set()
+    for t in threads + threads2:
+        t.join(60)
+        assert not t.is_alive()
+    replies = [out[0]] + [more[i] for i in range(n - 1)]
+    counters = metrics.snapshot()["counters"]
+    # fewer calls than requests, nobody lost: 1, then LANES, LANES, 2
+    assert calls == [1, bitgraph.LANES, bitgraph.LANES, 2]
+    assert counters["recurse_batch_total"] == 4
+    assert counters["recurse_batch_lanes_total"] == n
+    assert counters["query_device_recurse_total"] == n
+    for i, ((roots, depth), rep) in enumerate(zip(reqs, replies)):
+        assert not isinstance(rep, BaseException), rep
+        want = _plain_reached(csr, roots, depth - 1)
+        got = rep["data"]["khop"]
+        if i % 3 == 0:
+            assert [int(o["uid"], 16) for o in got] == (
+                np.flatnonzero(want) + graph500.FIRST_UID).tolist()
+        else:
+            assert got == [{"count": int(want.sum())}]
+        assert json.dumps(rep["data"], separators=(",", ":")) \
+            == _data(host, queries[i])
+        sl = rep["extensions"]["server_latency"]
+        assert sl["device_calls"] == 1
+        # its wait covers its queueing behind the call in flight
+        if i:
+            assert sl["device_wait_ns"] >= held_ns * 0.9
+        assert sl["device_wait_ns"] <= sl["processing_ns"]
+
+
+def test_the_wait_for_the_batch_is_not_host_time(worlds, monkeypatch):
+    """`recurse_host_ms` is recurse_ns_total less the family's three
+    phases: the wait at the rendezvous lands in `wait`, not in it."""
+    facts, dev, _, _ = worlds[9, 31_000_017]
+    _tile(dev)
+    gate, calls = _hold_first_call(monkeypatch)
+    metrics.reset()
+    tracing.clear()
+    q = [_q(KHOP, [graph500.FIRST_UID + i], 4) for i in range(3)]
+    out, threads = _serve(dev, q[:1])
+    while not calls:
+        time.sleep(0.001)
+    more, threads2 = _serve(dev, q[1:])
+    meet = Rendezvous.at(dev.tablets["link"]._device_badj, bitgraph.LANES)
+    _standing(meet, 2)
+    time.sleep(0.2)
+    gate.set()
+    for t in threads + threads2:
+        t.join(60)
+    c = metrics.snapshot()["counters"]
+    phases = sum(v for k, v in c.items()
+                 if k.startswith('device_call_ns_total{family="recurse"'))
+    assert phases >= 3 * 0.2e9 * 0.9
+    assert 0 <= c["recurse_ns_total"] - phases < 0.2e9
+    waits = sorted(s["args"]["batch_wait_us"] for s in tracing.recent_spans()
+                   if s["name"] == "recurse")
+    assert waits[0] == 0 and waits[1] >= 0.2e6 * 0.9
+    assert sorted(s["args"]["lanes"] for s in tracing.recent_spans()
+                  if s["name"] == "device.call") == [1, 2, 2]
+
+
+def test_a_request_past_its_deadline_leaves_and_the_others_answer(
+        worlds, monkeypatch):
+    facts, dev, host, _ = worlds[9, 31_000_017]
+    _tile(dev)
+    gate, calls = _hold_first_call(monkeypatch)
+    q = [_q(KHOP, [graph500.FIRST_UID + i], 5) for i in range(4)]
+    out, threads = _serve(dev, q[:1])
+    while not calls:
+        time.sleep(0.001)
+    more, threads2 = _serve(dev, q[2:])
+    meet = Rendezvous.at(dev.tablets["link"]._device_badj, bitgraph.LANES)
+    _standing(meet, 2)
+    late, threads3 = _serve(
+        dev, q[1:2], ctxs={0: RequestContext.with_timeout(0.05)})
+    threads3[0].join(60)    # the one with the deadline has left
+    _standing(meet, 2)
+    gate.set()
+    for t in threads + threads2:
+        t.join(60)
+        assert not t.is_alive()
+    assert isinstance(late[0], DeadlineExceeded)
+    for i, rep in ((0, out[0]), (2, more[0]), (3, more[1])):
+        assert json.dumps(rep["data"], separators=(",", ":")) \
+            == _data(host, q[i])
+    assert calls == [1, 2]
+
+
+def test_a_dispatch_that_raises_releases_every_member(worlds, monkeypatch):
+    facts, dev, host, _ = worlds[8, 2**31 + 5]
+    _tile(dev)
+    gate, calls = _hold_first_call(monkeypatch)
+    launch0 = executor_mod._launch_traversals
+    launches = []
+
+    def launch(badj, riders):
+        launches.append(len(riders))
+        if len(launches) == 2:
+            raise RuntimeError("RESOURCE_EXHAUSTED")
+        return launch0(badj, riders)
+
+    monkeypatch.setattr(executor_mod, "_launch_traversals", launch)
+    q = [_q(KHOP, [graph500.FIRST_UID + i], 4) for i in range(4)]
+    out, threads = _serve(dev, q[:1])
+    while not calls:
+        time.sleep(0.001)
+    more, threads2 = _serve(dev, q[1:])
+    meet = Rendezvous.at(dev.tablets["link"]._device_badj, bitgraph.LANES)
+    _standing(meet, 3)
+    before = _counter("query_device_recurse_total")
+    gate.set()
+    for t in threads + threads2:
+        t.join(60)
+        assert not t.is_alive()
+    assert launches == [1, 3]
+    assert all(isinstance(more[i], RuntimeError) for i in range(3))
+    # a block that raised counts no dispatch; the first request did
+    assert _counter("query_device_recurse_total") == before + 1
+    assert json.dumps(out[0]["data"], separators=(",", ":")) \
+        == _data(host, q[0])
+    # and the site serves on
+    assert _data(dev, q[1]) == _data(host, q[1])
+
+
+def test_requests_meet_only_over_the_tile_their_read_ts_resolves_to(
+        tmp_path):
+    from dgraph_tpu.cluster.coordinator import StaleSnapshot
+    db = _db(str(tmp_path), 8, 77, prefer_device=True, device_min_edges=1)
+    db.rollup_in_read = False
+    db.alter("link: [uid] @reverse .")
+    db.rollup_all(window=0)
+    root = graph500.FIRST_UID + 2
+    fwd = _q(KHOP, [root], 4)
+    rev = fwd.replace("n as link", "n as ~link")
+    _data(db, fwd)
+    _data(db, rev)
+    tab = db.tablets["link"]
+    tile, tile_t = tab._device_badj, tab._device_badj_t
+    # the transposed tile is another tile: another rendezvous
+    assert Rendezvous.at(tile, 8) is not Rendezvous.at(tile_t, 8)
+    old_ts = db.coordinator.max_assigned()
+    old = json.loads(_data(db, fwd))["khop"][0]["count"]
+    db.mutate(set_nquads=f"<{root:#x}> <link> <0xfffff> .\n"
+                         "<0xfffff> <link> <0xffffe> .")
+    # a dirty tablet never gets to the rendezvous: the host tier
+    # answers what the tile cannot speak for
+    before = _counter("query_device_recurse_total")
+    assert tab.dirty()
+    assert json.loads(_data(db, fwd))["khop"][0]["count"] == old + 2
+    assert _counter("query_device_recurse_total") == before
+    # rolled up, the tablet has ANOTHER tile with a rendezvous of its
+    # own; a reader pinned below its base_ts is refused before it
+    # could ride it
+    db.rollup_all(window=0)
+    assert json.loads(_data(db, fwd))["khop"][0]["count"] == old + 2
+    assert _counter("query_device_recurse_total") == before + 1
+    new_tile = tab._device_badj
+    assert new_tile is not tile and old_ts < tab.base_ts
+    assert Rendezvous.at(new_tile, 8) is not Rendezvous.at(tile, 8)
+    with pytest.raises(StaleSnapshot):
+        _data(db, fwd, read_ts=old_ts)
+    assert not Rendezvous.at(new_tile, 8)._waiting

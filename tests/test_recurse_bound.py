@@ -291,13 +291,15 @@ def test_a_bound_recurse_is_one_device_program_and_one_call(worlds):
     # depth is a runtime value: another depth is the same program
     _data(dev, KHOP % (root, 5))
     assert bitgraph.bfs_traverse._cache_size() == programs
-    # where a later block reads the uids, the bitmap comes too: one
-    # more shape of the same program, still one call
+    # where a later block reads the uids, the lanes' reached words
+    # come too: the SAME program (it keeps them on the device for
+    # whoever asks), still one call
     tracing.clear()
     _data(dev, UIDS % (root, 7))
     (call,) = [s for s in tracing.recent_spans()
                if s["name"] == "device.call"]
-    assert call["args"]["out_bytes"] == 8 + -(-facts["vertices"] // 8)
+    assert call["args"]["out_bytes"] == 8 + 4 * facts["vertices"]
+    assert bitgraph.bfs_traverse._cache_size() == programs
 
 
 def test_the_host_tier_has_its_span_and_counters(worlds):
@@ -425,8 +427,10 @@ def test_the_executor_asks_the_gate_and_nothing_it_measured(
     # the device's side of the choice is the tile's own layout: here
     # every degree class is cheaper streamed than gathered
     badj = tab._device_badj
+    # (a row is padded to whole vregs of 128 words)
+    assert bitgraph.hub_row_words(4097) == 256
     assert badj.dense_from == 0 and badj.dense.shape == (
-        badj.n_covered, -(-badj.n_slots // 32))
+        badj.n_covered, bitgraph.hub_row_words(badj.n_slots))
     assert bitgraph.level_seconds(badj) == pytest.approx(
         badj.dense.nbytes / bitgraph.DENSE_BYTES_PER_S)
 
@@ -453,11 +457,12 @@ def test_hub_rows_take_the_classes_the_budget_holds():
         shapes.append(n_dense)
         got = []
         for root in (1, 17, roots):
-            slots = bitgraph.seed_slots(badj, np.array([root], np.uint32), 8)
-            count, levels, bits = bitgraph.traverse(badj, slots, 6, True)
-            uids = bitgraph.packed_to_uids(badj, np.asarray(bits))
-            assert len(uids) == int(count)
-            got.append((int(count), int(levels), uids.tolist()))
+            slots = bitgraph.seed_slots(badj, np.array([root], np.uint32))
+            tally, reached = bitgraph.traverse(badj, [(slots, 6)])
+            counts, levels = np.asarray(tally)
+            uids = bitgraph.lane_uids(badj, np.asarray(reached), 0)
+            assert len(uids) == int(counts[0])
+            got.append((int(counts[0]), int(levels[0]), uids.tolist()))
         answers.append(got)
     assert shapes[0] == 0 < shapes[1] < shapes[2] == badj.n_covered
     assert answers[0] == answers[1] == answers[2]
