@@ -5,11 +5,16 @@ shape of it): calls x the least bytes of a call
 adjacency's edges, the gauge `device_bitadj_edges`, x 4 B + two
 bitmaps of the graph's vertices)), over the chip's HBM bandwidth, over
 the program's device time in the trace. The mix sends its templates
-equally often, so a call's levels are the mean of the templates'
-`depth` - 1. Memory-bound by statement; the bytes are a lower bound,
-so the share cannot pass 100%. It reads far under 1%: a level is
-gather-bound, an index a descriptor, and that is the finding. None
-where the program serves no such gauge or ran no such program."""
+equally often, so ONE traversal's levels are the mean of the
+templates' `depth` - 1. It multiplies CALLS by one traversal's least
+bytes: since PR 34 a call carries up to eight traversals
+(`recurse_lanes_per_call`) and runs to its deepest lane's depth, so
+the bytes are a lower bound of a call the more so the more it
+carries, and the share reads lower than one traversal alone would
+give. Memory-bound by statement; the share cannot pass 100%. It reads
+far under 1%: a level is gather-bound, an index a descriptor, and
+that is the finding. None where the program serves no such gauge or
+ran no such program."""
 
 import os
 import re

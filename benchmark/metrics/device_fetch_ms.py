@@ -1,8 +1,11 @@
 """Device: median `server_latency.device_fetch_ns` over the good
 replies that made a device call: from `block_until_ready`'s return to
 the host array (device-to-host copy, compaction), the last phase of a
-`device.call` span (dgraph_tpu/query/devicecall.py). None where the key
-is not served."""
+`device.call` span (dgraph_tpu/query/devicecall.py). In a k-hop cell
+(a bound `@recurse`, since PR 34) the call's result is fetched once
+for all its riders inside `device_wait_ms`; what is left here is a
+rider taking its own count out of it, microseconds. None where the
+key is not served."""
 
 
 def read(ctx):

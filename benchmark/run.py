@@ -28,10 +28,12 @@ file holds no name of a cell, a query or a metric), then
 
 stdout's LAST line is one JSON object with the keys `correct`,
 `attempted`, `failed`, `metrics`, `device` (and `breakdown` in a
-traced run that has one). Earlier lines say what each phase took, how
-late the client threads ran, the gate's constant, and each number
-compared beside its limit. With --trace 0 `metrics` holds the cell's
-end-to-end metrics, with --trace 1 its per-layer metrics.
+traced run that has one), then `compared`: each number `correct` was
+decided by, beside its limit (stderr's last lines say the same).
+Earlier lines say what each phase took, how late the client threads
+ran, the gate's constant, and each number compared beside its limit.
+With --trace 0 `metrics` holds the cell's end-to-end metrics, with
+--trace 1 its per-layer metrics.
 
 This parent never imports jax or the program: one process holds the
 chip. `--rehearse <scale>` with JAX_PLATFORMS=cpu in the caller's
@@ -836,8 +838,20 @@ def _run(args, bench, cell, config, platform, scale, cache_root,
         log(f"MISMATCH {e['name']}#{e['binding']}: HTTP {r['status']} "
             f"ok={r['ok']} {r['sha256'][:12]} != "
             f"{reference[r['pool']][:12]}")
-    correct = not bad_warm and not bad and compiles == 0 \
-        and not plain_differ
+    # each number compared, beside its limit: the result's last key and
+    # the run's last lines on stderr, which is what the driver's record
+    # keeps of a run that is not correct
+    compared = {
+        "window_replies_mismatching": {
+            "value": len(bad), "limit": 0, "of": len(replies)},
+        "warmup_replies_mismatching": {
+            "value": len(bad_warm), "limit": 0, "of": len(warm)},
+        "compile_cache_entries_added_in_window": {
+            "value": compiles, "limit": 0},
+        "plain_answers_differing": {
+            "value": len(plain_differ), "limit": 0, "of": plain_have},
+    }
+    correct = all(c["value"] <= c["limit"] for c in compared.values())
     say(f"compared: window replies mismatching the reference "
         f"{len(bad)} of {len(replies)} (limit 0); warm-up replies "
         f"mismatching {len(bad_warm)} of {len(warm)} (limit 0); "
@@ -934,9 +948,14 @@ def _run(args, bench, cell, config, platform, scale, cache_root,
                                "idle_gaps": trace["idle_gaps"][:10]}
         say("device programs by time: "
             + json.dumps(trace["programs"][:10]))
+    result["compared"] = compared
     for line in _SAID:
         print(line)
     print(json.dumps(result), flush=True)
+    for name, c in compared.items():
+        print(f"compared {name}: {c['value']} (limit {c['limit']})"
+              + (f" of {c['of']}" if "of" in c else ""),
+              file=sys.stderr, flush=True)
     return 0
 
 

@@ -71,8 +71,12 @@ def test_roots_are_one_contiguous_range_of_vertices_with_an_out_edge(
     assert g.class_of_literal(0x1) is None
     first, n = g.class_range("root", 10, facts)
     assert (first, n) == (g.FIRST_UID, facts["roots"])
-    mix = traffic.load_mix(os.path.join(BENCH, "traffic", "khop-deep.json"))
-    assert mix["uid_literals"] == {"zipf": 0} and mix["clients"] == 8
+    mix = traffic.load_mix(os.path.join(BENCH, "traffic", "khop-deep-c16.json"))
+    assert mix["uid_literals"] == {"zipf": 0}
+    # a closed loop over a rendezvous of capacity C sends at least
+    # 2 x C clients: eight then wait whenever a call lands, every call
+    # rides full, and no split of the connections can keep itself
+    assert mix["loop"] == "closed" and mix["clients"] == 16
     pool = traffic.build_pool(mix, g, 10, facts, 11)
     assert len(pool) == 64
     assert [e["name"] for e in pool] == ["khop3"] * 32 + ["khop6"] * 32
@@ -110,7 +114,7 @@ def test_the_plain_search_counts_as_dql_does():
 
 def test_the_plain_reference_answers_every_template_of_the_mix(traffic):
     _, facts = rdf(9, 21)
-    mix = traffic.load_mix(os.path.join(BENCH, "traffic", "khop-deep.json"))
+    mix = traffic.load_mix(os.path.join(BENCH, "traffic", "khop-deep-c16.json"))
     assert {t["name"] for t in mix["templates"]} == set(plain.ANSWERS)
     pool = traffic.build_pool(mix, g, 9, facts, 21)
     src, dst, _, vertices = g.graph(9, 21)

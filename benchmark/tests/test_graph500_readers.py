@@ -1,5 +1,6 @@
 """The readers PR 31 adds (`bfs_roofline`, `recurse_host_ms`,
-`bitadj_bytes`) and the traversal's cost function: each gives the
+`bitadj_bytes`), PR 36's `recurse_lanes_per_call` and the traversal's
+cost function: each gives the
 expected value on a synthetic `ctx` and on the recorded trace's
 reduction, and None (never an error) on what a program without the
 gauge, the counter or the device program serves: the parent commit,
@@ -61,6 +62,13 @@ CASES = [
                                 1024.0}), 18_978_132.0),
     # an evicted adjacency reads 0, not nothing
     ("bitadj_bytes", ctx(after={BYTES: 0.0}), 0.0),
+    # 879 calls carried 7,021 traversals in the window (PR 35's traced
+    # run of this traffic); the first pass's 64 calls of one lane each
+    # lie before it and do not count
+    ("recurse_lanes_per_call", ctx(
+        {"recurse_batch_total": 64, "recurse_batch_lanes_total": 64},
+        {"recurse_batch_total": 943, "recurse_batch_lanes_total": 7085}),
+     7021 / 879),
 ]
 
 
@@ -125,6 +133,13 @@ def test_reader_is_silent_where_the_program_serves_nothing(name, context):
 def test_no_call_in_the_window_is_no_mean():
     same = dict(WINDOW_AFTER)
     assert load("metrics/recurse_host_ms.py").read(ctx(same, same)) is None
+    lanes = load("metrics/recurse_lanes_per_call.py")
+    stood = {"recurse_batch_total": 64, "recurse_batch_lanes_total": 64}
+    assert lanes.read(ctx(stood, stood)) is None
+    # one counter without the other is a program that serves neither
+    # ratio: silent, not a division by what is not there
+    assert lanes.read(ctx({}, {"recurse_batch_total": 9})) is None
+    assert lanes.read(ctx({}, {"recurse_batch_lanes_total": 9})) is None
 
 
 def test_the_roofline_needs_peaks_device_time_and_a_depth():

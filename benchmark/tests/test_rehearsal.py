@@ -15,9 +15,10 @@ import sys
 
 import pytest
 
-from conftest import BENCH, ROOT
+from conftest import BENCH, ROOT, alter_answers, copy_checkout
 
-RESULT_KEYS = {"correct", "attempted", "failed", "metrics", "device"}
+RESULT_KEYS = {"correct", "attempted", "failed", "metrics", "device",
+               "compared"}
 
 
 @pytest.fixture(scope="module")
@@ -26,11 +27,7 @@ def checkout(tmp_path_factory):
     program linked in, and a throwaway configuration, traffic mix,
     cell and per-layer metric ADDED as new files and new entries."""
     root = str(tmp_path_factory.mktemp("checkout"))
-    bdir = os.path.join(root, "benchmark")
-    shutil.copytree(BENCH, bdir, ignore=shutil.ignore_patterns(
-        ".cache", "__pycache__"))
-    for name in ("dgraph_tpu", "native"):
-        os.symlink(os.path.join(ROOT, name), os.path.join(root, name))
+    bdir = copy_checkout(root)
     with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
         bench = json.load(f)
     with open(os.path.join(ROOT, bench["configs"][0]["file"])) as f:
@@ -113,6 +110,15 @@ def test_added_cell_runs_and_the_last_line_has_the_contracts_keys(checkout):
     # line; the plain reference answered the sort-page template
     assert p.stdout.count("(limit 0)") == 4
     assert "plain reference 0 of the 3 pool queries (of 6)" in p.stdout
+    # and in the result's line, under its last key, and as stderr's
+    # last lines: what the driver's record keeps of a run at fault
+    assert list(res)[-1] == "compared" and len(res["compared"]) == 4
+    assert all(c["value"] == 0 == c["limit"]
+               for c in res["compared"].values())
+    assert res["compared"]["plain_answers_differing"]["of"] == 3
+    tail = p.stderr.strip().splitlines()[-4:]
+    assert [ln.split(":")[0] for ln in tail] \
+        == ["compared " + k for k in res["compared"]]
     # which templates' stages went to the device, and the server's
     # full collections, are said in every run
     assert "first pass, device stages by template: throwaway_q: " in p.stdout
@@ -135,6 +141,8 @@ def test_degraded_graph_comes_out_not_correct(checkout):
                  "--control", "rating-1dp")
     res = last_line(p)
     assert res["correct"] is False and res["failed"] > 0
+    assert res["compared"]["window_replies_mismatching"]["value"] \
+        == res["failed"]
 
 
 def test_broken_timed_path_comes_out_not_correct(checkout, tmp_path):
@@ -146,19 +154,7 @@ def test_broken_timed_path_comes_out_not_correct(checkout, tmp_path):
     sound = broken + ".sound"
     shutil.copy(broken, sound)
     try:
-        with open(broken) as f:
-            src = f.read()
-        patch = (
-            "    import dgraph_tpu.engine.db as _db\n"
-            "    _q = _db.GraphDB.query_json\n"
-            "    def _altered(self, q, *a, **kw):\n"
-            "        return _q(self, q, *a, **kw).replace(\n"
-            "            '\"rating\":', '\"rating\":1', 1)\n"
-            "    _db.GraphDB.query_json = _altered\n")
-        marker = "    from dgraph_tpu.cli import main as cli_main\n"
-        assert marker in src
-        with open(broken, "w") as f:
-            f.write(src.replace(marker, patch + marker))
+        alter_answers(broken, "rating")
         p = rehearse(checkout, "--workload", "throwaway.cell", "--seed",
                      "7", "--seconds", "2", "--trace", "0",
                      "--rehearse", "2")

@@ -17,7 +17,8 @@ import pytest
 from conftest import BENCH, ROOT
 
 CELL = "sift1m-exact.knn-mix"
-RESULT_KEYS = {"correct", "attempted", "failed", "metrics", "device"}
+RESULT_KEYS = {"correct", "attempted", "failed", "metrics", "device",
+               "compared"}
 NEW_COUNTED = {"vector_block_bytes", "similar_host_ms"}
 
 
