@@ -122,12 +122,38 @@ def _runtime_report(prefer_device: bool) -> dict:
     }
 
 
+def _chips_mesh(chips: int):
+    """`alpha --chips N`: a mesh of N of this host's devices along ONE
+    axis, `uid`, over which the device tiles of one predicate are
+    split (parallel/mesh.make_mesh's default axes would factor four
+    devices into tablet 2 x uid 2 and hold the predicate twice).
+    Fewer devices than asked for is a start-up error."""
+    import jax
+
+    from dgraph_tpu.parallel.mesh import make_mesh
+
+    devs = jax.devices()
+    if chips > len(devs):
+        raise SystemExit(
+            f"alpha --chips {chips}: this host has {len(devs)} "
+            f"{devs[0].platform} device(s); a mesh of {chips} "
+            "cannot be built")
+    return make_mesh(chips, axes=("uid",))
+
+
 def cmd_alpha(args) -> int:
     from dgraph_tpu.engine.db import GraphDB
     from dgraph_tpu.server.http import serve
 
+    if args.chips < 1:
+        raise SystemExit(f"alpha --chips {args.chips}: at least 1")
     # before the (long) snapshot load: no chip is a start-up error
     runtime = _runtime_report(prefer_device=not args.no_device)
+    # a --no-device alpha opens no device, so it has no mesh either
+    mesh = _chips_mesh(args.chips) \
+        if args.chips > 1 and not args.no_device else None
+    if mesh is not None:
+        runtime["meshChips"] = args.chips
     print("dgraph-tpu alpha runtime: " + json.dumps(runtime),
           file=sys.stderr, flush=True)
     from dgraph_tpu.utils import metrics
@@ -143,12 +169,13 @@ def cmd_alpha(args) -> int:
         db = load_snapshot(args.snapshot,
                            GraphDB(wal_path=args.wal or None,
                                    prefer_device=not args.no_device,
-                                   enc_key=enc_key,
+                                   mesh=mesh, enc_key=enc_key,
                                    plan_cache_size=args.plan_cache_size,
                                    result_cache_entries=args.result_cache))
     else:
         db = GraphDB(wal_path=args.wal or None,
-                     prefer_device=not args.no_device, enc_key=enc_key,
+                     prefer_device=not args.no_device, mesh=mesh,
+                     enc_key=enc_key,
                      plan_cache_size=args.plan_cache_size,
                      result_cache_entries=args.result_cache)
     secret = None
@@ -877,6 +904,16 @@ def main(argv=None) -> int:
     a.add_argument("--snapshot", default="")
     a.add_argument("--no-device", action="store_true",
                    default=False)
+    a.add_argument("--chips", type=int, default=1,
+                   help="devices of this host ONE alpha serves from: "
+                        "above 1 the device tiles of a predicate are "
+                        "split over a mesh of that many chips along "
+                        "its uid range (the bound @recurse's bitmap "
+                        "adjacency, sharded expand, similar_to's "
+                        "block), each chip holding its share under "
+                        "its own tile budget. 1 (the default): one "
+                        "chip and no mesh, on any host. More than "
+                        "the host has is a start-up error")
     a.add_argument("--kernelcheck", action="store_true", default=False,
                    help="route POST /debug/kernelcheck: compile every "
                         "device kernel in this process and compare it "

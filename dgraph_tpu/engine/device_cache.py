@@ -287,11 +287,17 @@ def device_bitadjacency(db, tab, read_ts: int, transpose: bool = False,
     served traversal streams (bitgraph.attach_dense), as many as the
     HBM budget has room for when they are set, on first asking; they
     stay with the tile, and a tile evicted and built again takes the
-    room there is then.
+    room there is then. Where the engine has a mesh whose `uid` axis
+    splits a predicate (uid_mesh), the served traversal's rows are
+    split over its chips: the room is then ONE chip's, every chip
+    holds its run of the rows, and the tile counts a chip's bytes.
     A tile like the others: counted in `device_cache_bytes` under the
     HBM budget and evictable. The gauges
     `device_bitadj_bytes{predicate}` (the in-neighbour matrices' and
-    the hub rows' bytes on the device) and
+    the hub rows' bytes on the device, all chips together),
+    `device_bitadj_chip_bytes{predicate}` (the fullest chip's share
+    of them), `device_bitadj_shards{predicate}` (the chips they are
+    split over, 1 without a mesh) and
     `device_bitadj_edges{predicate}` say what is resident, 0 after an
     eviction; the transposed tile is labelled `~pred`."""
     if not _clean_resident(db, tab, read_ts):
@@ -302,7 +308,9 @@ def device_bitadjacency(db, tab, read_ts: int, transpose: bool = False,
     if not fresh and (badj.dense_from is not None or not dense):
         db.device_cache.touch(tab, attr)
         return badj
-    from dgraph_tpu.ops.bitgraph import attach_dense, build_bitadjacency
+    from dgraph_tpu.ops.bitgraph import (
+        attach_dense, build_bitadjacency, resident_bytes,
+    )
     if fresh:
         n_edges = sum(len(v) for v in tab.edges.values())
         if n_edges < db.device_min_edges:
@@ -317,21 +325,35 @@ def device_bitadjacency(db, tab, read_ts: int, transpose: bool = False,
         with _tile_load(pred=tab.pred, kind="bitadj_dense",
                         edges=badj.n_edges):
             attach_dense(badj, max(
-                0, db.device_cache.budget - db.device_cache.bytes))
+                0, db.device_cache.budget - db.device_cache.bytes),
+                mesh=uid_mesh(db))
     setattr(tab, attr, badj)
     setattr(tab, attr + "_ts", tab.base_ts)
     labels = {"predicate": ("~" if transpose else "") + tab.pred}
 
-    def gauges(nbytes: float, edges: float) -> None:
+    def gauges(nbytes: float, shards: float, edges: float) -> None:
         set_gauge("device_bitadj_bytes", nbytes, labels=labels)
+        # equal runs of rows a chip: every chip is the fullest
+        set_gauge("device_bitadj_chip_bytes", nbytes / max(shards, 1),
+                  labels=labels)
+        set_gauge("device_bitadj_shards", shards, labels=labels)
         set_gauge("device_bitadj_edges", edges, labels=labels)
 
     db.device_cache.put(tab, attr, badj,
-                        on_evict=lambda: gauges(0.0, 0.0))
-    gauges(float(sum(b.in_nb.nbytes for b in badj.buckets)
-                 + (badj.dense.nbytes if badj.dense is not None else 0)),
+                        on_evict=lambda: gauges(0.0, 0.0, 0.0))
+    gauges(float(resident_bytes(badj)), float(badj.shards),
            float(badj.n_edges))
     return badj
+
+
+def uid_mesh(db):
+    """The engine's mesh where its `uid` axis splits one predicate
+    over two chips or more, else None (one chip)."""
+    mesh = getattr(db, "mesh", None)
+    if mesh is None or "uid" not in mesh.axis_names \
+            or mesh.shape["uid"] < 2:
+        return None
+    return mesh
 
 
 def device_sharded_adjacency(db, tab, read_ts: int,
@@ -344,9 +366,8 @@ def device_sharded_adjacency(db, tab, read_ts: int,
 
     Residency rules match the single-chip tiles; requires db.mesh with
     a >1-sized `uid` axis."""
-    mesh = getattr(db, "mesh", None)
-    if mesh is None or "uid" not in mesh.axis_names \
-            or mesh.shape["uid"] < 2:
+    mesh = uid_mesh(db)
+    if mesh is None:
         return None
     if reverse and not tab.schema.reverse:
         return None

@@ -11,7 +11,9 @@ isinstance(jax.Array):
   * obj with class attr host_resident   -> HOST bytes (ValueColumns,
     TokenIndexCSR, CompressedTokenIndex, OrderPermutation,
     ops/codec.CompressedPack — explicit marker, no jax import)
-  * any other obj exposing .nbytes      -> DEVICE bytes (jax.Array)
+  * any other obj exposing .nbytes      -> DEVICE bytes (jax.Array):
+    ONE chip's, its shard where the array is split over a mesh, since
+    the budget is a chip's HBM
   * dataclasses / lists / tuples        -> recurse over fields, so a
     DeviceAdjacency's numpy side-tables land in the HOST column and
     its jax buffers in the DEVICE column — CONSISTENTLY.  (The old
@@ -46,7 +48,11 @@ def _tile_bytes(obj) -> tuple[int, int]:
     if getattr(obj, "host_resident", False):
         return 0, int(getattr(obj, "nbytes", 0))
     if hasattr(obj, "nbytes") and not dataclasses.is_dataclass(obj):
-        return int(obj.nbytes), 0
+        sharding = getattr(obj, "sharding", None)
+        if sharding is None:
+            return int(obj.nbytes), 0
+        return int(np.prod(sharding.shard_shape(obj.shape),
+                           dtype=np.int64)) * obj.dtype.itemsize, 0
     if isinstance(obj, (list, tuple)):
         dev = host = 0
         for x in obj:

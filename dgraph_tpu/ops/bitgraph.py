@@ -86,12 +86,32 @@ class BitAdjacency:
     # points at the row's slot
     dense: Optional[jax.Array] = None
     dense_from: Optional[int] = None     # None: attach_dense not run
+    # the served traversal over several chips (attach_dense with a
+    # mesh): the mesh whose `uid` axis the DESTINATION rows are split
+    # over, and the gathered classes' in-neighbour matrices as the
+    # chips hold them (`dense` is then held that way too): a class's
+    # rows padded to a multiple of the chips and cut into one run of
+    # rows a chip, the padding pointing at the dummy slot
+    mesh: Optional[jax.sharding.Mesh] = None
+    shard_nbs: Optional[list] = None
 
     @property
     def gathered(self) -> list[RevBucket]:
         """The degree classes the served traversal gathers: those
         below the hub rows, all of them where there are none."""
         return self.buckets[:self.dense_from]
+
+    @property
+    def shards(self) -> int:
+        """Chips the served traversal's rows are split over."""
+        return 1 if self.mesh is None else self.mesh.shape[SHARD_AXIS]
+
+    @property
+    def dense_rows(self) -> int:
+        """Hub rows, without the padding a split over chips adds."""
+        return sum(int(b.in_nb.shape[0])
+                   for b in self.buckets[self.dense_from:]) \
+            if self.dense is not None else 0
 
     @property
     def shape_sig(self):
@@ -313,6 +333,10 @@ DENSE_BYTES_PER_S = 6.5e11
 # eight lanes (PERF.md, PR 34).
 LANES = 8
 
+# the mesh axis a sharded traversal's destination rows are split over
+# (parallel/mesh.py: uid-range shards of one predicate)
+SHARD_AXIS = "uid"
+
 # the hub rows' kernel: rows a grid step holds in VMEM, and the width
 # (in words) a row is padded to so that a step's block is whole vregs
 _HUB_TILE_ROWS = 256
@@ -326,7 +350,21 @@ def hub_row_words(n_slots: int) -> int:
     return -(-n_slots // (32 * _HUB_WORDS_UNIT)) * _HUB_WORDS_UNIT
 
 
-def attach_dense(badj: BitAdjacency, budget_bytes: int) -> None:
+def _chip_rows(rows: int, shards: int) -> int:
+    """Rows of a block of `rows` one chip of `shards` holds: all of
+    them on one chip; else an equal run a chip, whole groups of the
+    eight rows _hub_kernel takes at a time."""
+    return rows if shards == 1 else -(-rows // (8 * shards)) * 8
+
+
+def _put_rows(block: np.ndarray, mesh) -> jax.Array:
+    """`block` on the mesh, an equal run of its rows a chip."""
+    return jax.device_put(block, jax.sharding.NamedSharding(
+        mesh, jax.sharding.PartitionSpec(SHARD_AXIS)))
+
+
+def attach_dense(badj: BitAdjacency, budget_bytes: int,
+                 mesh=None) -> None:
     """Give the adjacency its hub rows: the degree classes whose rows
     are cheaper streamed than gathered, from the highest class down,
     as many whole classes as `budget_bytes` holds. On a skewed graph
@@ -334,7 +372,13 @@ def attach_dense(badj: BitAdjacency, budget_bytes: int) -> None:
     bounded block of rows takes most of a level's gathers away. In a
     row of W words (hub_row_words) slot s is bit s // W of word s % W,
     so that a frontier over the slots folds into a row's layout
-    without a transpose (_frontier_words)."""
+    without a transpose (_frontier_words).
+
+    With a `mesh` the DESTINATION rows are split over its `uid` axis:
+    `budget_bytes` is ONE chip's room and a chip holds its run of the
+    hub rows and of every gathered class (bfs_traverse_sharded), so
+    the chips together hold their number of budgets of rows."""
+    shards = 1 if mesh is None else mesh.shape[SHARD_AXIS]
     n, words = badj.n_slots, hub_row_words(badj.n_slots)
     row_bytes = 4 * words
     first, rows = len(badj.buckets), 0
@@ -342,18 +386,27 @@ def attach_dense(badj: BitAdjacency, budget_bytes: int) -> None:
         b = badj.buckets[i]
         m = int(b.in_nb.shape[0])
         if b.degree * GATHER_SECONDS <= row_bytes / DENSE_BYTES_PER_S \
-                or (rows + m) * row_bytes > budget_bytes:
+                or _chip_rows(rows + m, shards) * row_bytes > budget_bytes:
             break
         first, rows = i, rows + m
     badj.dense_from = first
+    badj.dense = None
+    if mesh is not None:
+        badj.mesh = mesh
+        # the one-chip copies go: a chip holds its run and no more
+        # (a matrix then reads as its host copy, which the unserved
+        # kernels above bake into their programs as they always did)
+        for b in badj.buckets:
+            b.in_nb = _host_nb(b)
+        badj.shard_nbs = [_put_rows(np.pad(
+            b.in_nb, ((0, -len(b.in_nb) % shards), (0, 0)),
+            constant_values=n), mesh) for b in badj.gathered]
     if not rows:
-        badj.dense = None
         return
     start = badj.buckets[first].offset
     keys, bits = [], []
     for b in badj.buckets[first:]:
-        nb = b.in_nb_host if b.in_nb_host is not None \
-            else np.asarray(b.in_nb)
+        nb = _host_nb(b)
         r, c = np.nonzero(nb < n)
         src = nb[r, c].astype(np.int64)
         keys.append((r + (b.offset - start)) * words + src % words)
@@ -363,18 +416,40 @@ def attach_dense(badj: BitAdjacency, budget_bytes: int) -> None:
     order = np.argsort(keys, kind="stable")
     keys, bits = keys[order], bits[order]
     at = np.flatnonzero(np.r_[True, keys[1:] != keys[:-1]])
-    block = np.zeros(rows * words, np.uint32)
+    # (split over chips, the runs' padding lies behind the last row)
+    block = np.zeros(shards * _chip_rows(rows, shards) * words, np.uint32)
     block[keys[at]] = np.bitwise_or.reduceat(bits, at)
-    badj.dense = jnp.asarray(block.reshape(rows, words))
+    block = block.reshape(-1, words)
+    badj.dense = jnp.asarray(block) if mesh is None \
+        else _put_rows(block, mesh)
+
+
+def resident_bytes(badj: BitAdjacency) -> int:
+    """Bytes of the adjacency on the device, all chips together: the
+    in-neighbour matrices and the hub rows as the served traversal
+    holds them."""
+    held = badj.shard_nbs if badj.mesh is not None \
+        else [b.in_nb for b in badj.buckets]
+    return sum(int(a.nbytes) for a in held) \
+        + (int(badj.dense.nbytes) if badj.dense is not None else 0)
+
+
+def _host_nb(b: RevBucket) -> np.ndarray:
+    """A degree class's in-neighbour matrix on the host."""
+    return b.in_nb_host if b.in_nb_host is not None \
+        else np.asarray(b.in_nb)
 
 
 def level_seconds(badj: BitAdjacency) -> float:
-    """What one level of bfs_traverse costs on the device, from the
-    adjacency's layout alone: the gathered classes' padded in-edges
-    and the dense rows' bytes."""
+    """What one level of the served traversal costs on the device,
+    from the adjacency's layout alone: the gathered classes' padded
+    in-edges and the dense rows' bytes."""
     gathered = sum(int(b.in_nb.size) for b in badj.gathered)
     dense = 0 if badj.dense is None else int(badj.dense.nbytes)
-    return gathered * GATHER_SECONDS + dense / DENSE_BYTES_PER_S
+    # split over chips, a level costs what ONE chip gathers and
+    # streams of it (the collective that follows is a few megabytes)
+    return (gathered * GATHER_SECONDS
+            + dense / DENSE_BYTES_PER_S) / badj.shards
 
 
 def _lane_planes(words_by_slot: jax.Array, lanes: int) -> jax.Array:
@@ -512,6 +587,20 @@ def bfs_traverse(in_nbs, dense, riders, *, n_slots: int, n_covered: int,
                 They stay on the device unless a lane's reader wants
                 the uids (lane_uids)
     """
+    def level(frontier, active):
+        parts = [_gathered_reach(in_nbs, frontier)]
+        if dense is not None:
+            parts.append(_hub_reach(dense, frontier, active, lanes))
+        parts.append(jnp.zeros((n_slots - n_covered,), jnp.uint32))
+        return jnp.concatenate(parts)
+
+    return _traverse_lanes(level, riders, n_slots, lanes)
+
+
+def _traverse_lanes(level, riders, n_slots: int, lanes: int):
+    """bfs_traverse's loop over `level(frontier, active) -> reach`
+    (uint32[N] lane words both): the riders unpacked, every lane run
+    to its own depth, -> (tally, reached)."""
     n_seeds = (riders.shape[0] - lanes) // 2
     seed_slots = riders[:n_seeds]
     seed_bits = jax.lax.bitcast_convert_type(
@@ -525,13 +614,6 @@ def bfs_traverse(in_nbs, dense, riders, *, n_slots: int, n_covered: int,
         """The word of the lanes whose depth reaches past `lvl`."""
         return jnp.sum(jnp.where(depths > lvl, jnp.uint32(1) << lane,
                                  jnp.uint32(0)), dtype=jnp.uint32)
-
-    def level(frontier, active):
-        parts = [_gathered_reach(in_nbs, frontier)]
-        if dense is not None:
-            parts.append(_hub_reach(dense, frontier, active, lanes))
-        parts.append(jnp.zeros((n_slots - n_covered,), jnp.uint32))
-        return jnp.concatenate(parts)
 
     def cond(state):
         return state[-1] != 0
@@ -556,28 +638,114 @@ def bfs_traverse(in_nbs, dense, riders, *, n_slots: int, n_covered: int,
     return jnp.stack([counts, levels_run]), reached
 
 
-def traverse(badj: BitAdjacency, riders: list):
-    """bfs_traverse over an adjacency as attach_dense left it, one
-    lane a rider: `riders` is [(root slots as seed_slots gives them,
-    depth)], at most LANES of them; rider i is lane i of both
-    results. ONE compiled shape an adjacency while the riders' roots
-    number eight or fewer together (then a power of two), whatever
-    their count and depths."""
+def _chip_reach(in_nbs, dense, frontier, active, lanes: int):
+    """ONE chip's share of a level: the rows it holds of every
+    gathered class, then of the hub rows, against the whole frontier:
+    uint32 lane words, a word a row it holds (padding rows read 0)."""
+    parts = [_gathered_reach(in_nbs, frontier)]
+    if dense is not None:
+        parts.append(_hub_reach(dense, frontier, active, lanes))
+    return jnp.concatenate(parts)
+
+
+def _whole_reach(shares, part_rows, n_slots: int):
+    """The chips' shares of a level, uint32[chips, L] as _chip_reach
+    gives them, -> the level's reach in slot order, uint32[N]: a
+    part's rows (a gathered class, or the hub rows) lie chip after
+    chip, the padding behind the last of them. `part_rows`: (rows,
+    rows a chip holds) a part, in slot order."""
+    parts, at = [], 0
+    for rows, held in part_rows:
+        parts.append(shares[:, at:at + held].reshape(-1)[:rows])
+        at += held
+    covered = sum(rows for rows, _ in part_rows)
+    parts.append(jnp.zeros((n_slots - covered,), jnp.uint32))
+    return jnp.concatenate(parts)
+
+
+@functools.partial(jax.jit, static_argnames=(
+    "mesh", "part_rows", "n_slots", "lanes"))
+def bfs_traverse_sharded(in_nbs, dense, riders, *, mesh, part_rows,
+                         n_slots: int, lanes: int):
+    """bfs_traverse with the adjacency split over the chips of
+    `mesh`'s `uid` axis: same riders, same (tally, reached), bit for
+    bit. The DESTINATION rows are split: a chip holds a run of every
+    gathered class's rows (`in_nbs`) and of the hub rows (`dense`),
+    as attach_dense laid them out, and the lane words of frontier,
+    visited and reached sets whole. A level: every chip works out
+    which of ITS rows the frontier reaches (_chip_reach: a share of
+    the gathers and of the rows' stream, _hub_kernel as it is), then
+    ONE collective, an all-gather of the shares (a lane word a covered
+    slot, 4 B a vertex over all chips), hands every chip the whole
+    reach, from which each works out the next frontier for itself.
+    The loop runs inside the shard_map, so nothing else crosses
+    chips; the results come out replicated and are read from one."""
+    P = jax.sharding.PartitionSpec
+    rows_spec = P(SHARD_AXIS)
+
+    def per_chip(in_nbs, dense, riders):
+        def level(frontier, active):
+            share = _chip_reach(in_nbs, dense, frontier, active, lanes)
+            return _whole_reach(
+                jax.lax.all_gather(share, SHARD_AXIS), part_rows, n_slots)
+
+        return _traverse_lanes(level, riders, n_slots, lanes)
+
+    # every chip computes the same lane state from the gathered
+    # shares: replicated by construction, which the checker cannot see
+    return jax.shard_map(
+        per_chip, mesh=mesh,
+        in_specs=([rows_spec] * len(in_nbs),
+                  None if dense is None else rows_spec, P()),
+        out_specs=(P(), P()), check_vma=False)(in_nbs, dense, riders)
+
+
+def _pack_riders(n_slots: int, riders: list) -> np.ndarray:
+    """`riders` ([(root slots, depth)], a lane each) as the ONE int32
+    upload of a call (bfs_traverse's `riders`)."""
     from dgraph_tpu.ops.uidvec import pad_to
     if not 0 < len(riders) <= LANES:
         raise ValueError(f"{len(riders)} riders for {LANES} lanes")
     n_seeds = pad_to(sum(len(slots) for slots, _ in riders))
     packed = np.zeros(2 * n_seeds + LANES, np.int32)
-    packed[:n_seeds] = badj.n_slots
+    packed[:n_seeds] = n_slots
     at = 0
     for b, (slots, depth) in enumerate(riders):
         packed[at:at + len(slots)] = slots
         packed[n_seeds + at:n_seeds + at + len(slots)] = 1 << b
         packed[2 * n_seeds + b] = min(depth, 2**31 - 1)
         at += len(slots)
-    return bfs_traverse(
-        [b.in_nb for b in badj.gathered], badj.dense, packed,
-        n_slots=badj.n_slots, n_covered=badj.n_covered, lanes=LANES)
+    return packed
+
+
+def traverse(badj: BitAdjacency, riders: list):
+    """The served traversal over an adjacency as attach_dense left
+    it, one lane a rider: `riders` is [(root slots as seed_slots
+    gives them, depth)], at most LANES of them; rider i is lane i of
+    both results. bfs_traverse on one chip, bfs_traverse_sharded
+    where the rows are split over a mesh. ONE compiled shape an
+    adjacency while the riders' roots number eight or fewer together
+    (then a power of two), whatever their count and depths."""
+    packed = _pack_riders(badj.n_slots, riders)
+    if badj.mesh is None:
+        return bfs_traverse(
+            [b.in_nb for b in badj.gathered], badj.dense, packed,
+            n_slots=badj.n_slots, n_covered=badj.n_covered, lanes=LANES)
+    return bfs_traverse_sharded(
+        badj.shard_nbs, badj.dense, packed, mesh=badj.mesh,
+        part_rows=shard_parts(badj), n_slots=badj.n_slots, lanes=LANES)
+
+
+def shard_parts(badj: BitAdjacency) -> tuple:
+    """(rows, rows a chip holds) of every part of a sharded
+    adjacency, in slot order: the gathered classes, then the hub
+    rows."""
+    shards = badj.shards
+    parts = [(int(b.in_nb.shape[0]), int(nb.shape[0]) // shards)
+             for b, nb in zip(badj.gathered, badj.shard_nbs)]
+    if badj.dense is not None:
+        parts.append((badj.dense_rows, int(badj.dense.shape[0]) // shards))
+    return tuple(parts)
 
 
 def seed_slots(badj: BitAdjacency, uids32: np.ndarray
@@ -771,8 +939,7 @@ def build_core_adjacency(badj: BitAdjacency) -> CoreAdjacency:
         return CoreAdjacency([], jnp.zeros((0,), jnp.int32), ncov)
     dsts, srcs = [], []
     for b in badj.buckets:
-        nb = b.in_nb_host if b.in_nb_host is not None \
-            else np.asarray(b.in_nb)
+        nb = _host_nb(b)
         rr, cc = np.nonzero(nb < ncov)       # covered sources only
         dsts.append((rr + b.offset).astype(np.int64))
         srcs.append(nb[rr, cc])

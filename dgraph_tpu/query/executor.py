@@ -285,7 +285,9 @@ def _launch_traversals(badj, riders: list):
     bitgraph.bfs_traverse for `riders` ([(root slots, depth)], a lane
     each), not waited for. `recurse_batch_total` counts the calls,
     `recurse_batch_lanes_total` the traversals they carried: lanes a
-    call is their ratio."""
+    call is their ratio. `recurse_sharded_total` and
+    `recurse_sharded_lanes_total` count those of them that took the
+    program whose adjacency is split over a mesh's chips."""
     from dgraph_tpu.ops import bitgraph
     tally, reached = bitgraph.traverse(badj, riders)
     # the one small result starts for the host as soon as the device
@@ -293,6 +295,9 @@ def _launch_traversals(badj, riders: list):
     tally.copy_to_host_async()
     inc_counter("recurse_batch_total")
     inc_counter("recurse_batch_lanes_total", len(riders))
+    if badj.mesh is not None:
+        inc_counter("recurse_sharded_total")
+        inc_counter("recurse_sharded_lanes_total", len(riders))
     return tally, reached
 
 
@@ -4853,7 +4858,8 @@ class Executor:
                         ) -> Optional[tuple]:
         """The whole traversal as ONE device program and ONE
         device_call -> (reached count, reached uids or None, levels
-        run, {lanes of the call it rode, batch_wait_us}); None where
+        run, {lanes of the call it rode, batch_wait_us, the chips the
+        adjacency is split over, the program's name}); None where
         the host tier is to answer: the gate says so,
         or the device cannot speak for the traversal (roots over 32
         bits, a dirty tablet or one under device_min_edges, a root
@@ -4864,11 +4870,13 @@ class Executor:
         measured span: the host's cost from the depth, the root set's
         size and the tablet's degree moments (planner.recurse_costs),
         the device's from the levels and the adjacency's layout
-        (bitgraph.level_seconds). The tile is built only for a
-        traversal the device could win at all: one that costs the
-        host more than the in-edges' bytes cost the chip's memory."""
+        (bitgraph.level_seconds: ONE chip's share of it where the
+        engine's mesh splits the rows over several). The tile is
+        built only for a traversal the device could win at all: one
+        that costs the host more than the in-edges' bytes cost the
+        memory of the chips that share them."""
         from dgraph_tpu.engine.device_cache import (
-            _MAX_U32, device_bitadjacency,
+            _MAX_U32, device_bitadjacency, uid_mesh,
         )
         from dgraph_tpu.ops import bitgraph
         from dgraph_tpu.query.planner import recurse_costs
@@ -4882,18 +4890,23 @@ class Executor:
                 host, device_ratio=min(1.0, device_seconds / host)
                 if host else 1.0)
 
-        if not worth(levels * 4 * moments[1] / bitgraph.DENSE_BYTES_PER_S):
+        mesh = uid_mesh(self.db)
+        chips = 1 if mesh is None else mesh.shape[bitgraph.SHARD_AXIS]
+        if not worth(levels * 4 * moments[1] / chips
+                     / bitgraph.DENSE_BYTES_PER_S):
             return None
         badj = device_bitadjacency(self.db, tab, self.read_ts,
                                    transpose=rev, dense=True)
         if badj is None or badj.n_slots == 0 \
                 or not worth(levels * bitgraph.level_seconds(badj)):
             return None
+        program = "bfs_traverse" if badj.mesh is None \
+            else "bfs_traverse_sharded"
         # the traversals in flight over this tile ride one call
         # (devicecall.Rendezvous): this request's block is its own
         # all the same, its wait the time until its call's result
         with device_call("query_device_recurse_total", sink=self.lat,
-                         program="bfs_traverse") as dc:
+                         program=program) as dc:
             slots = bitgraph.seed_slots(badj, roots.astype(np.uint32))
             if slots is None:
                 return None
@@ -4906,7 +4919,8 @@ class Executor:
                 out_bytes=8 + (4 * badj.n_slots if want_uids else 0))
             count, levels, reached = ride.result
             batch = {"lanes": ride.lanes,
-                     "batch_wait_us": ride.waited_ns // 1000}
+                     "batch_wait_us": ride.waited_ns // 1000,
+                     "shards": badj.shards, "program": program}
             dc.note(**batch)
             uids = bitgraph.lane_uids(
                 badj, np.asarray(reached), ride.lane).astype(np.uint64) \

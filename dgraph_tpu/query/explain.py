@@ -57,11 +57,12 @@ _TABLET_BOUND_FNS = frozenset((
 _BASIS_RANK = {"exact": 0, "index": 1, "stats": 2, "unknown": 3}
 
 # stage spans ANALYZE surfaces from the request's trace, in recorded
-# order (subset of coststore.STAGES: the per-request ones)
+# order (coststore.STAGES' per-request ones, and `recurse`, whose
+# span names the tier a bound @recurse took and the chips it ran over)
 _ANALYZE_SPANS = frozenset((
     "parse", "plan.compile", "block", "eq", "ineq", "setops", "expand",
-    "sort", "match", "similar_to", "device.tile_load", "encode",
-    "batch.wait",
+    "sort", "match", "similar_to", "recurse", "device.tile_load",
+    "encode", "batch.wait",
 ))
 
 
@@ -285,7 +286,8 @@ def _stage_spans(trace_id: str) -> list[dict]:
         ent: dict[str, Any] = {"stage": rec["name"],
                                "durUs": round(rec.get("dur_us", 0.0), 1)}
         args = rec.get("args") or {}
-        for k in ("pred", "fn", "alias", "rows", "n", "tier", "role"):
+        for k in ("pred", "fn", "alias", "rows", "n", "tier", "shards",
+                  "program", "role"):
             if k in args:
                 ent[k] = args[k]
         out.append(ent)
@@ -345,6 +347,11 @@ def build_explain(db, ex, done, expinfo: dict) -> dict:
         "blocks": [_explain_node(db, gq, node, mode, -1)
                    for gq, node in done],
     }
+    mesh = getattr(db, "mesh", None)
+    if mesh is not None and "uid" in mesh.axis_names:
+        # chips the engine's mesh splits one predicate's device tiles
+        # over (`alpha --chips N`); absent: one chip, no mesh
+        out["tiers"]["deviceShards"] = int(mesh.shape["uid"])
     if mode == "analyze":
         out["traceId"] = expinfo.get("trace_id", "")
         # execution-side counter movement (post-parse: the plan-cache

@@ -54,7 +54,9 @@ REGISTERED = (
     # engine/device_cache.py: a uid predicate's resident bitmap
     # adjacency (`~pred` for the transposed one)
     "device_bitadj_bytes",
+    "device_bitadj_chip_bytes",
     "device_bitadj_edges",
+    "device_bitadj_shards",
     # query/devicecall.py; NOT `query_device_*`: readers sum that
     # prefix as a count of dispatches
     "device_call_ns_total",
@@ -126,10 +128,13 @@ REGISTERED = (
     "query_similar_sharded_total",
     # query/executor.py _run_recurse: the span's time, and which tier
     # a @recurse took; _launch_traversals: the device calls the
-    # rendezvous dispatched and the traversals they carried
+    # rendezvous dispatched and the traversals they carried, and
+    # those of them that took the program sharded over a mesh
     "recurse_batch_lanes_total",
     "recurse_batch_total",
     "recurse_ns_total",
+    "recurse_sharded_lanes_total",
+    "recurse_sharded_total",
     "recurse_tier_total",
     "similar_exact_fallback_total",
     "similar_mask_total",

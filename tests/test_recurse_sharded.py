@@ -1,0 +1,388 @@
+"""The k-hop traversal with its adjacency split over a mesh's chips
+(`alpha --chips N`): `ops/bitgraph.bfs_traverse_sharded` against the
+one-chip `bfs_traverse` and a plain numpy BFS, bit for bit; the shares
+of a level add up to the level; the served path with a mesh answers
+what the postings tier answers; the flag. The suite runs on 8 virtual
+CPU devices (conftest.py), four of which make the mesh."""
+
+import json
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from dgraph_tpu import cli
+from dgraph_tpu.engine.db import GraphDB
+from dgraph_tpu.ingest.bulk import bulk_load
+from dgraph_tpu.ops import bitgraph
+from dgraph_tpu.parallel.mesh import make_mesh
+from dgraph_tpu.query import executor as executor_mod
+from dgraph_tpu.utils import metrics, tracing
+
+CHIPS = 4
+LANES = bitgraph.LANES
+
+
+@pytest.fixture(scope="module")
+def mesh():
+    return make_mesh(CHIPS, axes=("uid",))
+
+
+def _edges(n: int, m: int, seed: int, back_to: int = 1) -> dict:
+    """A skewed directed graph over uids 1..n: m edges drawn with
+    Zipf targets (a few hubs, many classes of one or two rows), no
+    self-loops, and a cycle through `back_to` so that an edge leads
+    back to that root."""
+    rng = np.random.default_rng(seed)
+    src = rng.integers(1, n + 1, m)
+    dst = rng.zipf(1.5, m) % n + 1
+    pairs = {(int(s), int(d)) for s, d in zip(src, dst) if s != d}
+    pairs |= {(back_to, back_to + 1), (back_to + 1, back_to + 2),
+              (back_to + 2, back_to)}
+    out: dict = {}
+    for s, d in sorted(pairs):
+        out.setdefault(s, []).append(d)
+    return {s: np.array(d, np.uint32) for s, d in out.items()}
+
+
+def _plain(edges: dict, roots: list, hops: int) -> np.ndarray:
+    """Sorted uids an edge of the walk leads to within `hops` hops of
+    `roots`: numpy over the edge lists, nothing of the program's."""
+    seen = set(roots)
+    reached: set = set()
+    frontier = set(roots)
+    for _ in range(hops):
+        nxt = set()
+        for u in frontier:
+            nxt.update(int(v) for v in edges.get(u, ()))
+        reached |= nxt
+        frontier = nxt - seen
+        seen |= nxt
+        if not frontier:
+            break
+    return np.array(sorted(reached), np.uint32)
+
+
+def _pair(edges: dict, mesh, budget_rows):
+    """(one-chip adjacency, the same split over `mesh`), both with as
+    many hub rows as `budget_rows` rows of room allow (None: all the
+    room there is, 0: none), a chip of the mesh a quarter of it."""
+    one = bitgraph.build_bitadjacency(edges)
+    four = bitgraph.build_bitadjacency(edges)
+    row = 4 * bitgraph.hub_row_words(one.n_slots)
+    room = 1 << 40 if budget_rows is None else row * budget_rows
+    bitgraph.attach_dense(one, room)
+    bitgraph.attach_dense(four, room if budget_rows is None
+                          else room // CHIPS, mesh=mesh)
+    return one, four
+
+
+def _both(one, four, riders):
+    t1, r1 = bitgraph.traverse(one, riders)
+    t4, r4 = bitgraph.traverse(four, riders)
+    t1, r1, t4, r4 = (np.asarray(x) for x in (t1, r1, t4, r4))
+    assert t1.dtype == t4.dtype and r1.dtype == r4.dtype
+    assert np.array_equal(t1, t4) and np.array_equal(r1, r4)
+    return t4, r4
+
+
+# (vertices, edges drawn, seed): no vertex count is a multiple of 4 or
+# of a vreg's 4,096; the second has more than one vreg of them
+GRAPHS = {"small": (1003, 6000, 3), "wide": (4099 + 130, 30000, 11)}
+
+
+@pytest.fixture(scope="module")
+def graphs():
+    return {k: _edges(n, m, seed) for k, (n, m, seed) in GRAPHS.items()}
+
+
+@pytest.mark.parametrize("rows", (None, 40, 0),
+                         ids=("all_hub_rows", "some_hub_rows", "no_hub_rows"))
+@pytest.mark.parametrize("riders", (1, 2, 3, 5, LANES))
+def test_sharded_traversal_is_the_one_chip_traversal_bit_for_bit(
+        graphs, mesh, rows, riders):
+    edges = graphs["small"]
+    one, four = _pair(edges, mesh, rows)
+    assert four.mesh is mesh and four.shards == CHIPS
+    assert (four.dense is None) == (one.dense is None)
+    assert four.n_slots % CHIPS and four.n_slots % 4096
+    if rows == 0:
+        # degree classes with fewer rows than chips are among the parts
+        assert sum(0 < r < CHIPS
+                   for r, _ in bitgraph.shard_parts(four)) >= 3
+    # mixed depths: a lane that ends early beside ones that do not;
+    # lane 0's root is one an edge leads back to
+    starts = [1] + sorted(edges)[5:5 + riders - 1]
+    depths = [3, 1, 6, 2, 7, 4, 1, 5][:riders]
+    lanes = [(bitgraph.seed_slots(four, np.array([u], np.uint32)), d)
+             for u, d in zip(starts, depths)]
+    tally, reached = _both(one, four, lanes)
+    for i, (u, d) in enumerate(zip(starts, depths)):
+        want = _plain(edges, [u], d)
+        assert tally[0, i] == len(want), (u, d)
+        assert np.array_equal(bitgraph.lane_uids(four, reached, i), want)
+        assert 1 <= tally[1, i] <= d
+    assert 1 in _plain(edges, [1], 3)          # the root, led back to
+    # a lane nobody rides reaches nothing and runs no level
+    assert not tally[:, riders:].any()
+    assert not (reached >> np.uint32(riders)).any()
+
+
+def test_sharded_traversal_over_more_than_a_vreg_of_vertices(graphs, mesh):
+    edges = graphs["wide"]
+    one, four = _pair(edges, mesh, 300)
+    assert four.n_slots > 4096 and four.dense is not None
+    assert four.dense.shape[1] == 256 and four.dense.shape[0] % (8 * CHIPS) == 0
+    roots = sorted(edges)[:3]
+    # several roots a lane, and a lane of depth 0 among the riders
+    lanes = [(bitgraph.seed_slots(four, np.array(roots, np.uint32)), 4),
+             (bitgraph.seed_slots(four, np.array(roots[:1], np.uint32)), 0),
+             (bitgraph.seed_slots(four, np.array(roots[1:], np.uint32)), 64)]
+    tally, reached = _both(one, four, lanes)
+    assert tally[0, 0] == len(_plain(edges, roots, 4))
+    assert tally[:, 1].tolist() == [0, 0]
+    assert np.array_equal(bitgraph.lane_uids(four, reached, 2),
+                          _plain(edges, roots[1:], 64))
+    assert tally[1, 2] < 64                    # it ended early
+
+
+@pytest.mark.parametrize("rows", (None, 40, 0),
+                         ids=("all_hub_rows", "some_hub_rows", "no_hub_rows"))
+def test_the_chips_shares_add_up_to_the_level(graphs, mesh, rows):
+    """Each chip's reach segment, taken apart by part and put chip
+    after chip, is the one-chip level's reach."""
+    one, four = _pair(graphs["small"], mesh, rows)
+    rng = np.random.default_rng(7)
+    words = (rng.integers(0, 1 << LANES, one.n_slots)
+             * (rng.random(one.n_slots) < 0.1)).astype(np.uint32)
+    frontier = jnp.asarray(words)
+    active = jnp.bitwise_or.reduce(frontier)
+    # the one-chip level, as bfs_traverse's body works it out
+    parts = [bitgraph._gathered_reach(
+        [b.in_nb for b in one.gathered], frontier)]
+    if one.dense is not None:
+        parts.append(bitgraph._hub_reach(one.dense, frontier, active, LANES))
+    want = np.concatenate([np.asarray(p) for p in parts] + [
+        np.zeros(one.n_slots - one.n_covered, np.uint32)])
+    P = jax.sharding.PartitionSpec
+    shares = jax.jit(jax.shard_map(
+        lambda nbs, dense, f, a: bitgraph._chip_reach(
+            nbs, dense, f, a, LANES)[None],
+        mesh=mesh,
+        in_specs=([P("uid")] * len(four.shard_nbs),
+                  None if four.dense is None else P("uid"), P(), P()),
+        out_specs=P("uid")))(four.shard_nbs, four.dense, frontier, active)
+    part_rows = bitgraph.shard_parts(four)
+    assert shares.shape == (CHIPS, sum(held for _, held in part_rows))
+    assert sum(r for r, _ in part_rows) == four.n_covered
+    got = np.asarray(bitgraph._whole_reach(shares, part_rows, four.n_slots))
+    assert want.any() and np.array_equal(got, want)
+    # what lies behind a part's last row on the last chips is padding
+    at = 0
+    for r, held in part_rows:
+        flat = np.asarray(shares)[:, at:at + held].reshape(-1)
+        assert not flat[r:].any()
+        at += held
+
+
+def test_a_chip_holds_its_run_of_the_rows_and_prices_its_share(graphs, mesh):
+    one = bitgraph.build_bitadjacency(graphs["small"])
+    four = bitgraph.build_bitadjacency(graphs["small"])
+    room = 40 * 4 * bitgraph.hub_row_words(one.n_slots)
+    bitgraph.attach_dense(one, room)
+    bitgraph.attach_dense(four, room, mesh=mesh)   # a chip's room each
+    for nb in four.shard_nbs + [four.dense]:
+        assert nb.sharding.shard_shape(nb.shape)[0] * CHIPS == nb.shape[0]
+        assert len(nb.sharding.device_set) == CHIPS
+    # four budgets of rows hold at least the classes one budget holds
+    assert four.dense_from <= one.dense_from
+    assert four.dense_rows >= one.dense_rows
+    # the one-chip copies of the matrices are gone from the device
+    assert all(isinstance(b.in_nb, np.ndarray) for b in four.buckets)
+    assert bitgraph.resident_bytes(four) == sum(
+        int(a.nbytes) for a in four.shard_nbs) + int(four.dense.nbytes)
+    lone = bitgraph.build_bitadjacency(graphs["small"])
+    bitgraph.attach_dense(lone, 1 << 40)
+    split = bitgraph.build_bitadjacency(graphs["small"])
+    bitgraph.attach_dense(split, 1 << 40, mesh=mesh)
+    # the same rows on four chips cost a chip about a quarter
+    assert bitgraph.level_seconds(split) < 0.3 * bitgraph.level_seconds(lone)
+
+
+# -- the served path ---------------------------------------------------
+
+
+KHOP = ("{ var(func: uid(%s)) @recurse(depth: %d, loop: false) "
+        "{ n as link } khop(func: uid(n)) { %s } }")
+
+
+def _db(rdf: str, **kw) -> GraphDB:
+    return bulk_load([rdf], schema="link: [uid] .", db=GraphDB(**kw))
+
+
+def _data(db, q):
+    body = db.query_json(q)
+    return json.loads(body[len('{"data":'):body.rfind(',"extensions":')])
+
+
+def _counter(name):
+    return sum(v for k, v in metrics.snapshot()["counters"].items()
+               if k.startswith(name))
+
+
+def _gauge(name):
+    return sum(v for k, v in metrics.snapshot()["gauges"].items()
+               if k.startswith(name))
+
+
+@pytest.fixture()
+def every_bound_recurse_on_the_device(monkeypatch):
+    monkeypatch.setattr(executor_mod.Executor, "_device_worth",
+                        lambda self, *a, **kw: True)
+
+
+@pytest.fixture(scope="module")
+def rdf(graphs, tmp_path_factory):
+    path = str(tmp_path_factory.mktemp("sharded") / "g.rdf")
+    with open(path, "w") as f:
+        f.writelines(f"<{s:#x}> <link> <{int(d):#x}> .\n"
+                     for s, ds in graphs["small"].items() for d in ds)
+    return path
+
+
+@pytest.fixture(scope="module")
+def engines(graphs, mesh, rdf):
+    """(edges, an engine with the mesh, one without, the postings
+    tier's) over one bulk-loaded graph."""
+    edges = graphs["small"]
+    return (edges,
+            _db(rdf, prefer_device=True, device_min_edges=1, mesh=mesh),
+            _db(rdf, prefer_device=True, device_min_edges=1),
+            _db(rdf, prefer_device=False))
+
+
+@pytest.mark.parametrize("depth", (4, 7), ids=("khop3", "khop6"))
+@pytest.mark.parametrize("reader", ("count(uid)", "uid"))
+def test_an_engine_with_a_mesh_answers_as_the_postings_tier_does(
+        engines, every_bound_recurse_on_the_device, depth, reader):
+    edges, sharded, _, host = engines
+    before = {n: _counter(n) for n in (
+        "recurse_sharded_total", "recurse_sharded_lanes_total",
+        "recurse_batch_total", "query_device_recurse_total")}
+    roots = [1] + sorted(edges)[10:13]
+    for u in roots:
+        q = KHOP % (hex(u), depth, reader)
+        got = _data(sharded, q)
+        assert got == _data(host, q)
+        want = _plain(edges, [u], depth - 1)
+        if reader == "uid":
+            assert [int(x["uid"], 16) for x in got["khop"]] == want.tolist()
+        else:
+            assert got == {"khop": [{"count": len(want)}]}
+    for n, was in before.items():
+        assert _counter(n) == was + len(roots), n
+    tile = sharded.tablets["link"]._device_badj
+    assert tile.mesh is not None and tile.shards == CHIPS
+
+
+def test_the_tile_is_counted_and_gauged_a_chip_at_a_time(
+        rdf, mesh, every_bound_recurse_on_the_device):
+    db = _db(rdf, prefer_device=True, device_min_edges=1, mesh=mesh)
+    _data(db, KHOP % ("0x1", 4, "count(uid)"))
+    tile = db.tablets["link"]._device_badj
+    whole = bitgraph.resident_bytes(tile)
+    assert _gauge('device_bitadj_shards{predicate="link"}') == CHIPS
+    assert _gauge('device_bitadj_chip_bytes{predicate="link"}') \
+        == whole / CHIPS
+    assert _gauge('device_bitadj_bytes{predicate="link"}') == whole
+    # the LRU counts what ONE chip holds, against a chip's budget
+    assert db.device_cache.bytes == whole // CHIPS
+    assert _gauge("device_cache_bytes") == whole // CHIPS
+
+
+def test_the_span_the_call_and_explain_name_the_shards(
+        engines, every_bound_recurse_on_the_device):
+    _, sharded, _, _ = engines
+    q = KHOP % ("0x1", 4, "count(uid)")
+    body = json.loads(sharded.query_json(q, explain="analyze"))
+    ex = body["extensions"]["explain"]
+    assert ex["tiers"]["deviceShards"] == CHIPS
+    stage = [s for s in ex["stages"] if s["stage"] == "recurse"]
+    assert stage and stage[0]["tier"] == "device"
+    assert stage[0]["shards"] == CHIPS
+    assert stage[0]["program"] == "bfs_traverse_sharded"
+    calls = [r for r in tracing.spans_for(ex["traceId"])
+             if r["name"] == "device.call"]
+    assert calls and calls[0]["args"]["program"] == "bfs_traverse_sharded"
+    assert calls[0]["args"]["shards"] == CHIPS
+
+
+def test_without_a_mesh_nothing_is_sharded(
+        engines, every_bound_recurse_on_the_device):
+    edges, _, lone, host = engines
+    assert lone.mesh is None
+    before = (_counter("recurse_sharded_total"),
+              _counter("recurse_batch_total"),
+              _counter("recurse_sharded_lanes_total"))
+    programs = bitgraph.bfs_traverse_sharded._cache_size()
+    q = KHOP % ("0x1", 7, "count(uid)")
+    assert _data(lone, q) == _data(host, q)
+    assert _counter("recurse_sharded_total") == before[0]
+    assert _counter("recurse_batch_total") == before[1] + 1
+    assert _counter("recurse_sharded_lanes_total") == before[2]
+    assert bitgraph.bfs_traverse_sharded._cache_size() == programs
+    tile = lone.tablets["link"]._device_badj
+    assert tile.mesh is None and tile.shards == 1 and tile.shard_nbs is None
+    # the program's name is what the one-chip cells' traces show
+    lowered = bitgraph.bfs_traverse.lower(
+        [b.in_nb for b in tile.gathered], tile.dense,
+        np.zeros(2 * 8 + LANES, np.int32), n_slots=tile.n_slots,
+        n_covered=tile.n_covered, lanes=LANES)
+    assert "jit_bfs_traverse" in lowered.as_text()[:200] \
+        and "sharded" not in lowered.as_text()[:200]
+    body = json.loads(lone.query_json(q, explain="analyze"))
+    assert "deviceShards" not in body["extensions"]["explain"]["tiers"]
+
+
+def test_the_sharded_programs_name_is_its_own(graphs, mesh):
+    _, four = _pair(graphs["small"], mesh, 40)
+    lowered = bitgraph.bfs_traverse_sharded.lower(
+        four.shard_nbs, four.dense, np.zeros(2 * 8 + LANES, np.int32),
+        mesh=mesh, part_rows=bitgraph.shard_parts(four),
+        n_slots=four.n_slots, lanes=LANES)
+    text = lowered.as_text()
+    assert "jit_bfs_traverse_sharded" in text[:200]
+    # ONE collective a level: the all-gather of the chips' shares
+    assert text.count("stablehlo.all_gather") == 1
+    assert "all_reduce" not in text and "all_to_all" not in text \
+        and "collective_permute" not in text
+
+
+# -- the flag ------------------------------------------------------------
+
+
+def test_alpha_chips_parses_and_defaults_to_one(monkeypatch):
+    seen = []
+    monkeypatch.setattr(cli, "cmd_alpha", lambda a: seen.append(a) or 0)
+    assert cli.main(["alpha", "--chips", "4", "--port", "0"]) == 0
+    assert cli.main(["alpha", "--port", "0"]) == 0
+    assert [a.chips for a in seen] == [4, 1]
+
+
+def test_alpha_chips_makes_one_uid_axis(mesh):
+    got = cli._chips_mesh(CHIPS)
+    assert got.axis_names == ("uid",) and got.shape["uid"] == CHIPS
+    assert got == mesh
+
+
+def test_alpha_with_more_chips_than_the_host_has_exits_at_once(monkeypatch):
+    four = jax.devices()[:4]
+    monkeypatch.setattr(jax, "devices", lambda *a, **kw: four)
+    with pytest.raises(SystemExit) as e:
+        cli.main(["alpha", "--chips", "8", "--port", "0"])
+    assert e.value.code not in (0, None)
+    assert "--chips 8" in str(e.value.code) \
+        and "this host has 4" in str(e.value.code)
+    with pytest.raises(SystemExit):
+        cli.main(["alpha", "--chips", "0", "--port", "0"])

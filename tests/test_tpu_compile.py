@@ -1,5 +1,7 @@
 """The served k-hop traversal compiles for the chip at the width it
-has in the `graph500-khop.khop-deep` cell: the TPU's compiler is
+has in the `graph500-khop.khop-deep-c16` cell, and its sharded form
+for four chips at the width of `graph500-khop-x4.khop-deep-c16`: the
+TPU's compiler is
 installed here and compiles for a v5e that is described, not attached
 (nothing runs, so this says nothing about results or times). It is
 what interpret mode cannot show: whether Mosaic takes the hub rows'
@@ -23,13 +25,23 @@ WORDS = bitgraph.hub_row_words(N)
 GATHERED = ((60_000, 1), (22_000, 2), (9_000, 3), (5_000, 4))
 
 
+# the four-chip cell's shapes at SCALE 20 (PERF.md section 4; the
+# generator's seed 3700000011): vertices and vertices with an in-edge,
+# hub rows (the classes from 32 in-edges up) and the nine gathered
+# classes' (rows, in-edges)
+N4, COVERED4, ROWS4 = 646_960, 547_110, 80_938
+GATHERED4 = ((145_952, 1), (73_625, 2), (44_967, 3), (30_705, 4),
+             (46_064, 6), (36_127, 8), (30_177, 12), (11_542, 16),
+             (47_013, 24))
+CHIPS = 4
+
+
 @pytest.fixture(scope="module")
-def one_chip():
+def topo():
     from jax.experimental import topologies
     from jax.experimental.compilation_cache import compilation_cache
-    from jax.sharding import SingleDeviceSharding
     try:
-        topo = topologies.get_topology_desc(platform="tpu",
+        desc = topologies.get_topology_desc(platform="tpu",
                                             topology_name="v5e:2x2")
     except Exception as e:
         pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
@@ -38,9 +50,15 @@ def one_chip():
     was = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     compilation_cache.reset_cache()
-    yield SingleDeviceSharding(topo.devices[0])
+    yield desc
     jax.config.update("jax_enable_compilation_cache", was)
     compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    from jax.sharding import SingleDeviceSharding
+    return SingleDeviceSharding(topo.devices[0])
 
 
 def _shape(one_chip, shape, dtype):
@@ -76,3 +94,46 @@ def test_the_whole_traversal_compiles_with_the_kernel_in_it(
     # kernel's per-row words, far under a chip's 16 GB
     assert mem.argument_size_in_bytes >= 4 * ROWS * WORDS
     assert mem.temp_size_in_bytes < 256 << 20
+
+
+def test_the_sharded_traversal_compiles_for_four_chips_at_the_x4_cells_width(
+        topo, monkeypatch):
+    """`bfs_traverse_sharded` for the described v5e:2x2 as
+    `alpha --chips 4` lays SCALE 20 out: a chip's program holds ONE
+    kernel and ONE collective, and asks for little beside its rows."""
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert len(topo.devices) == CHIPS
+    mesh = Mesh(np.asarray(topo.devices), (bitgraph.SHARD_AXIS,))
+    rows, whole = NamedSharding(mesh, P(bitgraph.SHARD_AXIS)), \
+        NamedSharding(mesh, P())
+    lanes, words = bitgraph.LANES, bitgraph.hub_row_words(N4)
+    held = [-(-m // CHIPS) for m, _ in GATHERED4]
+    chip_rows = bitgraph._chip_rows(ROWS4, CHIPS)
+    assert chip_rows * 4 * words <= 2 << 30       # a chip's tile budget
+    part_rows = tuple((m, h) for (m, _), h in zip(GATHERED4, held)) \
+        + ((ROWS4, chip_rows),)
+    assert sum(m for m, _ in part_rows) == COVERED4
+    compiled = bitgraph.bfs_traverse_sharded.lower(
+        [jax.ShapeDtypeStruct((CHIPS * h, d), jnp.int32, sharding=rows)
+         for h, (_, d) in zip(held, GATHERED4)],
+        jax.ShapeDtypeStruct((CHIPS * chip_rows, words), jnp.uint32,
+                             sharding=rows),
+        jax.ShapeDtypeStruct((2 * 8 + lanes,), jnp.int32, sharding=whole),
+        mesh=mesh, part_rows=part_rows, n_slots=N4, lanes=lanes).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 1
+    collectives = [ln for ln in text.splitlines() if any(
+        f" {op}(" in ln or f" {op}-start(" in ln
+        for op in ("all-gather", "all-reduce", "all-to-all",
+                   "collective-permute", "reduce-scatter"))]
+    assert len(collectives) == 1, collectives
+    mem = compiled.memory_analysis()
+    # a chip's arguments are ITS run of the rows, not the whole block
+    assert 4 * chip_rows * words <= mem.argument_size_in_bytes \
+        < 2 * 4 * chip_rows * words
+    # lane state, the frontier in the rows' layout and the kernel's
+    # words a row: what a call adds to a chip (noted: PERF.md section 5)
+    print(f"x4 temp bytes a chip: {mem.temp_size_in_bytes}")
+    assert mem.temp_size_in_bytes < 512 << 20
