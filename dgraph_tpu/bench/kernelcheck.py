@@ -163,13 +163,14 @@ def check_bfs_traverse(tiny) -> dict:
              bfs_reach(adj, roots, depth, dedup=False)])))
     tally, reached = (np.asarray(x) for x in
                       bitgraph.traverse(badj, riders))
-    counts, levels = tally
+    counts, levels, tiles = tally
     got = [bitgraph.lane_uids(badj, reached, i)
            for i in range(len(riders))]
     same = [np.array_equal(g, w) and int(c) == len(w)
             for g, w, c in zip(got, want, counts)]
     return {"ok": all(same), "lanes_equal": int(sum(same)),
             "reached": counts.tolist(), "levels_run": levels.tolist(),
+            "hub_tiles_streamed": int(tiles[0]), "hub_tiles": int(tiles[1]),
             "shape": {"slots": badj.n_slots, "edges": badj.n_edges,
                       "hub_rows": 0 if badj.dense is None
                       else list(badj.dense.shape),

@@ -29,6 +29,15 @@ per row with the ladder caps). The gather unit is descriptor-rate bound
 (~20-40M row-fetches/s on v5e, measured), so the batched kernels below
 amortize each descriptor across thousands of bit-packed queries.
 
+The SERVED traversal (bfs_traverse, bfs_traverse_sharded) holds the
+high degree classes as bitmap rows instead (attach_dense) and streams
+them. There a level's cost is an UPPER BOUND: the gathered classes
+cost the same for every frontier, but of the hub rows a level reads
+only the tiles in which some live lane has not yet reached some row
+(_hub_pending: the bottom-up step's rule, look only at vertices not
+yet found), so the deep levels of a traversal, by which a skewed
+graph's hubs are all found, read few of them or none.
+
 SSSP follows the same layout with an int32 distance vector and a
 min-reduction instead of any(): Bellman-Ford over dense tiles, with
 optional per-edge weights aligned to the in-neighbor matrices.
@@ -342,6 +351,9 @@ SHARD_AXIS = "uid"
 _HUB_TILE_ROWS = 256
 _HUB_WORDS_UNIT = 128
 
+# what a level without hub rows streams of them, and has
+_NO_TILES = np.zeros(2, np.int32)
+
 
 def hub_row_words(n_slots: int) -> int:
     """Words of one hub row over `n_slots` slots: a bit a slot, padded
@@ -441,9 +453,12 @@ def _host_nb(b: RevBucket) -> np.ndarray:
 
 
 def level_seconds(badj: BitAdjacency) -> float:
-    """What one level of the served traversal costs on the device,
-    from the adjacency's layout alone: the gathered classes' padded
-    in-edges and the dense rows' bytes."""
+    """What one level of the served traversal costs on the device AT
+    MOST, from the adjacency's layout alone: the gathered classes'
+    padded in-edges and the dense rows' bytes. An upper bound: a
+    level streams only the tiles of rows some live lane has not
+    settled (_hub_pending), all of them at a call's first levels.
+    It is what the executor's gate prices a level with."""
     gathered = sum(int(b.in_nb.size) for b in badj.gathered)
     dense = 0 if badj.dense is None else int(badj.dense.nbytes)
     # split over chips, a level costs what ONE chip gathers and
@@ -459,19 +474,25 @@ def _lane_planes(words_by_slot: jax.Array, lanes: int) -> jax.Array:
     return (words_by_slot[None, :] >> b) & jnp.uint32(1)
 
 
-def _hub_kernel(live_ref, fw_ref, rows_ref, out_ref):
+def _hub_kernel(live_ref, plan_ref, fw_ref, rows_ref, out_ref):
     """A tile of hub rows against every LIVE lane's frontier, the
-    tile read from HBM once. live_ref int32[1 + LANES] (SMEM): how
-    many lanes are live, then their numbers; fw_ref uint32[LANES, 8,
+    tile read from HBM once, and not at all where no live lane needs
+    it (_hub_plan). live_ref int32[1 + LANES] (SMEM): how many lanes
+    are live, then their numbers; plan_ref int32[2 tiles] (SMEM):
+    _hub_plan's, of which the kernel reads whether the step's tile is
+    needed (the index maps read the rest); fw_ref uint32[LANES, 8,
     W]: a lane's frontier in the rows' own layout (_frontier_words),
-    on eight sublanes; rows_ref uint32[T, W]; out_ref uint32[T, 128]:
-    bit b of a row's words set where the row meets lane b's frontier
-    in that column of vregs (the caller ORs the 128 together). All
-    elementwise on whole vregs: no reduction across lanes of a vreg,
-    no relayout."""
+    on eight sublanes; rows_ref uint32[T, W]: the step's tile where
+    it is needed, else whatever block the pipeline already held;
+    out_ref uint32[T, 128]: bit b of a row's words set where the row
+    meets lane b's frontier in that column of vregs (the caller ORs
+    the 128 together), all 0 for a tile not needed. All elementwise
+    on whole vregs: no reduction across lanes of a vreg, no
+    relayout."""
     from jax.experimental import pallas as pl
 
     n_live = live_ref[0]
+    needed = (plan_ref[pl.num_programs(0) + pl.program_id(0)] & 1) != 0
     chunks = rows_ref.shape[1] // 128
 
     def group(g, carry):
@@ -492,7 +513,13 @@ def _hub_kernel(live_ref, fw_ref, rows_ref, out_ref):
             0, n_live, lane, jnp.zeros((8, 128), jnp.uint32))
         return carry
 
-    jax.lax.fori_loop(0, rows_ref.shape[0] // 8, group, 0)
+    @pl.when(needed)
+    def _():
+        jax.lax.fori_loop(0, rows_ref.shape[0] // 8, group, 0)
+
+    @pl.when(jnp.logical_not(needed))
+    def _():
+        out_ref[...] = jnp.zeros(out_ref.shape, jnp.uint32)
 
 
 def _frontier_words(frontier: jax.Array, words: int,
@@ -508,26 +535,97 @@ def _frontier_words(frontier: jax.Array, words: int,
                    axis=1, dtype=jnp.uint32)
 
 
-def _hub_reach(dense: jax.Array, frontier: jax.Array,
-               active: jax.Array, lanes: int) -> jax.Array:
-    """Which hub rows a frontier reaches, lane by lane: uint32[rows]
-    lane words. `frontier` uint32[N] lane words over every slot,
-    `active` the word of the lanes that hold any. The rows are read
-    ONCE for all lanes: on the chip by _hub_kernel, a tile of rows in
-    VMEM and a loop over the live lanes; elsewhere (the CPU the tests
-    run on) by the same ANDs in plain jnp."""
+def _hub_tile(rows: int, tile: int) -> int:
+    """Rows a grid step of _hub_call holds of a block of `rows`:
+    `tile`, or the whole of a smaller block, in groups of eight."""
+    return min(tile, -(-rows // 8) * 8)
+
+
+def _hub_pending(reached: jax.Array, active: jax.Array, start: int,
+                 rows: int, chips: int = 1) -> jax.Array:
+    """Which lanes still need which hub row: uint32[chips, rows a
+    chip holds] lane words, a chip's run of the rows a line. The hub
+    rows are the slots [start, start + rows); a row is a DESTINATION,
+    and all a level learns from it is whether that slot is reached,
+    so a lane that has the slot in `reached` already, or holds no
+    frontier (`active`), needs it no more. The padding behind the
+    last row (_chip_rows) is needed by nobody."""
+    run = ~reached[start:start + rows] & active
+    held = _chip_rows(rows, chips)
+    return jnp.pad(run, (0, chips * held - rows)).reshape(chips, held)
+
+
+def _tiles_needed(pending: jax.Array, tile: int) -> jax.Array:
+    """_hub_pending's words -> bool[chips, tiles]: the tiles of
+    `tile` rows in which some lane still needs some row."""
+    chips, rows = pending.shape
+    tiles = -(-rows // tile)
+    return jnp.any(jnp.pad(pending, ((0, 0), (0, tiles * tile - rows)))
+                   .reshape(chips, tiles, tile) != 0, axis=2)
+
+
+def _hub_plan(needed: jax.Array) -> jax.Array:
+    """bool[tiles] -> int32[2 tiles], what _hub_call's grid does,
+    step by step: the needed tiles first, in their order, then the
+    others. The pipeline issues a step's copy one step ahead, so a
+    needed tile behind one that is not would wait for its rows with
+    nothing to overlap them; in one run the copies overlap the ANDs
+    as in a stream of every row. [:tiles]: the block of rows a
+    step's index map names, its own tile's where that is needed,
+    else the last needed one's, which the pipeline holds already: no
+    copy is issued for it (where none is needed, block 0, once).
+    [tiles:]: twice the tile whose words the step writes, plus 1
+    where it is needed."""
+    tiles = needed.shape[0]
+    order = jnp.argsort(~needed, stable=True).astype(jnp.int32)
+    n_needed = jnp.sum(needed, dtype=jnp.int32)
+    is_needed = jnp.arange(tiles, dtype=jnp.int32) < n_needed
+    rows = jnp.where(is_needed, order, order[jnp.maximum(n_needed - 1, 0)])
+    return jnp.concatenate([rows, 2 * order + is_needed])
+
+
+def _hub_reach(dense: jax.Array, frontier: jax.Array, active: jax.Array,
+               pending: jax.Array, lanes: int, tile: int, chip=0):
+    """Which hub rows a frontier reaches, lane by lane -> (uint32[rows]
+    lane words, int32[2] tiles streamed and tiles there are, over all
+    chips). `frontier` uint32[N] lane words over every slot, `active`
+    the word of the lanes that hold any, `pending` _hub_pending's
+    words for every chip and `chip` the one whose rows `dense` is.
+    Only the tiles some live lane still needs are read (_tiles_needed),
+    ONCE for all lanes; the words of the others are 0, which changes
+    neither `new` nor `reached` in _traverse_lanes: every bit left
+    out is in both `reached` and `visited` already. On the chip by
+    _hub_kernel, a tile of rows in VMEM and a loop over the live
+    lanes; elsewhere (the CPU the tests run on) by the same ANDs in
+    plain jnp over every row, the same tiles' words then put to 0."""
+    rows = dense.shape[0]
+    tile = _hub_tile(rows, tile)
+    needed = _tiles_needed(pending, tile)
+    # a chip's pipeline fetches one block whatever its flags say
+    tiles = jnp.stack([
+        jnp.sum(jnp.maximum(jnp.sum(needed, axis=1, dtype=jnp.int32), 1)),
+        jnp.int32(needed.size)])
     fw = _frontier_words(frontier, dense.shape[1], lanes)
     if jax.default_backend() != "tpu":
         bit = jnp.uint32(1) << jnp.arange(lanes, dtype=jnp.uint32)
         met = jnp.any((dense[None, :, :] & fw[:, None, :]) != 0, axis=2)
-        return jnp.sum(jnp.where(met, bit[:, None], jnp.uint32(0)),
-                       axis=0, dtype=jnp.uint32)
-    return jnp.bitwise_or.reduce(
-        _hub_call(dense, fw, active, lanes), axis=1)
+        reach = jnp.sum(jnp.where(met, bit[:, None], jnp.uint32(0)),
+                        axis=0, dtype=jnp.uint32)
+        return jnp.where(jnp.repeat(needed[chip], tile)[:rows], reach,
+                         jnp.uint32(0)), tiles
+    return jnp.bitwise_or.reduce(_hub_call(
+        dense, fw, active, _hub_plan(needed[chip]), lanes, tile),
+        axis=1), tiles
 
 
-def _hub_call(dense, fw, active, lanes: int, interpret: bool = False):
-    """_hub_kernel over every tile of `dense` -> uint32[rows, 128]."""
+def _hub_call(dense, fw, active, plan, lanes: int, tile: int,
+              interpret: bool = False):
+    """_hub_kernel over the tiles of `dense` that `plan` (_hub_plan)
+    says are needed -> uint32[rows, 128]. `tile` as _hub_tile gives
+    it. Both index maps read the plan: a step writes the words of
+    the tile the plan gives it, and one whose tile is not needed
+    names the block of rows of the step before: the pipeline copies
+    a block only when its index changes."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -536,30 +634,33 @@ def _hub_call(dense, fw, active, lanes: int, interpret: bool = False):
     live = jnp.concatenate([
         jnp.sum(is_live, dtype=jnp.int32)[None],
         jnp.argsort(~is_live, stable=True).astype(jnp.int32)])
-    tile = min(_HUB_TILE_ROWS, -(-rows // 8) * 8)
+    tiles = pl.cdiv(rows, tile)
     return pl.pallas_call(
         _hub_kernel,
         out_shape=jax.ShapeDtypeStruct((rows, 128), jnp.uint32),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
-            grid=(pl.cdiv(rows, tile),),
+            num_scalar_prefetch=2,
+            grid=(tiles,),
             in_specs=[
-                pl.BlockSpec((lanes, 8, words), lambda i, live: (0, 0, 0)),
-                pl.BlockSpec((tile, words), lambda i, live: (i, 0))],
-            out_specs=pl.BlockSpec((tile, 128), lambda i, live: (i, 0))),
+                pl.BlockSpec((lanes, 8, words),
+                             lambda i, live, plan: (0, 0, 0)),
+                pl.BlockSpec((tile, words),
+                             lambda i, live, plan: (plan[i], 0))],
+            out_specs=pl.BlockSpec(
+                (tile, 128), lambda i, live, plan: (plan[tiles + i] >> 1, 0))),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
             vmem_limit_bytes=2 * 4 * words * (tile + 8 * lanes)
             + (8 << 20)),
         interpret=interpret,
         name="bfs_hub_rows",
-    )(live, jnp.broadcast_to(fw[:, None, :], (lanes, 8, words)), dense)
+    )(live, plan, jnp.broadcast_to(fw[:, None, :], (lanes, 8, words)), dense)
 
 
-@functools.partial(jax.jit,
-                   static_argnames=("n_slots", "n_covered", "lanes"))
+@functools.partial(jax.jit, static_argnames=(
+    "n_slots", "n_covered", "lanes", "tile"))
 def bfs_traverse(in_nbs, dense, riders, *, n_slots: int, n_covered: int,
-                 lanes: int):
+                 lanes: int, tile: int = _HUB_TILE_ROWS):
     """`@recurse(loop: false)` whole, for every LANE of the call: up
     to `lanes` traversals over one adjacency in one program, each
     with its own roots, depth, visited set and count. The frontier,
@@ -576,31 +677,40 @@ def bfs_traverse(in_nbs, dense, riders, *, n_slots: int, n_covered: int,
                 RUNTIME values: levels the lane expands (edge hops),
                 0 for a lane nobody rides; a lane stops after its
                 own, whatever the others do
+    tile        hub rows a step of the rows' kernel holds
     ->  (tally, reached)
-    tally       int32[2, lanes], ONE fetch a call. Row 0: distinct
+    tally       int32[3, lanes], ONE fetch a call. Row 0: distinct
                 slots a lane reached through an edge in 1..depth hops
                 (a root counts where an edge leads back to it): what
                 DQL's uid variable on the child holds. Row 1: levels
                 a lane expanded; it ends early once a level finds it
-                no new slot, and the loop ends when no lane is alive
+                no new slot, and the loop ends when no lane is alive.
+                Row 2, the call's, not a lane's: [0] tiles of hub
+                rows the levels streamed, [1] tiles they would have
+                streamed had every level read every row (levels x
+                tiles), the rest 0
     reached     uint32[N] lane words: the reached sets themselves.
                 They stay on the device unless a lane's reader wants
                 the uids (lane_uids)
     """
-    def level(frontier, active):
-        parts = [_gathered_reach(in_nbs, frontier)]
-        if dense is not None:
-            parts.append(_hub_reach(dense, frontier, active, lanes))
-        parts.append(jnp.zeros((n_slots - n_covered,), jnp.uint32))
-        return jnp.concatenate(parts)
+    rows = 0 if dense is None else dense.shape[0]
+
+    def level(frontier, active, reached):
+        pending = None if dense is None else _hub_pending(
+            reached, active, n_covered - rows, rows)
+        reach, tiles = _chip_reach(in_nbs, dense, frontier, active,
+                                   pending, lanes, tile)
+        return jnp.concatenate([
+            reach, jnp.zeros((n_slots - n_covered,), jnp.uint32)]), tiles
 
     return _traverse_lanes(level, riders, n_slots, lanes)
 
 
 def _traverse_lanes(level, riders, n_slots: int, lanes: int):
-    """bfs_traverse's loop over `level(frontier, active) -> reach`
-    (uint32[N] lane words both): the riders unpacked, every lane run
-    to its own depth, -> (tally, reached)."""
+    """bfs_traverse's loop over `level(frontier, active, reached) ->
+    (reach, tiles)` (uint32[N] lane words but for `tiles`, int32[2]:
+    hub-row tiles the level streamed and had): the riders unpacked,
+    every lane run to its own depth, -> (tally, reached)."""
     n_seeds = (riders.shape[0] - lanes) // 2
     seed_slots = riders[:n_seeds]
     seed_bits = jax.lax.bitcast_convert_type(
@@ -619,33 +729,40 @@ def _traverse_lanes(level, riders, n_slots: int, lanes: int):
         return state[-1] != 0
 
     def body(state):
-        lvl, frontier, visited, reached, levels_run, active = state
-        reach = level(frontier, active)
+        lvl, frontier, visited, reached, levels_run, tiles, active = state
+        reach, streamed = level(frontier, active, reached)
         new = reach & ~visited
         # a lane goes on only within its depth and from a new slot
         frontier = new & expanding(lvl + 1)
         return (lvl + 1, frontier, visited | new, reached | reach,
                 levels_run + ((active >> lane) & 1).astype(jnp.int32),
-                jnp.bitwise_or.reduce(frontier))
+                tiles + streamed, jnp.bitwise_or.reduce(frontier))
 
     first = seed & expanding(jnp.int32(0))
-    _, _, _, reached, levels_run, _ = jax.lax.while_loop(
+    _, _, _, reached, levels_run, tiles, _ = jax.lax.while_loop(
         cond, body, (jnp.int32(0), first, seed,
                      jnp.zeros((n_slots,), jnp.uint32),
-                     jnp.zeros((lanes,), jnp.int32),
+                     jnp.zeros((lanes,), jnp.int32), _NO_TILES,
                      jnp.bitwise_or.reduce(first)))
     counts = jnp.sum(_lane_planes(reached, lanes), axis=1, dtype=jnp.int32)
-    return jnp.stack([counts, levels_run]), reached
+    return jnp.stack([counts, levels_run,
+                      jnp.pad(tiles, (0, lanes - 2))]), reached
 
 
-def _chip_reach(in_nbs, dense, frontier, active, lanes: int):
-    """ONE chip's share of a level: the rows it holds of every
-    gathered class, then of the hub rows, against the whole frontier:
-    uint32 lane words, a word a row it holds (padding rows read 0)."""
-    parts = [_gathered_reach(in_nbs, frontier)]
+def _chip_reach(in_nbs, dense, frontier, active, pending, lanes: int,
+                tile: int, chip=0):
+    """ONE chip's share of a level (all of it where there is one
+    chip): the rows it holds of every gathered class, then of the hub
+    rows (those some lane still needs: `pending`, `chip` as
+    _hub_reach takes them), against the whole frontier -> (uint32
+    lane words, a word a row it holds, padding rows 0; the level's
+    hub-row tiles as _hub_reach counts them)."""
+    parts, tiles = [_gathered_reach(in_nbs, frontier)], _NO_TILES
     if dense is not None:
-        parts.append(_hub_reach(dense, frontier, active, lanes))
-    return jnp.concatenate(parts)
+        hub, tiles = _hub_reach(dense, frontier, active, pending, lanes,
+                                tile, chip)
+        parts.append(hub)
+    return jnp.concatenate(parts), tiles
 
 
 def _whole_reach(shares, part_rows, n_slots: int):
@@ -664,30 +781,42 @@ def _whole_reach(shares, part_rows, n_slots: int):
 
 
 @functools.partial(jax.jit, static_argnames=(
-    "mesh", "part_rows", "n_slots", "lanes"))
+    "mesh", "part_rows", "n_slots", "lanes", "tile"))
 def bfs_traverse_sharded(in_nbs, dense, riders, *, mesh, part_rows,
-                         n_slots: int, lanes: int):
+                         n_slots: int, lanes: int,
+                         tile: int = _HUB_TILE_ROWS):
     """bfs_traverse with the adjacency split over the chips of
     `mesh`'s `uid` axis: same riders, same (tally, reached), bit for
-    bit. The DESTINATION rows are split: a chip holds a run of every
-    gathered class's rows (`in_nbs`) and of the hub rows (`dense`),
-    as attach_dense laid them out, and the lane words of frontier,
-    visited and reached sets whole. A level: every chip works out
-    which of ITS rows the frontier reaches (_chip_reach: a share of
-    the gathers and of the rows' stream, _hub_kernel as it is), then
-    ONE collective, an all-gather of the shares (a lane word a covered
-    slot, 4 B a vertex over all chips), hands every chip the whole
-    reach, from which each works out the next frontier for itself.
-    The loop runs inside the shard_map, so nothing else crosses
-    chips; the results come out replicated and are read from one."""
+    bit but for the tiles of hub rows, which a chip counts in its
+    own run. The DESTINATION rows are split: a chip holds a run of
+    every gathered class's rows (`in_nbs`) and of the hub rows
+    (`dense`), as attach_dense laid them out, and the lane words of
+    frontier, visited and reached sets whole. A level: every chip
+    works out which of ITS rows the frontier reaches (_chip_reach: a
+    share of the gathers and, of the rows some lane still needs, of
+    the stream), then ONE collective, an all-gather of the shares (a
+    lane word a covered slot, 4 B a vertex over all chips), hands
+    every chip the whole reach, from which each works out the next
+    frontier for itself, and from the reached sets which tiles of
+    rows EVERY chip streams next, so that the count of them needs no
+    collective of its own. The loop runs inside the shard_map, so
+    nothing else crosses chips; the results come out replicated and
+    are read from one."""
     P = jax.sharding.PartitionSpec
     rows_spec = P(SHARD_AXIS)
+    chips = mesh.shape[SHARD_AXIS]
+    start = sum(rows for rows, _ in part_rows[:len(in_nbs)])
 
     def per_chip(in_nbs, dense, riders):
-        def level(frontier, active):
-            share = _chip_reach(in_nbs, dense, frontier, active, lanes)
+        def level(frontier, active, reached):
+            pending = None if dense is None else _hub_pending(
+                reached, active, start, part_rows[-1][0], chips)
+            share, tiles = _chip_reach(
+                in_nbs, dense, frontier, active, pending, lanes, tile,
+                jax.lax.axis_index(SHARD_AXIS))
             return _whole_reach(
-                jax.lax.all_gather(share, SHARD_AXIS), part_rows, n_slots)
+                jax.lax.all_gather(share, SHARD_AXIS), part_rows,
+                n_slots), tiles
 
         return _traverse_lanes(level, riders, n_slots, lanes)
 
@@ -718,22 +847,26 @@ def _pack_riders(n_slots: int, riders: list) -> np.ndarray:
     return packed
 
 
-def traverse(badj: BitAdjacency, riders: list):
+def traverse(badj: BitAdjacency, riders: list,
+             tile: int = _HUB_TILE_ROWS):
     """The served traversal over an adjacency as attach_dense left
     it, one lane a rider: `riders` is [(root slots as seed_slots
     gives them, depth)], at most LANES of them; rider i is lane i of
     both results. bfs_traverse on one chip, bfs_traverse_sharded
     where the rows are split over a mesh. ONE compiled shape an
     adjacency while the riders' roots number eight or fewer together
-    (then a power of two), whatever their count and depths."""
+    (then a power of two), whatever their count and depths. `tile`:
+    hub rows a step of the rows' kernel holds (the tests' to set)."""
     packed = _pack_riders(badj.n_slots, riders)
     if badj.mesh is None:
         return bfs_traverse(
             [b.in_nb for b in badj.gathered], badj.dense, packed,
-            n_slots=badj.n_slots, n_covered=badj.n_covered, lanes=LANES)
+            n_slots=badj.n_slots, n_covered=badj.n_covered, lanes=LANES,
+            tile=tile)
     return bfs_traverse_sharded(
         badj.shard_nbs, badj.dense, packed, mesh=badj.mesh,
-        part_rows=shard_parts(badj), n_slots=badj.n_slots, lanes=LANES)
+        part_rows=shard_parts(badj), n_slots=badj.n_slots, lanes=LANES,
+        tile=tile)
 
 
 def shard_parts(badj: BitAdjacency) -> tuple:

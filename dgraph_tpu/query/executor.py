@@ -303,11 +303,20 @@ def _launch_traversals(badj, riders: list):
 
 def _land_traversals(handle, n: int) -> list:
     """A Rendezvous' `land`: every rider's (reached count, levels
-    run, the lanes' reached sets still on the device), once the call
-    has run. One small array leaves the device for all of them."""
+    run, the lanes' reached sets still on the device, the call's
+    hub-row tiles), once the call has run. One small array leaves
+    the device for all of them. `recurse_hub_tiles_streamed_total`
+    counts the tiles of hub rows the calls' levels read and
+    `recurse_hub_tiles_total` those they would have read had every
+    level read every row: their ratio is the share of the rows'
+    stream that the lanes' reached sets left standing."""
     tally, reached = handle
-    counts, levels = np.asarray(tally)
-    return [(int(counts[i]), int(levels[i]), reached) for i in range(n)]
+    counts, levels, tiles = np.asarray(tally)
+    streamed, full = int(tiles[0]), int(tiles[1])
+    inc_counter("recurse_hub_tiles_streamed_total", streamed)
+    inc_counter("recurse_hub_tiles_total", full)
+    return [(int(counts[i]), int(levels[i]), reached, (streamed, full))
+            for i in range(n)]
 
 
 def _var_domain(vmap) -> np.ndarray:
@@ -4859,7 +4868,8 @@ class Executor:
         """The whole traversal as ONE device program and ONE
         device_call -> (reached count, reached uids or None, levels
         run, {lanes of the call it rode, batch_wait_us, the chips the
-        adjacency is split over, the program's name}); None where
+        adjacency is split over, the program's name, the call's
+        hub-row tiles streamed and all told}); None where
         the host tier is to answer: the gate says so,
         or the device cannot speak for the traversal (roots over 32
         bits, a dirty tablet or one under device_min_edges, a root
@@ -4917,10 +4927,11 @@ class Executor:
                     functools.partial(_launch_traversals, badj),
                     _land_traversals, self.ctx),
                 out_bytes=8 + (4 * badj.n_slots if want_uids else 0))
-            count, levels, reached = ride.result
+            count, levels, reached, tiles = ride.result
             batch = {"lanes": ride.lanes,
                      "batch_wait_us": ride.waited_ns // 1000,
-                     "shards": badj.shards, "program": program}
+                     "shards": badj.shards, "program": program,
+                     "hub_tiles_streamed": tiles[0], "hub_tiles": tiles[1]}
             dc.note(**batch)
             uids = bitgraph.lane_uids(
                 badj, np.asarray(reached), ride.lane).astype(np.uint64) \

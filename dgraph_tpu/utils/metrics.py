@@ -129,9 +129,13 @@ REGISTERED = (
     # query/executor.py _run_recurse: the span's time, and which tier
     # a @recurse took; _launch_traversals: the device calls the
     # rendezvous dispatched and the traversals they carried, and
-    # those of them that took the program sharded over a mesh
+    # those of them that took the program sharded over a mesh;
+    # _land_traversals: the tiles of hub rows the calls' levels
+    # streamed, and those a stream of every row a level would have
     "recurse_batch_lanes_total",
     "recurse_batch_total",
+    "recurse_hub_tiles_streamed_total",
+    "recurse_hub_tiles_total",
     "recurse_ns_total",
     "recurse_sharded_lanes_total",
     "recurse_sharded_total",

@@ -116,7 +116,7 @@ def _requests(facts, n, seed):
 def _traverse(badj, riders):
     """bitgraph.traverse on the host -> (counts, levels, reached)."""
     tally, reached = bitgraph.traverse(badj, riders)
-    counts, levels = np.asarray(tally)
+    counts, levels, _ = np.asarray(tally)
     return counts, levels, np.asarray(reached)
 
 
@@ -196,29 +196,235 @@ def test_one_compiled_traversal_an_adjacency_for_every_batch_size(worlds):
     assert bitgraph.bfs_traverse._cache_size() == programs
 
 
+def _frontier(badj, live, seed, share=0.05):
+    """Lane words over every slot, the lanes of `live` alone, and
+    the word of the lanes that hold any."""
+    import jax.numpy as jnp
+    rng = np.random.default_rng(seed)
+    words = (rng.integers(0, 1 << bitgraph.LANES, badj.n_slots)
+             * (rng.random(badj.n_slots) < share)).astype(np.uint32)
+    frontier = jnp.asarray(words & np.uint32(live))
+    return frontier, jnp.bitwise_or.reduce(frontier)
+
+
+def _hub_level(badj, frontier, active, reached, tile, dense=None):
+    """One level's hub rows by both forms -> (the plain form's lane
+    words, the kernel's in interpret mode, the tiles needed)."""
+    import jax.numpy as jnp
+    dense = badj.dense if dense is None else dense
+    rows, width = dense.shape
+    lanes = bitgraph.LANES
+    pending = bitgraph._hub_pending(
+        jnp.asarray(reached), active, badj.n_covered - rows, rows)
+    want, tiles = bitgraph._hub_reach(
+        dense, frontier, active, pending, lanes, tile)
+    step = bitgraph._hub_tile(rows, tile)
+    needed = bitgraph._tiles_needed(pending, step)[0]
+    assert np.asarray(tiles).tolist() == [max(int(needed.sum()), 1),
+                                          len(needed)]
+    got = jnp.bitwise_or.reduce(bitgraph._hub_call(
+        dense, bitgraph._frontier_words(frontier, width, lanes), active,
+        bitgraph._hub_plan(needed), lanes, step, interpret=True), axis=1)
+    return np.asarray(want), np.asarray(got), np.asarray(needed)
+
+
 def test_the_hub_kernel_reads_what_the_plain_form_reads(worlds):
     """_hub_kernel (the chip's path, here in interpret mode) against
     the jnp form the CPU runs, on a frontier of mixed lanes, some of
-    them dead."""
-    import jax.numpy as jnp
+    them dead, nothing reached yet: every tile is read."""
     facts, dev, _, _ = worlds[10, 7]
     badj = _tile(dev)
     assert badj.dense is not None and badj.dense.shape[1] % 128 == 0
-    rng = np.random.default_rng(5)
-    lanes = bitgraph.LANES
-    for live in (0b1, 0b10100101, (1 << lanes) - 1):
-        words = (rng.integers(0, 1 << lanes, badj.n_slots)
-                 * (rng.random(badj.n_slots) < 0.05)).astype(np.uint32)
-        frontier = jnp.asarray(words & np.uint32(live))
-        active = jnp.bitwise_or.reduce(frontier)
-        want = np.asarray(bitgraph._hub_reach(
-            badj.dense, frontier, active, lanes))
-        rows, width = badj.dense.shape
-        fw = bitgraph._frontier_words(frontier, width, lanes)
-        got = np.asarray(jnp.bitwise_or.reduce(bitgraph._hub_call(
-            badj.dense, fw, active, lanes, interpret=True), axis=1))
-        assert np.array_equal(got, want) and want.any()
+    nothing = np.zeros(badj.n_slots, np.uint32)
+    for live in (0b1, 0b10100101, (1 << bitgraph.LANES) - 1):
+        frontier, active = _frontier(badj, live, 5)
+        want, got, needed = _hub_level(
+            badj, frontier, active, nothing, bitgraph._HUB_TILE_ROWS)
+        assert np.array_equal(got, want) and want.any() and needed.all()
         assert not (want & ~np.uint32(live)).any()
+
+
+# -- the program: the rows no live lane needs are not read ---------------
+
+TILE = 8       # rows a tile in these tests: a graph here has few hundred
+
+
+def _mid_traversal(badj, csr, facts, seed):
+    """(frontier, active, visited, reached) as a call of mixed lanes
+    holds them three or four levels in: lane b from its own root, plain BFS."""
+    import jax.numpy as jnp
+    rng = np.random.default_rng(seed)
+    offsets, dst, vertices = csr
+    frontier = np.zeros(badj.n_slots, np.uint32)
+    visited, reached = frontier.copy(), frontier.copy()
+
+    def slots(mask):
+        return bitgraph.seed_slots(badj, (
+            np.flatnonzero(mask) + graph500.FIRST_UID).astype(np.uint32))
+
+    for b in range(bitgraph.LANES):
+        root = int(rng.integers(0, facts["roots"]))
+        seen = np.zeros(vertices, bool)
+        seen[root] = True
+        front, hit = seen.copy(), np.zeros(vertices, bool)
+        for _ in range(3 + b % 2):
+            nxt = np.zeros(vertices, bool)
+            for v in np.flatnonzero(front):
+                nxt[dst[offsets[v]:offsets[v + 1]]] = True
+            hit |= nxt
+            front = nxt & ~seen
+            seen |= nxt
+        for words, mask in ((frontier, front), (visited, seen),
+                            (reached, hit)):
+            words[slots(mask)] |= np.uint32(1 << b)
+    frontier = jnp.asarray(frontier)
+    return frontier, jnp.bitwise_or.reduce(frontier), visited, reached
+
+
+@pytest.mark.parametrize("graph", GRAPHS, ids=lambda g: f"scale{g[0]}")
+def test_the_rows_of_a_settled_tile_are_never_read(worlds, graph):
+    """The poison test: some levels into a call some tiles are settled
+    for every live lane; with ALL-ONES written over their rows both
+    forms answer what they answer over the true rows, 0 in those
+    tiles, and the level's `new` and `reached` come out as they do
+    from a stream of every row."""
+    import jax.numpy as jnp
+    facts, dev, _, csr = worlds[graph]
+    badj = _tile(dev)
+    rows = badj.dense.shape[0]
+    start = badj.n_covered - rows
+    frontier, active, visited, reached = _mid_traversal(
+        badj, csr, facts, graph[0])
+    want, got, needed = _hub_level(badj, frontier, active, reached, TILE)
+    assert needed.any() and not needed.all()
+    row_needed = np.repeat(needed, TILE)[:rows]
+    poisoned = jnp.asarray(np.where(
+        row_needed[:, None], np.asarray(badj.dense), np.uint32(0xFFFFFFFF)))
+    want_p, got_p, _ = _hub_level(badj, frontier, active, reached, TILE,
+                                  dense=poisoned)
+    assert np.array_equal(want, got)
+    assert np.array_equal(want_p, want) and np.array_equal(got_p, want)
+    assert want.any() and not want[~row_needed].any()
+    # against a stream of every row: nothing reached yet, so that
+    # every tile is needed
+    full, _, all_needed = _hub_level(
+        badj, frontier, active, np.zeros_like(reached), TILE)
+    assert all_needed.all() and full[~row_needed].any()
+    hub = slice(start, start + rows)
+    assert np.array_equal(full & ~visited[hub], want & ~visited[hub])
+    assert np.array_equal(full | reached[hub], want | reached[hub])
+
+
+def test_a_level_with_every_tile_settled_reads_nothing(worlds):
+    """Every hub row reached by every live lane: the plan names one
+    block for every step and marks none, and both forms answer 0
+    whatever the rows hold."""
+    import jax.numpy as jnp
+    facts, dev, _, _ = worlds[9, 31_000_017]
+    badj = _tile(dev)
+    frontier, active = _frontier(badj, 0b00110101, 3, share=0.5)
+    everything = np.full(badj.n_slots, (1 << bitgraph.LANES) - 1, np.uint32)
+    ones = jnp.full(badj.dense.shape, 0xFFFFFFFF, jnp.uint32)
+    want, got, needed = _hub_level(badj, frontier, active, everything,
+                                   TILE, dense=ones)
+    assert not needed.any() and not want.any() and not got.any()
+    plan = np.asarray(bitgraph._hub_plan(jnp.asarray(needed)))
+    assert not plan[:len(needed)].any() and not (plan & 1).any()
+    # a lane that holds no frontier holds no tile back either: rows
+    # that only IT has not reached are settled
+    but_lane_1 = everything & ~np.uint32(0b10)
+    assert not _hub_level(badj, frontier, active, but_lane_1, TILE)[2].any()
+    assert _hub_level(badj, frontier, active,
+                      everything & ~np.uint32(0b100), TILE)[2].all()
+
+
+def test_the_plan_runs_the_needed_tiles_first_and_copies_only_them():
+    import jax.numpy as jnp
+    needed = np.array([0, 0, 1, 0, 1, 1, 0, 0, 1, 0], bool)
+    plan = np.asarray(bitgraph._hub_plan(jnp.asarray(needed)))
+    rows, writes = plan[:10], plan[10:]
+    # the four needed tiles in one run, then the six others; every
+    # tile's words are written by one step
+    assert (writes >> 1).tolist() == [2, 4, 5, 8, 0, 1, 3, 6, 7, 9]
+    assert (writes & 1).tolist() == [1] * 4 + [0] * 6
+    assert rows.tolist() == [2, 4, 5, 8] + [8] * 6
+    # a copy is issued when a step's block differs from the step
+    # before's: once a needed tile, the first step's included
+    for flags in (needed, ~needed, np.ones(7, bool), np.zeros(7, bool),
+                  np.array([1], bool), np.array([0], bool)):
+        tiles = len(flags)
+        plan = np.asarray(bitgraph._hub_plan(jnp.asarray(flags)))
+        rows, writes = plan[:tiles], plan[tiles:]
+        step_needed = (writes & 1).astype(bool)
+        assert sorted(writes >> 1) == list(range(tiles))
+        assert flags[writes >> 1].tolist() == step_needed.tolist()
+        assert (rows[step_needed] == (writes >> 1)[step_needed]).all()
+        assert 1 + int((rows[1:] != rows[:-1]).sum()) \
+            == max(int(flags.sum()), 1)
+        assert ((0 <= rows) & (rows < tiles)).all()
+
+
+def _fresh(fn, **kw):
+    """`fn` (a jitted traversal) traced anew, so that what a test has
+    put in bitgraph's place is what runs."""
+    import functools
+    import jax
+    return jax.jit(functools.partial(fn.__wrapped__, **kw))
+
+
+def _lanes_of(badj, facts, seed):
+    """Eight riders, `khop3` and `khop6` as the cell's mix sends them
+    and depths 1, 2 and 7 beside them."""
+    rng = np.random.default_rng(seed)
+    depths = [3, 6, 6, 3, 1, 7, 2, 6]
+    return [(bitgraph.seed_slots(badj, np.array(
+        [graph500.FIRST_UID + int(rng.integers(0, facts["roots"]))],
+        np.uint32)), d) for d in depths]
+
+
+@pytest.mark.parametrize("form", ("plain", "kernel"))
+@pytest.mark.parametrize("graph", GRAPHS, ids=lambda g: f"scale{g[0]}")
+def test_a_call_answers_the_same_with_and_without_the_settled_tiles(
+        worlds, graph, form, monkeypatch):
+    """(counts, levels, reached) of a full call, bit for bit: tiles
+    of 8 rows with the settled ones left out, by the plain form and
+    by the kernel (interpret mode) inside the whole traversal,
+    against a stream of every row at every level."""
+    import functools
+    import jax
+    import jax.numpy as jnp
+    facts, dev, _, csr = worlds[graph]
+    badj = _tile(dev)
+    riders = _lanes_of(badj, facts, graph[0])
+    args = ([b.in_nb for b in badj.gathered], badj.dense,
+            bitgraph._pack_riders(badj.n_slots, riders))
+    kw = dict(n_slots=badj.n_slots, n_covered=badj.n_covered,
+              lanes=bitgraph.LANES, tile=TILE)
+    traced = []
+    if form == "kernel":
+        kernel = functools.partial(bitgraph._hub_call, interpret=True)
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        monkeypatch.setattr(bitgraph, "_hub_call", lambda *a: (
+            traced.append(a[-1]), kernel(*a))[1])
+    tally, reached = (np.asarray(x) for x in
+                      _fresh(bitgraph.bfs_traverse, **kw)(*args))
+    assert traced == [TILE] * (form == "kernel")
+    monkeypatch.undo()
+    monkeypatch.setattr(
+        bitgraph, "_tiles_needed", lambda pending, tile: jnp.ones(
+            (pending.shape[0], -(-pending.shape[1] // tile)), bool))
+    tally_all, reached_all = (np.asarray(x) for x in
+                              _fresh(bitgraph.bfs_traverse, **kw)(*args))
+    assert np.array_equal(tally[:2], tally_all[:2])
+    assert np.array_equal(reached, reached_all)
+    # the deep lanes ran past the level at which hubs are all found
+    assert tally[1].max() >= 4
+    assert tally_all[2, 0] == tally_all[2, 1] == tally[2, 1] \
+        == tally[1].max() * -(-badj.dense.shape[0] // TILE)
+    assert 0 < tally[2, 0] < tally[2, 1]
+    for i, (slots, depth) in enumerate(riders):
+        root = int(badj.slot_uids[slots[0]])
+        assert tally[0, i] == int(_plain_reached(csr, [root], depth).sum())
 
 
 # -- the rendezvous, alone ---------------------------------------------
@@ -514,6 +720,97 @@ def test_a_lone_request_is_one_call_of_one_lane_and_never_waits(worlds):
         assert spans[name]["batch_wait_us"] == 0
     assert "recurse_batch_total 1" in metrics.render_prometheus()
     assert _data(dev, q) == _data(host, q)
+
+
+STREAMED = "recurse_hub_tiles_streamed_total"
+TOTAL = "recurse_hub_tiles_total"
+
+
+def test_the_hub_tiles_are_counted_spanned_and_served(worlds):
+    """The call's third tally row reaches two registered counters,
+    the recurse span and the exposition the harness scrapes."""
+    import urllib.request
+    from dgraph_tpu.server.http import serve
+    facts, dev, host, _ = worlds[10, 7]
+    badj = _tile(dev)
+    tiles = -(-badj.dense.shape[0] // bitgraph._HUB_TILE_ROWS)
+    assert {STREAMED, TOTAL} <= set(metrics.REGISTERED)
+    metrics.reset()
+    tracing.clear()
+    # depth 2 is ONE hop: nothing is reached before a call's first
+    # level, which reads every tile
+    _data(dev, _q(KHOP, [graph500.FIRST_UID + 9], 2))
+    c = metrics.snapshot()["counters"]
+    assert c[STREAMED] == c[TOTAL] == tiles
+    span = [s["args"] for s in tracing.recent_spans()
+            if s["name"] == "recurse"][-1]
+    assert span["hub_tiles_streamed"] == span["hub_tiles"] == tiles
+    q = _q(KHOP, [graph500.FIRST_UID + 9], 7)
+    assert _data(dev, q) == _data(host, q)
+    span = [s["args"] for s in tracing.recent_spans()
+            if s["name"] == "recurse" and s["args"]["tier"] == "device"][-1]
+    c = metrics.snapshot()["counters"]
+    assert c[TOTAL] == tiles + span["levels_run"] * tiles
+    assert span["hub_tiles"] == span["levels_run"] * tiles
+    assert tiles + span["hub_tiles_streamed"] == c[STREAMED] <= c[TOTAL]
+    httpd, _ = serve(dev, host="127.0.0.1", port=0, block=False)
+    try:
+        with urllib.request.urlopen(
+                f"http://127.0.0.1:{httpd.server_address[1]}"
+                "/debug/prometheus_metrics") as r:
+            text = r.read().decode()
+    finally:
+        httpd.shutdown()
+    assert f"{STREAMED} {c[STREAMED]:g}" in text
+    assert f"{TOTAL} {c[TOTAL]:g}" in text
+
+
+READER = os.path.join(ROOT, "benchmark", "metrics",
+                      "bfs_rows_streamed_share.py")
+# (counters before the window, after it, what the reader says)
+SHARES = {
+    # 800 calls of six levels over 273 tiles, 63.4% of them read
+    "a-window": ({STREAMED: 1_000, TOTAL: 1_000},
+                 {STREAMED: 1_000 + 830_842, TOTAL: 1_000 + 1_310_400},
+                 100.0 * 830_842 / 1_310_400),
+    "every-level-reads-every-row": ({}, {STREAMED: 546, TOTAL: 546}, 100.0),
+    # what the parent serves (the reader is laid over its checkout
+    # too): other counters, neither of these
+    "a-program-without-the-counters": (
+        {"recurse_batch_total": 1}, {"recurse_batch_total": 900,
+                                     "recurse_batch_lanes_total": 7000},
+        None),
+    "one-counter-without-the-other": ({}, {STREAMED: 5}, None),
+    "the-other-without-the-one": ({}, {TOTAL: 5}, None),
+    "no-call-in-the-window": ({STREAMED: 9, TOTAL: 12},
+                              {STREAMED: 9, TOTAL: 12}, None),
+    "an-adjacency-without-hub-rows": ({}, {STREAMED: 0, TOTAL: 0}, None),
+    "nothing-served": ({}, {}, None),
+}
+
+
+@pytest.mark.parametrize("case", sorted(SHARES))
+def test_the_streamed_shares_reader(case):
+    spec = importlib.util.spec_from_file_location("tb_reader", READER)
+    reader = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(reader)
+    before, after, want = SHARES[case]
+    got = reader.read({"counters_before": before, "counters_after": after})
+    assert got == want if want is None else got == pytest.approx(want)
+
+
+def test_the_streamed_share_is_a_metric_of_both_khop_cells():
+    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
+        bench = json.load(f)
+    entry, = (m for m in bench["per_layer"]
+              if m["name"] == "bfs_rows_streamed_share")
+    assert entry == {
+        "name": "bfs_rows_streamed_share", "unit": "%", "better": "lower",
+        "source": "program_counter", "layer": "kernels", "moves": "ok_qps",
+        "workloads": ["graph500-khop.khop-deep-c16",
+                      "graph500-khop-x4.khop-deep-c16"]}
+    cells = {w["name"] for w in bench["workloads"]}
+    assert set(entry["workloads"]) <= cells
 
 
 @pytest.mark.parametrize("graph", GRAPHS, ids=lambda g: f"scale{g[0]}")

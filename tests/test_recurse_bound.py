@@ -459,7 +459,7 @@ def test_hub_rows_take_the_classes_the_budget_holds():
         for root in (1, 17, roots):
             slots = bitgraph.seed_slots(badj, np.array([root], np.uint32))
             tally, reached = bitgraph.traverse(badj, [(slots, 6)])
-            counts, levels = np.asarray(tally)
+            counts, levels, _ = np.asarray(tally)
             uids = bitgraph.lane_uids(badj, np.asarray(reached), 0)
             assert len(uids) == int(counts[0])
             got.append((int(counts[0]), int(levels[0]), uids.tolist()))

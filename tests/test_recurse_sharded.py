@@ -78,13 +78,26 @@ def _pair(edges: dict, mesh, budget_rows):
     return one, four
 
 
-def _both(one, four, riders):
-    t1, r1 = bitgraph.traverse(one, riders)
-    t4, r4 = bitgraph.traverse(four, riders)
-    t1, r1, t4, r4 = (np.asarray(x) for x in (t1, r1, t4, r4))
+def _both(one, four, riders, **kw):
+    """The same riders over both adjacencies -> the sharded (tally,
+    reached), held to the one-chip program's bit for bit: counts,
+    levels and reached sets. The hub-row tiles (tally row 2) are a
+    chip's own runs', so they differ by layout; neither program
+    streams more than there is."""
+    t1, r1 = _traverse(one, riders, **kw)
+    t4, r4 = _traverse(four, riders, **kw)
     assert t1.dtype == t4.dtype and r1.dtype == r4.dtype
-    assert np.array_equal(t1, t4) and np.array_equal(r1, r4)
+    assert np.array_equal(t1[:2], t4[:2]) and np.array_equal(r1, r4)
+    for t, adj in ((t1, one), (t4, four)):
+        streamed, full = t[2, :2]
+        assert 0 <= streamed <= full and not t[2, 2:].any()
+        assert (full > 0) == (adj.dense is not None)
     return t4, r4
+
+
+def _traverse(adj, riders, **kw):
+    """bitgraph.traverse -> (tally, reached) on the host."""
+    return tuple(np.asarray(x) for x in bitgraph.traverse(adj, riders, **kw))
 
 
 # (vertices, edges drawn, seed): no vertex count is a multiple of 4 or
@@ -125,7 +138,7 @@ def test_sharded_traversal_is_the_one_chip_traversal_bit_for_bit(
         assert 1 <= tally[1, i] <= d
     assert 1 in _plain(edges, [1], 3)          # the root, led back to
     # a lane nobody rides reaches nothing and runs no level
-    assert not tally[:, riders:].any()
+    assert not tally[:2, riders:].any()
     assert not (reached >> np.uint32(riders)).any()
 
 
@@ -141,7 +154,7 @@ def test_sharded_traversal_over_more_than_a_vreg_of_vertices(graphs, mesh):
              (bitgraph.seed_slots(four, np.array(roots[1:], np.uint32)), 64)]
     tally, reached = _both(one, four, lanes)
     assert tally[0, 0] == len(_plain(edges, roots, 4))
-    assert tally[:, 1].tolist() == [0, 0]
+    assert tally[:2, 1].tolist() == [0, 0]
     assert np.array_equal(bitgraph.lane_uids(four, reached, 2),
                           _plain(edges, roots[1:], 64))
     assert tally[1, 2] < 64                    # it ended early
@@ -159,21 +172,33 @@ def test_the_chips_shares_add_up_to_the_level(graphs, mesh, rows):
     frontier = jnp.asarray(words)
     active = jnp.bitwise_or.reduce(frontier)
     # the one-chip level, as bfs_traverse's body works it out
+    # nothing reached yet: every lane that holds a frontier needs
+    # every row, as at a call's first level
+    nothing = jnp.zeros(one.n_slots, jnp.uint32)
+    tile = bitgraph._HUB_TILE_ROWS
     parts = [bitgraph._gathered_reach(
         [b.in_nb for b in one.gathered], frontier)]
     if one.dense is not None:
-        parts.append(bitgraph._hub_reach(one.dense, frontier, active, LANES))
+        rows = one.dense.shape[0]
+        parts.append(bitgraph._hub_reach(
+            one.dense, frontier, active, bitgraph._hub_pending(
+                nothing, active, one.n_covered - rows, rows),
+            LANES, tile)[0])
     want = np.concatenate([np.asarray(p) for p in parts] + [
         np.zeros(one.n_slots - one.n_covered, np.uint32)])
     P = jax.sharding.PartitionSpec
+    part_rows = bitgraph.shard_parts(four)
+    pending = None if four.dense is None else bitgraph._hub_pending(
+        nothing, active, four.n_covered - four.dense_rows, four.dense_rows,
+        CHIPS)
     shares = jax.jit(jax.shard_map(
         lambda nbs, dense, f, a: bitgraph._chip_reach(
-            nbs, dense, f, a, LANES)[None],
+            nbs, dense, f, a, pending, LANES, tile,
+            jax.lax.axis_index("uid"))[0][None],
         mesh=mesh,
         in_specs=([P("uid")] * len(four.shard_nbs),
                   None if four.dense is None else P("uid"), P(), P()),
         out_specs=P("uid")))(four.shard_nbs, four.dense, frontier, active)
-    part_rows = bitgraph.shard_parts(four)
     assert shares.shape == (CHIPS, sum(held for _, held in part_rows))
     assert sum(r for r, _ in part_rows) == four.n_covered
     got = np.asarray(bitgraph._whole_reach(shares, part_rows, four.n_slots))
@@ -208,6 +233,158 @@ def test_a_chip_holds_its_run_of_the_rows_and_prices_its_share(graphs, mesh):
     bitgraph.attach_dense(split, 1 << 40, mesh=mesh)
     # the same rows on four chips cost a chip about a quarter
     assert bitgraph.level_seconds(split) < 0.3 * bitgraph.level_seconds(lone)
+
+
+# -- the rows no live lane needs are not read ----------------------------
+#
+# A graph made to settle tile by tile, tiles of TILE rows. R and the
+# fifteen HUBS hold 8 in-edges each (one degree class, R its first
+# row): seven FEEDERS point at every one of them, R at every hub, and
+# b back at R, the end of R -> a -> b -> R. So R is a hub ROOT that an
+# edge leads back to only at hop 3: `visited` from the start, not
+# `reached` until then. b also starts a chain b -> c -> d -> e, found
+# through the one-in-edge class at levels 3-5, after every hub is.
+# Forty leaves (an in-edge each, from a feeder) keep that class too
+# large for the budget that holds the hubs' rows.
+
+TILE = 8
+R, HUBS, FEEDERS = 1, list(range(2, 17)), list(range(100, 107))
+A, B, C, D, E = 50, 51, 52, 53, 54
+LEAVES = list(range(200, 240))
+
+
+def _settling_edges() -> dict:
+    out = {R: HUBS + [A], A: [B], B: [R, C], C: [D], D: [E]}
+    for i, f in enumerate(FEEDERS):
+        out[f] = [R] + HUBS + LEAVES[i::len(FEEDERS)]
+    return {s: np.array(sorted(d), np.uint32) for s, d in out.items()}
+
+
+@pytest.fixture(scope="module", params=("every_class_as_rows",
+                                        "the_hubs_as_rows"))
+def settling(request, mesh):
+    """(edges, one-chip adjacency, the same over the mesh): every
+    degree class as hub rows (61 rows: 7 tiles and 5 rows; 16 rows a
+    chip), or the hubs' class alone (16 rows: two tiles; 8 rows on
+    each of two chips and two chips of padding) with the
+    one-in-edge class gathered."""
+    edges = _settling_edges()
+    one = bitgraph.build_bitadjacency(edges)
+    four = bitgraph.build_bitadjacency(edges)
+    row = 4 * bitgraph.hub_row_words(one.n_slots)
+    if request.param == "every_class_as_rows":
+        bitgraph.attach_dense(one, 1 << 40)
+        bitgraph.attach_dense(four, 1 << 40, mesh=mesh)
+        assert one.dense_rows == four.dense_rows == 61 and 61 % TILE
+        assert not one.gathered
+    else:
+        bitgraph.attach_dense(one, 16 * row)
+        bitgraph.attach_dense(four, 8 * row, mesh=mesh)
+        assert one.dense_rows == four.dense_rows == 16
+        assert len(one.gathered) == len(four.gathered) == 1
+        # R's row is the first, the hubs' follow by uid
+        assert one.slot_uids[one.n_covered - 16:one.n_covered].tolist() \
+            == [R] + HUBS
+    return edges, one, four
+
+
+def _riders(adj, lanes):
+    return [(bitgraph.seed_slots(adj, np.array(roots, np.uint32)), depth)
+            for roots, depth in lanes]
+
+
+def _checked(settling, lanes):
+    """The riders over both adjacencies in tiles of TILE rows, every
+    lane held to the plain BFS -> the one-chip and the sharded
+    tallies."""
+    edges, one, four = settling
+    riders = _riders(one, lanes)
+    t4, reached = _both(one, four, riders, tile=TILE)
+    t1, _ = _traverse(one, riders, tile=TILE)
+    for i, (roots, depth) in enumerate(lanes):
+        want = _plain(edges, roots, depth)
+        assert t4[0, i] == len(want), (roots, depth)
+        assert np.array_equal(bitgraph.lane_uids(four, reached, i), want)
+    return t1, t4
+
+
+@pytest.mark.parametrize("depth", (1, 2, 3, 4, 6, 7))
+def test_a_hub_root_keeps_its_row_until_an_edge_leads_back(settling, depth):
+    edges, one, _ = settling
+    t1, _ = _checked(settling, [([R], depth)])
+    # R is its own third hop and nothing before
+    assert (R in _plain(edges, [R], depth)) == (depth >= 3)
+    assert t1[1, 0] == min(depth, 6)
+    if one.gathered:
+        # the hubs' two tiles: both at level 1; R's alone while R is
+        # not reached (levels 2, 3); then none, and the level still
+        # finds c, d, e through the gathered class (a step's first
+        # block is fetched whatever the flags say: 1 a level)
+        assert t1[2, :2].tolist() == [
+            sum([2, 1, 1, 1, 1, 1][:depth]), 2 * min(depth, 6)]
+    else:
+        assert t1[2, 1] == 8 * min(depth, 6)
+        assert (t1[2, 0] < t1[2, 1]) == (depth > 1)
+
+
+def test_a_lane_that_settled_a_tile_rides_beside_one_that_did_not(settling):
+    _, one, _ = settling
+    # a feeder's lane reaches R and every hub at its first level; R's
+    # own lane needs R's row for two levels more
+    lanes = [([R], 4), ([FEEDERS[0]], 3), ([FEEDERS[1], A], 2), ([B], 6)]
+    t1, t4 = _checked(settling, lanes)
+    for i, lane in enumerate(lanes):
+        alone1, alone4 = _checked(settling, [lane])
+        assert t1[:2, i].tolist() == alone1[:2, 0].tolist() \
+            == alone4[:2, 0].tolist()
+    assert 0 < t1[2, 0] < t1[2, 1]
+
+
+def test_a_dead_lane_and_an_unridden_one_hold_no_tile_back(settling):
+    # a hub has no out-edge: its lane finds nothing and is dead after
+    # one level; a rider of depth 0 never lives; lanes 4-7 are not
+    # ridden. None of them keeps a tile streaming for R's lane
+    alone1, alone4 = _checked(settling, [([R], 5)])
+    t1, t4 = _checked(settling, [([R], 5), ([HUBS[4]], 5), ([R], 0),
+                                  ([HUBS[0], HUBS[9]], 7)])
+    assert t1[:2, 1:4].tolist() == [[0, 0, 0], [1, 0, 1]]
+    assert t1[2].tolist() == alone1[2].tolist()
+    assert t4[2].tolist() == alone4[2].tolist()
+
+
+def test_lanes_of_one_hop_stream_every_tile(settling):
+    """Nothing is reached at a call's first level: it reads every
+    tile, and the count of them says so."""
+    t1, t4 = _checked(settling, [([R], 1), ([FEEDERS[2]], 1), ([B], 1)])
+    assert t1[2, 0] == t1[2, 1] > 0 and t4[2, 0] == t4[2, 1] >= t1[2, 1]
+
+
+def test_a_chips_tiles_follow_its_own_run_of_the_rows(settling, mesh):
+    """_hub_pending hands every chip every chip's needs (the count of
+    tiles then needs no collective); the padding behind the last row
+    is nobody's."""
+    _, one, four = settling
+    rows = four.dense_rows
+    held = four.dense.shape[0] // CHIPS
+    start = four.n_covered - rows
+    reached = np.zeros(four.n_slots, np.uint32)
+    reached[start + 3:start + rows] = 0b11          # all but three rows
+    active = jnp.uint32(0b01)
+    pending = np.asarray(bitgraph._hub_pending(
+        jnp.asarray(reached), active, start, rows, CHIPS))
+    assert pending.shape == (CHIPS, held)
+    assert pending.reshape(-1)[:3].tolist() == [1, 1, 1]
+    # lanes 2-7 have reached none of the other rows and are not live:
+    # they need nothing
+    assert not pending.reshape(-1)[3:].any()
+    needed = np.asarray(bitgraph._tiles_needed(jnp.asarray(pending), TILE))
+    assert needed.sum() == 1 and needed[0, 0]
+    assert not np.asarray(bitgraph._hub_pending(
+        jnp.asarray(reached), jnp.uint32(0), start, rows, CHIPS)).any()
+    assert np.array_equal(
+        np.asarray(bitgraph._hub_pending(
+            jnp.asarray(reached), active, start, rows)).reshape(-1),
+        pending.reshape(-1)[:rows])
 
 
 # -- the served path ---------------------------------------------------

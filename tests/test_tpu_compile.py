@@ -66,13 +66,17 @@ def _shape(one_chip, shape, dtype):
 
 
 def test_the_hub_rows_kernel_compiles_at_the_cells_width(one_chip):
-    lanes = bitgraph.LANES
+    """With the plan of needed tiles as its second prefetched scalar
+    operand, read by the rows' index map: whether Mosaic takes a
+    block index that comes out of SMEM."""
+    lanes, tile = bitgraph.LANES, bitgraph._HUB_TILE_ROWS
     compiled = jax.jit(
-        lambda dense, fw, active: bitgraph._hub_call(
-            dense, fw, active, lanes)).lower(
+        lambda dense, fw, active, plan: bitgraph._hub_call(
+            dense, fw, active, plan, lanes, tile)).lower(
         _shape(one_chip, (ROWS, WORDS), jnp.uint32),
         _shape(one_chip, (lanes, WORDS), jnp.uint32),
-        _shape(one_chip, (), jnp.uint32)).compile()
+        _shape(one_chip, (), jnp.uint32),
+        _shape(one_chip, (2 * -(-ROWS // tile),), jnp.int32)).compile()
     assert "tpu_custom_call" in compiled.as_text()
 
 
