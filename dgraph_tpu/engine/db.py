@@ -93,8 +93,10 @@ class Mutation:
 class Latency:
     """Per-phase latency returned with every response
     (ref api.Latency, edgraph/server.go:717), and the roll-up of the
-    request's device calls (query/devicecall.py writes the four
-    `device_*` fields; they lie inside `processing_ns`)."""
+    request's device calls (query/devicecall.py writes the five
+    `device_*` fields: the count, the three phases, which lie inside
+    `processing_ns`, and `device_queue_ns`, the stand at a rendezvous,
+    which lies inside `device_wait_ns`)."""
 
     parsing_ns: int = 0
     processing_ns: int = 0
@@ -103,6 +105,7 @@ class Latency:
     device_calls: int = 0
     device_enqueue_ns: int = 0
     device_wait_ns: int = 0
+    device_queue_ns: int = 0
     device_fetch_ns: int = 0
 
     def as_dict(self):
@@ -123,7 +126,9 @@ class Latency:
         device calls the request made and what they spent enqueueing,
         waiting for the device and fetching (all 0 on a host-only
         request; `extensions.latency` and the gRPC message keep the
-        reference's fields alone)."""
+        reference's fields alone). `device_wait_ns - device_queue_ns`
+        is the request's own calls without the stand behind the call
+        in flight before them."""
         return {"parsing_ns": self.parsing_ns,
                 "processing_ns": self.processing_ns,
                 "encoding_ns": self.encoding_ns,
@@ -131,6 +136,7 @@ class Latency:
                 "device_calls": self.device_calls,
                 "device_enqueue_ns": self.device_enqueue_ns,
                 "device_wait_ns": self.device_wait_ns,
+                "device_queue_ns": self.device_queue_ns,
                 "device_fetch_ns": self.device_fetch_ns}
 
 

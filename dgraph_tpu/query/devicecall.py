@@ -17,18 +17,36 @@ Three phases on `time.perf_counter_ns`:
            requests' programs on the chip, then the program's own run.
   fetch    -> block exit: device-to-host copy, compaction.
 
+and, INSIDE `wait`, for a block that rode a `Rendezvous`' call:
+
+  queue    joining -> its call's launch: the stand behind the call in
+           flight (`Ride.waited_ns`; 0 where it found the chip free).
+
+Every mark is a wall-clock read: the thread's CPU clock
+(`time.thread_time_ns`) is a system call that holds the interpreter,
+15-30 us under load on the chip's host (PERF.md section 6, PR 39):
+no request reads it, a scrape does (`metrics.watch_thread_cpu`).
+What a plain dispatch found at the chip is counted, not timed:
+`ahead`, the device calls of this process dispatched and not yet
+ready when it began to wait (a rendezvous' call counts as one, its
+riders as none).
+
 A block that dispatched (called `wait`) and raised nothing writes, at
 its exit: the site's own counter (names and labels as they always
 were: `query_device_*_total`, `query_fused_dispatch_total`,
 `query_sharded_expand_total`); a `device.call` span with `family`,
-`program`, `enqueue_us`, `wait_us`, `fetch_us`, `out_bytes`; the
-request's roll-up (`sink`: engine/db.py's Latency, or None outside a
-request); and `device_call_ns_total{family,phase}`. A block that
-never dispatched (the callee declined: >32-bit uids, an empty
-frontier) counts nothing, as before. Each phase is also a
-`jax.profiler.TraceAnnotation` (`device.enqueue`, `device.wait`,
-`device.fetch`), so an idle gap of a device profile reads as host
-dispatch overhead, queueing or transfer.
+`program`, `enqueue_us`, `wait_us`, `fetch_us`, `out_bytes`, and
+`ahead` (a plain dispatch) or `flight` (a rider: the id of the
+`device.flight` span its result came from); the request's roll-up
+(`sink`: engine/db.py's Latency, or None outside a request: the three
+phases and `device_queue_ns`); `device_call_ns_total{family,phase}`
+(the three phases: readers sum them), a rider's
+`device_call_queue_ns_total{family}` and a plain dispatch's
+`device_call_ahead_total{family}`. A block that never dispatched (the
+callee declined: >32-bit uids, an empty frontier) counts nothing, as
+before. Each phase is also a `jax.profiler.TraceAnnotation`
+(`device.enqueue`, `device.wait`, `device.fetch`), so an idle gap of a
+device profile reads as host dispatch overhead, queueing or transfer.
 
 Callees that dispatch and fetch in one function (`expand_np`,
 `setops.union_many_device`, `bitgraph.sssp_dist`) take `sync=dc.wait`:
@@ -39,15 +57,18 @@ k-hop traversal's lanes), each still runs its own block and meets the
 others at a `Rendezvous`: its `wait` is then `dc.wait_for(...)`, from
 joining until its call's result is in, queueing behind the call in
 flight included, exactly as queueing behind other requests' programs
-is above.
+is above. The call itself is spanned once, by the thread that lands
+it: `device.flight`, with the phases of the turn-round between two
+calls (`Rendezvous`).
 """
 
 from __future__ import annotations
 
+import contextlib
 import threading
 import time
 
-from dgraph_tpu.utils.metrics import inc_counter
+from dgraph_tpu.utils.metrics import inc_counter, watch_gauge
 from dgraph_tpu.utils.tracing import span, trace_annotation
 
 
@@ -58,11 +79,39 @@ def _family(counter: str) -> str:
     return name.removeprefix("device_")
 
 
+_NO_ANNOTATION = contextlib.nullcontext()
+
+
+def _annotation(name: str):
+    """`name` on the profiler's host plane for a `with` block; nothing
+    in a process that never imported jax."""
+    return trace_annotation(name) or _NO_ANNOTATION
+
+
+# the device calls of this process dispatched and not yet ready: a
+# plain dispatch from `wait` until the device has its result, a
+# rendezvous' call from its launch until `land` returns; published as
+# a gauge at a scrape, not at every move
+_inflight = 0
+_inflight_lock = threading.Lock()
+watch_gauge("device_calls_inflight", lambda: _inflight)
+
+
+def _dispatched(n: int) -> int:
+    """Move the count of calls on the device by `n`; -> what it was."""
+    global _inflight
+    with _inflight_lock:
+        was = _inflight
+        _inflight = was + n
+    return was
+
+
 class device_call:
     # dglint: guarded-by=*:single-thread (one block per dispatch of one
     # request: the thread that enters it leaves it)
     __slots__ = ("_sink", "_counter", "_labels", "_span", "_attrs",
-                 "_ann", "_t0", "_t1", "_t2", "_out_bytes")
+                 "_ann", "_t0", "_t1", "_t2", "_out_bytes", "_ahead",
+                 "_queue_ns")
 
     def __init__(self, counter: str, labels: dict | None = None, *,
                  sink=None, program: str = ""):
@@ -81,7 +130,7 @@ class device_call:
 
     def __enter__(self) -> "device_call":
         self._attrs = self._span.__enter__()
-        self._ann = None
+        self._ann = self._ahead = self._queue_ns = None
         self._t1 = self._t2 = 0
         self._phase("device.enqueue")
         self._t0 = time.perf_counter_ns()
@@ -91,7 +140,11 @@ class device_call:
         """The dispatched result, once the device has produced it."""
         import jax
 
-        ready = self.wait_for(lambda: jax.block_until_ready(out))
+        self._ahead = _dispatched(+1)
+        try:
+            ready = self.wait_for(lambda: jax.block_until_ready(out))
+        finally:
+            _dispatched(-1)
         nbytes = getattr(ready, "nbytes", None)
         self._out_bytes = int(nbytes) if nbytes is not None else sum(
             int(x.nbytes) for x in jax.tree_util.tree_leaves(ready))
@@ -101,8 +154,9 @@ class device_call:
         """`ready()`'s result, for a block whose dispatch is not a
         device array yet: `ready` returns once the device has produced
         what the block waits for (a seat in a call that a Rendezvous
-        dispatches). `out_bytes`: what the block takes off the device
-        of it."""
+        dispatches: the `Ride` it returns says how long the block
+        stood, and which flight brought its result). `out_bytes`: what
+        the block takes off the device of it."""
         t1 = time.perf_counter_ns()
         self._phase("device.wait")
         out = ready()
@@ -110,6 +164,9 @@ class device_call:
         self._phase("device.fetch")
         self._t1, self._t2 = t1, t2
         self._out_bytes = out_bytes
+        if isinstance(out, Ride):
+            self._queue_ns = out.waited_ns
+            self._attrs["flight"] = out.flight.span_id
         return out
 
     def note(self, **attrs) -> None:
@@ -125,11 +182,21 @@ class device_call:
                       ("wait", self._t2 - self._t1),
                       ("fetch", t3 - self._t2))
             a = self._attrs
+            family = a["family"]
             for phase, ns in phases:
                 a[phase + "_us"] = ns // 1000
                 inc_counter("device_call_ns_total", ns,
-                            labels={"family": a["family"],
-                                    "phase": phase})
+                            labels={"family": family, "phase": phase})
+            queue_ns = self._queue_ns
+            if queue_ns is not None:
+                # a series of its own: the stand lies INSIDE `wait`,
+                # and readers sum device_call_ns_total's phases
+                inc_counter("device_call_queue_ns_total", queue_ns,
+                            labels={"family": family})
+            if self._ahead is not None:
+                a["ahead"] = self._ahead
+                inc_counter("device_call_ahead_total", self._ahead,
+                            labels={"family": family})
             a["out_bytes"] = self._out_bytes
             sink = self._sink
             if sink is not None:
@@ -137,6 +204,7 @@ class device_call:
                 sink.device_enqueue_ns += phases[0][1]
                 sink.device_wait_ns += phases[1][1]
                 sink.device_fetch_ns += phases[2][1]
+                sink.device_queue_ns += queue_ns or 0
         self._span.__exit__(etype, exc, tb)
 
 
@@ -144,7 +212,8 @@ class _Flight:
     """One call of a Rendezvous: its riders in lane order, what
     `launch` handed back, and who blocks for the result."""
 
-    __slots__ = ("riders", "handle", "launched", "lander", "t_launch")
+    __slots__ = ("riders", "handle", "launched", "lander", "t_launch",
+                 "span_id")
 
     def __init__(self, riders: list):
         self.riders = riders
@@ -152,6 +221,7 @@ class _Flight:
         self.launched = False
         self.lander = None
         self.t_launch = 0
+        self.span_id = ""       # of its `device.flight` span
 
 
 class Ride:
@@ -209,26 +279,45 @@ class Rendezvous:
     Kept on the tile it serves (`Rendezvous.at`), so requests meet
     only over the very object their own read_ts resolved to: another
     base_ts, direction or predicate is another tile and another
-    rendezvous."""
+    rendezvous.
+
+    The thread that lands a call spans it: one `device.flight` span a
+    call, with `family`, `lanes`, `left_waiting` (riders the next call
+    could not seat) and the four phases of what that thread does, each
+    an attribute and a profiler annotation of its own: `flight.land`
+    (blocked for the device, then the fetch), `flight.board`,
+    `flight.launch` (the NEXT call's `launch`), `flight.settle`.
+    `flight.turnround` (`turnround_us`) runs from `land`'s return to
+    the next call's `launch` returning: an upper bound on the host's
+    share of the gap the chip stands idle in between two calls (the
+    device starts the call before `launch` returns); a call that
+    nobody waited behind has none. The span is a child of the lander's own
+    `device.call`; every rider's `device.call` names it (`flight`).
+    After the next call is launched the phases go to
+    `rendezvous_ns_total{family,phase}`, and
+    `rendezvous_chained_total{family}` counts the calls a landing
+    thread launched, the only ones that have a turn-round."""
 
     _POLL_S = 0.05      # how often a waiter looks at its context
     _make = threading.Lock()
 
-    def __init__(self, capacity: int):
+    def __init__(self, capacity: int, family: str = ""):
         self.capacity = capacity
+        self.family = family
         self._cond = threading.Condition()
         self._flight: _Flight | None = None     # the call on the chip
         self._waiting: list[Ride] = []
 
     @classmethod
-    def at(cls, tile, capacity: int) -> "Rendezvous":
-        """The tile's own rendezvous, made on first asking."""
+    def at(cls, tile, capacity: int, family: str = "") -> "Rendezvous":
+        """The tile's own rendezvous, made on first asking; `family`
+        labels its flights' span and counters."""
         meet = getattr(tile, "_rendezvous", None)
         if meet is None:
             with cls._make:
                 meet = getattr(tile, "_rendezvous", None)
                 if meet is None:
-                    meet = tile._rendezvous = cls(capacity)
+                    meet = tile._rendezvous = cls(capacity, family)
         return meet
 
     def _board(self, first: Ride | None = None) -> _Flight | None:
@@ -260,6 +349,7 @@ class Rendezvous:
                 self._flight = None
                 self._cond.notify_all()
             return e
+        _dispatched(+1)
         flight.launched = True
         return None
 
@@ -309,21 +399,56 @@ class Rendezvous:
             if not mine.launched:
                 self._launch(mine, launch)
             if mine.launched:
+                self._fly(mine, launch, land)
+        if me.error is not None:
+            raise me.error
+        return me
+
+    def _fly(self, mine: _Flight, launch, land) -> None:
+        """(the landing thread, outside the lock) Land `mine`, put the
+        waiters' call on the chip, then hand `mine`'s results out."""
+        cond = self._cond
+        family = self.family
+        flight_span = span("device.flight", family=family,
+                           lanes=len(mine.riders))
+        with flight_span as a:
+            mine.span_id = flight_span.span_id
+            t0 = time.perf_counter_ns()
+            with _annotation("flight.land"):
                 try:
                     results, error = land(mine.handle,
                                           len(mine.riders)), None
                 except BaseException as e:
                     results, error = None, e
-                # the chip is free: the waiters' call goes on it
-                # before anything is handed out
-                with cond:
+                finally:
+                    _dispatched(-1)
+            # the chip is free: the waiters' call goes on it before
+            # anything is handed out
+            t1 = time.perf_counter_ns()
+            with _annotation("flight.turnround"):
+                with _annotation("flight.board"), cond:
                     nxt = self._board()
-                failed = None if nxt is None else self._launch(nxt, launch)
-                with cond:
-                    self._settle(mine, results, error)
-                    cond.notify_all()
-                if failed is not None and not isinstance(failed, Exception):
-                    raise failed    # an interrupt is this thread's too
-        if me.error is not None:
-            raise me.error
-        return me
+                    left = len(self._waiting)
+                t2 = time.perf_counter_ns()
+                failed = None
+                if nxt is not None:
+                    with _annotation("flight.launch"):
+                        failed = self._launch(nxt, launch)
+                t3 = time.perf_counter_ns()
+            with _annotation("flight.settle"), cond:
+                self._settle(mine, results, error)
+                cond.notify_all()
+            t4 = time.perf_counter_ns()
+            phases = [("land", t1 - t0), ("board", t2 - t1),
+                      ("settle", t4 - t3)]
+            if nxt is not None:
+                phases += [("launch", t3 - t2), ("turnround", t3 - t1)]
+                inc_counter("rendezvous_chained_total",
+                            labels={"family": family})
+            a["left_waiting"] = left
+            for phase, ns in phases:
+                a[phase + "_us"] = ns // 1000
+                inc_counter("rendezvous_ns_total", ns,
+                            labels={"family": family, "phase": phase})
+            if failed is not None and not isinstance(failed, Exception):
+                raise failed    # an interrupt is this thread's too

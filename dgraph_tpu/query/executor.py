@@ -4920,7 +4920,8 @@ class Executor:
             slots = bitgraph.seed_slots(badj, roots.astype(np.uint32))
             if slots is None:
                 return None
-            meet = Rendezvous.at(badj, bitgraph.LANES)
+            meet = Rendezvous.at(badj, bitgraph.LANES,
+                                 family="recurse")
             ride = dc.wait_for(
                 lambda: meet.ride(
                     (slots, depth),

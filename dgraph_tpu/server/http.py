@@ -996,6 +996,16 @@ class _Handler(BaseHTTPRequestHandler):
     def setup(self):
         super().setup()
         metrics.inc_counter("http_connections_total")
+        # this thread serves the connection's requests: its CPU time
+        # against the handler's wall time (`http_request_ns_total`)
+        # is what the requests computed against what they waited
+        metrics.watch_thread_cpu()
+
+    def finish(self):
+        try:
+            super().finish()
+        finally:
+            metrics.watch_thread_cpu(False)
 
     def log_message(self, fmt, *args):  # quiet by default
         pass

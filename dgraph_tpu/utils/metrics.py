@@ -12,6 +12,8 @@ from __future__ import annotations
 import threading
 from bisect import bisect_right
 
+from dgraph_tpu.utils import tracing
+
 _LOCK = threading.Lock()
 _COUNTERS: dict[tuple[str, tuple], float] = {}
 _GAUGES: dict[tuple[str, tuple], float] = {}
@@ -58,8 +60,15 @@ REGISTERED = (
     "device_bitadj_edges",
     "device_bitadj_shards",
     # query/devicecall.py; NOT `query_device_*`: readers sum that
-    # prefix as a count of dispatches
+    # prefix as a count of dispatches. A block's phases, a rider's
+    # stand at its rendezvous (inside `wait`, so a series of its own),
+    # the calls a plain dispatch found ahead of it on the chip, those
+    # on it now; and a Rendezvous' flights: the landing thread's
+    # phases, and the calls it launched itself
+    "device_call_ahead_total",
     "device_call_ns_total",
+    "device_call_queue_ns_total",
+    "device_calls_inflight",
     "device_dispatch_seconds",
     # engine/device_cache.py: a vector predicate's resident candidate
     # masks, and its resident block
@@ -77,6 +86,7 @@ REGISTERED = (
     "dgraph_pending_queries",
     "dgraph_queries_shed_total",
     "http_connections_total",
+    "http_handler_cpu_ns_total",
     "http_request_ns_total",
     "http_requests_total",
     # compiled plan cache + micro-batcher (query/plan.py,
@@ -140,6 +150,8 @@ REGISTERED = (
     "recurse_sharded_lanes_total",
     "recurse_sharded_total",
     "recurse_tier_total",
+    "rendezvous_chained_total",
+    "rendezvous_ns_total",
     "similar_exact_fallback_total",
     "similar_mask_total",
     "similar_masked_total",
@@ -355,21 +367,41 @@ _STARTED_AT_MONO = _time_mod.monotonic()
 # the collector's pauses by generation, kept by _on_gc. The callback
 # runs on whichever thread tripped the collector, possibly inside a
 # region that holds _LOCK (an allocation there can trip it), so it
-# takes no lock and touches nothing but these two lists; collections
-# never nest, and collect_runtime_gauges publishes the sums.
+# takes no lock and touches nothing but these lists; collections
+# never nest, and collect_runtime_gauges publishes the sums. A full
+# collection is also a `gc.pause` annotation on the profiler's host
+# plane, open from its start to its stop.
 _GC_PAUSE_S = [0.0, 0.0, 0.0]
 _GC_PUBLISHED = [0.0, 0.0, 0.0]
 _GC_STARTED = [0.0]
+_GC_ANNOTATION: list = [None]
 # devices whose memory_stats() the runtime gauges read (watch_devices)
 _DEVICES: list = []
+# gauges read from their owner at a scrape (watch_gauge): name -> read
+_POLLED: dict = {}
+# the threads whose CPU time the runtime gauges sum (watch_thread_cpu):
+# thread ident -> (its CPU clock's id, the clock when it was first
+# watched); and the ns of those that have left. The lock is taken as a
+# thread comes and goes and at a scrape, never inside a request.
+_THREAD_CLOCKS: dict[int, tuple[int, int]] = {}
+_THREAD_CPU_NS = [0, 0]     # of threads that left; published so far
+_THREAD_CPU_LOCK = threading.Lock()
 
 
 def _on_gc(phase: str, info: dict) -> None:
     if phase == "start":
+        if info["generation"] == 2:
+            ann = _GC_ANNOTATION[0] = tracing.trace_annotation("gc.pause")
+            if ann is not None:
+                ann.__enter__()
         _GC_STARTED[0] = _time_mod.perf_counter()
     else:
         _GC_PAUSE_S[info["generation"]] += \
             _time_mod.perf_counter() - _GC_STARTED[0]
+        ann = _GC_ANNOTATION[0]
+        if ann is not None:
+            _GC_ANNOTATION[0] = None
+            ann.__exit__(None, None, None)
 
 
 def watch_gc() -> None:
@@ -390,6 +422,48 @@ def watch_devices(devices) -> None:
     after it took them: asking jax for its devices here would be what
     takes the chip."""
     _DEVICES[:] = list(devices)
+
+
+def watch_gauge(name: str, read) -> None:
+    """Publish `read()` as the gauge `name` on every scrape: for a
+    number its owner moves on a hot path, too often to publish there."""
+    _POLLED[name] = read
+
+
+def watch_thread_cpu(on: bool = True) -> None:
+    """Count the calling thread's CPU time from now on in
+    `http_handler_cpu_ns_total` (`on=False`: the thread is about to
+    leave, what it used is kept). The thread's own CPU clock is read
+    at a scrape, from the scraping thread, and once as the thread
+    comes and once as it goes: never inside a request, because that
+    read is a system call that holds the interpreter (15-30 us under
+    load on a sandboxed kernel: PERF.md section 6, PR 39)."""
+    ident = threading.get_ident()
+    with _THREAD_CPU_LOCK:
+        if on:
+            try:
+                clock = _time_mod.pthread_getcpuclockid(ident)
+                _THREAD_CLOCKS[ident] = (
+                    clock, _time_mod.clock_gettime_ns(clock))
+            except (AttributeError, OSError):
+                pass    # no such clock on this platform: nothing counted
+        elif ident in _THREAD_CLOCKS:
+            _, since = _THREAD_CLOCKS.pop(ident)
+            _THREAD_CPU_NS[0] += _time_mod.thread_time_ns() - since
+
+
+def _collect_thread_cpu() -> None:
+    with _THREAD_CPU_LOCK:
+        total = _THREAD_CPU_NS[0]
+        for ident, (clock, since) in list(_THREAD_CLOCKS.items()):
+            try:
+                total += _time_mod.clock_gettime_ns(clock) - since
+            except OSError:     # it died unannounced: its clock with it
+                del _THREAD_CLOCKS[ident]
+        if total > _THREAD_CPU_NS[1]:
+            inc_counter("http_handler_cpu_ns_total",
+                        total - _THREAD_CPU_NS[1])
+            _THREAD_CPU_NS[1] = total
 
 
 def collect_runtime_gauges():
@@ -415,6 +489,9 @@ def collect_runtime_gauges():
                         seconds - _GC_PUBLISHED[gen],
                         labels={"gen": str(gen)})
             _GC_PUBLISHED[gen] = seconds
+    _collect_thread_cpu()
+    for name, read in list(_POLLED.items()):
+        set_gauge(name, read())
     for d in _DEVICES:
         peak = (d.memory_stats() or {}).get("peak_bytes_in_use")
         if peak is not None:  # the CPU backend reports none

@@ -86,6 +86,7 @@ SPAN_NAMES = (
     "block",
     "commit",
     "device.call",
+    "device.flight",
     "device.tile_load",
     "encode",
     "eq",
@@ -262,6 +263,13 @@ class span:
         self._tok = _CUR.set((trace_id, sid))
         self._t0 = time.perf_counter_ns()
         return self._attrs
+
+    @property
+    def span_id(self) -> str:
+        """The open span's id ("" where nothing records spans), for a
+        span of another thread to name this one as its cause."""
+        rec = self._rec
+        return rec["span_id"] if rec is not None else ""
 
     def __exit__(self, *exc) -> None:
         rec = self._rec

@@ -316,3 +316,253 @@ def test_device_memory_peak_is_read_from_watched_devices():
         assert 'device_memory_peak_bytes{device="0"}' not in g
     finally:
         metrics.watch_devices([])
+
+
+# -- where a request waits (PR 39): the stand at a rendezvous, the
+# chip's queue by count, the interpreter by thread CPU time ------------
+
+
+def _held_chip():
+    """`launch`, `land` of a chip that keeps a call until `gate` is
+    set, and the list of the calls launched."""
+    gate, calls = threading.Event(), []
+
+    def launch(items):
+        calls.append(list(items))
+        return len(calls) - 1
+
+    def land(handle, n):
+        gate.wait(30)
+        return [("answer", x) for x in calls[handle]]
+
+    return gate, calls, launch, land
+
+
+def _ride_in_a_block(meet, launch, land, item, out):
+    from dgraph_tpu.engine.db import Latency
+    from dgraph_tpu.query.devicecall import device_call
+
+    lat = Latency()
+    with device_call("query_device_recurse_total", sink=lat,
+                     program="t") as dc:
+        ride = dc.wait_for(lambda: meet.ride(item, launch, land))
+    out[item] = (lat, ride)
+
+
+@pytest.fixture
+def lead_and_rider():
+    """One request that finds the chip free and one that joins while
+    the first's call is on it -> ({item: (Latency, Ride)}, moved
+    counters, the spans by name)."""
+    from dgraph_tpu.query.devicecall import Rendezvous
+
+    meet = Rendezvous(4, family="t")
+    gate, calls, launch, land = _held_chip()
+    out: dict = {}
+    before = metrics.counters_snapshot()
+    tracing.clear()
+    threads = [threading.Thread(target=_ride_in_a_block,
+                                args=(meet, launch, land, x, out))
+               for x in ("lead", "rider")]
+    threads[0].start()
+    while not calls:
+        time.sleep(0.001)
+    threads[1].start()
+    deadline = time.monotonic() + 10
+    while len(meet._waiting) != 1 and time.monotonic() < deadline:
+        time.sleep(0.001)
+    time.sleep(0.02)
+    gate.set()
+    for t in threads:
+        t.join(30)
+        assert not t.is_alive()
+    return out, metrics.counters_delta(before), tracing.recent_spans()
+
+
+@pytest.mark.parametrize("who", ("lead", "rider"))
+def test_the_stand_at_a_rendezvous_lies_inside_the_wait(lead_and_rider,
+                                                        who):
+    out, moved, _ = lead_and_rider
+    lat, ride = out[who]
+    assert lat.device_calls == 1
+    assert lat.device_queue_ns == ride.waited_ns
+    if who == "lead":       # it found the chip free
+        assert lat.device_queue_ns == 0
+    else:
+        assert 0.02e9 * 0.9 <= lat.device_queue_ns <= lat.device_wait_ns
+    assert lat.server_latency()["device_queue_ns"] == lat.device_queue_ns
+    # a series of its own: the three phases' sum stays the block's time
+    assert moved['device_call_queue_ns_total{family="recurse"}'] \
+        == out["rider"][0].device_queue_ns
+    assert not any(k.startswith("device_call_ns_total")
+                   and 'phase="queue"' in k for k in moved)
+
+
+def test_a_rider_counts_no_calls_ahead_and_a_flight_is_one(lead_and_rider):
+    from dgraph_tpu.query import devicecall
+
+    out, moved, spans = lead_and_rider
+    assert devicecall._inflight == 0
+    assert not any(k.startswith("device_call_ahead_total") for k in moved)
+    assert all("ahead" not in s["args"] for s in spans
+               if s["name"] == "device.call")
+    metrics.collect_runtime_gauges()    # the gauge is read at a scrape
+    assert metrics.gauges_snapshot()["device_calls_inflight"] == 0
+
+
+def test_a_lone_dispatch_finds_nothing_ahead_of_it():
+    import jax.numpy as jnp
+    from dgraph_tpu.query import devicecall
+
+    tracing.clear()
+    before = metrics.counters_snapshot()
+    with devicecall.device_call("query_device_setops_total") as dc:
+        dc.wait(jnp.arange(8) + 1)
+    call = [s for s in tracing.recent_spans()
+            if s["name"] == "device.call"][-1]["args"]
+    assert call["ahead"] == 0
+    # the series is there at 0: a reader tells "none ahead" from "not
+    # served"
+    assert metrics.counters_snapshot()[
+        'device_call_ahead_total{family="setops"}'] \
+        == before.get('device_call_ahead_total{family="setops"}', 0)
+    assert devicecall._inflight == 0
+
+
+def test_a_dispatch_behind_a_flight_finds_one_call_ahead():
+    import jax.numpy as jnp
+    from dgraph_tpu.query import devicecall
+
+    meet = devicecall.Rendezvous(4, family="t")
+    gate, calls, launch, land = _held_chip()
+    t = threading.Thread(target=meet.ride, args=("x", launch, land))
+    t.start()
+    while devicecall._inflight != 1:
+        time.sleep(0.001)
+    tracing.clear()
+    with devicecall.device_call("query_device_setops_total") as dc:
+        dc.wait(jnp.arange(8) + 1)
+    gate.set()
+    t.join(30)
+    call = [s for s in tracing.recent_spans()
+            if s["name"] == "device.call"][-1]["args"]
+    assert call["ahead"] == 1
+    assert devicecall._inflight == 0
+
+
+def test_a_wait_that_raises_leaves_nothing_in_flight():
+    from dgraph_tpu.query import devicecall
+
+    class Broken:
+        """What `jax.block_until_ready` gives up on."""
+
+        def block_until_ready(self):
+            raise RuntimeError("the device said no")
+
+    before = metrics.counters_snapshot()
+    with pytest.raises(RuntimeError):
+        with devicecall.device_call("query_device_setops_total") as dc:
+            dc.wait(Broken())
+    assert devicecall._inflight == 0
+    metrics.collect_runtime_gauges()    # the gauge is read at a scrape
+    assert metrics.gauges_snapshot()["device_calls_inflight"] == 0
+    assert "query_device_setops_total" not in metrics.counters_delta(before)
+
+
+def test_a_request_that_never_stood_serves_a_stand_of_zero():
+    sl = _graph(device_min_edges=1).query(PAGE)[
+        "extensions"]["server_latency"]
+    assert sl["device_calls"] >= 1 and sl["device_queue_ns"] == 0
+    host = _graph(prefer_device=False).query(PAGE)
+    assert host["extensions"]["server_latency"]["device_queue_ns"] == 0
+
+
+# -- the request threads' CPU time, read at a scrape -------------------
+
+CPU = "http_handler_cpu_ns_total"
+
+
+def _spin(seconds: float) -> None:
+    t = time.thread_time()
+    while time.thread_time() - t < seconds:
+        pass
+
+
+def _scraped() -> float:
+    metrics.collect_runtime_gauges()
+    return metrics.counters_snapshot().get(CPU, 0)
+
+
+def test_a_watched_threads_cpu_time_is_read_from_another_thread():
+    spun, leave = threading.Event(), threading.Event()
+
+    def serve():
+        _spin(0.02)             # before it is watched: not counted
+        metrics.watch_thread_cpu()
+        _spin(0.05)
+        spun.set()
+        leave.wait(30)
+        _spin(0.03)
+        metrics.watch_thread_cpu(False)
+
+    before = _scraped()
+    t0 = time.perf_counter()
+    t = threading.Thread(target=serve)
+    t.start()
+    assert spun.wait(30)
+    # read while the thread lives and waits, by this thread
+    live = _scraped() - before
+    assert 0.05e9 * 0.9 <= live <= (time.perf_counter() - t0) * 1e9
+    assert live < 0.07e9 * 1.5      # the 20 ms before the watch are not in
+    leave.set()
+    t.join(30)
+    # what it used is kept when it has left, and counted once
+    gone = _scraped() - before
+    assert 0.08e9 * 0.9 <= gone <= (time.perf_counter() - t0) * 1e9
+    assert _scraped() - before == gone
+
+
+def test_a_thread_that_died_unannounced_is_dropped_at_the_next_scrape():
+    t = threading.Thread(target=metrics.watch_thread_cpu)
+    t.start()
+    t.join(30)
+    assert t.ident in metrics._THREAD_CLOCKS
+    # a scrape raises nothing; the kernel may take a moment to reap it
+    deadline = time.monotonic() + 10.0
+    while time.monotonic() < deadline:
+        before = _scraped()
+        if t.ident not in metrics._THREAD_CLOCKS:
+            break
+        time.sleep(0.01)
+    assert t.ident not in metrics._THREAD_CLOCKS
+    assert _scraped() == before
+
+
+def test_the_threads_that_serve_connections_are_watched():
+    import http.client
+
+    from dgraph_tpu.server.http import serve
+
+    httpd, _ = serve(_graph(prefer_device=False), block=False, port=0)
+    try:
+        before = _scraped()
+        watched = set(metrics._THREAD_CLOCKS)
+        t0 = time.perf_counter()
+        conn = http.client.HTTPConnection(*httpd.server_address[:2])
+        for _ in range(5):
+            conn.request("POST", "/query", PAGE,
+                         {"Content-Type": "application/dql"})
+            assert json.loads(conn.getresponse().read())["data"]["q"]
+        # the connection is open: its thread is read where it stands
+        assert len(set(metrics._THREAD_CLOCKS) - watched) == 1
+        live = _scraped() - before
+        assert 0 < live <= (time.perf_counter() - t0) * 1e9
+        conn.close()
+        deadline = time.monotonic() + 10.0
+        while set(metrics._THREAD_CLOCKS) - watched \
+                and time.monotonic() < deadline:
+            time.sleep(0.01)
+        assert not set(metrics._THREAD_CLOCKS) - watched
+        assert _scraped() - before >= live
+    finally:
+        httpd.shutdown()

@@ -11,9 +11,10 @@ import os
 from dgraph_tpu.utils import failpoint, metrics
 
 _REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-# device_call (query/devicecall.py) increments the counter it is named
+# device_call (query/devicecall.py) increments the counter it is named;
+# watch_gauge names a gauge that a scrape reads from its owner
 _EMITTERS = {"inc_counter", "set_gauge", "observe", "get_counter",
-             "device_call"}
+             "device_call", "watch_gauge"}
 
 
 def _py_files():

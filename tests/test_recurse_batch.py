@@ -664,6 +664,177 @@ def test_a_tile_has_one_rendezvous_and_another_tile_another():
     assert Rendezvous.at(b, 8) is not made[0]
 
 
+# -- the flight, spanned by the thread that lands it (PR 39) ------------
+
+PHASES = ("land", "board", "launch", "settle")
+
+
+class _Annotation:
+    """A stand-in for jax's TraceAnnotation that keeps a log."""
+
+    log: list = []
+
+    def __init__(self, name):
+        self.name = name
+
+    def __enter__(self):
+        self.log.append(("enter", self.name, threading.get_ident()))
+        return self
+
+    def __exit__(self, *exc):
+        self.log.append(("exit", self.name, threading.get_ident()))
+
+
+@pytest.fixture
+def annotations(monkeypatch):
+    from dgraph_tpu.query import devicecall
+    monkeypatch.setattr(_Annotation, "log", [])
+    monkeypatch.setattr(devicecall, "trace_annotation", _Annotation)
+    return _Annotation.log
+
+
+def _by_lander(log):
+    """The `flight.*` annotations of a log, a list a landing thread in
+    the order the threads began to land."""
+    threads: dict = {}
+    for what, name, tid in log:
+        if name.startswith("flight."):
+            threads.setdefault(tid, []).append((what, name))
+    return list(threads.values())
+
+
+def _ride_in_blocks(meet, chip, items):
+    """Every item from a thread and a `device_call` block of its own."""
+    from dgraph_tpu.query.devicecall import device_call
+
+    def one(x):
+        with device_call("query_device_recurse_total") as dc:
+            try:
+                dc.wait_for(lambda: meet.ride(x, chip.launch, chip.land))
+            except RuntimeError:
+                pass
+
+    threads = [threading.Thread(target=one, args=(x,)) for x in items]
+    for t in threads:
+        t.start()
+    return threads
+
+
+def _flights():
+    return [s for s in tracing.recent_spans()
+            if s["name"] == "device.flight"]
+
+
+def test_two_chained_flights_are_a_span_each_with_their_phases(
+        annotations):
+    meet, chip = Rendezvous(4, family="t"), _Chip()
+    chip.gate.clear()
+    metrics.reset()
+    tracing.clear()
+    first = _ride_in_blocks(meet, chip, ["lead"])
+    while not chip.calls:
+        time.sleep(0.001)
+    rest = _ride_in_blocks(meet, chip, ["a", "b"])
+    _standing(meet, 2)
+    chip.gate.set()
+    for t in first + rest:
+        t.join(30)
+    flights = _flights()
+    assert [f["args"]["lanes"] for f in flights] == [1, 2]
+    assert all(f["args"]["family"] == "t" for f in flights)
+    # the lead's flight launched the waiters' call: it has all four
+    # phases and a turn-round, from land's return to launch's; the
+    # second launched nothing
+    lead, second = (f["args"] for f in flights)
+    assert all(p + "_us" in lead for p in PHASES)
+    assert lead["board_us"] + lead["launch_us"] - 2 \
+        <= lead["turnround_us"] <= lead["board_us"] + lead["launch_us"] + 2
+    assert "launch_us" not in second and "turnround_us" not in second
+    assert (lead["left_waiting"], second["left_waiting"]) == (0, 0)
+    counters = metrics.snapshot()["counters"]
+    assert counters['rendezvous_chained_total{family="t"}'] == 1
+    for phase, flights_with_it in (("land", 2), ("board", 2), ("settle", 2),
+                                   ("launch", 1), ("turnround", 1)):
+        key = f'rendezvous_ns_total{{family="t",phase="{phase}"}}'
+        assert counters[key] >= 0
+        assert abs(counters[key] // 1000
+                   - sum(f["args"].get(phase + "_us", 0) for f in flights)
+                   ) <= flights_with_it
+    # a rider's span names the flight that brought its result; the
+    # flight hangs under the span of the thread that landed it
+    calls = [s for s in tracing.recent_spans() if s["name"] == "device.call"]
+    by_lanes = {f["args"]["lanes"]: f for f in flights}
+    assert sorted(c["args"]["flight"] for c in calls) == sorted(
+        [by_lanes[1]["span_id"]] + 2 * [by_lanes[2]["span_id"]])
+    ids = {c["span_id"]: c for c in calls}
+    for f in flights:
+        assert ids[f["parent_id"]]["args"]["flight"] == f["span_id"]
+    # each phase is an annotation of its own, closed in order, board
+    # and launch nested in the turn-round
+    lead_thread, second_thread = _by_lander(annotations)
+    assert lead_thread == [
+        ("enter", "flight.land"), ("exit", "flight.land"),
+        ("enter", "flight.turnround"),
+        ("enter", "flight.board"), ("exit", "flight.board"),
+        ("enter", "flight.launch"), ("exit", "flight.launch"),
+        ("exit", "flight.turnround"),
+        ("enter", "flight.settle"), ("exit", "flight.settle")]
+    assert second_thread == [
+        e for e in lead_thread if e[1] != "flight.launch"]
+
+
+def test_riders_the_next_call_cannot_seat_are_left_waiting():
+    meet, chip = Rendezvous(2, family="t"), _Chip()
+    chip.gate.clear()
+    tracing.clear()
+    first, _ = _ride_all(meet, chip, ["lead"])
+    while not chip.calls:
+        time.sleep(0.001)
+    threads, _ = _ride_all(meet, chip, ["a", "b", "c"])
+    _standing(meet, 3)
+    chip.gate.set()
+    for t in first + threads:
+        t.join(30)
+    assert [(f["args"]["lanes"], f["args"]["left_waiting"])
+            for f in _flights()] == [(1, 1), (2, 0), (1, 0)]
+
+
+@pytest.mark.parametrize("where", ("launch", "land"))
+def test_a_flight_that_raises_closes_its_span_and_annotations(
+        where, annotations):
+    from dgraph_tpu.query import devicecall
+
+    meet, chip = Rendezvous(2, family="t"), _Chip()
+    chip.gate.clear()
+    setattr(chip, "fail_" + where, (1, RuntimeError("the device said no")))
+    metrics.reset()
+    tracing.clear()
+    first = _ride_in_blocks(meet, chip, ["lead"])
+    while not chip.calls:
+        time.sleep(0.001)
+    rest = _ride_in_blocks(meet, chip, ["a", "b"])
+    _standing(meet, 2)
+    chip.gate.set()
+    for t in first + rest:
+        t.join(30)
+        assert not t.is_alive()
+    # the lead's flight is whole whichever way the next call went; one
+    # that failed at its launch never flew, one that failed at its
+    # landing has a span with its phases all the same
+    flights = _flights()
+    assert len(flights) == (1 if where == "launch" else 2)
+    assert all(p + "_us" in flights[0]["args"] for p in PHASES)
+    assert all(p + "_us" in flights[-1]["args"]
+               for p in ("land", "board", "settle"))
+    for seen in _by_lander(annotations):
+        assert sorted(n for what, n in seen if what == "enter") \
+            == sorted(n for what, n in seen if what == "exit")
+    assert ("exit", "flight.launch") in _by_lander(annotations)[0]
+    assert metrics.snapshot()["counters"][
+        'rendezvous_chained_total{family="t"}'] == 1
+    assert devicecall._inflight == 0
+
+
 # -- the executor's site -----------------------------------------------
 
 
@@ -720,6 +891,27 @@ def test_a_lone_request_is_one_call_of_one_lane_and_never_waits(worlds):
         assert spans[name]["batch_wait_us"] == 0
     assert "recurse_batch_total 1" in metrics.render_prometheus()
     assert _data(dev, q) == _data(host, q)
+
+
+def test_a_served_traversal_is_a_flight_of_the_recurse_family(worlds):
+    facts, dev, host, _ = worlds[10, 7]
+    _tile(dev)
+    q = _q(KHOP, [graph500.FIRST_UID + 9], 7)
+    metrics.reset()
+    tracing.clear()
+    sl = json.loads(dev.query_json(q))["extensions"]["server_latency"]
+    flight, = _flights()
+    call, = [s for s in tracing.recent_spans() if s["name"] == "device.call"]
+    assert (flight["args"]["family"], flight["args"]["lanes"]) \
+        == ("recurse", 1)
+    assert call["args"]["flight"] == flight["span_id"]
+    assert flight["parent_id"] == call["span_id"]
+    # it found the chip free: no stand, and nobody to launch for
+    assert sl["device_queue_ns"] == 0 < sl["device_wait_ns"]
+    counters = metrics.snapshot()["counters"]
+    assert counters['rendezvous_ns_total{family="recurse",phase="land"}'] > 0
+    assert 'rendezvous_chained_total{family="recurse"}' not in counters
+    assert counters['device_call_queue_ns_total{family="recurse"}'] == 0
 
 
 STREAMED = "recurse_hub_tiles_streamed_total"

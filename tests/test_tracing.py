@@ -229,7 +229,7 @@ def test_server_latency_and_trace_over_http():
         assert set(sl) == {"parsing_ns", "processing_ns",
                            "encoding_ns", "total_ns", "device_calls",
                            "device_enqueue_ns", "device_wait_ns",
-                           "device_fetch_ns"}
+                           "device_queue_ns", "device_fetch_ns"}
         assert all(v >= 0 for v in sl.values())
         assert sl["total_ns"] >= (sl["parsing_ns"]
                                   + sl["processing_ns"]
@@ -395,3 +395,56 @@ def test_disabled_span_builds_no_record(monkeypatch):
     with tracing.span("y"):
         pass
     assert len(minted) == 1 and len(tracing.recent_spans()) == 1
+
+
+# ------------------------------- the collector on the profiler's clock
+
+
+class _Annotation:
+    log: list = []
+
+    def __init__(self, name):
+        self.name = name
+
+    def __enter__(self):
+        self.log.append(("enter", self.name))
+
+    def __exit__(self, *exc):
+        self.log.append(("exit", self.name))
+
+
+def _collect_watched(monkeypatch, generation):
+    import gc
+
+    from dgraph_tpu.utils import metrics
+
+    monkeypatch.setattr(_Annotation, "log", [])
+    monkeypatch.setattr(tracing, "trace_annotation", _Annotation)
+    was_enabled = gc.isenabled()
+    gc.disable()            # no collection but the one asked for
+    metrics.watch_gc()
+    try:
+        gc.collect(generation)
+    finally:
+        gc.callbacks.remove(metrics._on_gc)
+        if was_enabled:
+            gc.enable()
+    return _Annotation.log
+
+
+def test_a_full_collection_is_one_gc_pause_annotation(monkeypatch):
+    assert _collect_watched(monkeypatch, 2) == [
+        ("enter", "gc.pause"), ("exit", "gc.pause")]
+
+
+def test_a_young_collection_opens_no_annotation(monkeypatch):
+    assert _collect_watched(monkeypatch, 0) == []
+
+
+def test_an_open_span_says_its_id():
+    tracing.clear()
+    sp = tracing.span("query")
+    with sp:
+        sid = sp.span_id
+        assert tracing.current()[1] == sid
+    assert tracing.recent_spans()[-1]["span_id"] == sid
