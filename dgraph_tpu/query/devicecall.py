@@ -20,7 +20,9 @@ Three phases on `time.perf_counter_ns`:
 and, INSIDE `wait`, for a block that rode a `Rendezvous`' call:
 
   queue    joining -> its call's launch: the stand behind the call in
-           flight (`Ride.waited_ns`; 0 where it found the chip free).
+           flight (`Ride.waited_ns`; 0 where it found the chip free or
+           filled a call that went behind the one in flight: the rest
+           of that call is then in `wait` beyond `queue`).
 
 Every mark is a wall-clock read: the thread's CPU clock
 (`time.thread_time_ns`) is a system call that holds the interpreter,
@@ -210,25 +212,28 @@ class device_call:
 
 class _Flight:
     """One call of a Rendezvous: its riders in lane order, what
-    `launch` handed back, and who blocks for the result."""
+    `launch` handed back, who blocks for the result, and whether it
+    went onto the device's queue behind a call still in flight."""
 
     __slots__ = ("riders", "handle", "launched", "lander", "t_launch",
-                 "span_id")
+                 "span_id", "ahead")
 
-    def __init__(self, riders: list):
+    def __init__(self, riders: list, ahead: bool):
         self.riders = riders
         self.handle = None
         self.launched = False
         self.lander = None
         self.t_launch = 0
         self.span_id = ""       # of its `device.flight` span
+        self.ahead = ahead
 
 
 class Ride:
     """A caller's seat: `result` is its own element of what `land`
     returned, `lane` its place in the call, `lanes` how many rode the
     call, `waited_ns` how long it stood before its call was launched
-    (0 for a caller that found the chip free)."""
+    (0 for a caller that found the chip free, and for the one whose
+    arrival filled a call)."""
 
     __slots__ = ("item", "flight", "done", "result", "error", "lane",
                  "t_join", "stood")
@@ -258,23 +263,41 @@ class Rendezvous:
 
     A caller that finds no call of the tile in flight launches at
     once, with whoever waits with it: alone, if alone, and then it
-    never waits. One that finds a call in flight waits; when that
-    call's result is in, the thread that took it launches ALL the
-    waiters (up to `capacity`; the rest form the next call) before it
-    hands anything out, so the chip never stands idle for a thread to
-    wake, and one of the new call's riders then blocks for its
-    result. A call is closed by who is waiting when the chip comes
-    free, never by a timer: there is no window and no knob.
+    never waits. One that finds a call in flight waits. A call is
+    closed by who waits when the chip comes free, or at once when
+    full, never by a timer: there is no window and no knob.
+
+    *When the chip comes free:* the thread that took a call's result
+    launches ALL the waiters (up to `capacity`; the rest form the next
+    call) before it hands anything out, so the chip never stands idle
+    for a thread to wake, and one of the new call's riders then blocks
+    for its result.
+
+    *At once when full:* a call of `capacity` riders can gain none by
+    waiting, so while a call is in flight and none stands behind it,
+    the rider whose arrival fills the waiters' call launches it there
+    and then, onto the device's queue BEHIND the call in flight, and
+    blocks for its result as a lone caller does. The device runs its
+    programs in order: the second starts when the first ends, with no
+    host thread in between. At most ONE call stands behind the one in
+    flight (the chip is never idle with one behind, and a deeper queue
+    would only lengthen what a cancelled rider's dropped lane costs);
+    a landing that moves it up puts a full set of waiters, if there is
+    one, behind it in turn, and boards nobody otherwise. Launches
+    never overlap (a call goes behind one that `launch` has returned
+    for), so the device's order is the order of boarding, on every
+    chip of a mesh.
 
     `launch(items) -> handle` puts one call for `items` (the riders'
     own, in lane order) on the device and returns without waiting;
     `land(handle, n) -> [n results]` returns once the device has
     produced them. Every caller passes the same two. One that raises
     fails the riders of ITS call with that error, each in its own
-    thread, and frees the chip for the waiters; none waits for ever.
-    A rider whose context is cancelled or past its deadline while it
-    waits leaves with its own error; its lane, if its call is
-    launched already, is computed and dropped.
+    thread, and takes that call off the chip's queue: the call ahead
+    of it or behind it, its lander and the waiters are untouched, and
+    none waits for ever. A rider whose context is cancelled or past
+    its deadline while it waits leaves with its own error; its lane,
+    if its call is boarded already, is computed and dropped.
 
     Kept on the tile it serves (`Rendezvous.at`), so requests meet
     only over the very object their own read_ts resolved to: another
@@ -282,21 +305,27 @@ class Rendezvous:
     rendezvous.
 
     The thread that lands a call spans it: one `device.flight` span a
-    call, with `family`, `lanes`, `left_waiting` (riders the next call
-    could not seat) and the four phases of what that thread does, each
+    call, with `family`, `lanes`, `ahead` (it was launched behind a
+    call still in flight: its `land` then includes the rest of the
+    call ahead), `left_waiting` (riders still standing after this
+    landing's boarding) and the phases of what that thread does, each
     an attribute and a profiler annotation of its own: `flight.land`
     (blocked for the device, then the fetch), `flight.board`,
-    `flight.launch` (the NEXT call's `launch`), `flight.settle`.
-    `flight.turnround` (`turnround_us`) runs from `land`'s return to
-    the next call's `launch` returning: an upper bound on the host's
-    share of the gap the chip stands idle in between two calls (the
-    device starts the call before `launch` returns); a call that
-    nobody waited behind has none. The span is a child of the lander's own
-    `device.call`; every rider's `device.call` names it (`flight`).
-    After the next call is launched the phases go to
-    `rendezvous_ns_total{family,phase}`, and
-    `rendezvous_chained_total{family}` counts the calls a landing
-    thread launched, the only ones that have a turn-round."""
+    `flight.launch` (the launch of the call this landing boarded, if
+    any), `flight.settle`. `flight.turnround` (`turnround_us`) is the
+    host's share of the gap between this call and its successor: from
+    `land`'s return to the next call's `launch` returning where this
+    thread launched it onto the free chip (an upper bound: the device
+    starts the call before `launch` returns), 0 where the successor
+    was on the device's queue already; a call without a successor has
+    none. The span is a child of the lander's own `device.call`; every
+    rider's `device.call` names it (`flight`). After the boarded call
+    is launched the phases go to `rendezvous_ns_total{family,phase}`,
+    and `rendezvous_chained_total{family}` counts the calls that had a
+    successor at their landing, launched already or by the landing
+    thread: the ones that have a turn-round.
+    `rendezvous_ahead_total{family}` counts the calls launched behind
+    one still in flight."""
 
     _POLL_S = 0.05      # how often a waiter looks at its context
     _make = threading.Lock()
@@ -305,7 +334,9 @@ class Rendezvous:
         self.capacity = capacity
         self.family = family
         self._cond = threading.Condition()
+        # the device's queue of this tile's calls, in its order
         self._flight: _Flight | None = None     # the call on the chip
+        self._behind: _Flight | None = None     # the one queued behind it
         self._waiting: list[Ride] = []
 
     @classmethod
@@ -321,24 +352,44 @@ class Rendezvous:
         return meet
 
     def _board(self, first: Ride | None = None) -> _Flight | None:
-        """(under the lock) The waiters' call, oldest first, `first`
-        among them; None where nobody waits."""
+        """(under the lock) The call the waiters make NOW, oldest
+        first, `first` among them: whoever waits where the chip is
+        free; a full call and no less where one call is on it,
+        launched, and none stands behind. None otherwise."""
+        ahead = self._flight
+        if ahead is not None and (
+                self._behind is not None or not ahead.launched
+                or len(self._waiting) < self.capacity):
+            return None
         riders = self._waiting[:self.capacity]
+        if not riders:
+            return None
         if first is not None and first not in riders:
             riders[-1] = first
-        if not riders:
-            self._flight = None
-            return None
         self._waiting = [r for r in self._waiting if r not in riders]
-        flight = self._flight = _Flight(riders)
+        flight = _Flight(riders, ahead is not None)
+        if ahead is None:
+            self._flight = flight
+        else:
+            self._behind = flight
         for lane, r in enumerate(riders):
             r.flight, r.lane = flight, lane
         return flight
 
+    def _leave(self, flight: _Flight) -> _Flight | None:
+        """(under the lock) `flight` is off the device's queue; -> the
+        call that stood behind it and moves up, if any."""
+        if self._behind is flight:
+            self._behind = None
+        elif self._flight is flight:
+            self._flight, self._behind = self._behind, None
+            return self._flight
+        return None
+
     def _launch(self, flight: _Flight, launch):
         """(outside the lock) Put `flight` on the device. Where that
-        raises, its riders fail with the error, the chip is free
-        again, and the error is handed back."""
+        raises, its riders fail with the error, the call leaves the
+        queue, and the error is handed back."""
         flight.t_launch = time.perf_counter_ns()
         try:
             flight.handle = launch([r.item for r in flight.riders])
@@ -346,10 +397,14 @@ class Rendezvous:
             # raise; an interrupt is raised again by ride()
             with self._cond:
                 self._settle(flight, None, e)
-                self._flight = None
+                self._leave(flight)
                 self._cond.notify_all()
             return e
         _dispatched(+1)
+        # the series is there at 0: a reader tells "none went ahead"
+        # from "not served"
+        inc_counter("rendezvous_ahead_total", int(flight.ahead),
+                    labels={"family": self.family})
         flight.launched = True
         return None
 
@@ -372,12 +427,13 @@ class Rendezvous:
             self._waiting.append(me)
             while not me.done:
                 f = me.flight
-                if f is None and self._flight is None:
-                    mine = self._board(me)      # the chip is free
-                    mine.lander = me
-                    break
-                if f is not None and f.launched and f.lander is None:
+                if f is None:
+                    # the chip is free, or my arrival filled the call
+                    # that goes behind the one in flight
+                    mine = self._board(me)
+                elif f.launched and f.lander is None:
                     mine = f
+                if mine is not None:
                     mine.lander = me
                     break
                 me.stood = True
@@ -405,12 +461,13 @@ class Rendezvous:
         return me
 
     def _fly(self, mine: _Flight, launch, land) -> None:
-        """(the landing thread, outside the lock) Land `mine`, put the
-        waiters' call on the chip, then hand `mine`'s results out."""
+        """(the landing thread, outside the lock) Land `mine`; put the
+        call the waiters make now, if any, on the device; then hand
+        `mine`'s results out."""
         cond = self._cond
         family = self.family
         flight_span = span("device.flight", family=family,
-                           lanes=len(mine.riders))
+                           lanes=len(mine.riders), ahead=mine.ahead)
         with flight_span as a:
             mine.span_id = flight_span.span_id
             t0 = time.perf_counter_ns()
@@ -422,11 +479,14 @@ class Rendezvous:
                     results, error = None, e
                 finally:
                     _dispatched(-1)
-            # the chip is free: the waiters' call goes on it before
+            # the call behind, if any, has the chip already; the
+            # waiters' call goes on it, or behind that one, before
             # anything is handed out
             t1 = time.perf_counter_ns()
             with _annotation("flight.turnround"):
                 with _annotation("flight.board"), cond:
+                    succ = self._leave(mine)
+                    queued = succ is not None and succ.launched
                     nxt = self._board()
                     left = len(self._waiting)
                 t2 = time.perf_counter_ns()
@@ -442,7 +502,11 @@ class Rendezvous:
             phases = [("land", t1 - t0), ("board", t2 - t1),
                       ("settle", t4 - t3)]
             if nxt is not None:
-                phases += [("launch", t3 - t2), ("turnround", t3 - t1)]
+                phases.append(("launch", t3 - t2))
+            if queued or nxt is not None:
+                # the host's share of the gap to the successor: none
+                # where the device had it queued
+                phases.append(("turnround", 0 if queued else t3 - t1))
                 inc_counter("rendezvous_chained_total",
                             labels={"family": family})
             a["left_waiting"] = left

@@ -64,7 +64,8 @@ REGISTERED = (
     # stand at its rendezvous (inside `wait`, so a series of its own),
     # the calls a plain dispatch found ahead of it on the chip, those
     # on it now; and a Rendezvous' flights: the landing thread's
-    # phases, and the calls it launched itself
+    # phases, the calls that had a successor at their landing, and
+    # those launched behind a call still in flight
     "device_call_ahead_total",
     "device_call_ns_total",
     "device_call_queue_ns_total",
@@ -150,6 +151,7 @@ REGISTERED = (
     "recurse_sharded_lanes_total",
     "recurse_sharded_total",
     "recurse_tier_total",
+    "rendezvous_ahead_total",
     "rendezvous_chained_total",
     "rendezvous_ns_total",
     "similar_exact_fallback_total",

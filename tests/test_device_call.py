@@ -324,15 +324,21 @@ def test_device_memory_peak_is_read_from_watched_devices():
 
 def _held_chip():
     """`launch`, `land` of a chip that keeps a call until `gate` is
-    set, and the list of the calls launched."""
-    gate, calls = threading.Event(), []
+    set and runs its calls in order (one behind another takes 10 ms
+    more), and the list of the calls launched."""
+    gate, calls, over = threading.Event(), [], []
 
     def launch(items):
         calls.append(list(items))
+        over.append(threading.Event())
         return len(calls) - 1
 
     def land(handle, n):
         gate.wait(30)
+        if handle and not over[handle - 1].is_set():
+            over[handle - 1].wait(30)
+            time.sleep(0.01)
+        over[handle].set()
         return [("answer", x) for x in calls[handle]]
 
     return gate, calls, launch, land
@@ -408,6 +414,90 @@ def test_a_rider_counts_no_calls_ahead_and_a_flight_is_one(lead_and_rider):
                if s["name"] == "device.call")
     metrics.collect_runtime_gauges()    # the gauge is read at a scrape
     assert metrics.gauges_snapshot()["device_calls_inflight"] == 0
+
+
+@pytest.fixture
+def lead_and_a_full_call():
+    """One request that finds the chip free, then two that fill a call
+    of two while the first's is still on it (PR 40) -> ({item:
+    (Latency, Ride)}, moved counters, the spans by name)."""
+    from dgraph_tpu.query.devicecall import Rendezvous
+
+    meet = Rendezvous(2, family="t")
+    gate, calls, launch, land = _held_chip()
+    out: dict = {}
+    before = metrics.counters_snapshot()
+    tracing.clear()
+    threads = [threading.Thread(target=_ride_in_a_block,
+                                args=(meet, launch, land, x, out))
+               for x in ("lead", "early", "filler")]
+    threads[0].start()
+    while not calls:
+        time.sleep(0.001)
+    threads[1].start()
+    deadline = time.monotonic() + 10
+    while len(meet._waiting) != 1 and time.monotonic() < deadline:
+        time.sleep(0.001)
+    time.sleep(0.02)
+    threads[2].start()
+    while len(calls) < 2 and time.monotonic() < deadline:
+        time.sleep(0.001)
+    # on the device's queue with the lead's call still on the chip
+    assert calls == [["lead"], ["early", "filler"]]
+    assert threads[0].is_alive()
+    gate.set()
+    for t in threads:
+        t.join(30)
+        assert not t.is_alive()
+    return out, metrics.counters_delta(before), tracing.recent_spans()
+
+
+def test_a_call_launched_behind_another_is_counted_and_spanned(
+        lead_and_a_full_call):
+    from dgraph_tpu.query import devicecall
+
+    out, moved, spans = lead_and_a_full_call
+    assert "rendezvous_ahead_total" in metrics.REGISTERED
+    # two launches, one of them behind a call in flight
+    assert moved['rendezvous_ahead_total{family="t"}'] == 1
+    flights = {f["args"]["lanes"]: f for f in spans
+               if f["name"] == "device.flight"}
+    assert flights[1]["args"]["ahead"] is False
+    assert flights[2]["args"]["ahead"] is True
+    # the one whose arrival filled the call launched and landed it: the
+    # flight hangs under ITS block; it never stood, the other stood
+    # until that launch and no longer
+    calls = {c["span_id"]: c for c in spans if c["name"] == "device.call"}
+    assert calls[flights[2]["parent_id"]]["args"]["flight"] \
+        == flights[2]["span_id"]
+    assert out["filler"][1].waited_ns == 0 == out["filler"][0].device_queue_ns
+    assert 0.02e9 * 0.9 <= out["early"][0].device_queue_ns \
+        == out["early"][1].waited_ns
+    assert out["early"][1].flight is out["filler"][1].flight
+    assert (out["early"][1].lane, out["filler"][1].lane) == (0, 1)
+    # a call is on the count of those on the device once, whoever
+    # launched it
+    assert devicecall._inflight == 0
+
+
+def test_a_landing_that_finds_its_successor_launched_has_no_turnround(
+        lead_and_a_full_call):
+    _, moved, spans = lead_and_a_full_call
+    lead, second = sorted(
+        (f["args"] for f in spans if f["name"] == "device.flight"),
+        key=lambda a: a["lanes"])
+    # the lead's call had a successor, on the device already: one
+    # chained call, 0 ns of the host between the two, nothing launched
+    assert moved['rendezvous_chained_total{family="t"}'] == 1
+    turn = 'rendezvous_ns_total{family="t",phase="turnround"}'
+    assert turn in metrics.counters_snapshot() and turn not in moved
+    assert lead["turnround_us"] == 0 and "launch_us" not in lead
+    assert not any('phase="launch"' in k for k in moved
+                   if k.startswith("rendezvous_ns_total"))
+    # the call behind had none
+    assert "turnround_us" not in second
+    assert all(p + "_us" in f for f in (lead, second)
+               for p in ("land", "board", "settle"))
 
 
 def test_a_lone_dispatch_finds_nothing_ahead_of_it():

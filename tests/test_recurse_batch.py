@@ -431,31 +431,55 @@ def test_a_call_answers_the_same_with_and_without_the_settled_tiles(
 
 
 class _Chip:
-    """A device that runs one call at a time, as slowly as told."""
+    """A device that runs its calls in the order they were launched,
+    as slowly as told: `gate` holds every call, `hold(i)` call `i`
+    until `release(i)`."""
 
     def __init__(self):
         self.calls = []
+        self.launched_by = []   # thread ids, a call each
+        self.landed = []        # handles, in the order `land` returned
+        self.on_device = self.worst = 0
+        self.lock = threading.Lock()
         self.gate = threading.Event()
         self.gate.set()
+        self.held = {}
         self.fail_launch = self.fail_land = None
+
+    def hold(self, i):
+        self.held[i] = threading.Event()
+
+    def release(self, i):
+        self.held[i].set()
 
     def launch(self, items):
         if self.fail_launch and len(self.calls) == self.fail_launch[0]:
             self.calls.append(None)
+            self.launched_by.append(threading.get_ident())
             raise self.fail_launch[1]
-        self.calls.append(list(items))
-        return len(self.calls) - 1
+        with self.lock:
+            self.calls.append(list(items))
+            self.launched_by.append(threading.get_ident())
+            self.on_device += 1
+            self.worst = max(self.worst, self.on_device)
+            return len(self.calls) - 1
 
     def land(self, handle, n):
         self.gate.wait(30)
+        if handle in self.held:
+            self.held[handle].wait(30)
+        with self.lock:
+            self.on_device -= 1
+            self.landed.append(handle)
         if self.fail_land and handle == self.fail_land[0]:
             raise self.fail_land[1]
         return [("answer", x) for x in self.calls[handle]]
 
 
-def _ride_all(meet, chip, items, ctxs=None, stagger=None):
-    """Every item from a thread of its own -> {item: Ride or error}."""
-    out = {}
+def _ride_all(meet, chip, items, ctxs=None, stagger=None, out=None):
+    """Every item from a thread of its own -> {item: Ride or error}
+    (`out`, where the caller keeps one for several sets)."""
+    out = {} if out is None else out
 
     def one(x):
         try:
@@ -472,11 +496,28 @@ def _ride_all(meet, chip, items, ctxs=None, stagger=None):
     return threads, out
 
 
-def _standing(meet, n):
-    deadline = time.monotonic() + 10
-    while len(meet._waiting) != n and time.monotonic() < deadline:
+def _until(what, seconds=10):
+    """Poll until `what()` holds, or give up after `seconds`."""
+    deadline = time.monotonic() + seconds
+    while not what() and time.monotonic() < deadline:
         time.sleep(0.001)
+
+
+def _standing(meet, n):
+    _until(lambda: len(meet._waiting) == n)
     assert len(meet._waiting) == n
+
+
+def _launched(chip, n):
+    _until(lambda: len(chip.calls) >= n)
+    assert len(chip.calls) == n
+
+
+def _lead(meet, chip):
+    """One caller that finds the chip free, its call held on it."""
+    first, got = _ride_all(meet, chip, ["lead"])
+    _launched(chip, 1)
+    return first, got
 
 
 def test_a_lone_caller_launches_at_once_and_never_waits():
@@ -492,25 +533,164 @@ def test_a_lone_caller_launches_at_once_and_never_waits():
 def test_waiters_ride_the_next_call_and_the_overflow_the_one_after():
     meet, chip = Rendezvous(4), _Chip()
     chip.gate.clear()
-    first, got1 = _ride_all(meet, chip, ["lead"])
-    while not chip.calls:
-        time.sleep(0.001)
+    first, got1 = _lead(meet, chip)
     rest = [f"w{i}" for i in range(6)]
     threads, got = _ride_all(meet, chip, rest)
-    _standing(meet, 6)
+    # capacity 4: four of the six are a full call, on the device behind
+    # the lead's already; the two others stand until a call lands
+    _launched(chip, 2)
+    _standing(meet, 2)
     chip.gate.set()
     for t in first + threads:
         t.join(30)
-    # capacity 4: the six waiters are two calls, oldest first, and
-    # nobody is lost or answered from another's result
+    # two calls, oldest first, and nobody is lost or answered from
+    # another's result
     assert [len(c) for c in chip.calls] == [1, 4, 2]
     assert sorted(chip.calls[1] + chip.calls[2]) == rest
     for x in rest:
         assert got[x].result == ("answer", x)
         assert got[x].lanes == (4 if x in chip.calls[1] else 2)
-        assert got[x].waited_ns > 0
+    assert sum(got[x].waited_ns > 0 for x in rest) >= 5
     assert got1["lead"].lanes == 1
-    assert meet._flight is None and not meet._waiting
+    assert meet._flight is None and meet._behind is None
+    assert not meet._waiting
+
+
+# -- a full call does not wait for the chip (PR 40) -----------------------
+
+
+def test_a_full_call_goes_behind_the_call_in_flight_at_once():
+    """Launched by the rider whose arrival filled it, while the call
+    ahead is still on the chip and before anything lands."""
+    meet, chip = Rendezvous(4), _Chip()
+    chip.gate.clear()
+    first, got1 = _lead(meet, chip)
+    early, got = _ride_all(meet, chip, ["a", "b", "c"])
+    _standing(meet, 3)
+    time.sleep(0.02)
+    assert len(chip.calls) == 1         # three of four: they stand
+    last, _ = _ride_all(meet, chip, ["d"], out=got)
+    _launched(chip, 2)
+    assert chip.landed == [] and first[0].is_alive()
+    assert chip.launched_by[1] == last[0].ident
+    assert meet._flight.riders[0].item == "lead"
+    assert sorted(r.item for r in meet._behind.riders) == list("abcd")
+    assert meet._behind.ahead and not meet._flight.ahead
+    assert not meet._waiting
+    chip.gate.set()
+    for t in first + early + last:
+        t.join(30)
+        assert not t.is_alive()
+    assert chip.calls[1][:3] == sorted(chip.calls[1][:3])   # lane order
+    for x in "abcd":
+        assert got[x].result == ("answer", x)
+        assert got[x].lanes == 4
+        assert got[x].lane == chip.calls[1].index(x)
+    # the one that filled the call never stood; the others stood from
+    # joining to ITS launch, not to the lead's landing
+    assert got["d"].waited_ns == 0
+    assert all(0.02e9 * 0.9 <= got[x].waited_ns for x in "abc")
+    assert got1["lead"].result == ("answer", "lead")
+    assert meet._flight is None and meet._behind is None
+
+
+def test_at_most_two_calls_are_on_the_device_and_they_land_in_order():
+    meet, chip = Rendezvous(2), _Chip()
+    for i in range(4):
+        chip.hold(i)
+    first, got = _lead(meet, chip)
+    threads = []
+    for pair, stand in (("ab", 0), ("cd", 2), ("ef", 4)):
+        threads += _ride_all(meet, chip, list(pair), out=got)[0]
+        _standing(meet, stand)
+    # one call behind the one in flight and no more: two full sets
+    # stand, launched by nobody
+    time.sleep(0.02)
+    assert [len(c) for c in chip.calls] == [1, 2]
+    for i in range(4):
+        _launched(chip, min(i + 2, 4))
+        assert chip.on_device <= 2
+        chip.release(i)
+        _until(lambda: len(chip.landed) > i)
+    for t in first + threads:
+        t.join(30)
+        assert not t.is_alive()
+    assert chip.landed == [0, 1, 2, 3] and chip.worst == 2
+    assert sorted(map(sorted, chip.calls[1:])) \
+        == [["a", "b"], ["c", "d"], ["e", "f"]]
+    for x in "abcdef":
+        assert got[x].result == ("answer", x) and got[x].lanes == 2
+    assert meet._flight is None and meet._behind is None
+
+
+def test_fewer_than_a_full_call_waits_for_the_landing():
+    """Seven of eight behind a call in flight: the parent's path, call
+    for call: launched by the thread that lands the call ahead."""
+    meet, chip = Rendezvous(8, family="t"), _Chip()
+    chip.gate.clear()
+    metrics.reset()
+    tracing.clear()
+    first, _ = _lead(meet, chip)
+    rest = [f"w{i}" for i in range(7)]
+    threads, got = _ride_all(meet, chip, rest)
+    _standing(meet, 7)
+    time.sleep(0.05)
+    assert len(chip.calls) == 1 and meet._behind is None
+    chip.gate.set()
+    for t in first + threads:
+        t.join(30)
+        assert not t.is_alive()
+    assert [len(c) for c in chip.calls] == [1, 7]
+    assert chip.launched_by[1] == first[0].ident
+    assert all(got[x].result == ("answer", x) for x in rest)
+    lead, second = (f["args"] for f in _flights())
+    assert (lead["ahead"], second["ahead"]) == (False, False)
+    assert lead["turnround_us"] >= lead["launch_us"] >= 0
+    assert "turnround_us" not in second
+    counters = metrics.snapshot()["counters"]
+    assert counters['rendezvous_chained_total{family="t"}'] == 1
+    # the series is served, at 0: nothing went ahead
+    assert counters['rendezvous_ahead_total{family="t"}'] == 0
+
+
+def test_the_third_set_goes_behind_the_second_at_the_first_landing():
+    """More than 2 x capacity callers: when the queued call moves up,
+    the landing thread puts a full set of waiters behind it."""
+    meet, chip = Rendezvous(2, family="t"), _Chip()
+    for i in range(3):
+        chip.hold(i)
+    metrics.reset()
+    tracing.clear()
+    first, got = _lead(meet, chip)
+    second, _ = _ride_all(meet, chip, ["a", "b"], out=got)
+    _launched(chip, 2)
+    third, _ = _ride_all(meet, chip, ["c", "d"], out=got)
+    _standing(meet, 2)
+    chip.release(0)
+    _launched(chip, 3)
+    first[0].join(30)
+    # launched by the lead's thread as it landed, behind ("a", "b"),
+    # which is still on the chip
+    assert chip.launched_by[2] == first[0].ident
+    assert chip.landed == [0] and sorted(chip.calls[2]) == ["c", "d"]
+    assert sorted(r.item for r in meet._flight.riders) == ["a", "b"]
+    assert sorted(r.item for r in meet._behind.riders) == ["c", "d"]
+    chip.release(1)
+    chip.release(2)
+    for t in second + third:
+        t.join(30)
+        assert not t.is_alive()
+    for x in "abcd":
+        assert got[x].result == ("answer", x) and got[x].lanes == 2
+    flights = {f["args"]["lanes"]: f["args"] for f in _flights()[:1]}
+    # the lead's landing found its successor launched: a turn-round of
+    # nothing, though it launched a call (not its successor) itself
+    assert flights[1]["turnround_us"] == 0 and "launch_us" in flights[1]
+    counters = metrics.snapshot()["counters"]
+    assert counters['rendezvous_ahead_total{family="t"}'] == 2
+    assert counters['rendezvous_chained_total{family="t"}'] == 2
+    assert counters[
+        'rendezvous_ns_total{family="t",phase="turnround"}'] == 0
 
 
 def test_the_next_call_is_on_the_chip_before_results_are_handed_out():
@@ -546,13 +726,13 @@ def test_the_next_call_is_on_the_chip_before_results_are_handed_out():
 
 @pytest.mark.parametrize("where", ("launch", "land"))
 def test_a_call_that_raises_fails_its_riders_and_frees_the_chip(where):
-    meet, chip = Rendezvous(2), _Chip()
+    """Fewer than a full call behind the lead: the landing thread
+    launches them, and the call that fails is theirs alone."""
+    meet, chip = Rendezvous(4), _Chip()
     chip.gate.clear()
     boom = RuntimeError("the device said no")
     setattr(chip, "fail_" + where, (1, boom))
-    first, got1 = _ride_all(meet, chip, ["lead"])
-    while not chip.calls:
-        time.sleep(0.001)
+    first, got1 = _lead(meet, chip)
     threads, got = _ride_all(meet, chip, ["a", "b", "c"])
     _standing(meet, 3)
     chip.gate.set()
@@ -560,23 +740,55 @@ def test_a_call_that_raises_fails_its_riders_and_frees_the_chip(where):
         t.join(30)
         assert not t.is_alive()
     assert got1["lead"].result == ("answer", "lead")
-    failed = [x for x in "abc" if got[x] is boom]
-    answered = [x for x in "abc" if not isinstance(got[x], Exception)]
-    # the call of two failed whole, the third rode the next and none
-    # waits for ever
-    assert len(failed) == 2 and len(answered) == 1
-    assert got[answered[0]].result == ("answer", answered[0])
+    assert all(got[x] is boom for x in "abc")
     assert meet._flight is None and not meet._waiting
     # and the rendezvous serves on
+    assert meet.ride("z", chip.launch, chip.land).result == ("answer", "z")
+
+
+@pytest.mark.parametrize("where", (
+    "launch-behind", "land-behind", "land-ahead"))
+def test_of_two_calls_on_the_device_the_one_that_raises_fails_alone(where):
+    meet, chip = Rendezvous(2), _Chip()
+    chip.gate.clear()
+    boom = RuntimeError("the device said no")
+    what, _, which = where.partition("-")
+    setattr(chip, "fail_" + what, (1 if which == "behind" else 0, boom))
+    first, got = _lead(meet, chip)
+    pair, _ = _ride_all(meet, chip, ["a", "b"], out=got)
+    _launched(chip, 2)
+    if where == "launch-behind":
+        # its riders have their error while the lead's call, untouched,
+        # is still on the chip with its lander blocked for it
+        for t in pair:
+            t.join(30)
+            assert not t.is_alive()
+        assert first[0].is_alive() and chip.landed == []
+        assert meet._flight.riders[0].item == "lead"
+        assert meet._behind is None
+    late, _ = _ride_all(meet, chip, ["c"], out=got)
+    _standing(meet, 1)
+    chip.gate.set()
+    for t in first + pair + late:
+        t.join(30)
+        assert not t.is_alive()
+    failed = ["lead"] if which == "ahead" else ["a", "b"]
+    for x in ("lead", "a", "b", "c"):
+        if x in failed:
+            assert got[x] is boom
+        else:
+            assert got[x].result == ("answer", x)
+    # the one that stood rode a call of its own after a landing
+    assert chip.calls[2] == ["c"]
+    assert meet._flight is None and meet._behind is None
+    assert not meet._waiting
     assert meet.ride("z", chip.launch, chip.land).result == ("answer", "z")
 
 
 def test_a_rider_past_its_deadline_leaves_alone():
     meet, chip = Rendezvous(8), _Chip()
     chip.gate.clear()
-    first, _ = _ride_all(meet, chip, ["lead"])
-    while not chip.calls:
-        time.sleep(0.001)
+    first, _ = _lead(meet, chip)
     gone = RequestContext.background()
     threads, got = _ride_all(meet, chip, ["a", "gone", "b"], {"gone": gone})
     _standing(meet, 3)
@@ -597,14 +809,42 @@ def test_a_rider_past_its_deadline_leaves_alone():
     assert sorted(chip.calls[1]) == ["a", "b"]
 
 
+def test_a_rider_past_its_deadline_leaves_a_call_behind():
+    """Its call is on the device's queue already: it leaves with its
+    own error, its lane is computed and dropped."""
+    meet, chip = Rendezvous(2), _Chip()
+    chip.gate.clear()
+    first, _ = _lead(meet, chip)
+    gone = RequestContext.background()
+    early, got = _ride_all(meet, chip, ["gone"], {"gone": gone})
+    _standing(meet, 1)
+    filler, _ = _ride_all(meet, chip, ["a"], out=got)
+    _launched(chip, 2)          # "a" filled the call and lands it
+    gone.cancel()
+    early[0].join(30)
+    assert not early[0].is_alive() and isinstance(got["gone"], Cancelled)
+    assert chip.landed == [] and filler[0].is_alive()
+    chip.gate.set()
+    for t in first + filler:
+        t.join(30)
+        assert not t.is_alive()
+    assert chip.calls[1] == ["gone", "a"]
+    assert got["a"].result == ("answer", "a")
+    assert (got["a"].lanes, got["a"].lane) == (2, 1)
+    assert meet._flight is None and meet._behind is None
+
+
 def test_many_threads_lose_no_rider_and_never_share_the_chip():
     """More threads than cores under a short switch interval: every
-    ride gets its own answer, the calls' lanes add up to the rides,
-    and no two calls are on the chip at once."""
+    ride gets its own answer out of its own call, the calls' lanes add
+    up to the rides, never more than two calls are on the device (the
+    one in flight and the one behind it), and full calls do go
+    behind."""
     import sys
-    meet = Rendezvous(8)
+    meet = Rendezvous(8, family="stress")
     lock = threading.Lock()
     state = {"on_chip": 0, "worst": 0, "calls": 0, "lanes": 0}
+    before = metrics.counters_snapshot()
 
     def launch(items):
         with lock:
@@ -612,22 +852,28 @@ def test_many_threads_lose_no_rider_and_never_share_the_chip():
             state["worst"] = max(state["worst"], state["on_chip"])
             state["calls"] += 1
             state["lanes"] += len(items)
-        return list(items)
+            return state["calls"], list(items)
 
     def land(handle, n):
         time.sleep(0.0002)
         with lock:
             state["on_chip"] -= 1
-        return [x * x for x in handle]
+        call, items = handle
+        return [(call, x * x) for x in items]
 
     threads_n, rides_n = 48, 40
     wrong = []
+    seats: dict = {}
 
     def client(k):
         for j in range(rides_n):
             x = k * 1000 + j
-            if meet.ride(x, launch, land).result != x * x:
+            ride = meet.ride(x, launch, land)
+            call, answer = ride.result
+            if answer != x * x:
                 wrong.append(x)
+            with lock:
+                seats.setdefault(call, []).append((ride.lane, ride.lanes))
 
     was = sys.getswitchinterval()
     sys.setswitchinterval(1e-5)
@@ -643,9 +889,18 @@ def test_many_threads_lose_no_rider_and_never_share_the_chip():
         sys.setswitchinterval(was)
     assert not wrong
     assert state["lanes"] == threads_n * rides_n
-    assert state["worst"] == 1 and state["on_chip"] == 0
+    assert state["worst"] == 2 and state["on_chip"] == 0
     assert state["calls"] < state["lanes"]
-    assert meet._flight is None and not meet._waiting
+    # every call handed each of its lanes to one rider, and to no other
+    assert len(seats) == state["calls"]
+    for riders in seats.values():
+        assert sorted(lane for lane, _ in riders) \
+            == list(range(riders[0][1]))
+    moved = metrics.counters_delta(before)
+    assert 0 < moved['rendezvous_ahead_total{family="stress"}'] \
+        < state["calls"]
+    assert meet._flight is None and meet._behind is None
+    assert not meet._waiting
 
 
 def test_a_tile_has_one_rendezvous_and_another_tile_another():
@@ -791,7 +1046,9 @@ def test_riders_the_next_call_cannot_seat_are_left_waiting():
     while not chip.calls:
         time.sleep(0.001)
     threads, _ = _ride_all(meet, chip, ["a", "b", "c"])
-    _standing(meet, 3)
+    # two of the three are a full call, behind the lead's already
+    _launched(chip, 2)
+    _standing(meet, 1)
     chip.gate.set()
     for t in first + threads:
         t.join(30)
@@ -804,7 +1061,7 @@ def test_a_flight_that_raises_closes_its_span_and_annotations(
         where, annotations):
     from dgraph_tpu.query import devicecall
 
-    meet, chip = Rendezvous(2, family="t"), _Chip()
+    meet, chip = Rendezvous(4, family="t"), _Chip()
     chip.gate.clear()
     setattr(chip, "fail_" + where, (1, RuntimeError("the device said no")))
     metrics.reset()
@@ -839,15 +1096,15 @@ def test_a_flight_that_raises_closes_its_span_and_annotations(
 
 
 def _hold_first_call(monkeypatch):
-    """Keep the first traversal on the 'chip' until released, so that
-    what arrives meanwhile is known to wait."""
+    """Keep the first traversal on the 'chip' until released, and a
+    full call that went behind it meanwhile (the device runs them in
+    order), so that what arrives meanwhile is known to wait."""
     gate, calls = threading.Event(), []
     land0 = executor_mod._land_traversals
 
     def land(handle, n):
         calls.append(n)
-        if len(calls) == 1:
-            gate.wait(30)
+        gate.wait(30)
         return land0(handle, n)
 
     monkeypatch.setattr(executor_mod, "_land_traversals", land)
@@ -981,13 +1238,18 @@ SHARES = {
 }
 
 
-@pytest.mark.parametrize("case", sorted(SHARES))
-def test_the_streamed_shares_reader(case):
-    spec = importlib.util.spec_from_file_location("tb_reader", READER)
+def _read(path, before, after):
+    """What the benchmark's reader at `path` makes of two scrapes."""
+    spec = importlib.util.spec_from_file_location("tb_reader", path)
     reader = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(reader)
+    return reader.read({"counters_before": before, "counters_after": after})
+
+
+@pytest.mark.parametrize("case", sorted(SHARES))
+def test_the_streamed_shares_reader(case):
     before, after, want = SHARES[case]
-    got = reader.read({"counters_before": before, "counters_after": after})
+    got = _read(READER, before, after)
     assert got == want if want is None else got == pytest.approx(want)
 
 
@@ -1003,6 +1265,93 @@ def test_the_streamed_share_is_a_metric_of_both_khop_cells():
                       "graph500-khop-x4.khop-deep-c16"]}
     cells = {w["name"] for w in bench["workloads"]}
     assert set(entry["workloads"]) <= cells
+
+
+AHEAD_READER = os.path.join(ROOT, "benchmark", "metrics",
+                            "rendezvous_ahead_share.py")
+AHEAD = 'rendezvous_ahead_total{family="recurse"}'
+CALLS = "recurse_batch_total"
+# (counters before the window, after it, what the reader says)
+AHEAD_SHARES = {
+    # 2,535 calls a window, 2,460 of them launched behind another
+    "a-window": ({AHEAD: 40, CALLS: 50},
+                 {AHEAD: 40 + 2_460, CALLS: 50 + 2_535},
+                 100.0 * 2_460 / 2_535),
+    "every-call-behind-another": ({}, {AHEAD: 800, CALLS: 800}, 100.0),
+    # fewer than 2 x LANES connections: served, and nothing went ahead
+    "no-call-ever-full": ({AHEAD: 0, CALLS: 10},
+                          {AHEAD: 0, CALLS: 410}, 0.0),
+    # what the parent serves (the reader is laid over its checkout
+    # too): the calls, not this counter
+    "a-program-without-the-counter": (
+        {CALLS: 1}, {CALLS: 900, "recurse_batch_lanes_total": 7000,
+                     'rendezvous_chained_total{family="recurse"}': 890},
+        None),
+    "the-counter-without-the-calls": ({}, {AHEAD: 5}, None),
+    "another-familys-counter": (
+        {}, {'rendezvous_ahead_total{family="similar"}': 5, CALLS: 9},
+        None),
+    "no-call-in-the-window": ({AHEAD: 9, CALLS: 12},
+                              {AHEAD: 9, CALLS: 12}, None),
+    "nothing-served": ({}, {}, None),
+}
+
+
+@pytest.mark.parametrize("case", sorted(AHEAD_SHARES))
+def test_the_ahead_shares_reader(case):
+    before, after, want = AHEAD_SHARES[case]
+    got = _read(AHEAD_READER, before, after)
+    assert got == want if want is None else got == pytest.approx(want)
+
+
+def test_the_ahead_share_is_a_metric_of_both_khop_cells_and_no_other():
+    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
+        bench = json.load(f)
+    entry, = (m for m in bench["per_layer"]
+              if m["name"] == "rendezvous_ahead_share")
+    assert entry == {
+        "name": "rendezvous_ahead_share", "unit": "%", "better": "higher",
+        "source": "program_counter", "layer": "executor", "moves": "ok_qps",
+        "workloads": ["graph500-khop.khop-deep-c16",
+                      "graph500-khop-x4.khop-deep-c16"]}
+    assert bench["per_layer"][-1] is entry     # appended, nothing moved
+    # the cells that send a bound @recurse to a device tier, all of them
+    recursing = [w["name"] for w in bench["workloads"]
+                 if w["config"].startswith("graph500-khop")]
+    assert entry["workloads"] == recursing
+
+
+def test_a_served_call_behind_another_is_what_the_reader_reads(
+        worlds, monkeypatch):
+    """The executor's site end to end: 1 + LANES requests are two
+    calls, the second launched behind the first, and the reader makes
+    of the served counters one call in two."""
+    facts, dev, host, _ = worlds[9, 31_000_017]
+    _tile(dev)
+    gate, calls = _hold_first_call(monkeypatch)
+    before = metrics.counters_snapshot()
+    tracing.clear()
+    q = [_q(KHOP, [graph500.FIRST_UID + i], 4)
+         for i in range(1 + bitgraph.LANES)]
+    out, threads = _serve(dev, q[:1])
+    while not calls:
+        time.sleep(0.001)
+    more, threads2 = _serve(dev, q[1:])
+    while len(calls) < 2:
+        time.sleep(0.001)
+    assert calls == [1, bitgraph.LANES] and threads[0].is_alive()
+    gate.set()
+    for t in threads + threads2:
+        t.join(60)
+        assert not t.is_alive()
+    for i, rep in [(0, out[0])] + [(i + 1, more[i])
+                                   for i in range(bitgraph.LANES)]:
+        assert json.dumps(rep["data"], separators=(",", ":")) \
+            == _data(host, q[i])
+    assert sorted((f["args"]["lanes"], f["args"]["ahead"])
+                  for f in _flights()) == [(1, False), (bitgraph.LANES, True)]
+    assert _read(AHEAD_READER, before, metrics.counters_snapshot()) \
+        == pytest.approx(50.0)
 
 
 @pytest.mark.parametrize("graph", GRAPHS, ids=lambda g: f"scale{g[0]}")
@@ -1022,8 +1371,13 @@ def test_requests_in_flight_share_calls_and_keep_their_own_accounts(
         time.sleep(0.001)
     more, threads2 = _serve(dev, queries[1:])
     meet = Rendezvous.at(dev.tablets["link"]._device_badj, bitgraph.LANES)
-    _standing(meet, n - 1)
-    held_ns = 150_000_000   # every one of them stands this long at least
+    # the first LANES of them are a full call, on the device behind the
+    # one in flight already; the others stand
+    _standing(meet, n - 1 - bitgraph.LANES)
+    while len(calls) < 2:
+        time.sleep(0.001)
+    assert calls == [1, bitgraph.LANES]
+    held_ns = 150_000_000   # every one of them waits this long at least
     time.sleep(held_ns / 1e9)
     gate.set()
     for t in threads + threads2:

@@ -463,6 +463,66 @@ def test_an_engine_with_a_mesh_answers_as_the_postings_tier_does(
     assert tile.mesh is not None and tile.shards == CHIPS
 
 
+def test_a_full_call_over_the_mesh_goes_behind_the_call_in_flight(
+        engines, every_bound_recurse_on_the_device, monkeypatch):
+    """PR 40 over the sharded program: with one call held "on the
+    chips", LANES requests are a full call that one of them launches
+    at once (another thread than the one blocked for the first), and
+    each gets the postings tier's answer out of its own lane."""
+    import threading
+    import time
+
+    edges, sharded, _, host = engines
+    roots = sorted(edges)[:1 + LANES]
+    queries = [KHOP % (hex(u), 4 + 3 * (i % 2), "count(uid)")
+               for i, u in enumerate(roots)]
+    _data(sharded, queries[0])      # the tile built, the program warm
+    gate, calls = threading.Event(), []
+    land0 = executor_mod._land_traversals
+
+    def land(handle, n):
+        calls.append(n)
+        gate.wait(60)
+        return land0(handle, n)
+
+    monkeypatch.setattr(executor_mod, "_land_traversals", land)
+    before = {n: _counter(n) for n in (
+        "recurse_sharded_total", "recurse_sharded_lanes_total",
+        'rendezvous_ahead_total{family="recurse"}')}
+    got: dict = {}
+
+    def one(i):
+        try:
+            got[i] = _data(sharded, queries[i])
+        except BaseException as e:  # noqa: BLE001 -- asserted below
+            got[i] = e
+
+    threads = [threading.Thread(target=one, args=(i,))
+               for i in range(len(queries))]
+    threads[0].start()
+    deadline = time.monotonic() + 60
+    while not calls and time.monotonic() < deadline:
+        time.sleep(0.001)
+    for t in threads[1:]:
+        t.start()
+    while len(calls) < 2 and time.monotonic() < deadline:
+        time.sleep(0.001)
+    # launched while the first call's lander is still blocked for it
+    assert calls == [1, LANES] and threads[0].is_alive()
+    gate.set()
+    for t in threads:
+        t.join(60)
+        assert not t.is_alive()
+    for i, q in enumerate(queries):
+        assert got[i] == _data(host, q), q
+    assert _counter("recurse_sharded_total") \
+        == before["recurse_sharded_total"] + 2
+    assert _counter("recurse_sharded_lanes_total") \
+        == before["recurse_sharded_lanes_total"] + 1 + LANES
+    assert _counter('rendezvous_ahead_total{family="recurse"}') \
+        == before['rendezvous_ahead_total{family="recurse"}'] + 1
+
+
 def test_the_tile_is_counted_and_gauged_a_chip_at_a_time(
         rdf, mesh, every_bound_recurse_on_the_device):
     db = _db(rdf, prefer_device=True, device_min_edges=1, mesh=mesh)
