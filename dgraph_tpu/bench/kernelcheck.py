@@ -171,6 +171,7 @@ def check_bfs_traverse(tiny) -> dict:
     return {"ok": all(same), "lanes_equal": int(sum(same)),
             "reached": counts.tolist(), "levels_run": levels.tolist(),
             "hub_tiles_streamed": int(tiles[0]), "hub_tiles": int(tiles[1]),
+            "column_levels": int(tiles[2]),
             "shape": {"slots": badj.n_slots, "edges": badj.n_edges,
                       "hub_rows": 0 if badj.dense is None
                       else list(badj.dense.shape),

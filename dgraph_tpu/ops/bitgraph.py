@@ -36,7 +36,10 @@ cost the same for every frontier, but of the hub rows a level reads
 only the tiles in which some live lane has not yet reached some row
 (_hub_pending: the bottom-up step's rule, look only at vertices not
 yet found), so the deep levels of a traversal, by which a skewed
-graph's hubs are all found, read few of them or none.
+graph's hubs are all found, read few of them or none. And a call's
+FIRST level, whose frontier is its handful of roots, reads neither:
+the out-neighbours of a root are its COLUMN of these reverse
+structures (_chip_columns), where that is cheaper (columns_cheaper).
 
 SSSP follows the same layout with an int32 distance vector and a
 min-reduction instead of any(): Bellman-Ford over dense tiles, with
@@ -457,14 +460,51 @@ def level_seconds(badj: BitAdjacency) -> float:
     MOST, from the adjacency's layout alone: the gathered classes'
     padded in-edges and the dense rows' bytes. An upper bound: a
     level streams only the tiles of rows some live lane has not
-    settled (_hub_pending), all of them at a call's first levels.
-    It is what the executor's gate prices a level with."""
+    settled (_hub_pending): all of them at a call's second and third
+    levels, fewer after, and its first level reads the roots' columns
+    where those are cheaper still (columns_cheaper). It is what the
+    executor's gate prices a level with."""
     gathered = sum(int(b.in_nb.size) for b in badj.gathered)
     dense = 0 if badj.dense is None else int(badj.dense.nbytes)
     # split over chips, a level costs what ONE chip gathers and
     # streams of it (the collective that follows is a few megabytes)
-    return (gathered * GATHER_SECONDS
-            + dense / DENSE_BYTES_PER_S) / badj.shards
+    return _streamed_seconds(gathered, dense) / badj.shards
+
+
+def _streamed_seconds(gathered: int, dense_bytes: int) -> float:
+    """A level that gathers `gathered` padded in-edges and streams
+    `dense_bytes` of hub rows, on one chip."""
+    return gathered * GATHER_SECONDS + dense_bytes / DENSE_BYTES_PER_S
+
+
+def columns_cheaper(n_seeds: int, rows: int, words: int,
+                    gathered: int) -> bool:
+    """Whether a call's FIRST level costs less read from the columns
+    of its `n_seeds` seed slots (_chip_columns) than streamed and
+    gathered as any level (_chip_reach), from ONE chip's shapes
+    alone: its `rows` hub rows of `words` words and the `gathered`
+    padded in-edges of its other classes. A seed's column of the hub
+    rows costs the (8, 128) tiles that hold it, a vreg's width of
+    every row, and its column of a gathered class a compare an index,
+    priced as the index's bytes; the level they replace costs what
+    level_seconds says. A few roots read a sliver of the rows; past
+    a row's count of column blocks (and what the gathers cost) the
+    stream is the cheaper again. And never past the seed slots the
+    column level was TIMED with: a larger root set takes the level
+    every call took before there was another."""
+    columns = n_seeds * 4 * (rows * _HUB_WORDS_UNIT + gathered) \
+        / DENSE_BYTES_PER_S
+    return n_seeds <= _COLUMN_SEEDS_TIMED \
+        and columns < _streamed_seconds(gathered, 4 * rows * words)
+
+
+# The most seed slots the column level was timed with on the chip
+# (PERF.md, PR 43: 8 and 32, at both k-hop cells' shapes). The prices
+# above put the turn further out (64 slots at the one-chip cell's
+# shapes, 512 at a chip's of the four-chip cell), but the compare of
+# every gathered index with every seed is priced as the index's bytes
+# and was not timed there, and no benchmark cell sends such a call.
+_COLUMN_SEEDS_TIMED = 32
 
 
 def _lane_planes(words_by_slot: jax.Array, lanes: int) -> jax.Array:
@@ -658,9 +698,10 @@ def _hub_call(dense, fw, active, plan, lanes: int, tile: int,
 
 
 @functools.partial(jax.jit, static_argnames=(
-    "n_slots", "n_covered", "lanes", "tile"))
+    "n_slots", "n_covered", "lanes", "tile", "columns"))
 def bfs_traverse(in_nbs, dense, riders, *, n_slots: int, n_covered: int,
-                 lanes: int, tile: int = _HUB_TILE_ROWS):
+                 lanes: int, tile: int = _HUB_TILE_ROWS,
+                 columns: Optional[bool] = None):
     """`@recurse(loop: false)` whole, for every LANE of the call: up
     to `lanes` traversals over one adjacency in one program, each
     with its own roots, depth, visited set and count. The frontier,
@@ -678,6 +719,11 @@ def bfs_traverse(in_nbs, dense, riders, *, n_slots: int, n_covered: int,
                 0 for a lane nobody rides; a lane stops after its
                 own, whatever the others do
     tile        hub rows a step of the rows' kernel holds
+    columns     whether the first level is read from the S seeds'
+                columns (_chip_columns) and not gathered and streamed;
+                None: where the shapes say that is cheaper
+                (columns_cheaper). The results are the same bit for
+                bit but for the tiles streamed (the tests' to set)
     ->  (tally, reached)
     tally       int32[3, lanes], ONE fetch a call. Row 0: distinct
                 slots a lane reached through an edge in 1..depth hops
@@ -686,9 +732,10 @@ def bfs_traverse(in_nbs, dense, riders, *, n_slots: int, n_covered: int,
                 a lane expanded; it ends early once a level finds it
                 no new slot, and the loop ends when no lane is alive.
                 Row 2, the call's, not a lane's: [0] tiles of hub
-                rows the levels streamed, [1] tiles they would have
-                streamed had every level read every row (levels x
-                tiles), the rest 0
+                rows the levels streamed (none for a level read from
+                columns), [1] tiles they would have streamed had
+                every level read every row (levels x tiles), [2]
+                levels read from columns (0 or 1), the rest 0
     reached     uint32[N] lane words: the reached sets themselves.
                 They stay on the device unless a lane's reader wants
                 the uids (lane_uids)
@@ -698,19 +745,50 @@ def bfs_traverse(in_nbs, dense, riders, *, n_slots: int, n_covered: int,
     def level(frontier, active, reached):
         pending = None if dense is None else _hub_pending(
             reached, active, n_covered - rows, rows)
-        reach, tiles = _chip_reach(in_nbs, dense, frontier, active,
-                                   pending, lanes, tile)
+        return _chip_reach(in_nbs, dense, frontier, active, pending,
+                           lanes, tile)
+
+    def whole(share):
         return jnp.concatenate([
-            reach, jnp.zeros((n_slots - n_covered,), jnp.uint32)]), tiles
+            share, jnp.zeros((n_slots - n_covered,), jnp.uint32)])
 
-    return _traverse_lanes(level, riders, n_slots, lanes)
+    return _traverse_lanes(
+        level, _first_level(in_nbs, dense, riders, lanes, tile, columns),
+        whole, riders, n_slots, lanes)
 
 
-def _traverse_lanes(level, riders, n_slots: int, lanes: int):
-    """bfs_traverse's loop over `level(frontier, active, reached) ->
-    (reach, tiles)` (uint32[N] lane words but for `tiles`, int32[2]:
-    hub-row tiles the level streamed and had): the riders unpacked,
-    every lane run to its own depth, -> (tally, reached)."""
+def _first_level(in_nbs, dense, riders, lanes: int, tile: int,
+                 columns: Optional[bool], chips: int = 1):
+    """_traverse_lanes' `first` for ONE chip's `in_nbs` and `dense`
+    of an adjacency split over `chips`: _chip_columns over them where
+    `columns` (bfs_traverse's) says so, else None."""
+    rows, words = (0, 0) if dense is None else dense.shape
+    if columns is None:
+        columns = columns_cheaper(
+            (riders.shape[0] - lanes) // 2, rows, words,
+            sum(int(nb.size) for nb in in_nbs))
+    if not columns:
+        return None
+    # what the level streams of the hub rows, and what a stream of
+    # every row would have: _hub_reach's count, over all chips
+    tiles = jnp.array(
+        [0, chips * -(-rows // _hub_tile(rows, tile)) if rows else 0],
+        jnp.int32)
+    return lambda slots, bits: (_chip_columns(in_nbs, dense, slots, bits),
+                                tiles)
+
+
+def _traverse_lanes(level, first, whole, riders, n_slots: int,
+                    lanes: int):
+    """bfs_traverse's loop: the riders unpacked, every lane run to
+    its own depth, -> (tally, reached). `level(frontier, active,
+    reached) -> (share, tiles)`: the rows this chip holds that a
+    frontier reaches (uint32 lane words, as `frontier` and `reached`
+    are over every slot) and int32[2], the hub-row tiles the level
+    streamed and had; `whole(share)` -> the level's reach over every
+    slot, uint32[N]. `first(seed slots, their lane bits) -> (share,
+    tiles)`, or None: a call's first level answered from its seeds
+    and not from the frontier they make, which is the same set."""
     n_seeds = (riders.shape[0] - lanes) // 2
     seed_slots = riders[:n_seeds]
     seed_bits = jax.lax.bitcast_convert_type(
@@ -730,7 +808,15 @@ def _traverse_lanes(level, riders, n_slots: int, lanes: int):
 
     def body(state):
         lvl, frontier, visited, reached, levels_run, tiles, active = state
-        reach, streamed = level(frontier, active, reached)
+        if first is None:
+            share, streamed = level(frontier, active, reached)
+        else:
+            # (a lane of depth 0 expands nothing: its seeds' bits go)
+            share, streamed = jax.lax.cond(
+                lvl == 0,
+                lambda: first(seed_slots, seed_bits & expanding(0)),
+                lambda: level(frontier, active, reached))
+        reach = whole(share)
         new = reach & ~visited
         # a lane goes on only within its depth and from a new slot
         frontier = new & expanding(lvl + 1)
@@ -738,15 +824,17 @@ def _traverse_lanes(level, riders, n_slots: int, lanes: int):
                 levels_run + ((active >> lane) & 1).astype(jnp.int32),
                 tiles + streamed, jnp.bitwise_or.reduce(frontier))
 
-    first = seed & expanding(jnp.int32(0))
+    start = seed & expanding(jnp.int32(0))
+    active = jnp.bitwise_or.reduce(start)
     _, _, _, reached, levels_run, tiles, _ = jax.lax.while_loop(
-        cond, body, (jnp.int32(0), first, seed,
+        cond, body, (jnp.int32(0), start, seed,
                      jnp.zeros((n_slots,), jnp.uint32),
-                     jnp.zeros((lanes,), jnp.int32), _NO_TILES,
-                     jnp.bitwise_or.reduce(first)))
+                     jnp.zeros((lanes,), jnp.int32), _NO_TILES, active))
     counts = jnp.sum(_lane_planes(reached, lanes), axis=1, dtype=jnp.int32)
-    return jnp.stack([counts, levels_run,
-                      jnp.pad(tiles, (0, lanes - 2))]), reached
+    # the first level ran where any lane held a frontier at all
+    from_columns = jnp.int32(first is not None) * (active != 0)
+    return jnp.stack([counts, levels_run, jnp.pad(
+        jnp.append(tiles, from_columns), (0, lanes - 3))]), reached
 
 
 def _chip_reach(in_nbs, dense, frontier, active, pending, lanes: int,
@@ -765,6 +853,114 @@ def _chip_reach(in_nbs, dense, frontier, active, pending, lanes: int,
     return jnp.concatenate(parts), tiles
 
 
+def _chip_columns(in_nbs, dense, slots, bits):
+    """_chip_reach's lane words for a frontier of a FEW slots, read
+    from the slots' COLUMNS: `slots` int32[S] (the dummy slot on
+    padding) and `bits` uint32[S], the lanes each is in (0 on
+    padding). The structures are the reverse adjacency, a row a
+    destination, so the out-neighbours of slot s are the rows whose
+    in-neighbours hold s: of a gathered class those with an entry
+    EQUAL to s (a compare an index: no gather), of the hub rows
+    those with bit s // W of word s % W set (_hub_columns). The
+    dummy slot equals every row's padding, and carries no bit."""
+    parts = [jnp.bitwise_or.reduce(
+        jnp.where(nb[:, :, None] == slots, bits, jnp.uint32(0)),
+        axis=(1, 2)) for nb in in_nbs]
+    if dense is not None:
+        parts.append(_hub_columns(dense, slots, bits))
+    return jnp.concatenate(parts) if parts else jnp.zeros((0,), jnp.uint32)
+
+
+# rows a grid step of the columns' kernel holds: blocks of a megabyte
+_COLUMN_TILE_ROWS = 2048
+
+
+def _hub_columns(dense, slots, bits):
+    """The hub rows a few slots point at -> uint32[rows] lane words.
+    Slot s is bit s // W of word s % W of every row (attach_dense),
+    and a row's words lie in (8, 128) tiles, so a seed costs the
+    block of 128 word columns that holds its word, rows x 512 B,
+    where the stream of every row costs rows x 4 W. On the chip by
+    _column_kernel, whose index map names a seed's block of columns
+    (XLA's own slice of it cannot know the offset is a whole tile's
+    and reads at half the stream's rate on a v5e: PERF.md, PR 43);
+    elsewhere (the CPU the tests run on) the same block sliced out
+    in plain jnp, a seed at a time."""
+    rows, words = dense.shape
+    word = slots % words
+    block, column = word // _HUB_WORDS_UNIT, word % _HUB_WORDS_UNIT
+    bit = (slots // words).astype(jnp.uint32)
+    if jax.default_backend() == "tpu":
+        return jnp.bitwise_or.reduce(
+            _columns_call(dense, block, column, bit, bits), axis=1)
+    columns = jnp.arange(_HUB_WORDS_UNIT, dtype=jnp.int32)
+
+    def one(i, reach):
+        held = jax.lax.dynamic_slice(
+            dense, (0, block[i] * _HUB_WORDS_UNIT),
+            (rows, _HUB_WORDS_UNIT))
+        hit = ((held >> bit[i]) & 1 != 0) & (columns == column[i])
+        return reach | jnp.where(jnp.any(hit, axis=1), bits[i],
+                                 jnp.uint32(0))
+
+    return jax.lax.fori_loop(0, slots.shape[0], one,
+                             jnp.zeros((rows,), jnp.uint32))
+
+
+def _column_kernel(seeds_ref, rows_ref, out_ref):
+    """A tile of rows' block of 128 word columns against ONE seed:
+    seeds_ref int32[4 S] (SMEM): every seed's block of columns (the
+    index map's), its column in the block, its bit in the word and
+    its lane bits; rows_ref uint32[T, 128]: the step's rows, the
+    seed's block of them; out_ref uint32[T, 128]: the seeds' lane
+    bits ORed where a row's word holds a seed's bit, in the seed's
+    column (the caller ORs the 128 together). The seeds are the
+    grid's inner axis: a tile's words stay in VMEM for all of them."""
+    from jax.experimental import pallas as pl
+
+    n, s = seeds_ref.shape[0] // 4, pl.program_id(1)
+
+    @pl.when(s == 0)
+    def _():
+        out_ref[...] = jnp.zeros(out_ref.shape, jnp.uint32)
+
+    hit = ((rows_ref[...] >> seeds_ref[2 * n + s].astype(jnp.uint32)) & 1
+           != 0) & (jax.lax.broadcasted_iota(jnp.int32, rows_ref.shape, 1)
+                    == seeds_ref[n + s])
+    out_ref[...] |= jnp.where(
+        hit, seeds_ref[3 * n + s].astype(jnp.uint32), jnp.uint32(0))
+
+
+def _columns_call(dense, block, column, bit, bits,
+                  interpret: bool = False):
+    """_column_kernel over every tile of `dense`'s rows and every
+    seed -> uint32[rows, 128]. A step copies rows x 128 words of the
+    ONE block of columns its seed names: the pipeline reads the
+    (8, 128) tiles that hold the seed's word and no other."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    rows = dense.shape[0]
+    tile = _hub_tile(rows, _COLUMN_TILE_ROWS)
+    seeds = jnp.concatenate([block, column, bit.astype(jnp.int32),
+                             bits.astype(jnp.int32)])
+    return pl.pallas_call(
+        _column_kernel,
+        out_shape=jax.ShapeDtypeStruct((rows, _HUB_WORDS_UNIT), jnp.uint32),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(pl.cdiv(rows, tile), block.shape[0]),
+            in_specs=[pl.BlockSpec((tile, _HUB_WORDS_UNIT),
+                                   lambda i, s, seeds: (i, seeds[s]))],
+            out_specs=pl.BlockSpec((tile, _HUB_WORDS_UNIT),
+                                   lambda i, s, seeds: (i, 0))),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary")),
+        interpret=interpret,
+        name="bfs_hub_columns",
+    )(seeds, dense)
+
+
 def _whole_reach(shares, part_rows, n_slots: int):
     """The chips' shares of a level, uint32[chips, L] as _chip_reach
     gives them, -> the level's reach in slot order, uint32[N]: a
@@ -781,10 +977,11 @@ def _whole_reach(shares, part_rows, n_slots: int):
 
 
 @functools.partial(jax.jit, static_argnames=(
-    "mesh", "part_rows", "n_slots", "lanes", "tile"))
+    "mesh", "part_rows", "n_slots", "lanes", "tile", "columns"))
 def bfs_traverse_sharded(in_nbs, dense, riders, *, mesh, part_rows,
                          n_slots: int, lanes: int,
-                         tile: int = _HUB_TILE_ROWS):
+                         tile: int = _HUB_TILE_ROWS,
+                         columns: Optional[bool] = None):
     """bfs_traverse with the adjacency split over the chips of
     `mesh`'s `uid` axis: same riders, same (tally, reached), bit for
     bit but for the tiles of hub rows, which a chip counts in its
@@ -794,7 +991,9 @@ def bfs_traverse_sharded(in_nbs, dense, riders, *, mesh, part_rows,
     frontier, visited and reached sets whole. A level: every chip
     works out which of ITS rows the frontier reaches (_chip_reach: a
     share of the gathers and, of the rows some lane still needs, of
-    the stream), then ONE collective, an all-gather of the shares (a
+    the stream; at a call's first level the seeds' columns of ITS
+    rows instead, as bfs_traverse's `columns` has it, by a chip's
+    shapes), then ONE collective, an all-gather of the shares (a
     lane word a covered slot, 4 B a vertex over all chips), hands
     every chip the whole reach, from which each works out the next
     frontier for itself, and from the reached sets which tiles of
@@ -811,14 +1010,18 @@ def bfs_traverse_sharded(in_nbs, dense, riders, *, mesh, part_rows,
         def level(frontier, active, reached):
             pending = None if dense is None else _hub_pending(
                 reached, active, start, part_rows[-1][0], chips)
-            share, tiles = _chip_reach(
+            return _chip_reach(
                 in_nbs, dense, frontier, active, pending, lanes, tile,
                 jax.lax.axis_index(SHARD_AXIS))
-            return _whole_reach(
-                jax.lax.all_gather(share, SHARD_AXIS), part_rows,
-                n_slots), tiles
 
-        return _traverse_lanes(level, riders, n_slots, lanes)
+        def whole(share):
+            return _whole_reach(jax.lax.all_gather(share, SHARD_AXIS),
+                                part_rows, n_slots)
+
+        return _traverse_lanes(
+            level, _first_level(in_nbs, dense, riders, lanes, tile,
+                                columns, chips),
+            whole, riders, n_slots, lanes)
 
     # every chip computes the same lane state from the gathered
     # shares: replicated by construction, which the checker cannot see

@@ -304,19 +304,23 @@ def _launch_traversals(badj, riders: list):
 def _land_traversals(handle, n: int) -> list:
     """A Rendezvous' `land`: every rider's (reached count, levels
     run, the lanes' reached sets still on the device, the call's
-    hub-row tiles), once the call has run. One small array leaves
-    the device for all of them. `recurse_hub_tiles_streamed_total`
-    counts the tiles of hub rows the calls' levels read and
-    `recurse_hub_tiles_total` those they would have read had every
-    level read every row: their ratio is the share of the rows'
-    stream that the lanes' reached sets left standing."""
+    hub-row tiles and column levels), once the call has run. One
+    small array leaves the device for all of them.
+    `recurse_hub_tiles_streamed_total` counts the tiles of hub rows
+    the calls' levels read and `recurse_hub_tiles_total` those they
+    would have read had every level read every row: their ratio is
+    the share of the rows' stream that the lanes' reached sets, and
+    a first level read from the roots' columns, left standing.
+    `recurse_column_levels_total` counts those first levels (0 or 1
+    a call; +0 serves the series from the first call on)."""
     tally, reached = handle
     counts, levels, tiles = np.asarray(tally)
-    streamed, full = int(tiles[0]), int(tiles[1])
+    streamed, full, columns = (int(t) for t in tiles[:3])
     inc_counter("recurse_hub_tiles_streamed_total", streamed)
     inc_counter("recurse_hub_tiles_total", full)
-    return [(int(counts[i]), int(levels[i]), reached, (streamed, full))
-            for i in range(n)]
+    inc_counter("recurse_column_levels_total", columns)
+    return [(int(counts[i]), int(levels[i]), reached,
+             (streamed, full, columns)) for i in range(n)]
 
 
 def _var_domain(vmap) -> np.ndarray:
@@ -4932,7 +4936,8 @@ class Executor:
             batch = {"lanes": ride.lanes,
                      "batch_wait_us": ride.waited_ns // 1000,
                      "shards": badj.shards, "program": program,
-                     "hub_tiles_streamed": tiles[0], "hub_tiles": tiles[1]}
+                     "hub_tiles_streamed": tiles[0], "hub_tiles": tiles[1],
+                     "column_levels": tiles[2]}
             dc.note(**batch)
             uids = bitgraph.lane_uids(
                 badj, np.asarray(reached), ride.lane).astype(np.uint64) \

@@ -142,9 +142,11 @@ REGISTERED = (
     # rendezvous dispatched and the traversals they carried, and
     # those of them that took the program sharded over a mesh;
     # _land_traversals: the tiles of hub rows the calls' levels
-    # streamed, and those a stream of every row a level would have
+    # streamed, those a stream of every row a level would have, and
+    # the first levels read from the roots' columns instead
     "recurse_batch_lanes_total",
     "recurse_batch_total",
+    "recurse_column_levels_total",
     "recurse_hub_tiles_streamed_total",
     "recurse_hub_tiles_total",
     "recurse_ns_total",

@@ -23,6 +23,7 @@ from dgraph_tpu.utils import metrics, tracing
 from dgraph_tpu.utils.reqctx import (
     Cancelled, DeadlineExceeded, RequestContext,
 )
+from recurse_cases import column_lanes, traverse_as
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -427,6 +428,228 @@ def test_a_call_answers_the_same_with_and_without_the_settled_tiles(
         assert tally[0, i] == int(_plain_reached(csr, [root], depth).sum())
 
 
+# -- the first level from the roots' columns ---------------------------
+#
+# The out-neighbours of a root are its COLUMN of the reverse
+# structures the device holds: a call's first level read that way
+# (bitgraph._chip_columns) is held, bit for bit, to the level that
+# gathers and streams as every other does.
+
+
+LAYOUTS = {"all_hub_rows": None, "some_hub_rows": 40, "no_hub_rows": 0}
+# the file's three Graph500 graphs, and one whose hub rows are three
+# blocks of 128 word columns wide
+COLUMN_GRAPHS = [f"scale{g[0]}" for g in GRAPHS] + ["wide"]
+
+
+def _wide_edges(n=9_000, m=40_000, seed=11):
+    rng = np.random.default_rng(seed)
+    src = rng.integers(0, n, m)
+    dst = rng.zipf(1.4, m) % n
+    keep = src != dst
+    return src[keep], dst[keep]
+
+
+@pytest.fixture(scope="module")
+def column_worlds():
+    """{(graph, layout): (edges uid -> out-neighbour uids, the plain
+    walk's csr, adjacency)}: a graph's adjacency with as many hub rows
+    as the layout's room holds (None: every class, 0: none)."""
+    out = {}
+    for name, graph in zip(COLUMN_GRAPHS, GRAPHS + [None]):
+        src, dst = _wide_edges() if graph is None \
+            else graph500.graph(*graph)[:2]
+        order = np.lexsort((dst, src))
+        src, dst = src[order], dst[order]
+        vertices = int(max(src.max(), dst.max())) + 1
+        offsets = np.zeros(vertices + 1, np.int64)
+        np.cumsum(np.bincount(src, minlength=vertices), out=offsets[1:])
+        heads, starts = np.unique(src, return_index=True)
+        edges = {int(u) + graph500.FIRST_UID:
+                 np.unique(d + graph500.FIRST_UID).astype(np.uint32)
+                 for u, d in zip(heads, np.split(dst, starts[1:]))}
+        for layout, rows in LAYOUTS.items():
+            badj = bitgraph.build_bitadjacency(edges)
+            bitgraph.attach_dense(
+                badj, 1 << 40 if rows is None
+                else rows * 4 * bitgraph.hub_row_words(badj.n_slots))
+            assert (badj.dense is None) == (rows == 0)
+            assert bool(badj.gathered) == (rows is not None)
+            out[name, layout] = edges, (offsets, dst, vertices), badj
+    assert out["wide", "all_hub_rows"][2].dense.shape[1] == 3 * 128
+    return out
+
+
+def _three_forms(badj, riders, **kw):
+    """(tally, reached) of the riders with the first level streamed,
+    read from columns, and as the rule has it."""
+    return [tuple(np.asarray(x) for x in traverse_as(
+        badj, riders, c, **kw)) for c in (False, True, None)]
+
+
+@pytest.mark.parametrize("case", (
+    "padding_seeds", "eight_seeds_no_padding", "one_root_in_two_lanes",
+    "a_lane_of_depth_0", "every_lane_of_depth_0",
+    "a_root_with_no_out_edge", "a_root_that_is_a_hub",
+    "several_roots_a_lane"))
+@pytest.mark.parametrize("layout", sorted(LAYOUTS))
+@pytest.mark.parametrize("graph", COLUMN_GRAPHS)
+def test_the_column_first_level_is_the_streamed_one_bit_for_bit(
+        column_worlds, graph, layout, case):
+    edges, csr, badj = column_worlds[graph, layout]
+    lanes = column_lanes(case, edges)
+    riders = [(bitgraph.seed_slots(badj, np.array(roots, np.uint32)), d)
+              for roots, d in lanes]
+    (streamed, reached), (columns, reached_c), (ruled, reached_r) = \
+        _three_forms(badj, riders, tile=TILE)
+    assert streamed.dtype == columns.dtype
+    assert np.array_equal(streamed[:2], columns[:2])
+    assert np.array_equal(reached, reached_c)
+    assert np.array_equal(streamed[:2], ruled[:2])
+    assert np.array_equal(reached, reached_r)
+    # and both are the plain walk
+    for i, (roots, depth) in enumerate(lanes):
+        want = graph500.FIRST_UID + np.flatnonzero(
+            _plain_reached(csr, roots, depth))
+        assert columns[0, i] == len(want), (roots, depth)
+        assert np.array_equal(bitgraph.lane_uids(badj, reached_c, i), want)
+    # the tally's third row: a level from columns streams no tile and
+    # still counts among those a stream of every row would have read
+    ran = int(streamed[1].max())
+    assert streamed[2, 2] == 0 and columns[2, 2] == (ran > 0)
+    assert not streamed[2, 3:].any() and not columns[2, 3:].any()
+    assert columns[2, 1] == streamed[2, 1]
+    if badj.dense is not None and ran:
+        tiles = -(-badj.dense.shape[0] // TILE)
+        assert streamed[2, 1] == ran * tiles
+        assert columns[2, 0] <= streamed[2, 0] - tiles
+    else:
+        assert not streamed[2, :2].any() and not columns[2, :2].any()
+    assert ruled[2, 2] == (ran > 0) * bitgraph.columns_cheaper(
+        8, *((0, 0) if badj.dense is None else badj.dense.shape),
+        sum(int(b.in_nb.size) for b in badj.gathered))
+
+
+@pytest.mark.parametrize("graph", COLUMN_GRAPHS)
+def test_the_column_kernel_reads_what_the_plain_form_reads(
+        column_worlds, graph, monkeypatch):
+    """_column_kernel (interpret mode) against the plain slices over
+    the same rows, seeds in every block of columns a row has, the
+    dummy slot and a seed in two lanes among them; then inside the
+    whole traversal, where the chip's branch takes it."""
+    import functools
+    import jax
+    import jax.numpy as jnp
+    edges, _, badj = column_worlds[graph, "all_hub_rows"]
+    rng = np.random.default_rng(5)
+    slots = np.r_[rng.integers(0, badj.n_slots, 13), badj.n_slots,
+                  badj.n_slots - 1, 0].astype(np.int32)
+    slots[1] = slots[0]
+    bits = (np.uint32(1) << (np.arange(16) % 8).astype(np.uint32))
+    bits[13] = 0
+    args = (badj.dense, jnp.asarray(slots), jnp.asarray(bits))
+    plain = np.asarray(bitgraph._hub_columns(*args))
+    assert plain.any()
+    traced = []
+    kernel = functools.partial(bitgraph._columns_call, interpret=True)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(bitgraph, "_columns_call", lambda *a: (
+        traced.append(a[1].shape), kernel(*a))[1])
+    monkeypatch.setattr(bitgraph, "_hub_call", functools.partial(
+        bitgraph._hub_call, interpret=True))
+    assert np.array_equal(np.asarray(bitgraph._hub_columns(*args)), plain)
+    lanes = column_lanes("a_root_that_is_a_hub", edges) \
+        + column_lanes("padding_seeds", edges)
+    riders = [(bitgraph.seed_slots(badj, np.array(roots, np.uint32)), d)
+              for roots, d in lanes]
+    packed = bitgraph._pack_riders(badj.n_slots, riders)
+    kw = dict(n_slots=badj.n_slots, n_covered=badj.n_covered,
+              lanes=bitgraph.LANES, tile=TILE)
+    tally, reached = (np.asarray(x) for x in _fresh(
+        bitgraph.bfs_traverse, columns=True, **kw)([], badj.dense, packed))
+    assert traced == [(16,), (8,)] and tally[2, 2] == 1
+    monkeypatch.undo()
+    want, want_reached = (np.asarray(x) for x in traverse_as(
+        badj, riders, False, tile=TILE))
+    assert np.array_equal(tally[:2], want[:2])
+    assert np.array_equal(reached, want_reached)
+
+
+@pytest.mark.parametrize("graph", COLUMN_GRAPHS)
+def test_more_roots_than_the_rule_allows_take_the_streamed_level(
+        column_worlds, graph):
+    """From shapes alone: eight seed slots read their columns, the
+    count of them at which the rule turns streams as before."""
+    edges, _, badj = column_worlds[graph, "some_hub_rows"]
+    shapes = (*badj.dense.shape,
+              sum(int(b.in_nb.size) for b in badj.gathered))
+    turn = next(s for s in (16, 32, 64, 128, 256, 512, 1024)
+                if not bitgraph.columns_cheaper(s, *shapes))
+    assert bitgraph.columns_cheaper(8, *shapes)
+    heads = sorted(edges)
+    for seeds, want in ((8, 1), (turn // 2, 1), (turn, 0)):
+        # `seeds` (root, lane) pairs over the eight lanes
+        a_lane = seeds // bitgraph.LANES
+        assert a_lane <= len(heads)
+        riders = [(bitgraph.seed_slots(badj, np.array(
+            heads[i:i + a_lane], np.uint32)), 2 + i % 3)
+            for i in range(bitgraph.LANES)]
+        (streamed, reached), _, (ruled, reached_r) = _three_forms(
+            badj, riders)
+        assert ruled[2, 2] == want
+        assert np.array_equal(streamed[:2], ruled[:2])
+        assert np.array_equal(reached, reached_r)
+
+
+# the two cells' own shapes, a chip's (PERF.md section 4): hub rows,
+# their width in words, padded in-edges gathered, and the first count
+# of seed slots (a power of two from eight up) the stream is PRICED
+# cheaper at
+CELL_SHAPES = {
+    "graph500-khop": (69_700, 5_504, 151_000, 64),
+    "graph500-khop-x4": (20_240, 20_224, 697_900, 512),
+}
+
+
+@pytest.mark.parametrize("cell", sorted(CELL_SHAPES))
+def test_the_turn_rule_at_the_cells_shapes(cell, monkeypatch):
+    """Eight riders of one root each read their columns in both
+    cells; where the rule turns back to the stream moves with
+    GATHER_SECONDS and DENSE_BYTES_PER_S, and this says to where."""
+    rows, words, gathered, turn = CELL_SHAPES[cell]
+    assert words == bitgraph.hub_row_words(32 * words)
+    counts = (8, 16, 32, 64, 128, 256, 512, 1024)
+    cheaper = [s for s in counts
+               if bitgraph.columns_cheaper(s, rows, words, gathered)]
+    # the rule takes the columns where they are priced cheaper AND
+    # were timed on the chip: up to 32 seed slots in both cells
+    timed = bitgraph._COLUMN_SEEDS_TIMED
+    assert timed == 32
+    assert cheaper == [s for s in counts if s < turn and s <= timed] \
+        == [8, 16, 32]
+    # by the prices alone it would turn at the cell's own count
+    monkeypatch.setattr(bitgraph, "_COLUMN_SEEDS_TIMED", 1 << 20)
+    assert [s for s in counts if bitgraph.columns_cheaper(
+        s, rows, words, gathered)] == [s for s in counts if s < turn]
+    # eight columns cost a sixth of the level they replace, or less
+    eight = 8 * 4 * (rows * 128 + gathered) / bitgraph.DENSE_BYTES_PER_S
+    level = gathered * bitgraph.GATHER_SECONDS \
+        + 4 * rows * words / bitgraph.DENSE_BYTES_PER_S
+    assert 6 * eight < level
+    # no rows and nothing gathered: nothing to read either way
+    assert not bitgraph.columns_cheaper(8, 0, 0, 0)
+
+
+def test_level_seconds_is_still_the_streamed_levels_price(column_worlds):
+    """The gate's price of a level is what it was: the column level
+    is under it, and no term of it moved."""
+    badj = column_worlds["wide", "some_hub_rows"][2]
+    gathered = sum(int(b.in_nb.size) for b in badj.gathered)
+    assert bitgraph.level_seconds(badj) == pytest.approx(
+        gathered * bitgraph.GATHER_SECONDS
+        + badj.dense.nbytes / bitgraph.DENSE_BYTES_PER_S)
+
+
 # -- the rendezvous, alone ---------------------------------------------
 
 
@@ -676,6 +899,9 @@ def test_the_third_set_goes_behind_the_second_at_the_first_landing():
     assert sorted(r.item for r in meet._flight.riders) == ["a", "b"]
     assert sorted(r.item for r in meet._behind.riders) == ["c", "d"]
     chip.release(1)
+    # (two landers let go at once may reach the rendezvous out of
+    # order, and the call that lands second then had no successor)
+    _until(lambda: meet._behind is None)
     chip.release(2)
     for t in second + third:
         t.join(30)
@@ -1041,6 +1267,7 @@ def test_two_chained_flights_are_a_span_each_with_their_phases(
 def test_riders_the_next_call_cannot_seat_are_left_waiting():
     meet, chip = Rendezvous(2, family="t"), _Chip()
     chip.gate.clear()
+    chip.hold(1)
     tracing.clear()
     first, _ = _ride_all(meet, chip, ["lead"])
     while not chip.calls:
@@ -1050,6 +1277,9 @@ def test_riders_the_next_call_cannot_seat_are_left_waiting():
     _launched(chip, 2)
     _standing(meet, 1)
     chip.gate.set()
+    # (the calls land in the device's order: the lead's first)
+    first[0].join(30)
+    chip.release(1)
     for t in first + threads:
         t.join(30)
     assert [(f["args"]["lanes"], f["args"]["left_waiting"])
@@ -1314,11 +1544,135 @@ def test_the_ahead_share_is_a_metric_of_both_khop_cells_and_no_other():
         "source": "program_counter", "layer": "executor", "moves": "ok_qps",
         "workloads": ["graph500-khop.khop-deep-c16",
                       "graph500-khop-x4.khop-deep-c16"]}
-    assert bench["per_layer"][-1] is entry     # appended, nothing moved
+    # appended, nothing moved: behind it only what later PRs appended
+    names = [m["name"] for m in bench["per_layer"]]
+    assert names[names.index("rendezvous_ahead_share"):] == [
+        "rendezvous_ahead_share", "bfs_column_levels_per_call"]
     # the cells that send a bound @recurse to a device tier, all of them
     recursing = [w["name"] for w in bench["workloads"]
                  if w["config"].startswith("graph500-khop")]
     assert entry["workloads"] == recursing
+
+
+COLUMN_READER = os.path.join(ROOT, "benchmark", "metrics",
+                             "bfs_column_levels_per_call.py")
+COLUMNS = "recurse_column_levels_total"
+# (counters before the window, after it, what the reader says)
+COLUMN_LEVELS = {
+    # 2,800 calls a window, every one's first level from columns
+    "a-window": ({COLUMNS: 60, CALLS: 60},
+                 {COLUMNS: 60 + 2_800, CALLS: 60 + 2_800}, 1.0),
+    # one call in four carried more roots than the rule allows
+    "some-calls-streamed": ({COLUMNS: 3, CALLS: 5},
+                            {COLUMNS: 3 + 600, CALLS: 5 + 800}, 0.75),
+    # served, and the rule kept every first level streamed
+    "no-call-took-columns": ({COLUMNS: 0, CALLS: 10},
+                             {COLUMNS: 0, CALLS: 410}, 0.0),
+    # what the parent serves (the reader is laid over its checkout
+    # too): the calls and the tiles, not this counter
+    "a-program-without-the-counter": (
+        {CALLS: 1}, {CALLS: 900, STREAMED: 9_000, TOTAL: 14_000,
+                     'rendezvous_ahead_total{family="recurse"}': 890},
+        None),
+    "the-counter-without-the-calls": ({}, {COLUMNS: 5}, None),
+    "no-call-in-the-window": ({COLUMNS: 9, CALLS: 12},
+                              {COLUMNS: 9, CALLS: 12}, None),
+    "nothing-served": ({}, {}, None),
+}
+
+
+@pytest.mark.parametrize("case", sorted(COLUMN_LEVELS))
+def test_the_column_levels_reader(case):
+    before, after, want = COLUMN_LEVELS[case]
+    got = _read(COLUMN_READER, before, after)
+    assert got == want if want is None else got == pytest.approx(want)
+
+
+def test_the_column_levels_are_a_metric_of_both_khop_cells_and_no_other():
+    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
+        bench = json.load(f)
+    entry, = (m for m in bench["per_layer"]
+              if m["name"] == "bfs_column_levels_per_call")
+    assert entry == {
+        "name": "bfs_column_levels_per_call", "unit": "levels",
+        "better": "higher", "source": "program_counter",
+        "layer": "kernels", "moves": "ok_qps",
+        "workloads": ["graph500-khop.khop-deep-c16",
+                      "graph500-khop-x4.khop-deep-c16"]}
+    assert bench["per_layer"][-1] is entry     # appended, nothing moved
+    recursing = [w["name"] for w in bench["workloads"]
+                 if w["config"].startswith("graph500-khop")]
+    assert entry["workloads"] == recursing
+    # the one reader this PR adds, beside every other metric's
+    assert os.path.exists(COLUMN_READER)
+
+
+def test_the_column_levels_are_counted_spanned_and_served(
+        worlds, monkeypatch):
+    """The call's third tally row, [2], reaches a registered counter,
+    the recurse span, its device.call child and the exposition the
+    harness scrapes: 0 where the rule keeps the first level streamed
+    (served all the same), 1 where it is read from columns; the
+    answer is the host tier's both ways."""
+    import functools
+    import urllib.request
+    from dgraph_tpu.server.http import serve
+    facts, dev, host, _ = worlds[10, 7]
+    badj = _tile(dev)
+    tiles = -(-badj.dense.shape[0] // bitgraph._HUB_TILE_ROWS)
+    # a row of this graph is ONE block of 128 word columns: a column
+    # a seed costs a stream of every row, and the rule says stream
+    assert badj.dense.shape[1] == 128 and not bitgraph.columns_cheaper(
+        8, *badj.dense.shape, 0)
+    assert COLUMNS in metrics.REGISTERED
+
+    def spans(name):
+        return [s["args"] for s in tracing.recent_spans()
+                if s["name"] == name and s["args"].get("tier", "device")
+                == "device" and "column_levels" in s["args"]]
+
+    metrics.reset()
+    tracing.clear()
+    before = metrics.counters_snapshot()
+    q = _q(KHOP, [graph500.FIRST_UID + 9], 7)
+    assert _data(dev, q) == _data(host, q)
+    c = metrics.snapshot()["counters"]
+    assert c[COLUMNS] == 0 and c[CALLS] == 1         # served, and 0
+    streamed = spans("recurse")[-1]
+    assert streamed["column_levels"] == 0 \
+        == spans("device.call")[-1]["column_levels"]
+    assert _read(COLUMN_READER, before, metrics.counters_snapshot()) == 0.0
+    # the same request with the first level read from columns
+    monkeypatch.setattr(bitgraph, "traverse", functools.partial(
+        traverse_as, columns=True))
+    assert _data(dev, q) == _data(host, q)
+    c = metrics.snapshot()["counters"]
+    assert c[COLUMNS] == 1 and c[CALLS] == 2
+    columns = spans("recurse")[-1]
+    assert columns["column_levels"] == 1 \
+        == spans("device.call")[-1]["column_levels"]
+    assert columns["levels_run"] == streamed["levels_run"]
+    assert columns["reached"] == streamed["reached"]
+    # a level's tiles fewer streamed, as many counted
+    assert columns["hub_tiles"] == streamed["hub_tiles"]
+    assert columns["hub_tiles_streamed"] \
+        == streamed["hub_tiles_streamed"] - tiles
+    assert _read(COLUMN_READER, before, metrics.counters_snapshot()) \
+        == pytest.approx(0.5)
+    share = os.path.join(ROOT, "benchmark", "metrics",
+                         "bfs_rows_streamed_share.py")
+    assert _read(share, before, metrics.counters_snapshot()) \
+        == pytest.approx(100.0 * (2 * streamed["hub_tiles_streamed"] - tiles)
+                         / (2 * streamed["hub_tiles"]))
+    httpd, _ = serve(dev, host="127.0.0.1", port=0, block=False)
+    try:
+        with urllib.request.urlopen(
+                f"http://127.0.0.1:{httpd.server_address[1]}"
+                "/debug/prometheus_metrics") as r:
+            text = r.read().decode()
+    finally:
+        httpd.shutdown()
+    assert f"{COLUMNS} 1" in text
 
 
 def test_a_served_call_behind_another_is_what_the_reader_reads(

@@ -19,6 +19,7 @@ from dgraph_tpu.ops import bitgraph
 from dgraph_tpu.parallel.mesh import make_mesh
 from dgraph_tpu.query import executor as executor_mod
 from dgraph_tpu.utils import metrics, tracing
+from recurse_cases import column_lanes, traverse_as
 
 CHIPS = 4
 LANES = bitgraph.LANES
@@ -83,21 +84,33 @@ def _both(one, four, riders, **kw):
     reached), held to the one-chip program's bit for bit: counts,
     levels and reached sets. The hub-row tiles (tally row 2) are a
     chip's own runs', so they differ by layout; neither program
-    streams more than there is."""
-    t1, r1 = _traverse(one, riders, **kw)
-    t4, r4 = _traverse(four, riders, **kw)
+    streams more than there is. Both programs twice: the first level
+    streamed (what is returned) and read from the roots' COLUMNS,
+    which is the same to the bit and streams a level's tiles fewer."""
+    t1, r1 = _traverse(one, riders, columns=False, **kw)
+    t4, r4 = _traverse(four, riders, columns=False, **kw)
     assert t1.dtype == t4.dtype and r1.dtype == r4.dtype
     assert np.array_equal(t1[:2], t4[:2]) and np.array_equal(r1, r4)
     for t, adj in ((t1, one), (t4, four)):
         streamed, full = t[2, :2]
         assert 0 <= streamed <= full and not t[2, 2:].any()
-        assert (full > 0) == (adj.dense is not None)
+        assert (full > 0) == (adj.dense is not None and t[1].any())
+        c, rc = _traverse(adj, riders, columns=True, **kw)
+        assert np.array_equal(c[:2], t[:2]) and np.array_equal(rc, r1)
+        # (a chip whose run is all padding streams one tile a level,
+        # not a level's: at most a level's tiles fewer)
+        assert c[2, 1:].tolist() == [full, int(t[1].any())] \
+            + [0] * (LANES - 3)
+        assert streamed - (full // t[1].max() if full else 0) \
+            <= c[2, 0] <= streamed - bool(full)
     return t4, r4
 
 
-def _traverse(adj, riders, **kw):
-    """bitgraph.traverse -> (tally, reached) on the host."""
-    return tuple(np.asarray(x) for x in bitgraph.traverse(adj, riders, **kw))
+def _traverse(adj, riders, columns=None, **kw):
+    """bitgraph.traverse -> (tally, reached) on the host, the first
+    level's form forced where `columns` says which."""
+    return tuple(np.asarray(x) for x in traverse_as(
+        adj, riders, columns, **kw))
 
 
 # (vertices, edges drawn, seed): no vertex count is a multiple of 4 or
@@ -158,6 +171,61 @@ def test_sharded_traversal_over_more_than_a_vreg_of_vertices(graphs, mesh):
     assert np.array_equal(bitgraph.lane_uids(four, reached, 2),
                           _plain(edges, roots[1:], 64))
     assert tally[1, 2] < 64                    # it ended early
+
+
+@pytest.mark.parametrize("case", (
+    "padding_seeds", "one_root_in_two_lanes", "a_lane_of_depth_0",
+    "every_lane_of_depth_0", "a_root_with_no_out_edge",
+    "a_root_that_is_a_hub", "eight_seeds_no_padding",
+    "more_roots_than_the_rule_allows"))
+@pytest.mark.parametrize("rows", (None, 40, 0),
+                         ids=("all_hub_rows", "some_hub_rows", "no_hub_rows"))
+@pytest.mark.parametrize("graph", ("small", "wide"))
+def test_the_sharded_first_level_from_columns_is_the_streamed_one(
+        graphs, mesh, graph, rows, case):
+    """A chip reads the seeds' columns of ITS run of the hub rows and
+    of every gathered class; the level's one all-gather and the rest
+    of the call are as they were: (counts, levels, reached) bit for
+    bit (_both), the plain walk's, and by the rule from a chip's own
+    shapes where nobody forces a form."""
+    edges = dict(graphs[graph])
+    # (a vertex no edge leaves, which these graphs may lack)
+    last = max(max(edges), max(int(d[-1]) for d in edges.values()))
+    edges[min(edges)] = np.append(edges[min(edges)], np.uint32(last + 1))
+    one, four = _pair(edges, mesh, rows)
+
+    def ruled(adj, seeds):
+        """The rule's word for `seeds` seed slots over ONE chip's
+        shapes of `adj`."""
+        chip_rows, words = (0, 0) if adj.dense is None else (
+            adj.dense.shape[0] // adj.shards, adj.dense.shape[1])
+        held = adj.shard_nbs if adj.mesh is not None \
+            else [b.in_nb for b in adj.gathered]
+        return bitgraph.columns_cheaper(
+            seeds, chip_rows, words,
+            sum(int(nb.size) for nb in held) // adj.shards)
+
+    # the count of seed slots, a power of two, at which both layouts
+    # turn back to the stream
+    turn = next(s for s in (8, 16, 32, 64, 128, 256, 512, 1024, 2048)
+                if not (ruled(one, s) or ruled(four, s)))
+    lanes = column_lanes(case, edges, turn // LANES)
+    riders = _riders(four, lanes)
+    tally, reached = _both(one, four, riders, tile=TILE)
+    for i, (roots, depth) in enumerate(lanes):
+        want = _plain(edges, roots, depth)
+        assert tally[0, i] == len(want), (roots, depth)
+        assert np.array_equal(bitgraph.lane_uids(four, reached, i), want)
+    seeds = max(8, sum(len(roots) for roots, _ in lanes))
+    for adj in (one, four):
+        t, r = _traverse(adj, riders, tile=TILE)
+        assert np.array_equal(t[:2], tally[:2])
+        assert np.array_equal(r, reached)
+        assert t[2, 2] == (ruled(adj, seeds) and tally[1].any())
+        if case == "more_roots_than_the_rule_allows":
+            assert seeds == turn and t[2, 2] == 0
+        elif rows is not None and tally[1].any():
+            assert t[2, 2] == 1
 
 
 @pytest.mark.parametrize("rows", (None, 40, 0),
@@ -296,11 +364,11 @@ def _riders(adj, lanes):
 def _checked(settling, lanes):
     """The riders over both adjacencies in tiles of TILE rows, every
     lane held to the plain BFS -> the one-chip and the sharded
-    tallies."""
+    tallies, every level of both streamed."""
     edges, one, four = settling
     riders = _riders(one, lanes)
     t4, reached = _both(one, four, riders, tile=TILE)
-    t1, _ = _traverse(one, riders, tile=TILE)
+    t1, _ = _traverse(one, riders, tile=TILE, columns=False)
     for i, (roots, depth) in enumerate(lanes):
         want = _plain(edges, roots, depth)
         assert t4[0, i] == len(want), (roots, depth)

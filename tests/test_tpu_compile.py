@@ -80,6 +80,22 @@ def test_the_hub_rows_kernel_compiles_at_the_cells_width(one_chip):
     assert "tpu_custom_call" in compiled.as_text()
 
 
+@pytest.mark.parametrize("rows,words,seeds", (
+    (ROWS, WORDS, 8), (ROWS, WORDS, 32),
+    (bitgraph._chip_rows(ROWS4, CHIPS), bitgraph.hub_row_words(N4), 8)),
+    ids=("khop", "khop-32-seeds", "khop-x4-a-chip"))
+def test_the_columns_kernel_compiles_at_the_cells_widths(
+        one_chip, rows, words, seeds):
+    """With a seed's block of columns as a prefetched scalar that the
+    rows' index map reads on the MINOR axis, and the seeds as the
+    grid's inner axis over an output block that stands still."""
+    compiled = jax.jit(bitgraph._columns_call).lower(
+        _shape(one_chip, (rows, words), jnp.uint32),
+        *[_shape(one_chip, (seeds,), jnp.int32)] * 2,
+        *[_shape(one_chip, (seeds,), jnp.uint32)] * 2).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
 def test_the_whole_traversal_compiles_with_the_kernel_in_it(
         one_chip, monkeypatch):
     # the program asks which backend it is traced for; here that is
@@ -92,7 +108,13 @@ def test_the_whole_traversal_compiles_with_the_kernel_in_it(
         _shape(one_chip, (ROWS, WORDS), jnp.uint32),
         _shape(one_chip, (2 * 8 + lanes,), jnp.int32),
         n_slots=N, n_covered=covered, lanes=lanes).compile()
-    assert compiled.as_text().count("tpu_custom_call") == 1
+    # eight seed slots at this width: the first level reads their
+    # columns (a branch of the loop's body, the columns' kernel in
+    # it), the others stream (the rows' kernel)
+    assert bitgraph.columns_cheaper(
+        8, ROWS, WORDS, sum(m * d for m, d in GATHERED))
+    assert " conditional(" in compiled.as_text()
+    assert compiled.as_text().count("tpu_custom_call") == 2
     mem = compiled.memory_analysis()
     # the rows are an argument; what a call adds is lane state and the
     # kernel's per-row words, far under a chip's 16 GB
@@ -127,7 +149,13 @@ def test_the_sharded_traversal_compiles_for_four_chips_at_the_x4_cells_width(
         jax.ShapeDtypeStruct((2 * 8 + lanes,), jnp.int32, sharding=whole),
         mesh=mesh, part_rows=part_rows, n_slots=N4, lanes=lanes).compile()
     text = compiled.as_text()
-    assert text.count("tpu_custom_call") == 1
+    # the first level from the seeds' columns of a chip's own rows, a
+    # branch of the loop's body with the columns' kernel in it, beside
+    # the rows' kernel: the level's collective stays the one
+    assert bitgraph.columns_cheaper(
+        8, chip_rows, words, sum(h * d for h, (_, d) in zip(held, GATHERED4)))
+    assert " conditional(" in text
+    assert text.count("tpu_custom_call") == 2
     collectives = [ln for ln in text.splitlines() if any(
         f" {op}(" in ln or f" {op}-start(" in ln
         for op in ("all-gather", "all-reduce", "all-to-all",
