@@ -179,6 +179,69 @@ def check_bfs_traverse(tiny) -> dict:
                       "lanes": bitgraph.LANES}}
 
 
+def check_bfs_paths(tiny) -> dict:
+    """bitgraph.bfs_paths, the served one-path `shortest`, every lane
+    ridden with its own pair and depth, over the TRANSPOSED tile with
+    hub rows and gathered classes both; twin = a plain search in
+    NumPy: distances to the target against the edges, then from the
+    source the smallest-uid out-neighbour one nearer."""
+    from dgraph_tpu.bench.bfsgraph import csr_to_dict, make_graph
+    from dgraph_tpu.ops import bitgraph
+
+    nodes, n_edges = (2000, 20_000) if tiny else (200_000, 4_000_000)
+    uniq_src, indptr, dst = make_graph(nodes, n_edges)
+    edges = csr_to_dict(uniq_src, indptr, dst)
+    # the edges against their direction: a vertex's in-neighbours at
+    # src[at[v]:at[v + 1]], sorted
+    order = np.argsort(dst, kind="stable")
+    src = np.repeat(uniq_src, np.diff(indptr))[order].astype(np.int64)
+    at = np.searchsorted(dst[order], np.arange(nodes + 2))
+    badj = bitgraph.build_bitadjacency({
+        v: src[at[v]:at[v + 1]].astype(np.uint32)
+        for v in np.unique(dst).tolist()})
+    rows = sum(int(b.in_nb.shape[0]) for b in badj.buckets) // 2
+    bitgraph.attach_dense(
+        badj, rows * 4 * bitgraph.hub_row_words(badj.n_slots))
+    bitgraph.attach_uids(badj)
+    rng = _rng(11)
+    pairs, want = [], []
+    for lane in range(bitgraph.LANES):
+        a = int(uniq_src[rng.integers(0, len(uniq_src))])
+        b = int(dst[rng.integers(0, len(dst))])
+        depth = (2, 3, 15)[lane % 3]
+        slots, _ = bitgraph._uid_slots(badj, np.asarray([a, b], np.uint32))
+        pairs.append((int(slots[0]), int(slots[1]), depth))
+        dist = np.full(nodes + 1, -1, np.int64)
+        dist[b], frontier = 0, np.asarray([b])
+        for hop in range(1, depth + 1):
+            if dist[a] >= 0 or not len(frontier):
+                break
+            lens = at[frontier + 1] - at[frontier]
+            met = np.unique(src[np.repeat(
+                at[frontier] - (np.cumsum(lens) - lens), lens)
+                + np.arange(int(lens.sum()))])
+            frontier = met[dist[met] < 0]
+            dist[frontier] = hop
+        path = [a] if dist[a] >= 0 else []
+        while path and path[-1] != b:
+            nbs = edges[path[-1]].astype(np.int64)
+            path.append(int(nbs[dist[nbs] == dist[path[-1]] - 1][0]))
+        want.append(path)
+    out = np.asarray(bitgraph.paths(badj, pairs))
+    got = [bitgraph.path_uids(badj, out[i]) for i in range(len(pairs))]
+    same = [g == w for g, w in zip(got, want)]
+    return {"ok": all(same), "lanes_equal": int(sum(same)),
+            "hops": out[:len(pairs), 0].tolist(),
+            "levels_run": int(out[-1, 0]),
+            "hub_tiles_streamed": int(out[-1, 1]),
+            "hub_tiles": int(out[-1, 2]), "column_levels": int(out[-1, 3]),
+            "shape": {"slots": badj.n_slots, "edges": badj.n_edges,
+                      "hub_rows": 0 if badj.dense is None
+                      else list(badj.dense.shape),
+                      "gathered_classes": len(badj.gathered),
+                      "lanes": bitgraph.LANES}}
+
+
 def check_sssp_dist(db, pred) -> dict:
     """sssp_dist over the loaded graph's bitadjacency; twin = a NumPy
     level-synchronous BFS over the tablet's flat edge list, walked
@@ -312,6 +375,7 @@ def run(db, pred: str | None = None, checks: tuple = (),
     table = [
         ("bfs_digest_xla", lambda: check_bfs_digest(tiny)),
         ("bfs_traverse", lambda: check_bfs_traverse(tiny)),
+        ("bfs_paths", lambda: check_bfs_paths(tiny)),
         ("sssp_dist", lambda: check_sssp_dist(db, pred)),
         ("range_select", lambda: check_range_select(db)),
         ("fused_rank_page", lambda: check_fused_rank_page(tiny)),

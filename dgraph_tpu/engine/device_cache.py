@@ -278,7 +278,7 @@ def device_radjacency(db, tab, read_ts: int,
 
 
 def device_bitadjacency(db, tab, read_ts: int, transpose: bool = False,
-                        dense: bool = False):
+                        dense: bool = False, walk: bool = False):
     """Bitmap adjacency (ops/bitgraph) for @recurse, BFS and SSSP.
     Same residency policy as device_adjacency: clean rolled-up tablets
     only; cached per base_ts. With transpose=True the expansion walks
@@ -291,6 +291,10 @@ def device_bitadjacency(db, tab, read_ts: int, transpose: bool = False,
     splits a predicate (uid_mesh), the served traversal's rows are
     split over its chips: the room is then ONE chip's, every chip
     holds its run of the rows, and the tile counts a chip's bytes.
+    With walk=True the tile also carries its slots' uids
+    (bitgraph.attach_uids: 4 B a vertex), by which the served
+    shortest path's walk breaks ties (bitgraph.bfs_paths, over the
+    transposed tile WITH its hub rows).
     A tile like the others: counted in `device_cache_bytes` under the
     HBM budget and evictable. The gauges
     `device_bitadj_bytes{predicate}` (the in-neighbour matrices' and
@@ -298,18 +302,21 @@ def device_bitadjacency(db, tab, read_ts: int, transpose: bool = False,
     `device_bitadj_chip_bytes{predicate}` (the fullest chip's share
     of them), `device_bitadj_shards{predicate}` (the chips they are
     split over, 1 without a mesh) and
-    `device_bitadj_edges{predicate}` say what is resident, 0 after an
+    `device_bitadj_edges{predicate}` and
+    `device_bitadj_hub_rows{predicate}` (the vertices held as bitmap
+    rows and not gathered) say what is resident, 0 after an
     eviction; the transposed tile is labelled `~pred`."""
     if not _clean_resident(db, tab, read_ts):
         return None
     attr = "_device_badj_t" if transpose else "_device_badj"
     badj = getattr(tab, attr, None)
     fresh = badj is None or getattr(tab, attr + "_ts", -1) != tab.base_ts
-    if not fresh and (badj.dense_from is not None or not dense):
+    if not fresh and (badj.dense_from is not None or not dense) \
+            and (badj.uids_dev is not None or not walk):
         db.device_cache.touch(tab, attr)
         return badj
     from dgraph_tpu.ops.bitgraph import (
-        attach_dense, build_bitadjacency, resident_bytes,
+        attach_dense, attach_uids, build_bitadjacency, resident_bytes,
     )
     if fresh:
         n_edges = sum(len(v) for v in tab.edges.values())
@@ -321,17 +328,21 @@ def device_bitadjacency(db, tab, read_ts: int, transpose: bool = False,
             return None
         with _tile_load(pred=tab.pred, kind="bitadj", edges=n_edges):
             badj = build_bitadjacency(edges32)
-    if dense:
+    if dense and badj.dense_from is None:
         with _tile_load(pred=tab.pred, kind="bitadj_dense",
                         edges=badj.n_edges):
             attach_dense(badj, max(
                 0, db.device_cache.budget - db.device_cache.bytes),
                 mesh=uid_mesh(db))
+    if walk:
+        attach_uids(badj)
     setattr(tab, attr, badj)
     setattr(tab, attr + "_ts", tab.base_ts)
     labels = {"predicate": ("~" if transpose else "") + tab.pred}
 
-    def gauges(nbytes: float, shards: float, edges: float) -> None:
+    def gauges(nbytes: float, shards: float, edges: float,
+               hub_rows: float = 0.0) -> None:
+        set_gauge("device_bitadj_hub_rows", hub_rows, labels=labels)
         set_gauge("device_bitadj_bytes", nbytes, labels=labels)
         # equal runs of rows a chip: every chip is the fullest
         set_gauge("device_bitadj_chip_bytes", nbytes / max(shards, 1),
@@ -342,7 +353,7 @@ def device_bitadjacency(db, tab, read_ts: int, transpose: bool = False,
     db.device_cache.put(tab, attr, badj,
                         on_evict=lambda: gauges(0.0, 0.0, 0.0))
     gauges(float(resident_bytes(badj)), float(badj.shards),
-           float(badj.n_edges))
+           float(badj.n_edges), float(badj.dense_rows))
     return badj
 
 

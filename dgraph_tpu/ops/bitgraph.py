@@ -41,6 +41,10 @@ FIRST level, whose frontier is its handful of roots, reads neither:
 the out-neighbours of a root are its COLUMN of these reverse
 structures (_chip_columns), where that is cheaper (columns_cheaper).
 
+The SERVED shortest path (bfs_paths) is that loop again, run from a
+pair's TARGET over the transposed tile and ended when every lane has
+met its source; the path is then walked out on the device.
+
 SSSP follows the same layout with an int32 distance vector and a
 min-reduction instead of any(): Bellman-Ford over dense tiles, with
 optional per-edge weights aligned to the in-neighbor matrices.
@@ -106,6 +110,8 @@ class BitAdjacency:
     # rows a chip, the padding pointing at the dummy slot
     mesh: Optional[jax.sharding.Mesh] = None
     shard_nbs: Optional[list] = None
+    # the served shortest path (attach_uids): slot_uids on the device
+    uids_dev: Optional[jax.Array] = None
 
     @property
     def gathered(self) -> list[RevBucket]:
@@ -442,11 +448,12 @@ def attach_dense(badj: BitAdjacency, budget_bytes: int,
 def resident_bytes(badj: BitAdjacency) -> int:
     """Bytes of the adjacency on the device, all chips together: the
     in-neighbour matrices and the hub rows as the served traversal
-    holds them."""
+    holds them, and the slots' uids where the served shortest path
+    has asked for them."""
     held = badj.shard_nbs if badj.mesh is not None \
         else [b.in_nb for b in badj.buckets]
-    return sum(int(a.nbytes) for a in held) \
-        + (int(badj.dense.nbytes) if badj.dense is not None else 0)
+    return sum(int(a.nbytes) for a in (
+        *held, *(x for x in (badj.dense, badj.uids_dev) if x is not None)))
 
 
 def _host_nb(b: RevBucket) -> np.ndarray:
@@ -740,6 +747,15 @@ def bfs_traverse(in_nbs, dense, riders, *, n_slots: int, n_covered: int,
                 They stay on the device unless a lane's reader wants
                 the uids (lane_uids)
     """
+    return _traverse_lanes(
+        *_one_chip(in_nbs, dense, riders, n_slots, n_covered, lanes, tile,
+                   columns), riders, n_slots, lanes)
+
+
+def _one_chip(in_nbs, dense, riders, n_slots: int, n_covered: int,
+              lanes: int, tile: int, columns: Optional[bool]):
+    """_traverse_lanes' (level, first, whole) for an adjacency held
+    whole on one chip, as bfs_traverse's arguments describe it."""
     rows = 0 if dense is None else dense.shape[0]
 
     def level(frontier, active, reached):
@@ -752,9 +768,8 @@ def bfs_traverse(in_nbs, dense, riders, *, n_slots: int, n_covered: int,
         return jnp.concatenate([
             share, jnp.zeros((n_slots - n_covered,), jnp.uint32)])
 
-    return _traverse_lanes(
-        level, _first_level(in_nbs, dense, riders, lanes, tile, columns),
-        whole, riders, n_slots, lanes)
+    return level, _first_level(in_nbs, dense, riders, lanes, tile,
+                               columns), whole
 
 
 def _first_level(in_nbs, dense, riders, lanes: int, tile: int,
@@ -779,7 +794,7 @@ def _first_level(in_nbs, dense, riders, lanes: int, tile: int,
 
 
 def _traverse_lanes(level, first, whole, riders, n_slots: int,
-                    lanes: int):
+                    lanes: int, ends=None):
     """bfs_traverse's loop: the riders unpacked, every lane run to
     its own depth, -> (tally, reached). `level(frontier, active,
     reached) -> (share, tiles)`: the rows this chip holds that a
@@ -788,7 +803,15 @@ def _traverse_lanes(level, first, whole, riders, n_slots: int,
     streamed and had; `whole(share)` -> the level's reach over every
     slot, uint32[N]. `first(seed slots, their lane bits) -> (share,
     tiles)`, or None: a call's first level answered from its seeds
-    and not from the frontier they make, which is the same set."""
+    and not from the frontier they make, which is the same set.
+
+    `ends` (bfs_paths'): int32[lanes], the slot at which a lane's
+    search ENDS (n_slots: none). A lane that has reached its end
+    holds no frontier from then on, so the loop is over when every
+    lane has met its end, emptied its frontier or spent its depth;
+    and the level at which a lane reached a slot is kept, ->
+    (tally, reached, levels int32[lanes, N], INT32_INF where it did
+    not). Without it the loop is the k-hop program's, op for op."""
     n_seeds = (riders.shape[0] - lanes) // 2
     seed_slots = riders[:n_seeds]
     seed_bits = jax.lax.bitcast_convert_type(
@@ -803,11 +826,18 @@ def _traverse_lanes(level, first, whole, riders, n_slots: int,
         return jnp.sum(jnp.where(depths > lvl, jnp.uint32(1) << lane,
                                  jnp.uint32(0)), dtype=jnp.uint32)
 
+    def unmet(seen):
+        """The word of the lanes whose end is not in `seen`."""
+        hit = (seen.at[ends].get(mode="fill", fill_value=0) >> lane) & 1
+        return jnp.sum(jnp.where(hit == 0, jnp.uint32(1) << lane,
+                                 jnp.uint32(0)), dtype=jnp.uint32)
+
     def cond(state):
-        return state[-1] != 0
+        return state[6] != 0
 
     def body(state):
-        lvl, frontier, visited, reached, levels_run, tiles, active = state
+        lvl, frontier, visited, reached, levels_run, tiles, active = \
+            state[:7]
         if first is None:
             share, streamed = level(frontier, active, reached)
         else:
@@ -820,21 +850,33 @@ def _traverse_lanes(level, first, whole, riders, n_slots: int,
         new = reach & ~visited
         # a lane goes on only within its depth and from a new slot
         frontier = new & expanding(lvl + 1)
+        kept = ()
+        if ends is not None:
+            # ... and only until it has met its end
+            frontier = frontier & unmet(visited | new)
+            kept = (jnp.where(_lane_planes(new, lanes) != 0, lvl + 1,
+                              state[7]),)
         return (lvl + 1, frontier, visited | new, reached | reach,
                 levels_run + ((active >> lane) & 1).astype(jnp.int32),
-                tiles + streamed, jnp.bitwise_or.reduce(frontier))
+                tiles + streamed, jnp.bitwise_or.reduce(frontier)) + kept
 
     start = seed & expanding(jnp.int32(0))
+    kept = ()
+    if ends is not None:
+        start = start & unmet(seed)
+        kept = (jnp.where(_lane_planes(seed, lanes) != 0, jnp.int32(0),
+                          INT32_INF),)
     active = jnp.bitwise_or.reduce(start)
-    _, _, _, reached, levels_run, tiles, _ = jax.lax.while_loop(
+    _, _, _, reached, levels_run, tiles, _, *kept = jax.lax.while_loop(
         cond, body, (jnp.int32(0), start, seed,
                      jnp.zeros((n_slots,), jnp.uint32),
-                     jnp.zeros((lanes,), jnp.int32), _NO_TILES, active))
+                     jnp.zeros((lanes,), jnp.int32), _NO_TILES, active)
+        + kept)
     counts = jnp.sum(_lane_planes(reached, lanes), axis=1, dtype=jnp.int32)
     # the first level ran where any lane held a frontier at all
     from_columns = jnp.int32(first is not None) * (active != 0)
-    return jnp.stack([counts, levels_run, jnp.pad(
-        jnp.append(tiles, from_columns), (0, lanes - 3))]), reached
+    return (jnp.stack([counts, levels_run, jnp.pad(
+        jnp.append(tiles, from_columns), (0, lanes - 3))]), reached, *kept)
 
 
 def _chip_reach(in_nbs, dense, frontier, active, pending, lanes: int,
@@ -1100,6 +1142,169 @@ def lane_uids(badj: BitAdjacency, reached: np.ndarray,
     """One lane of bfs_traverse's `reached` -> sorted uid uint32
     array."""
     return bits_to_uids(badj, (reached >> np.uint32(lane)) & np.uint32(1))
+
+
+# -- the served shortest path: the lanes' searches, then the walk ---------------
+
+
+# columns the path matrix has at least: a path of up to 15 hops, the
+# depth of the social benchmarks' query, in ONE compiled shape
+_PATH_WIDTH = 16
+
+
+def path_width(depth: int, n_slots: int) -> int:
+    """Columns of bfs_paths' path matrix for a call whose deepest
+    rider asks `depth` hops: a slot a hop and the source's, a power of
+    two (a compiled shape each), never more than the adjacency has
+    slots for."""
+    from dgraph_tpu.ops.uidvec import pad_to
+    return pad_to(min(depth, max(n_slots - 1, 0)) + 1, _PATH_WIDTH)
+
+
+@functools.partial(jax.jit, static_argnames=(
+    "n_slots", "n_covered", "lanes", "width", "tile", "columns"))
+def bfs_paths(in_nbs, dense, slot_uids, riders, *, n_slots: int,
+              n_covered: int, lanes: int, width: int = _PATH_WIDTH,
+              tile: int = _HUB_TILE_ROWS, columns: Optional[bool] = None):
+    """`shortest(from:, to:, depth:)` over one predicate, unweighted,
+    one path, for every LANE of the call: up to `lanes` pairs over one
+    adjacency in one program. The adjacency is the TRANSPOSED tile of
+    the direction the paths follow: a row a vertex that has an
+    out-edge, holding its OUT-neighbours (`in_nbs`, `dense`: what
+    bfs_traverse takes, of the transposed edges), so that a level of
+    the k-hop loop, run from a lane's TARGET, reaches the vertices one
+    hop further FROM which the target is reached: the level at which
+    a lane reaches a slot is that slot's distance TO the target. The
+    loop is bfs_traverse's (_traverse_lanes: the same gathers, hub
+    rows, tiles skipped and first level from the targets' columns)
+    and it ENDS when every lane has met its source, emptied its
+    frontier or spent its depth: 3 to 6 levels between two profiles
+    of a social graph, whatever depth the query allows.
+
+    Then the walk, on the device: from a lane's source, at every hop
+    the out-neighbour of the SMALLEST UID among those one level
+    nearer the target, which read from the source is the
+    lexicographically least of the shortest paths: the one path every
+    tier gives (docs/deployment.md, "shortest"). A hop reads the row
+    of the slot it stands on (a gathered class's indices, or a hub
+    row's bits), a lane's levels at those slots and their uids.
+
+    slot_uids   uint32[N]: the uid in every slot (the tie rule's)
+    riders      int32[2 S + 2 lanes], ONE upload a call:
+                bfs_traverse's riders with a lane's TARGET as its one
+                root and the hops it allows as its depth, then a
+                SOURCE slot a lane (n_slots for a lane nobody rides)
+    width       columns of the path matrix (path_width)
+    ->  int32[lanes + 1, 2 + width], ONE fetch a call. Row b, lane
+        b's: [0] the path's hops, -1 where the target is not reached
+        from the source within the lane's depth; [1] levels the lane
+        expanded; [2:] the path's slots from the source to the
+        target, n_slots behind them (and AT a hop where the walk
+        found no neighbour one level nearer, which a sound tile never
+        shows: the caller then answers as for a tile that cannot
+        speak). Row `lanes`, the call's: [0] levels the loop ran,
+        [1] tiles of hub rows they streamed, [2] tiles a stream of
+        every row at every level reads, [3] levels read from columns.
+    """
+    ends = riders[-lanes:]
+    riders = riders[:-lanes]
+    tally, _, levels = _traverse_lanes(
+        *_one_chip(in_nbs, dense, riders, n_slots, n_covered, lanes, tile,
+                   columns), riders, n_slots, lanes, ends=ends)
+    lane = jnp.arange(lanes)
+    far = jnp.full((lanes, 1), INT32_INF)
+    levels_ext = jnp.concatenate([levels, far], axis=1)
+    hops = levels_ext[lane, ends]
+    hops = jnp.where(hops == INT32_INF, -1, hops)
+    uid_ext = jnp.concatenate([slot_uids, jnp.zeros((1,), jnp.uint32)])
+    no_uid = jnp.uint32(0xFFFFFFFF)
+
+    def least(ok, uids, slots):
+        """Of the candidates `ok` ([lanes, C]) the slot of the least
+        uid a lane, and whether it has any."""
+        at = jnp.argmin(jnp.where(ok, uids, no_uid), axis=1)
+        return jnp.broadcast_to(slots, ok.shape)[lane, at], \
+            jnp.any(ok, axis=1)
+
+    def hop(state):
+        k, cur, path = state
+        # the level of a lane's k-th slot, where its path has one
+        want = jnp.where(k <= hops, hops - k, -1)[:, None]
+        nxt = jnp.full((lanes,), n_slots, jnp.int32)
+        at = 0
+        for nb in in_nbs:
+            m = nb.shape[0]
+            out = nb[jnp.clip(cur - at, 0, m - 1)]
+            ok = (jnp.take_along_axis(levels_ext, out, axis=1) == want) \
+                & ((cur >= at) & (cur < at + m))[:, None]
+            slot, found = least(ok, uid_ext[out], out)
+            nxt = jnp.where(found, slot, nxt)
+            at += m
+        if dense is not None:
+            rows, words = dense.shape
+            row = dense[jnp.clip(cur - (n_covered - rows), 0, rows - 1)]
+            # slot s is bit s // words of word s % words
+            out = ((row[:, None, :] >> jnp.arange(
+                32, dtype=jnp.uint32)[None, :, None]) & 1).reshape(
+                    lanes, 32 * words)[:, :n_slots] != 0
+            ok = out & (levels == want) \
+                & ((cur >= n_covered - rows) & (cur < n_covered))[:, None]
+            slot, found = least(ok, slot_uids[None, :],
+                                jnp.arange(n_slots, dtype=jnp.int32)[None, :])
+            nxt = jnp.where(found, slot, nxt)
+        return k + 1, nxt, jax.lax.dynamic_update_slice(
+            path, nxt[:, None], (0, k))
+
+    start = jnp.where(hops >= 0, ends, n_slots)
+    _, _, path = jax.lax.while_loop(
+        lambda state: state[0] <= jnp.minimum(jnp.max(hops), width - 1),
+        hop, (jnp.int32(1), start, jnp.full(
+            (lanes, width), n_slots, jnp.int32).at[:, 0].set(start)))
+    call = jnp.pad(jnp.concatenate([
+        jnp.max(tally[1])[None], tally[2, :3]]), (0, width - 2))
+    return jnp.concatenate([
+        jnp.concatenate([hops[:, None], tally[1][:, None], path], axis=1),
+        call[None, :]])
+
+
+def paths(badj: BitAdjacency, pairs: list, tile: int = _HUB_TILE_ROWS,
+          columns: Optional[bool] = None):
+    """bfs_paths over a TRANSPOSED adjacency as attach_dense and
+    attach_uids left it, one lane a pair: `pairs` is [(source slot,
+    target slot, depth)], at most LANES of them; pair i is row i of
+    the result. ONE compiled shape an adjacency while no pair asks
+    more than 15 hops (then a power of two of them). `tile` and
+    `columns` as bfs_traverse takes them (the tests' to set)."""
+    packed = np.concatenate([
+        _pack_riders(badj.n_slots, [
+            (np.asarray([dst], np.int32), depth) for _, dst, depth in pairs]),
+        np.asarray([src for src, _, _ in pairs]
+                   + [badj.n_slots] * (LANES - len(pairs)), np.int32)])
+    return bfs_paths(
+        [b.in_nb for b in badj.gathered], badj.dense, badj.uids_dev, packed,
+        n_slots=badj.n_slots, n_covered=badj.n_covered, lanes=LANES,
+        width=path_width(max(d for _, _, d in pairs), badj.n_slots),
+        tile=tile, columns=columns)
+
+
+def attach_uids(badj: BitAdjacency) -> None:
+    """Give the adjacency its slots' uids on the device, which the
+    walk of bfs_paths breaks ties by."""
+    if badj.uids_dev is None:
+        badj.uids_dev = jnp.asarray(badj.slot_uids)
+
+
+def path_uids(badj: BitAdjacency, row: np.ndarray) -> Optional[list]:
+    """A lane's row of bfs_paths' result -> its path's uids from the
+    source to the target, [] where there is none, None where the walk
+    did not finish (bfs_paths says when)."""
+    hops = int(row[0])
+    if hops < 0:
+        return []
+    slots = row[2:3 + hops]
+    if len(slots) != hops + 1 or (slots >= badj.n_slots).any():
+        return None
+    return badj.slot_uids[slots].tolist()
 
 
 # -- batched (multi-query) kernels -------------------------------------------

@@ -302,7 +302,10 @@ class Rendezvous:
     Kept on the tile it serves (`Rendezvous.at`), so requests meet
     only over the very object their own read_ts resolved to: another
     base_ts, direction or predicate is another tile and another
-    rendezvous.
+    rendezvous. And one a FAMILY: the k-hop traversals (`recurse`)
+    and the shortest paths (`shortest`) over one tile are two
+    programs, so each family has its own calls, queue and counters,
+    and a rider never boards the other's.
 
     The thread that lands a call spans it: one `device.flight` span a
     call, with `family`, `lanes`, `ahead` (it was launched behind a
@@ -341,14 +344,16 @@ class Rendezvous:
 
     @classmethod
     def at(cls, tile, capacity: int, family: str = "") -> "Rendezvous":
-        """The tile's own rendezvous, made on first asking; `family`
-        labels its flights' span and counters."""
-        meet = getattr(tile, "_rendezvous", None)
+        """The tile's own rendezvous of `family`, made on first
+        asking; `family` labels its flights' span and counters."""
+        attr = "_rendezvous_" + family
+        meet = getattr(tile, attr, None)
         if meet is None:
             with cls._make:
-                meet = getattr(tile, "_rendezvous", None)
+                meet = getattr(tile, attr, None)
                 if meet is None:
-                    meet = tile._rendezvous = cls(capacity, family)
+                    meet = cls(capacity, family)
+                    setattr(tile, attr, meet)
         return meet
 
     def _board(self, first: Ride | None = None) -> _Flight | None:

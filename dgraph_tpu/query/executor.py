@@ -323,6 +323,42 @@ def _land_traversals(handle, n: int) -> list:
              (streamed, full, columns)) for i in range(n)]
 
 
+def _launch_paths(badj, pairs: list):
+    """A Rendezvous' `launch` for the one-path `shortest`: ONE call of
+    bitgraph.bfs_paths for `pairs` ([(source slot, target slot,
+    depth)], a lane each), not waited for. `shortest_calls_total`
+    counts the calls, `shortest_riders_total` the pairs they carried:
+    lanes a call is their ratio."""
+    from dgraph_tpu.ops import bitgraph
+    out = bitgraph.paths(badj, pairs)
+    out.copy_to_host_async()
+    inc_counter("shortest_calls_total")
+    inc_counter("shortest_riders_total", len(pairs))
+    return out
+
+
+def _land_paths(handle, n: int) -> list:
+    """A Rendezvous' `land`: every rider's (own row of the call's one
+    small result: hops, levels, the path's slots; the call's levels
+    run, hub-row tiles streamed and all told, column levels), once
+    the call has run. `shortest_fetch_bytes_total` counts the bytes
+    of that result, all that leaves the device for the call's riders;
+    `shortest_levels_run_total` the levels the calls' loops ran (a
+    call ends when its LAST lane has met its source, emptied its
+    frontier or spent its depth); `shortest_rows_streamed_tiles_total`
+    over `shortest_rows_tiles_total` is the share of the hub rows'
+    stream the levels still read, `shortest_column_levels_total` the
+    first levels read from the targets' columns (0 or 1 a call)."""
+    out = np.asarray(handle)
+    levels, streamed, full, columns = (int(t) for t in out[-1, :4])
+    inc_counter("shortest_fetch_bytes_total", out.nbytes)
+    inc_counter("shortest_levels_run_total", levels)
+    inc_counter("shortest_rows_streamed_tiles_total", streamed)
+    inc_counter("shortest_rows_tiles_total", full)
+    inc_counter("shortest_column_levels_total", columns)
+    return [(out[i], (levels, streamed, full, columns)) for i in range(n)]
+
+
 def _var_domain(vmap) -> np.ndarray:
     """The sorted uid set a value var is defined on — columnar vars
     answer from their uid array without materializing Vals."""
@@ -3716,6 +3752,16 @@ class Executor:
         margin = est_host_seconds * (1.0 - device_ratio)
         return margin > self.db.device_dispatch_seconds() * 1.25
 
+    def _device_beats(self, host_seconds: float,
+                      device_seconds: float) -> bool:
+        """_device_worth for a family whose two sides are both
+        reckoned before it runs (the traversals: planner.recurse_costs
+        and shortest_costs against bitgraph.level_seconds)."""
+        return self._device_worth(
+            host_seconds,
+            device_ratio=min(1.0, device_seconds / host_seconds)
+            if host_seconds else 1.0)
+
     def _device_expand(self, tab: Tablet, src: np.ndarray,
                        reverse: bool = False) -> Optional[np.ndarray]:
         from dgraph_tpu.engine.device_cache import (
@@ -4898,12 +4944,7 @@ class Executor:
             return None       # (a federated proxy is host-only)
         moments = tab.degree_moments(rev)
         host, levels = recurse_costs(len(roots), depth, *moments)
-
-        def worth(device_seconds: float) -> bool:
-            return self._device_worth(
-                host, device_ratio=min(1.0, device_seconds / host)
-                if host else 1.0)
-
+        worth = functools.partial(self._device_beats, host)
         mesh = uid_mesh(self.db)
         chips = 1 if mesh is None else mesh.shape[bitgraph.SHARD_AXIS]
         if not worth(levels * 4 * moments[1] / chips
@@ -5083,7 +5124,14 @@ class Executor:
         """shortest(from, to, numpaths, depth, minweight, maxweight)
         with optional @facets(<key>) edge weights on the predicate
         children. Ref: query/shortest.go:451 route() (Dijkstra),
-        :287 runKShortestPaths, gql/parser.go:2501 args."""
+        :287 runKShortestPaths, gql/parser.go:2501 args.
+
+        Every block runs under the span `shortest` (`depth`, `tier`;
+        on the device tier `lanes`, `levels`, `program` too), counted
+        in `shortest_tier_total{tier}`; the span's time accumulates
+        in `shortest_ns_total`, so that less
+        `device_call_ns_total{family="shortest"}` it is what a block
+        costs off the chip."""
         gq = node.gq
         sa = gq.shortest
         if sa is None or sa.from_ is None or sa.to is None:
@@ -5096,21 +5144,46 @@ class Executor:
         simple = (sa.numpaths <= 1 and not weighted
                   and sa.minweight == float("-inf")
                   and sa.maxweight == float("inf"))
-        if self.db.prefer_device and simple and len(pred_specs) == 1:
-            path = self._device_shortest(pred_specs[0][0], src, dst,
-                                         maxdepth)
+        t0 = _time.perf_counter_ns()
+        try:
+            with _span("shortest", depth=maxdepth) as sp:
+                sp["tier"] = "host"
+                path = self._one_path(pred_specs[0], src, dst, maxdepth,
+                                      sp) \
+                    if simple and len(pred_specs) == 1 else None
+                if path is not None:
+                    paths = [(path, float(len(path) - 1))] if path else []
+                else:
+                    paths = self._k_shortest(pred_specs, src, dst, maxdepth,
+                                             max(1, sa.numpaths),
+                                             sa.minweight, sa.maxweight)
+                self._finish_shortest(node, paths, pred_specs)
+                inc_counter("shortest_tier_total",
+                            labels={"tier": sp["tier"]})
+        finally:
+            inc_counter("shortest_ns_total", _time.perf_counter_ns() - t0)
+
+    def _one_path(self, spec: tuple, src: int, dst: int, depth: int,
+                  sp: dict) -> Optional[list[int]]:
+        """THE path of the simple block (one uid predicate, unweighted,
+        one path: docs/deployment.md, "shortest"), [] where there is
+        none, from the device tier (_device_shortest) or the host's
+        (storage/tablet.least_path): the same path from either. None
+        where the predicate is no local uid tablet (a federated proxy
+        keeps the general search)."""
+        _, tab, rev, _ = spec
+        if tab.schema.value_type != TypeID.UID \
+                or not hasattr(tab, "expand_in"):
+            return None
+        if src == dst:
+            return [src]
+        self._checkpoint("shortest")
+        if self.db.prefer_device:
+            path = self._device_shortest(tab, rev, src, dst, depth, sp)
             if path is not None:
-                # [] is the unreachable sentinel, None means not
-                # device-resident (fall through to host)
-                self._finish_shortest(
-                    node,
-                    [(path, float(len(path) - 1))] if path else [],
-                    pred_specs)
-                return
-        paths = self._k_shortest(pred_specs, src, dst, maxdepth,
-                                 max(1, sa.numpaths),
-                                 sa.minweight, sa.maxweight)
-        self._finish_shortest(node, paths, pred_specs)
+                return path
+        from dgraph_tpu.storage.tablet import least_path
+        return least_path(tab, src, dst, depth, self.read_ts, rev)
 
     def _shortest_preds(self, gq) -> list[tuple]:
         """[(attr, tablet, reverse, weight_facet_key)] for the block's
@@ -5274,60 +5347,67 @@ class Executor:
             else:
                 self.uid_vars[gq.var] = _EMPTY
 
-    def _device_shortest(self, pred: str, src: int, dst: int,
-                         maxdepth: int) -> Optional[list[int]]:
-        """Hop-count shortest path via the device SSSP kernel.
+    def _device_shortest(self, tab: Tablet, rev: bool, src: int, dst: int,
+                         depth: int, sp: dict) -> Optional[list[int]]:
+        """_one_path's device tier: the pair rides ONE call of
+        bitgraph.bfs_paths with the other `shortest` blocks in flight
+        over the tile (a Rendezvous of family `shortest`, eight lanes
+        a call, a full call queued behind the one in flight), and
+        fetches its own row of the call's result: the path's slots,
+        chosen on the device. -> the path's uids, [] where there is
+        none within `depth`; None where the host tier is to answer:
+        the gate says so, or the device cannot speak for the search
+        (uids over 32 bits, a dirty tablet or one under
+        device_min_edges, an engine whose mesh splits the predicate:
+        bfs_paths has no sharded form).
 
-        Distances-to-target come from one dense Bellman-Ford over the
-        traversal graph's transpose (ops/bitgraph.make_sssp_bits, the
-        TPU translation of query/shortest.go:451's priority queue);
-        the path itself is reconstructed on host by walking forward
-        from `src`, at each hop picking the smallest-uid neighbor one
-        step closer. Returns None when the tablet isn't device-resident
-        (caller falls back to host BFS), [] when unreachable."""
-        from dgraph_tpu.engine.device_cache import device_bitadjacency
-
-        rev = pred.startswith("~")
-        tab = self._tablet(pred[1:] if rev else pred)
-        if tab is None or tab.schema.value_type != TypeID.UID:
+        The family's `_device_worth` site, as _recurse_device is the
+        k-hop family's: the host's cost from the depth and the
+        tablet's degree moments (planner.shortest_costs), the
+        device's from the expected levels and the adjacency's layout
+        (bitgraph.level_seconds), both before the search runs. The
+        distances TO the target are pulled along out-neighbours, so
+        the tile is the TRANSPOSED one of the direction the path
+        follows, with its hub rows and its slots' uids."""
+        from dgraph_tpu.engine.device_cache import (
+            _MAX_U32, device_bitadjacency, uid_mesh,
+        )
+        from dgraph_tpu.ops import bitgraph
+        from dgraph_tpu.query.planner import shortest_costs
+        if max(src, dst) > _MAX_U32 or uid_mesh(self.db) is not None:
             return None
-        if rev and not tab.schema.reverse:
-            raise GQLError(
-                f"reverse edges are not defined for predicate "
-                f"{pred[1:]!r} (add @reverse to the schema)")
-        if src > 0xFFFFFFFE or dst > 0xFFFFFFFE:
+        moments = tab.degree_moments(rev)
+        host, levels = shortest_costs(depth, *moments)
+        worth = functools.partial(self._device_beats, host)
+        if not worth(levels * 4 * moments[1] / bitgraph.DENSE_BYTES_PER_S):
             return None
-        # walking ~pred backwards follows pred forwards, so the
-        # distance-to-target pass uses the untransposed adjacency
-        badj_t = device_bitadjacency(self.db, tab, self.read_ts,
-                                     transpose=not rev)
-        if badj_t is None:
+        badj = device_bitadjacency(self.db, tab, self.read_ts,
+                                   transpose=not rev, dense=True, walk=True)
+        if badj is None or badj.n_slots == 0 \
+                or not worth(levels * bitgraph.level_seconds(badj)):
             return None
-        from dgraph_tpu.ops.bitgraph import sssp_dist
-        if src == dst:
-            return [src]
-        with device_call("query_device_sssp_total", sink=self.lat,
-                         program="sssp") as dc:
-            dist_to = sssp_dist(badj_t, np.asarray([dst], np.uint32),
-                                max_iters=maxdepth, sync=dc.wait)
-        d0 = dist_to.get(src)
-        if d0 is None or d0 > maxdepth:
-            return []
-        path = [src]
-        u = src
-        while u != dst:
-            want = dist_to[u] - 1
-            nbrs = (tab.get_reverse_uids(u, self.read_ts) if rev
-                    else tab.get_dst_uids(u, self.read_ts))
-            nxt = None
-            for v in nbrs.tolist():
-                if dist_to.get(int(v)) == want:
-                    nxt = int(v)
-                    break
-            if nxt is None:  # overlay changed under us — fall back
-                return None
-            path.append(nxt)
-            u = nxt
+        with device_call("query_device_shortest_total", sink=self.lat,
+                         program="bfs_paths") as dc:
+            slots, hit = bitgraph._uid_slots(
+                badj, np.asarray([src, dst], np.uint32))
+            if not hit.all():
+                return []       # a uid no edge of the predicate touches
+            meet = Rendezvous.at(badj, bitgraph.LANES, family="shortest")
+            ride = dc.wait_for(
+                lambda: meet.ride(
+                    (int(slots[0]), int(slots[1]), depth),
+                    functools.partial(_launch_paths, badj),
+                    _land_paths, self.ctx),
+                out_bytes=4 * (2 + bitgraph.path_width(depth, badj.n_slots)))
+            row, (ran, streamed, full, columns) = ride.result
+            batch = {"lanes": ride.lanes, "levels": ran,
+                     "batch_wait_us": ride.waited_ns // 1000,
+                     "program": "bfs_paths", "hub_tiles_streamed": streamed,
+                     "hub_tiles": full, "column_levels": columns}
+            dc.note(**batch)
+            path = bitgraph.path_uids(badj, row)
+        if path is not None:
+            sp.update(tier="device", hops=len(path) - 1, **batch)
         return path
 
     def _fn_single_uid(self, fn: Function) -> int:

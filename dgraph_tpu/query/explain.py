@@ -57,12 +57,14 @@ _TABLET_BOUND_FNS = frozenset((
 _BASIS_RANK = {"exact": 0, "index": 1, "stats": 2, "unknown": 3}
 
 # stage spans ANALYZE surfaces from the request's trace, in recorded
-# order (coststore.STAGES' per-request ones, and `recurse`, whose
-# span names the tier a bound @recurse took and the chips it ran over)
+# order (coststore.STAGES' per-request ones; `recurse`, whose span
+# names the tier a bound @recurse took and the chips it ran over; and
+# `shortest`, whose span names the tier a shortest-path block took
+# and, on the device tier, the lanes and levels of the call it rode)
 _ANALYZE_SPANS = frozenset((
     "parse", "plan.compile", "block", "eq", "ineq", "setops", "expand",
-    "sort", "match", "similar_to", "recurse", "device.tile_load",
-    "encode", "batch.wait",
+    "sort", "match", "similar_to", "recurse", "shortest",
+    "device.tile_load", "encode", "batch.wait",
 ))
 
 
@@ -287,7 +289,7 @@ def _stage_spans(trace_id: str) -> list[dict]:
                                "durUs": round(rec.get("dur_us", 0.0), 1)}
         args = rec.get("args") or {}
         for k in ("pred", "fn", "alias", "rows", "n", "tier", "shards",
-                  "program", "role"):
+                  "program", "role", "lanes", "levels"):
             if k in args:
                 ent[k] = args[k]
         out.append(ent)

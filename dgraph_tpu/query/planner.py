@@ -75,6 +75,8 @@ correctness for speed.
 
 from __future__ import annotations
 
+import math
+
 import threading
 import time as _time
 from typing import Any, Optional
@@ -218,6 +220,45 @@ def recurse_costs(n_roots: int, depth: int, rows: int, edges: int,
         frontier, degree = min(touched, float(rows)), later
         levels += 1
     return host, min(depth, levels + 2)
+
+
+def shortest_costs(depth: int, rows: int, edges: int,
+                   sum_sq: int) -> tuple[float, int]:
+    """(host seconds, device levels) the one-path `shortest` block of
+    at most `depth` hops is expected to take between a random pair of
+    a tablet of `rows` edge rows, `edges` edges and `sum_sq` = the
+    sum of the squared row lengths (Tablet.degree_moments), reckoned
+    like recurse_costs before the block runs.
+
+    Both tiers search from the target against the edges, a level at a
+    time, until the source is met (storage/tablet.least_path,
+    ops/bitgraph.bfs_paths). Host: the edges the levels touch, each
+    level weighed by the chance that the search still runs it: that
+    the source is not among the uids met before. The frontier grows
+    as recurse_costs has it, and a uid an edge leads to is new with
+    the chance that none of the level's other edges found it first,
+    1 - exp(-edges touched / rows), of those not yet met: on a
+    social graph the last level before everything is met holds most
+    of the vertices and the one after it the rest. Device: the
+    levels, rounded up, whatever the frontiers hold
+    (ops/bitgraph.level_seconds says what one costs)."""
+    if rows <= 0 or edges <= 0 or depth <= 0:
+        return 0.0, 0
+    degree, later = edges / rows, sum_sq / edges
+    frontier, seen, left = 1.0, 1.0, float(edges)
+    host = levels = 0.0
+    for _ in range(depth):
+        runs = max(0.0, 1.0 - seen / rows)      # the source not yet met
+        if left <= 0 or frontier < 1 or runs < 0.01:
+            break
+        touched = min(frontier * degree, left)
+        host += runs * (frontier * RECURSE_HOST_PER_UID
+                        + touched * RECURSE_HOST_PER_EDGE)
+        levels += runs
+        left -= touched
+        frontier = max(0.0, rows - seen) * -math.expm1(-touched / rows)
+        seen, degree = seen + frontier, later
+    return host, min(depth, math.ceil(levels))
 
 
 def token_quantile(token_index: dict, q: float = 0.75) -> float:

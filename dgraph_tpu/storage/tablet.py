@@ -805,21 +805,46 @@ class Tablet:
         parts = [getter(int(u), read_ts) for u in slow.tolist()]
         parts = [p for p in parts if len(p)]
         if len(frontier):
-            srcs, offs, flat = self._csr(reverse)
-            idx = np.searchsorted(srcs, frontier)
-            idx[idx == len(srcs)] = 0
-            idx = idx[srcs[idx] == frontier] if len(srcs) else idx[:0]
-            starts = offs[idx]
-            lens = offs[idx + 1] - starts
-            total = int(lens.sum())
-            if total:
-                # row i's edges sit at starts[i] .. starts[i] + lens[i]
-                pos = np.repeat(starts - (np.cumsum(lens) - lens), lens)
-                pos += np.arange(total, dtype=np.int64)
-                parts.append(flat[pos])
+            parts.append(_csr_rows(self._csr(reverse), frontier))
         if not parts:
             return _EMPTY.copy()
         return np.unique(np.concatenate(parts))
+
+    def _csr_in(self) -> tuple[np.ndarray, np.ndarray, np.ndarray]:
+        """_csr's triple for the BASE edges read against their
+        direction (sorted dsts, offsets, flat srcs), from the forward
+        rows and so there whether or not the schema keeps `@reverse`;
+        cached per base_ts."""
+        cached = getattr(self, "_csr_in_cache", None)
+        if cached is not None and cached[0] == self.base_ts:
+            return cached[1]
+        srcs, offs, flat = self._csr(False)
+        order = np.argsort(flat, kind="stable")
+        dsts, starts = np.unique(flat[order], return_index=True)
+        csr = (dsts, np.append(starts, len(flat)).astype(np.int64),
+               np.repeat(srcs, np.diff(offs))[order])
+        self._csr_in_cache = (self.base_ts, csr)
+        return csr
+
+    def expand_in(self, frontier: np.ndarray, read_ts: int) -> np.ndarray:
+        """expand_frontier AGAINST the edges: the sorted uids that have
+        an edge into `frontier` at read_ts. With `@reverse` that is the
+        reverse rows' expansion; without, the base edges' transpose
+        (_csr_in), and for the uids whose rows the overlay touches
+        their rows as they read now."""
+        if self.schema.reverse:
+            return self.expand_frontier(frontier, read_ts, True)
+        reach = np.unique(_csr_rows(self._csr_in(), frontier))
+        if self.deltas:
+            touched = self.overlay_srcs(read_ts)
+            reach = np.union1d(
+                np.setdiff1d(reach, np.fromiter(
+                    touched, np.uint64, len(touched)), assume_unique=True),
+                np.asarray(sorted(
+                    u for u in touched if len(np.intersect1d(
+                        self.get_dst_uids(u, read_ts), frontier,
+                        assume_unique=True))), np.uint64))
+        return reach
 
     def degree_moments(self, reverse: bool = False
                        ) -> tuple[int, int, int]:
@@ -1429,6 +1454,25 @@ class Tablet:
         return out
 
 
+def _csr_rows(csr, frontier: np.ndarray) -> np.ndarray:
+    """The rows of `csr` (Tablet._csr's triple) that `frontier` (sorted
+    uids) names, end to end: what their edges lead to, not yet
+    deduplicated."""
+    srcs, offs, flat = csr
+    idx = np.searchsorted(srcs, frontier)
+    idx[idx == len(srcs)] = 0
+    idx = idx[srcs[idx] == frontier] if len(srcs) else idx[:0]
+    starts = offs[idx]
+    lens = offs[idx + 1] - starts
+    total = int(lens.sum())
+    if not total:
+        return _EMPTY.copy()
+    # row i's edges sit at starts[i] .. starts[i] + lens[i]
+    pos = np.repeat(starts - (np.cumsum(lens) - lens), lens)
+    pos += np.arange(total, dtype=np.int64)
+    return flat[pos]
+
+
 def bfs_levels(expanders, seeds: np.ndarray, depth: int,
                dedup: bool = True):
     """The tree's one host breadth-first search, a level at a time.
@@ -1453,3 +1497,41 @@ def bfs_levels(expanders, seeds: np.ndarray, depth: int,
             visited = np.union1d(visited, nxt)
         yield reaches, nxt
         frontier = nxt
+
+
+def least_path(tablet, src: int, dst: int, depth: int, read_ts: int,
+               reverse: bool = False) -> list[int]:
+    """THE path `shortest(from: src, to: dst, depth: depth)` over one
+    uid predicate returns, unweighted, one path (docs/deployment.md,
+    "shortest"): a path of the fewest hops from `src` to `dst` along
+    the predicate's direction (`reverse`: against it, `~pred`) if one
+    of at most `depth` hops exists, else []; among the paths of that
+    length the one whose uid sequence read from `src` is
+    lexicographically least, which is: from `src`, at every hop the
+    smallest-uid out-neighbour whose distance to `dst` is one less.
+    `src == dst` is the one-vertex path. The host tier's form of what
+    ops/bitgraph.bfs_paths does on the device: a search from `dst`
+    AGAINST the edges (bfs_levels), a level at a time until `src` is
+    met, which gives every vertex met its distance to `dst`; then the
+    walk from `src`, a level nearer a hop."""
+    if src == dst:
+        return [src]
+
+    def ins(frontier):
+        return tablet.expand_frontier(frontier, read_ts) if reverse \
+            else tablet.expand_in(frontier, read_ts)
+
+    levels = [np.asarray([dst], np.uint64)]
+    for _, nxt in bfs_levels([ins], levels[0], depth):
+        at = int(np.searchsorted(nxt, src))
+        if at < len(nxt) and int(nxt[at]) == src:
+            break
+        levels.append(nxt)
+    else:
+        return []
+    get = tablet.get_reverse_uids if reverse else tablet.get_dst_uids
+    path = [src]
+    for level in levels[::-1]:
+        nbs = get(path[-1], read_ts)
+        path.append(int(nbs[np.isin(nbs, level, assume_unique=True)][0]))
+    return path

@@ -58,6 +58,7 @@ REGISTERED = (
     "device_bitadj_bytes",
     "device_bitadj_chip_bytes",
     "device_bitadj_edges",
+    "device_bitadj_hub_rows",
     "device_bitadj_shards",
     # query/devicecall.py; NOT `query_device_*`: readers sum that
     # prefix as a count of dispatches. A block's phases, a rider's
@@ -125,8 +126,8 @@ REGISTERED = (
     "query_device_setops_total",
     "query_device_similar_sharded_total",
     "query_device_similar_total",
+    "query_device_shortest_total",
     "query_device_sort_page_total",
-    "query_device_sssp_total",
     "query_flat_json_total",
     "query_groupby_fast_total",
     "query_index_csr_probe_total",
@@ -156,6 +157,22 @@ REGISTERED = (
     "rendezvous_ahead_total",
     "rendezvous_chained_total",
     "rendezvous_ns_total",
+    # query/executor.py _run_shortest: the span's time, and which tier
+    # a shortest-path block took; _launch_paths: the device calls the
+    # `shortest` rendezvous dispatched and the pairs they carried;
+    # _land_paths: the bytes of the calls' results (all that leaves
+    # the device), the levels their loops ran, the tiles of hub rows
+    # those streamed and a stream of every row would have, and the
+    # first levels read from the targets' columns
+    "shortest_calls_total",
+    "shortest_column_levels_total",
+    "shortest_fetch_bytes_total",
+    "shortest_levels_run_total",
+    "shortest_ns_total",
+    "shortest_riders_total",
+    "shortest_rows_streamed_tiles_total",
+    "shortest_rows_tiles_total",
+    "shortest_tier_total",
     "similar_exact_fallback_total",
     "similar_mask_total",
     "similar_masked_total",

@@ -104,6 +104,7 @@ SPAN_NAMES = (
     "rpc.recv",
     "rpc.send",
     "setops",
+    "shortest",
     "similar_to",
     "snapshot.load",
     "sort",

@@ -224,8 +224,7 @@ def test_smoke_asserts_every_reachable_family(smoke):
     assert tagged == {
         smoke.FWD, smoke.REV, smoke.FUSED,
         "query_device_sort_page_total", "query_device_count_page_total",
-        "query_device_multisort_total", "query_device_setops_total",
-        "query_device_sssp_total"}
+        "query_device_multisort_total", "query_device_setops_total"}
     assert not tagged & set(smoke.NOT_ASSERTED)
 
 

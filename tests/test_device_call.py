@@ -32,10 +32,10 @@ DISPATCH_COUNTERS = (
     "query_device_range_total",
     "query_device_recurse_total",
     "query_device_setops_total",
+    "query_device_shortest_total",
     "query_device_similar_sharded_total",
     "query_device_similar_total",
     "query_device_sort_page_total",
-    "query_device_sssp_total",
     "query_fused_dispatch_total",
     "query_sharded_expand_total",
 )

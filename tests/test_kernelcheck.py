@@ -25,7 +25,7 @@ def db():
     return db
 
 
-CHECKS = ["bfs_digest_xla", "bfs_traverse", "fused_rank_page",
+CHECKS = ["bfs_digest_xla", "bfs_paths", "bfs_traverse", "fused_rank_page",
           "knn_exact", "range_select", "setops_cosort", "sssp_dist"]
 
 
@@ -45,7 +45,8 @@ def test_refusal_is_recorded_not_raised(db, monkeypatch):
     def refuse(*_a):
         raise RuntimeError("Mosaic: not implemented")
 
-    for fn in ("check_bfs_digest", "check_bfs_traverse", "check_sssp_dist",
+    for fn in ("check_bfs_digest", "check_bfs_traverse", "check_bfs_paths",
+               "check_sssp_dist",
                "check_range_select", "check_fused_rank_page",
                "check_knn_exact"):
         monkeypatch.setattr(kernelcheck, fn, lambda *_a: {"ok": True})

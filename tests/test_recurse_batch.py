@@ -1546,8 +1546,10 @@ def test_the_ahead_share_is_a_metric_of_both_khop_cells_and_no_other():
                       "graph500-khop-x4.khop-deep-c16"]}
     # appended, nothing moved: behind it only what later PRs appended
     names = [m["name"] for m in bench["per_layer"]]
-    assert names[names.index("rendezvous_ahead_share"):] == [
+    assert names[names.index("rendezvous_ahead_share"):][:2] == [
         "rendezvous_ahead_share", "bfs_column_levels_per_call"]
+    # (PR 45's eight, of the other family's rendezvous and cell)
+    assert all(n.startswith("shortest_") for n in names[41:])
     # the cells that send a bound @recurse to a device tier, all of them
     recursing = [w["name"] for w in bench["workloads"]
                  if w["config"].startswith("graph500-khop")]
@@ -1599,7 +1601,7 @@ def test_the_column_levels_are_a_metric_of_both_khop_cells_and_no_other():
         "layer": "kernels", "moves": "ok_qps",
         "workloads": ["graph500-khop.khop-deep-c16",
                       "graph500-khop-x4.khop-deep-c16"]}
-    assert bench["per_layer"][-1] is entry     # appended, nothing moved
+    assert bench["per_layer"][40] is entry     # appended (PR 43), nothing moved
     recursing = [w["name"] for w in bench["workloads"]
                  if w["config"].startswith("graph500-khop")]
     assert entry["workloads"] == recursing
@@ -1724,7 +1726,8 @@ def test_requests_in_flight_share_calls_and_keep_their_own_accounts(
     while not calls:
         time.sleep(0.001)
     more, threads2 = _serve(dev, queries[1:])
-    meet = Rendezvous.at(dev.tablets["link"]._device_badj, bitgraph.LANES)
+    meet = Rendezvous.at(dev.tablets["link"]._device_badj, bitgraph.LANES,
+                         family="recurse")
     # the first LANES of them are a full call, on the device behind the
     # one in flight already; the others stand
     _standing(meet, n - 1 - bitgraph.LANES)
@@ -1776,7 +1779,8 @@ def test_the_wait_for_the_batch_is_not_host_time(worlds, monkeypatch):
     while not calls:
         time.sleep(0.001)
     more, threads2 = _serve(dev, q[1:])
-    meet = Rendezvous.at(dev.tablets["link"]._device_badj, bitgraph.LANES)
+    meet = Rendezvous.at(dev.tablets["link"]._device_badj, bitgraph.LANES,
+                         family="recurse")
     _standing(meet, 2)
     time.sleep(0.2)
     gate.set()
@@ -1804,7 +1808,8 @@ def test_a_request_past_its_deadline_leaves_and_the_others_answer(
     while not calls:
         time.sleep(0.001)
     more, threads2 = _serve(dev, q[2:])
-    meet = Rendezvous.at(dev.tablets["link"]._device_badj, bitgraph.LANES)
+    meet = Rendezvous.at(dev.tablets["link"]._device_badj, bitgraph.LANES,
+                         family="recurse")
     _standing(meet, 2)
     late, threads3 = _serve(
         dev, q[1:2], ctxs={0: RequestContext.with_timeout(0.05)})
@@ -1840,7 +1845,8 @@ def test_a_dispatch_that_raises_releases_every_member(worlds, monkeypatch):
     while not calls:
         time.sleep(0.001)
     more, threads2 = _serve(dev, q[1:])
-    meet = Rendezvous.at(dev.tablets["link"]._device_badj, bitgraph.LANES)
+    meet = Rendezvous.at(dev.tablets["link"]._device_badj, bitgraph.LANES,
+                         family="recurse")
     _standing(meet, 3)
     before = _counter("query_device_recurse_total")
     gate.set()

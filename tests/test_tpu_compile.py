@@ -1,7 +1,8 @@
 """The served k-hop traversal compiles for the chip at the width it
-has in the `graph500-khop.khop-deep-c16` cell, and its sharded form
-for four chips at the width of `graph500-khop-x4.khop-deep-c16`: the
-TPU's compiler is
+has in the `graph500-khop.khop-deep-c16` cell, its sharded form for
+four chips at the width of `graph500-khop-x4.khop-deep-c16`, and the
+served shortest path's search and walk at the width of
+`pokec-shortest.pairs-c16`: the TPU's compiler is
 installed here and compiles for a v5e that is described, not attached
 (nothing runs, so this says nothing about results or times). It is
 what interpret mode cannot show: whether Mosaic takes the hub rows'
@@ -34,6 +35,14 @@ GATHERED4 = ((145_952, 1), (73_625, 2), (44_967, 3), (30_705, 4),
              (46_064, 6), (36_127, 8), (30_177, 12), (11_542, 16),
              (47_013, 24))
 CHIPS = 4
+
+# the cell `pokec-shortest.pairs-c16` at scale 10 (the generator's seed
+# 3700004501, counted on the CPU): vertices and vertices with an
+# out-edge (the TRANSPOSED tile's rows), hub rows (the classes from 5
+# out-edges up, 2.13 GB of the 2 GiB budget) and the four gathered
+# classes' (rows, out-edges)
+NP, COVEREDP, ROWSP = 157_022, 152_787, 106_688
+GATHEREDP = ((12_815, 1), (12_346, 2), (11_194, 3), (9_744, 4))
 
 
 @pytest.fixture(scope="module")
@@ -119,6 +128,37 @@ def test_the_whole_traversal_compiles_with_the_kernel_in_it(
     # the rows are an argument; what a call adds is lane state and the
     # kernel's per-row words, far under a chip's 16 GB
     assert mem.argument_size_in_bytes >= 4 * ROWS * WORDS
+    assert mem.temp_size_in_bytes < 256 << 20
+
+
+def test_the_path_search_compiles_with_its_walk_at_the_pokec_cells_width(
+        one_chip, monkeypatch):
+    """`bfs_paths` as a call of `pokec-shortest.pairs-c16` reaches it:
+    eight pairs, depth 15, the transposed tile of a tenth of Pokec:
+    the k-hop loop's two kernels (the first level reads the eight
+    targets' columns) with a level a lane and slot kept, then the
+    walk's loop; what a call adds to the rows is 32 B a vertex of
+    levels and lane state."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    lanes, words = bitgraph.LANES, bitgraph.hub_row_words(NP)
+    assert sum(m for m, _ in GATHEREDP) + ROWSP == COVEREDP
+    assert 4 * ROWSP * words <= 2 << 30           # the tile budget
+    compiled = bitgraph.bfs_paths.lower(
+        [_shape(one_chip, g, jnp.int32) for g in GATHEREDP],
+        _shape(one_chip, (ROWSP, words), jnp.uint32),
+        _shape(one_chip, (NP,), jnp.uint32),
+        _shape(one_chip, (2 * 8 + 2 * lanes,), jnp.int32),
+        n_slots=NP, n_covered=COVEREDP, lanes=lanes,
+        width=bitgraph.path_width(15, NP)).compile()
+    assert bitgraph.path_width(15, NP) == 16
+    assert bitgraph.columns_cheaper(
+        8, ROWSP, words, sum(m * d for m, d in GATHEREDP))
+    text = compiled.as_text()
+    assert " conditional(" in text
+    assert text.count("tpu_custom_call") == 2
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes >= 4 * ROWSP * words
+    print(f"bfs_paths temp bytes: {mem.temp_size_in_bytes}")
     assert mem.temp_size_in_bytes < 256 << 20
 
 
