@@ -82,35 +82,52 @@ def device_adjacency(db, tab, read_ts: int,
     return adj
 
 
-def device_vector_block(db, tab, base_vecs: np.ndarray):
+class VectorBlock:
+    """The resident base block of a vector tablet: `rows` (float32,
+    zero-padded to the bucket unit) and `live` (bool over the padded
+    rows, all True: the mask operand of a lane of ops/knn's program
+    that has none of its own) on the device, and `nbytes`, both
+    together. An object and not the bare array, so that the requests
+    whose read_ts resolved to THIS block can meet on it
+    (query/devicecall.Rendezvous.at)."""
+
+    def __init__(self, rows, live):
+        self.rows = rows
+        self.live = live
+        self.nbytes = int(rows.nbytes) + int(live.nbytes)   # tile_cache
+
+
+def device_vector_block(db, tab, base_vecs: np.ndarray) -> VectorBlock:
     """The tablet's base vector block (storage/vecstore.py) on the
     device: a tile like the adjacency tiles, cached per base_ts,
     counted in `device_cache_bytes` under the HBM budget and evictable
     (a tile larger than the budget is admitted alone, tile_cache.py).
     Rows are zero-padded to the bucket unit ONCE here, host-side, so
-    ops/knn.topk_device never copies the block per query. The gauge
-    `device_vector_block_bytes{predicate}` is what is resident: dtype
-    x padded shape, 0 after an eviction."""
+    ops/knn never copies the block per query. The gauge
+    `device_vector_block_bytes{predicate}` is the rows resident (what
+    a scan reads): dtype x padded shape, 0 after an eviction."""
     from dgraph_tpu.ops.knn import pad_rows
 
-    arr = getattr(tab, "_device_vecs", None)
-    if arr is not None and getattr(tab, "_device_vecs_ts", -1) \
+    block = getattr(tab, "_device_vecs", None)
+    if block is not None and getattr(tab, "_device_vecs_ts", -1) \
             == tab.base_ts:
         db.device_cache.touch(tab, "_device_vecs")
-        return arr
+        return block
     with _tile_load(pred=tab.pred, kind="vecs", rows=len(base_vecs)):
-        arr = jax.block_until_ready(
-            jax.numpy.asarray(pad_rows(base_vecs)))
-    tab._device_vecs = arr
+        rows = pad_rows(base_vecs)
+        block = VectorBlock(*jax.block_until_ready(
+            (jax.numpy.asarray(rows),
+             jax.device_put(np.ones(len(rows), bool)))))
+    tab._device_vecs = block
     tab._device_vecs_ts = tab.base_ts
     labels = {"predicate": tab.pred}
     db.device_cache.put(
-        tab, "_device_vecs", arr,
+        tab, "_device_vecs", block,
         on_evict=lambda: set_gauge("device_vector_block_bytes", 0.0,
                                    labels=labels))
-    set_gauge("device_vector_block_bytes", float(arr.nbytes),
+    set_gauge("device_vector_block_bytes", float(block.rows.nbytes),
               labels=labels)
-    return arr
+    return block
 
 
 _MASK_ATTR = "_device_mask@"

@@ -31,6 +31,15 @@ Three tiers, matching the repo's conventions:
   sharded — corpus rows sharded over a mesh axis via shard_map
             (parallel/dist_knn.py), per-shard top-k then a k-way merge.
 
+The device tier is ONE program of LANES query rows, a mask and a k
+a lane (`launch_lanes` / `land_lanes`): the `similar_to` calls in
+flight over one resident block ride one call of it
+(query/devicecall.Rendezvous, family `similar`), and one small array
+leaves the device for all of them. The static k is the call's
+largest; a lane's answer is the first k_lane of it, proved at its
+own k. 1..LANES riders run one compiled shape, so a lane's bits are
+the same alone and in company.
+
 Approximation is another index, asked for in the schema
 (`@index(vector(ivf))`, ops/ivf.py); `@index(vector)` never
 approximates, whatever the predicate's size.
@@ -209,11 +218,13 @@ def _score_device(corpus, queries, metric: str):
     raise ValueError(f"unknown metric {metric!r}")
 
 
-def _two_stage_topk_dev(scores, k: int, l_per_bucket: int):
-    """Bucketed top-k on device, proved exact. scores is (q, n_pad)
-    with -inf in padded/masked columns; -> (vals, idx, proved): the k
-    best of the bucket candidates by (-score, row), and whether they
-    ARE the top-k of the whole row, for every query of the batch.
+def _two_stage_topk_dev(scores, ks, k: int, l_per_bucket: int):
+    """Bucketed top-k on device, proved exact a lane. scores is
+    (q, n_pad) with -inf in padded/masked columns, ks int32[q] the
+    lanes' own k (each at most the static `k`, the call's largest);
+    -> (vals, idx, proved[q]): the k best of the bucket candidates by
+    (-score, row), and for every lane whether the first ks[lane] of
+    them ARE the top-ks[lane] of its whole row.
 
     Bucket j holds rows j, j + nb, j + 2 nb, ...: a reshape, no
     gather, and rows ingested under consecutive uids (near-duplicate
@@ -240,49 +251,67 @@ def _two_stage_topk_dev(scores, k: int, l_per_bucket: int):
         (-jnp.concatenate(cand_vals, axis=1),
          jnp.concatenate(cand_idx, axis=1)), dimension=1, num_keys=2)
     vals, idx = -neg[:, :k], idx[:, :k]
-    # the proof: the k-th result is a live row (finite, so all k are:
-    # k distinct rows, each with its own score) and exactly k rows of
-    # the whole row rank at or before it in (-score, row) order. With
-    # fewer than k live rows the k-th value is -inf (an exhausted
-    # bucket emits (-inf, lane), lane live or not) and nothing is
-    # proved: the full row answers
-    v_k, i_k = vals[:, -1:], idx[:, -1:]
+    # the proof, a lane at its OWN k (a category of 50 rows asked for
+    # 10 is not failed by a k of 100 riding along): the lane's k-th
+    # result is a live row (finite, so all k are: k distinct rows,
+    # each with its own score) and exactly k rows of the whole row
+    # rank at or before it in (-score, row) order. With fewer than k
+    # live rows the k-th value is -inf (an exhausted bucket emits
+    # (-inf, lane), lane live or not) and nothing is proved: the full
+    # row answers. A dead lane (k 0) asks nothing and is proved
+    kth = (jnp.maximum(ks, 1) - 1)[:, None]
+    v_k = jnp.take_along_axis(vals, kth, axis=1)
+    i_k = jnp.take_along_axis(idx, kth, axis=1)
     col = jnp.arange(n_pad, dtype=jnp.int32)[None, :]
     ahead = jnp.sum((scores > v_k) | ((scores == v_k) & (col <= i_k)),
                     axis=1)
-    return vals, idx, jnp.all((ahead == k) & jnp.isfinite(v_k[:, 0]))
+    return vals, idx, (ks == 0) | (
+        (ahead == ks) & jnp.isfinite(v_k[:, 0]))
 
 
 @partial(jax.jit,
          static_argnames=("k", "metric", "two_stage", "l_per_bucket",
                           "n_real"))
-def _topk_device_jit(corpus, queries, mask, k, metric, two_stage,
+def _topk_device_jit(corpus, queries, masks, ks, k, metric, two_stage,
                      l_per_bucket, n_real):
-    """-> (vals, idx, fell_back): the exact top-k by (-score, row)
-    (lax.top_k keeps the lower index first among equal scores), and
-    whether a failed two-stage proof sent the batch to the full row."""
+    """The lanes program: a query row, a mask (bool over the padded
+    rows, True = the row may answer) and a k (`ks`, dynamic, at most
+    the static `k`) a lane -> int32[lanes, 2 k + 1], a lane's row its
+    exact top-k by (-score, row) (lax.top_k keeps the lower index
+    first among equal scores): k row indices, the k scores' bits,
+    and whether a live lane's failed two-stage proof sent the call to
+    the full row. A lane's answer is the first ks[lane] of its k; its
+    bits depend on nothing but its own row, mask and k."""
     import jax.numpy as jnp
 
     scores = _score_device(corpus, queries, metric)
     n_pad = scores.shape[1]
-    col = jnp.arange(n_pad)
-    invalid = col[None, :] >= n_real
-    if mask is not None:
-        invalid = invalid | ~mask[None, :]
-    scores = jnp.where(invalid, -jnp.inf, scores)
+    live = jnp.stack(masks) & (jnp.arange(n_pad) < n_real)[None, :]
+    scores = jnp.where(live, scores, -jnp.inf)
     k = min(k, n_pad)
     if not two_stage:
         vals, idx = jax.lax.top_k(scores, k)
-        return vals, idx, jnp.bool_(False)
-    vals, idx, proved = _two_stage_topk_dev(scores, k, l_per_bucket)
-    vals, idx = jax.lax.cond(
-        proved, lambda: (vals, idx),
-        lambda: tuple(jax.lax.top_k(scores, k)))
-    return vals, idx, ~proved
+        fell_back = jnp.bool_(False)
+    else:
+        vals, idx, proved = _two_stage_topk_dev(scores, ks, k,
+                                                l_per_bucket)
+        fell_back = ~jnp.all(proved)
+        vals, idx = jax.lax.cond(
+            fell_back, lambda: tuple(jax.lax.top_k(scores, k)),
+            lambda: (vals, idx))
+    # ONE small array leaves the device for the call's riders
+    return jnp.concatenate(
+        [idx.astype(jnp.int32),
+         jax.lax.bitcast_convert_type(vals, jnp.int32),
+         jnp.broadcast_to(fell_back.astype(jnp.int32),
+                          (len(queries), 1))], axis=1)
 
 
-# what a device profile calls the one program topk_device dispatches
+# what a device profile calls the one program this module dispatches
 DEVICE_PROGRAM = "jit_" + _topk_device_jit.__name__
+# query rows a call of it carries (a constant of the program, as
+# bitgraph.LANES is of the traversal's: not a knob)
+LANES = 8
 
 
 def padded_rows(n: int) -> int:
@@ -319,52 +348,27 @@ def candidate_mask(row_uids: np.ndarray, candidates: np.ndarray,
     return mask
 
 
-def topk_device(corpus_dev, queries: np.ndarray, k: int,
-                metric: str = "cosine",
-                mask=None,
-                two_stage: bool | None = None,
-                l_per_bucket: int | None = None,
-                n_real: int | None = None,
-                sync=None, info: dict | None = None
-                ) -> tuple[np.ndarray, np.ndarray]:
-    """Exact device top-k over a (possibly already device-resident)
-    corpus. Returns host (idx (q, k'), scores (q, k')) ordered by
-    (-score, row) like topk_host — idx into the corpus row axis; rows
-    masked out / padded return -inf scores.
+def _padded_mask(mask, n_pad: int):
+    """A lane's mask as the program's operand: a device array as it
+    is (a resident over the padded rows), a host array over the live
+    or the padded rows as bool over the padded rows."""
+    if isinstance(mask, jax.Array):
+        return mask
+    mask = np.asarray(mask, bool)
+    if len(mask) == n_pad:
+        return mask
+    out = np.zeros(n_pad, bool)
+    out[:len(mask)] = mask
+    return out
 
-    `n_real` marks a corpus whose trailing rows are zero padding
-    (pad_rows): only the first n_real rows are live. Hot-path callers
-    should pre-pad their cached block so no per-query device copy
-    happens here.
 
-    `mask` (bool, True = the row may answer) is None for every live
-    row, a host array over the live or the padded rows (uploaded with
-    the call), or a device array over the padded rows (a resident of
-    engine/device_cache.store_similar_mask: nothing is uploaded).
-    Host or device, it is the same operand to the one compiled program.
-
+def _plan(n: int, n_pad: int, k: int, two_stage: bool | None,
+          l_per_bucket: int | None) -> tuple[bool, int]:
+    """(two_stage, L) of a call whose largest k is `k`:
     two_stage=None takes the proved two-stage reduce where
     plan_two_stage finds an L for it and lax.top_k over the full row
     otherwise; two_stage=True with an explicit l_per_bucket forces the
-    reduce at that L (a test's way to a failing proof). `sync` is
-    applied to the dispatched result before it is fetched
-    (query/devicecall.py's `dc.wait`); `info` receives
-    `exact_fallback` (a two-stage proof failed and the full row was
-    searched as well)."""
-    import jax.numpy as jnp
-
-    corpus_dev = jnp.asarray(corpus_dev, jnp.float32)
-    n_rows, d = corpus_dev.shape
-    n = n_rows if n_real is None else int(n_real)
-    # host arrays ride the jitted call: no upload (and no program) of
-    # their own, each one more turn in the interpreter
-    q = np.atleast_2d(np.asarray(queries, np.float32))
-    # pad the n axis so buckets tile exactly; padding scores are
-    # forced to -inf via n_real
-    n_pad = padded_rows(n_rows)
-    if n_pad != n_rows:
-        corpus_dev = jnp.concatenate(
-            [corpus_dev, jnp.zeros((n_pad - n_rows, d), jnp.float32)])
+    reduce at that L (a test's way to a failing proof)."""
     plan = plan_two_stage(n, k)
     forced = bool(two_stage) and l_per_bucket is not None \
         and k <= (n_pad // BUCKET_SIZE) * l_per_bucket
@@ -374,22 +378,102 @@ def topk_device(corpus_dev, queries: np.ndarray, k: int,
         two_stage = False  # too few buckets for this k: the full row
     if l_per_bucket is None:
         l_per_bucket = max(plan, 1)
-    if mask is not None and not isinstance(mask, jax.Array):
-        mask = np.asarray(mask, bool)
-        if len(mask) != n_pad:
-            mask_pad = np.zeros(n_pad, bool)
-            mask_pad[:n] = mask
-            mask = mask_pad
-    out = _topk_device_jit(
-        corpus_dev, q, mask, int(k), str(metric), bool(two_stage),
-        int(l_per_bucket), int(n))
-    if sync is not None:
-        out = sync(out)
-    # one fetch of the three results, not three
-    vals, idx, fell_back = jax.device_get(out)
+    return bool(two_stage), int(l_per_bucket)
+
+
+def launch_lanes(block, live, lanes: list, metric: str, n_real: int,
+                 two_stage: bool | None = None,
+                 l_per_bucket: int | None = None):
+    """ONE call of the lanes program over `block` (float32, on the
+    device, rows padded by pad_rows; the first `n_real` are live) for
+    `lanes`, 1 to LANES of (query vector, k, mask), not waited for;
+    -> the call's one result, its copy to the host begun. A lane's
+    mask is None for every live row (it takes `live`, the block's
+    all-True resident), a host array over the live or the padded rows
+    (uploaded with the call), or a device array over the padded rows
+    (a resident of engine/device_cache.store_similar_mask: nothing is
+    uploaded): host or device, the same operand. Empty lanes are dead
+    (k 0), so 1..LANES riders run ONE compiled shape a largest k, and
+    a query is scored by the same program alone and in company."""
+    if not 0 < len(lanes) <= LANES:
+        raise ValueError(f"{len(lanes)} riders for {LANES} lanes")
+    n_pad, d = block.shape
+    q = np.zeros((LANES, d), np.float32)
+    ks = np.zeros(LANES, np.int32)
+    masks = [live] * LANES
+    for i, (qvec, k, mask) in enumerate(lanes):
+        q[i], ks[i] = qvec, min(int(k), n_pad)
+        if mask is not None:
+            masks[i] = _padded_mask(mask, n_pad)
+    k = int(ks.max())
+    two_stage, l_per_bucket = _plan(int(n_real), n_pad, k, two_stage,
+                                    l_per_bucket)
+    # host arrays ride the jitted call: no upload (and no program) of
+    # their own, each one more turn in the interpreter
+    out = _topk_device_jit(block, q, tuple(masks), ks, k, str(metric),
+                           two_stage, l_per_bucket, int(n_real))
+    # the one small result starts for the host as soon as the device
+    # has it, not a round trip after somebody asks
+    out.copy_to_host_async()
+    return out
+
+
+def land_lanes(handle) -> tuple[np.ndarray, np.ndarray, bool]:
+    """launch_lanes' result, once the device has it: (idx int64
+    [LANES, k], scores float32 [LANES, k], fell_back) for the call's
+    largest k; lane i's answer is the first k_i of row i (rows masked
+    out / padded carry -inf scores). ONE transfer."""
+    out = np.asarray(handle)
+    k = (out.shape[1] - 1) // 2
+    return (out[:, :k].astype(np.int64),
+            out[:, k:2 * k].view(np.float32), bool(out[0, -1]))
+
+
+def topk_device(corpus_dev, queries: np.ndarray, k: int,
+                metric: str = "cosine",
+                mask=None,
+                two_stage: bool | None = None,
+                l_per_bucket: int | None = None,
+                n_real: int | None = None,
+                info: dict | None = None
+                ) -> tuple[np.ndarray, np.ndarray]:
+    """Exact device top-k over a (possibly already device-resident)
+    corpus, the one-shot entry over the lanes program: the query
+    matrix goes LANES rows a call. Returns host (idx (q, k'),
+    scores (q, k')) ordered by (-score, row) like topk_host — idx
+    into the corpus row axis; rows masked out / padded return -inf
+    scores.
+
+    `n_real` marks a corpus whose trailing rows are zero padding
+    (pad_rows): only the first n_real rows are live. `mask` (bool,
+    True = the row may answer; one for every query), `two_stage` and
+    `l_per_bucket` as launch_lanes takes them; `info` receives
+    `exact_fallback` (a two-stage proof failed and the full row was
+    searched as well)."""
+    import jax.numpy as jnp
+
+    corpus_dev = jnp.asarray(corpus_dev, jnp.float32)
+    n_rows, d = corpus_dev.shape
+    n = n_rows if n_real is None else int(n_real)
+    q = np.atleast_2d(np.asarray(queries, np.float32))
+    # pad the n axis so buckets tile exactly; padding scores are
+    # forced to -inf via n_real
+    n_pad = padded_rows(n_rows)
+    if n_pad != n_rows:
+        corpus_dev = jnp.concatenate(
+            [corpus_dev, jnp.zeros((n_pad - n_rows, d), jnp.float32)])
+    # one upload for all the calls: every lane takes the one mask,
+    # as a lane without one takes the block's all-live resident
+    mask = jax.device_put(_padded_mask(
+        np.ones(n_pad, bool) if mask is None else mask, n_pad))
+    calls = [launch_lanes(corpus_dev, mask,
+                          [(row, k, None) for row in q[lo:lo + LANES]],
+                          metric, n, two_stage, l_per_bucket)
+             for lo in range(0, len(q), LANES)]
+    idx, vals, fell_back = zip(*(land_lanes(c) for c in calls))
     if info is not None:
-        info["exact_fallback"] = bool(fell_back)
-    return idx.astype(np.int64), vals
+        info["exact_fallback"] = any(fell_back)
+    return np.concatenate(idx)[:len(q)], np.concatenate(vals)[:len(q)]
 
 
 # ---------------------------------------------------------------------------

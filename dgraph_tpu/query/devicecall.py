@@ -54,14 +54,19 @@ Callees that dispatch and fetch in one function (`expand_np`,
 `setops.union_many_device`, `bitgraph.sssp_dist`) take `sync=dc.wait`:
 a function applied to the dispatched result before it is fetched.
 
-Where the requests in flight can share ONE call of a program (the
-k-hop traversal's lanes), each still runs its own block and meets the
-others at a `Rendezvous`: its `wait` is then `dc.wait_for(...)`, from
-joining until its call's result is in, queueing behind the call in
-flight included, exactly as queueing behind other requests' programs
-is above. The call itself is spanned once, by the thread that lands
-it: `device.flight`, with the phases of the turn-round between two
-calls (`Rendezvous`).
+Where the requests in flight can share ONE call of a program, each
+still runs its own block and meets the others at a `Rendezvous`: its
+`wait` is then `dc.wait_for(...)`, from joining until its call's
+result is in, queueing behind the call in flight included, exactly as
+queueing behind other requests' programs is above. Three families do:
+the k-hop traversal's lanes (`recurse`), the one-path `shortest`'s
+pairs (`shortest`) and the exact `similar_to`'s query rows over a
+resident vector block (`similar`, one rendezvous a metric). The call
+itself is spanned once, by the thread that lands it: `device.flight`,
+with the phases of the turn-round between two calls (`Rendezvous`),
+and counted once, for every family alike:
+`rendezvous_calls_total{family}` and `rendezvous_riders_total{family}`
+(riders a call is their ratio).
 """
 
 from __future__ import annotations
@@ -305,7 +310,9 @@ class Rendezvous:
     rendezvous. And one a FAMILY: the k-hop traversals (`recurse`)
     and the shortest paths (`shortest`) over one tile are two
     programs, so each family has its own calls, queue and counters,
-    and a rider never boards the other's.
+    and a rider never boards the other's. (And one a `key` within a
+    family where its calls cannot share a program: the vector scan's
+    metric.)
 
     The thread that lands a call spans it: one `device.flight` span a
     call, with `family`, `lanes`, `ahead` (it was launched behind a
@@ -328,7 +335,9 @@ class Rendezvous:
     successor at their landing, launched already or by the landing
     thread: the ones that have a turn-round.
     `rendezvous_ahead_total{family}` counts the calls launched behind
-    one still in flight."""
+    one still in flight, `rendezvous_calls_total{family}` every call
+    launched and `rendezvous_riders_total{family}` the riders they
+    carried."""
 
     _POLL_S = 0.05      # how often a waiter looks at its context
     _make = threading.Lock()
@@ -343,10 +352,14 @@ class Rendezvous:
         self._waiting: list[Ride] = []
 
     @classmethod
-    def at(cls, tile, capacity: int, family: str = "") -> "Rendezvous":
+    def at(cls, tile, capacity: int, family: str = "",
+           key: str = "") -> "Rendezvous":
         """The tile's own rendezvous of `family`, made on first
-        asking; `family` labels its flights' span and counters."""
-        attr = "_rendezvous_" + family
+        asking; `family` labels its flights' span and counters. `key`
+        tells apart the calls of one family that cannot share a
+        program (the vector scan's metric): one rendezvous each."""
+        attr = f"_rendezvous_{family}_{key}" if key \
+            else "_rendezvous_" + family
         meet = getattr(tile, attr, None)
         if meet is None:
             with cls._make:
@@ -406,10 +419,14 @@ class Rendezvous:
                 self._cond.notify_all()
             return e
         _dispatched(+1)
+        labels = {"family": self.family}
+        inc_counter("rendezvous_calls_total", labels=labels)
+        inc_counter("rendezvous_riders_total", len(flight.riders),
+                    labels=labels)
         # the series is there at 0: a reader tells "none went ahead"
         # from "not served"
         inc_counter("rendezvous_ahead_total", int(flight.ahead),
-                    labels={"family": self.family})
+                    labels=labels)
         flight.launched = True
         return None
 

@@ -359,6 +359,31 @@ def _land_paths(handle, n: int) -> list:
     return [(out[i], (levels, streamed, full, columns)) for i in range(n)]
 
 
+def _launch_similar(block, metric: str, n_real: int, riders: list):
+    """A Rendezvous' `launch` for the exact `similar_to`: ONE call of
+    ops/knn's lanes program over the resident `block` for `riders`
+    ([(query vector, k, mask)], a lane each; they share the block,
+    hence its live rows, and the metric), not waited for.
+    `rendezvous_calls_total{family="similar"}` counts the calls,
+    `rendezvous_riders_total` the queries they carried."""
+    from dgraph_tpu.ops import knn
+    return knn.launch_lanes(block.rows, block.live, riders, metric,
+                            n_real)
+
+
+def _land_similar(handle, n: int) -> list:
+    """A Rendezvous' `land`: every rider's (own row's indices, scores,
+    whether the call fell back), once the call has run. One small
+    array leaves the device for all of them.
+    `similar_exact_fallback_total` counts the CALLS a live lane's
+    failed proof sent to the full row."""
+    from dgraph_tpu.ops import knn
+    idx, scores, fell_back = knn.land_lanes(handle)
+    if fell_back:
+        inc_counter("similar_exact_fallback_total")
+    return [(idx[i], scores[i], fell_back) for i in range(n)]
+
+
 def _var_domain(vmap) -> np.ndarray:
     """The sorted uid set a value var is defined on — columnar vars
     answer from their uid array without materializing Vals."""
@@ -1592,21 +1617,33 @@ class Executor:
                 if mask is not None:
                     # a mask went up, with the call or as its tile
                     inc_counter("similar_masked_total")
-                info: dict = {}
+                # the calls in flight over this block ride one call
+                # of the program (devicecall.Rendezvous): this
+                # request's block is its own all the same, its wait
+                # the time until its call's result
                 with device_call("query_device_similar_total",
                                  sink=self.lat,
                                  program=_knn.DEVICE_PROGRAM) as dc:
-                    idx, sc = _knn.topk_device(
-                        block, qm, k, metric, mask=dev_mask, n_real=n,
-                        sync=dc.wait, info=info)
-                if info["exact_fallback"]:
-                    inc_counter("similar_exact_fallback_total")
+                    meet = Rendezvous.at(block, _knn.LANES,
+                                         family="similar", key=metric)
+                    ride = dc.wait_for(
+                        lambda: meet.ride(
+                            (qvec, k, dev_mask),
+                            functools.partial(_launch_similar, block,
+                                              metric, n),
+                            _land_similar, self.ctx),
+                        out_bytes=4 * (2 * min(k, n_pad) + 1))
+                    dc.note(lanes=ride.lanes,
+                            batch_wait_us=ride.waited_ns // 1000)
+                    # the lane's own k of the call's largest
+                    lane_idx, lane_sc, fell_back = ride.result
+                    idx, sc = lane_idx[None, :k], lane_sc[None, :k]
                 vdec["tier"] = "two_stage" \
                     if _knn.plan_two_stage(n, k) > 0 else "exact"
                 if sp is not None:
                     sp["tier"] = "device"
                     sp["n"] = int(n)
-                    sp["exact_fallback"] = int(info["exact_fallback"])
+                    sp["exact_fallback"] = int(fell_back)
             else:
                 idx, sc = _knn.topk_host(view.base_vecs, qm, k,
                                          metric, mask=host_mask())

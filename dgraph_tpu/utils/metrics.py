@@ -65,8 +65,9 @@ REGISTERED = (
     # stand at its rendezvous (inside `wait`, so a series of its own),
     # the calls a plain dispatch found ahead of it on the chip, those
     # on it now; and a Rendezvous' flights: the landing thread's
-    # phases, the calls that had a successor at their landing, and
-    # those launched behind a call still in flight
+    # phases, the calls that had a successor at their landing, those
+    # launched behind a call still in flight, and (every family) the
+    # calls launched and the riders they carried
     "device_call_ahead_total",
     "device_call_ns_total",
     "device_call_queue_ns_total",
@@ -155,8 +156,10 @@ REGISTERED = (
     "recurse_sharded_total",
     "recurse_tier_total",
     "rendezvous_ahead_total",
+    "rendezvous_calls_total",
     "rendezvous_chained_total",
     "rendezvous_ns_total",
+    "rendezvous_riders_total",
     # query/executor.py _run_shortest: the span's time, and which tier
     # a shortest-path block took; _launch_paths: the device calls the
     # `shortest` rendezvous dispatched and the pairs they carried;

@@ -364,8 +364,10 @@ def test_the_vector_block_is_a_counted_and_evictable_tile():
     db.query(q)
     tab = db.tablets["embedding"]
     block = 6016 * 8 * 4        # rows padded to the bucket unit
-    assert tab._device_vecs.nbytes == block
-    assert db.device_cache.stats()["bytes"] >= block
+    # the tile: the rows, and the all-live mask of a lane without one
+    assert tab._device_vecs.rows.nbytes == block
+    assert tab._device_vecs.nbytes == block + 6016
+    assert db.device_cache.stats()["bytes"] >= block + 6016
     assert _gauge("device_cache_bytes") >= block
     gauges = metrics.snapshot()["gauges"]
     assert gauges['device_vector_block_bytes{predicate="embedding"}'] \

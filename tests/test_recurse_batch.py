@@ -1549,7 +1549,9 @@ def test_the_ahead_share_is_a_metric_of_both_khop_cells_and_no_other():
     assert names[names.index("rendezvous_ahead_share"):][:2] == [
         "rendezvous_ahead_share", "bfs_column_levels_per_call"]
     # (PR 45's eight, of the other family's rendezvous and cell)
-    assert all(n.startswith("shortest_") for n in names[41:])
+    assert all(n.startswith("shortest_") for n in names[41:49])
+    # (PR 46's one, of the vector scan's rendezvous and cell)
+    assert names[49] == "similar_lanes_per_call"
     # the cells that send a bound @recurse to a device tier, all of them
     recursing = [w["name"] for w in bench["workloads"]
                  if w["config"].startswith("graph500-khop")]

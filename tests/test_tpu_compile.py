@@ -2,7 +2,8 @@
 has in the `graph500-khop.khop-deep-c16` cell, its sharded form for
 four chips at the width of `graph500-khop-x4.khop-deep-c16`, and the
 served shortest path's search and walk at the width of
-`pokec-shortest.pairs-c16`: the TPU's compiler is
+`pokec-shortest.pairs-c16`, and the vector scan's lanes program at the
+width of `sift1m-exact.knn-mix`: the TPU's compiler is
 installed here and compiles for a v5e that is described, not attached
 (nothing runs, so this says nothing about results or times). It is
 what interpret mode cannot show: whether Mosaic takes the hub rows'
@@ -159,6 +160,32 @@ def test_the_path_search_compiles_with_its_walk_at_the_pokec_cells_width(
     mem = compiled.memory_analysis()
     assert mem.argument_size_in_bytes >= 4 * ROWSP * words
     print(f"bfs_paths temp bytes: {mem.temp_size_in_bytes}")
+    assert mem.temp_size_in_bytes < 256 << 20
+
+
+def test_the_vector_scans_lanes_program_compiles_at_the_knn_cells_width(
+        one_chip):
+    """`jit__topk_device_jit` as a call of `sift1m-exact.knn-mix` with
+    a `knn100` aboard reaches it: eight lanes over the 500,000 x 128
+    block, a mask a lane, largest k 100 (three candidates a bucket);
+    what a call adds to the block is the lanes' score rows."""
+    from dgraph_tpu.ops import knn
+    n, k = 500_000, 100
+    n_pad = knn.padded_rows(n)
+    compiled = knn._topk_device_jit.lower(
+        _shape(one_chip, (n_pad, 128), jnp.float32),
+        _shape(one_chip, (knn.LANES, 128), jnp.float32),
+        tuple(_shape(one_chip, (n_pad,), jnp.bool_)
+              for _ in range(knn.LANES)),
+        _shape(one_chip, (knn.LANES,), jnp.int32),
+        k=k, metric="euclidean", two_stage=True,
+        l_per_bucket=knn.plan_two_stage(n, k), n_real=n).compile()
+    assert knn.plan_two_stage(n, k) == 3
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes >= 4 * n_pad * 128
+    # ONE small array leaves the device: indices, scores' bits, flag
+    assert mem.output_size_in_bytes <= 2 * 4 * knn.LANES * (2 * k + 1)
+    print(f"_topk_device_jit temp bytes: {mem.temp_size_in_bytes}")
     assert mem.temp_size_in_bytes < 256 << 20
 
 
